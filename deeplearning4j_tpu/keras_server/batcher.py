@@ -18,8 +18,12 @@ batch so the device runs a large fused program. The policy:
 
 Padding is semantics-free: rows are independent under inference-mode
 forward (running BN statistics, no dropout), so the sliced-back outputs
-are **bitwise identical** to a per-request dispatch — pinned across bucket
-boundaries by tests/test_serving.py.
+are **bitwise identical** to the same rows dispatched in an unpadded batch
+of the bucket's size, and equal to a per-request dispatch to float32
+rounding — XLA picks its matmul kernel by batch extent, so a row alone and
+the same row in a bucket of 4 may differ in the last ulp (nn/inference.py,
+"The serving equality contract"). Pinned across bucket boundaries by
+tests/test_serving.py.
 
 PR 2/5/7 infrastructure rides on the dispatch loop wholesale: per-batch
 latency histograms and occupancy/queue gauges (``dl4j_serve_*``), a

@@ -55,7 +55,7 @@ win is dispatch amortization (and, on real hardware, HBM read reuse).
 ``mode="static"`` runs the SAME compiled step but only admits when every
 slot has drained — the request-level baseline for the A/B in
 scripts/serve_load.jsonl. Because per-slot math is row-independent (the
-bitwise padding property test_serving.py pins for the MLP path), a
+padding property test_serving.py pins for the MLP path), a
 session's token stream is bitwise identical under either schedule.
 
 Sampling is greedy argmax on device: deterministic, so continuous-vs-

@@ -82,8 +82,8 @@ class HostSyncInHotLoop(Rule):
 
     ``float(loss)``, ``.item()``, ``np.asarray(device_array)``,
     ``block_until_ready()`` and ``jax.device_get`` each block the host on
-    the device stream — behind a network-attached TPU relay that is a full
-    round-trip per call, and it serializes the dispatch pipeline the K-step
+    the device stream: a full round-trip per call, and it serializes the
+    dispatch pipeline the K-step
     and prefetch machinery exist to keep full. The ONE trusted sync point is
     ``LazyScore.score_value`` (cached, listener-driven, measured by
     telemetry); everything else in a hot path must stay device-resident.

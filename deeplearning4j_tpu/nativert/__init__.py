@@ -10,9 +10,12 @@ producer-thread batch loader, a numeric CSV reader, and the binary stats
 codec (SBE-codec equivalent, reference ui-model ui/stats/sbe/*) — consumed
 via ctypes. Device compute stays in XLA; this layer only stages host memory.
 
-The shared library is built on demand with g++ (toolchain is baked into the
-image); every entry point degrades to ``None``/pure-Python when the build is
-unavailable so the framework never hard-requires the native path.
+The shared library is built on demand with g++ from the one input git
+commits (``native/src/dl4j_runtime.cpp``); every entry point degrades to
+``None``/pure-Python when the build is unavailable so the framework never
+hard-requires the native path. A degraded load is not silent:
+:func:`load_error` says why, and ``chip_smoke.py`` fails on it wherever a
+compiler exists.
 """
 from __future__ import annotations
 
@@ -33,6 +36,7 @@ _SRC_PATH = _NATIVE_DIR / "src" / "dl4j_runtime.cpp"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+_load_error: Optional[str] = None
 
 c_i64 = ctypes.c_int64
 c_f32p = ctypes.POINTER(ctypes.c_float)
@@ -42,7 +46,9 @@ c_i64p = ctypes.POINTER(ctypes.c_int64)
 
 
 def _build() -> bool:
+    global _load_error
     if not _SRC_PATH.exists():
+        _load_error = f"{_SRC_PATH} is missing"
         return False
     try:
         subprocess.run(
@@ -51,6 +57,7 @@ def _build() -> bool:
             check=True, capture_output=True, timeout=120)
         return _LIB_PATH.exists()
     except (OSError, subprocess.SubprocessError) as e:
+        _load_error = f"build failed: {e!r}"
         # leave a post-mortem breadcrumb (worker_exit-style): a silent False
         # here used to mean "mysteriously slow Python paths" with no trace
         try:
@@ -151,7 +158,7 @@ def _declare(lib: ctypes.CDLL) -> None:
 def get_runtime() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native runtime; None when unavailable.
     Set DL4J_TPU_DISABLE_NATIVE=1 to force the pure-Python paths."""
-    global _lib, _load_attempted
+    global _lib, _load_attempted, _load_error
     if os.environ.get("DL4J_TPU_DISABLE_NATIVE") == "1":
         return None
     with _lock:
@@ -168,14 +175,23 @@ def get_runtime() -> Optional[ctypes.CDLL]:
             lib = ctypes.CDLL(str(_LIB_PATH))
             _declare(lib)
             if lib.dl4j_runtime_version() != 4:
+                _load_error = (f"{_LIB_PATH} is runtime version "
+                               f"{lib.dl4j_runtime_version()}, expected 4")
                 return None
             _lib = lib
-        except (OSError, AttributeError):
+        except (OSError, AttributeError) as e:
             # AttributeError: a stale older-version .so whose rebuild failed
             # is missing current-version symbols — fall back to pure Python
             # rather than raising out of native_available()
+            _load_error = f"load failed: {e!r}"
             _lib = None
         return _lib
+
+
+def load_error() -> Optional[str]:
+    """Why :func:`get_runtime` came back empty (None while it has not, or
+    when the kill switch asked for the Python paths)."""
+    return _load_error
 
 
 def native_available() -> bool:

@@ -42,10 +42,10 @@ class PairBatch(NamedTuple):
 #: vocab-size ceiling for the dense one-hot-matmul update path (auto mode).
 #: A (rows, V) one-hot times (rows, D) update is exact scatter-add math on
 #: the MXU — but it rewrites the WHOLE V x D table per chunk, so its HBM
-#: traffic is O(V*D) regardless of how few rows changed. Round-5 on-chip
-#: A/B (v5e, scripts/bench_log.jsonl): scatter wins at every measured vocab
-#: — 946k vs 645k pairs/s at V=10k, 1.09M vs 968k at V=2048 — so the dense
-#: path is OFF by default (ceiling 0) and remains an explicit opt-in via
+#: traffic is O(V*D) regardless of how few rows changed. Calibrated
+#: 2026-07-31 on a v5e, record not kept; re-derive in a cell: scatter won at
+#: every measured vocab (V=2048 and V=10k), so the dense path is OFF by
+#: default (ceiling 0) and remains an explicit opt-in via
 #: DL4J_W2V_DENSE=1 for dtypes/shapes where a future chip's scatter unit is
 #: the bottleneck.
 DENSE_UPDATE_MAX_VOCAB = int(os.environ.get("DL4J_W2V_DENSE_MAX_VOCAB", "0"))

@@ -31,12 +31,26 @@ Two halves:
   warmup (ModelRegistry pin, ReplicaSet construction, decode pre-warm)
   builds on.
 
+Placement (:func:`cache_root`): the store is the ``executables``
+subdirectory of JAX's own persistent compilation cache, so one directory
+holds everything a warm start needs. Where ``JAX_COMPILATION_CACHE_DIR`` is
+set, that is the root and JAX is left to read its variable; where it is
+not, both go to ``.jax_cache`` at the root of the checkout — a fixed path,
+because the path is part of what a cache key means (a directory that moves
+never hits). The test suite repoints the variable per test; elastic ships
+the resolved root to spawned workers.
+
+An executable is bound to the devices it was compiled for (on JAX 0.9 a
+serialized executable carries its device assignment and cannot be moved):
+the fingerprint includes the device ids of its inputs' placement, the entry
+records the assignment, and a hit is loaded onto exactly those devices. A
+replica on a second chip therefore compiles once for that chip and
+warm-starts there from then on.
+
 Kill switch: ``DL4J_COMPILE_CACHE=0`` makes ``build_program`` return the
 exact pre-existing ``tracker.wrap(jax.jit(...))`` path — no disk, no AOT.
-``DL4J_COMPILE_CACHE_DIR`` overrides the store location (the test suite
-points it at a per-test tmp dir; elastic ships the resolved dir to spawned
-workers). ``DL4J_COMPILE_CACHE_EPOCH`` salts the fingerprint for manual
-invalidation without deleting files.
+``DL4J_COMPILE_CACHE_EPOCH`` salts the fingerprint for manual invalidation
+without deleting files.
 """
 from __future__ import annotations
 
@@ -47,6 +61,7 @@ import pickle
 import tempfile
 import threading
 import time
+import zlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from deeplearning4j_tpu.observability.compile_tracker import (_signature,
@@ -58,10 +73,13 @@ from deeplearning4j_tpu.observability.names import (
 
 log = logging.getLogger(__name__)
 
-#: on-disk entry format: MAGIC + sha256(body) + body. Bump the magic when
-#: the pickle layout changes — old entries then read as version-mismatched
-#: and are quarantined on first touch.
-MAGIC = b"DL4JXC01"
+#: on-disk entry format: MAGIC + sha256(body) + body, body = zlib(pickle).
+#: Serialized TPU executables compress about threefold (ResNet-50's K-step
+#: train program: 126 MB raw), which is what keeps a chip machine's cache
+#: directory under its size limit. Bump the magic when the layout changes —
+#: old entries then read as version-mismatched and are quarantined on first
+#: touch.
+MAGIC = b"DL4JXC02"
 _DIGEST_LEN = 32
 
 _DEFAULT_MAX_MB = 512.0
@@ -74,13 +92,31 @@ def enabled() -> bool:
         not in ("0", "off", "false")
 
 
+#: home of both persistent caches when JAX_COMPILATION_CACHE_DIR is unset
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def cache_root() -> str:
+    """THE placement helper: root directory of JAX's persistent compilation
+    cache and (in ``executables/``) of this module's store. A set
+    ``JAX_COMPILATION_CACHE_DIR`` is returned as is — JAX reads the variable
+    itself, nothing here repoints it. Unset, JAX's cache is pointed at the
+    checkout's ``.jax_cache`` and that is returned."""
+    root = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if root:
+        return root
+    import jax
+
+    if jax.config.jax_compilation_cache_dir != _CHECKOUT_CACHE:
+        jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+    return _CHECKOUT_CACHE
+
+
 def cache_dir() -> str:
-    """Resolved store directory (not necessarily created yet)."""
-    d = os.environ.get("DL4J_COMPILE_CACHE_DIR")
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "deeplearning4j_tpu", "executables")
+    """Resolved executable-store directory (not necessarily created yet)."""
+    return os.path.join(cache_root(), "executables")
 
 
 def _max_bytes() -> int:
@@ -94,14 +130,28 @@ def _max_bytes() -> int:
 
 def _backend_key() -> Tuple:
     """Everything about the runtime that invalidates an executable: jax
-    version, backend platform, device kind, and visible device count
-    (a parent on an 8-device host mesh and its 1-device elastic child
-    must never share entries)."""
+    version, backend platform, device kind, visible device count (a parent
+    on an 8-device host mesh and its 1-device elastic child must never
+    share entries), and the JAX settings that change what a trace lowers to
+    without changing any argument's shape or dtype."""
     import jax
 
     devs = jax.devices()
     return (jax.__version__, jax.default_backend(),
-            devs[0].device_kind if devs else "none", len(devs))
+            devs[0].device_kind if devs else "none", len(devs),
+            jax.config.jax_default_matmul_precision,
+            jax.config.jax_enable_x64)
+
+
+def _trace_env() -> Tuple:
+    """Every ``DL4J_*`` switch in the environment, the store's own knobs
+    aside. Kernel gates and dtype knobs are read at trace time and baked
+    into the program, so an executable traced under one setting must never
+    be handed to a process running under another (an A/B that flips
+    ``DL4J_TPU_DISABLE_PALLAS`` would otherwise measure one side twice)."""
+    return tuple(sorted(
+        (k, v) for k, v in os.environ.items()
+        if k.startswith("DL4J_") and not k.startswith("DL4J_COMPILE_CACHE")))
 
 
 def conf_fingerprint(conf: Any) -> str:
@@ -123,27 +173,34 @@ def conf_fingerprint(conf: Any) -> str:
 
 
 def _placement_key(args: tuple, kwargs: dict) -> Optional[Tuple]:
-    """Per-leaf input sharding reprs. An AOT ``Compiled`` strictly requires
-    the placements it was built with — where jit would quietly re-dispatch
-    (and recompile) for a resharded input, the cache must resolve a sibling
-    executable. Kept separate from the tracker's shape/dtype ``_signature``
-    so compile-storm accounting granularity is unchanged."""
+    """Per-leaf input placement: sharding repr plus the ids of the devices
+    it spans. An AOT ``Compiled`` strictly requires the placements it was
+    built with — where jit would quietly re-dispatch (and recompile) for a
+    resharded input, the cache must resolve a sibling executable — and it
+    runs only on the devices it was compiled for, so two replicas of one
+    program on two chips are siblings too. Kept separate from the tracker's
+    shape/dtype ``_signature`` so compile-storm accounting granularity is
+    unchanged."""
     try:
         import jax
 
+        default = {jax.devices()[0]}
         leaves, _ = jax.tree_util.tree_flatten((args, kwargs))
+        # this runs on every dispatch: a parameter tree's leaves share a few
+        # sharding objects, each resolved once
+        keys = {id(None): None}
         out = []
         for leaf in leaves:
             if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
                 s = getattr(leaf, "sharding", None)
-                # single-device placement normalizes to None: a host numpy
-                # array and the device array a step handed back are the
-                # same program to jit AND to the strict Compiled check —
-                # only genuinely sharded (mesh) inputs need siblings
-                if s is None or type(s).__name__ == "SingleDeviceSharding":
-                    out.append(None)
-                else:
-                    out.append(repr(s))
+                if id(s) not in keys:
+                    # placement on the default device normalizes to None: a
+                    # host numpy array and the device array a step handed
+                    # back are the same program to jit AND to the strict
+                    # Compiled check
+                    keys[id(s)] = None if s.device_set == default else (
+                        repr(s), tuple(d.id for d in s._device_assignment))
+                out.append(keys[id(s)])
         return tuple(out)
     except Exception:
         return None
@@ -209,8 +266,8 @@ class CompileCache:
 
     # ------------------------------------------------------------- read
     def get(self, fp_hex: str, name: str) -> Optional[tuple]:
-        """-> (payload, in_tree, out_tree) or None. Any validation failure
-        (bad magic, truncation, digest mismatch, unpicklable body)
+        """-> (payload, in_tree, out_tree, meta) or None. Any validation
+        failure (bad magic, truncation, digest mismatch, unpicklable body)
         quarantines the entry and reads as a miss."""
         path = self.entry_path(fp_hex)
         try:
@@ -230,8 +287,7 @@ class CompileCache:
                 why = "digest-mismatch"
             else:
                 try:
-                    payload, in_tree, out_tree, _meta = pickle.loads(body)
-                    return (payload, in_tree, out_tree)
+                    return pickle.loads(zlib.decompress(body))
                 except Exception as e:
                     why = f"unpicklable: {e!r}"
         self.quarantine(fp_hex, name=name, why=why)
@@ -253,7 +309,8 @@ class CompileCache:
     def put(self, fp_hex: str, payload: bytes, in_tree, out_tree,
             meta: dict) -> None:
         try:
-            body = pickle.dumps((payload, in_tree, out_tree, meta))
+            body = zlib.compress(
+                pickle.dumps((payload, in_tree, out_tree, meta)), 1)
             os.makedirs(self.directory, exist_ok=True)
             fd, tmp = tempfile.mkstemp(dir=self.directory, suffix=".tmp")
             try:
@@ -308,7 +365,7 @@ _instances: Dict[str, CompileCache] = {}
 
 def global_cache() -> CompileCache:
     """Store for the currently-resolved directory (env-sensitive: tests
-    repoint ``DL4J_COMPILE_CACHE_DIR`` per test and get a fresh store)."""
+    repoint ``JAX_COMPILATION_CACHE_DIR`` per test and get a fresh store)."""
     d = cache_dir()
     with _instances_lock:
         cache = _instances.get(d)
@@ -378,7 +435,7 @@ class CachedProgram:
         # donation, specs) arrive via ``extra``; ``pk`` keeps differently
         # placed (sharded) callers on sibling entries.
         try:
-            material = repr((MAGIC, _backend_key(),
+            material = repr((MAGIC, _backend_key(), _trace_env(),
                              os.environ.get("DL4J_COMPILE_CACHE_EPOCH", ""),
                              self._fingerprint_name, sig, pk,
                              self._conf_fp, self._extra))
@@ -409,6 +466,9 @@ class CachedProgram:
 
     def _build(self, sig: Tuple, pk: Optional[Tuple], args: tuple,
                kwargs: dict) -> Callable:
+        from deeplearning4j_tpu.ops.pallas_kernels import (
+            recorded_dispatch, replay_dispatch)
+
         tracker = self._tr()
         tracker._ensure_monitoring()
         fp = self._fp_hex(sig, pk)
@@ -421,9 +481,18 @@ class CachedProgram:
             got = store.get(fp, self._name)
             if got is not None:
                 try:
+                    import jax
                     from jax.experimental import serialize_executable as se
 
-                    compiled = se.deserialize_and_load(*got)
+                    # onto the devices it was compiled for — the default,
+                    # every local device, is wrong for all but a program
+                    # that spans the host
+                    payload, in_tree, out_tree, meta = got
+                    by_id = {d.id: d for d in jax.devices()}
+                    compiled = se.deserialize_and_load(
+                        payload, in_tree, out_tree, execution_devices=[
+                            by_id[i] for i in meta["device_ids"]])
+                    replay_dispatch(meta["dispatch"])
                     load_s = time.perf_counter() - t0
                     reg.counter(
                         COMPILE_CACHE_HITS_TOTAL,
@@ -452,7 +521,8 @@ class CachedProgram:
         stack.append(self._name)
         t0 = time.perf_counter()
         try:
-            compiled = self._jitted.lower(*args, **kwargs).compile()
+            with recorded_dispatch() as dispatch_notes:
+                compiled = self._jitted.lower(*args, **kwargs).compile()
         except Exception as e:
             log.debug("AOT compile failed for %s (%r); using plain jit",
                       self._name, e)
@@ -474,7 +544,11 @@ class CachedProgram:
                 payload, in_tree, out_tree = se.serialize(compiled)
                 store.put(fp, payload, in_tree, out_tree,
                           {"fn": self._fingerprint_name,
-                           "wall_s": wall, "shapes": repr(sig[0])})
+                           "wall_s": wall, "shapes": repr(sig[0]),
+                           "dispatch": dispatch_notes,
+                           "device_ids": [
+                               d.id for d in compiled.runtime_executable()
+                               .local_devices()]})
             except Exception as e:
                 log.debug("serialize failed for %s: %r", self._name, e)
         return compiled

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu import jax_compat
 from deeplearning4j_tpu.utils.pytree import flatten_params, unflatten_params
 
 log = logging.getLogger(__name__)
@@ -58,7 +57,7 @@ def check_pretrain_gradients(net, layer_idx: int, x, *, eps: float = 1e-6,
     saved_policy = common.get_policy()
     common.set_policy(jnp.float64, jnp.float64, jnp.float64)
     try:
-        with jax_compat.enable_x64(True):
+        with jax.enable_x64(True):
             layer = net.conf.layers[layer_idx]
             params64 = jax.tree_util.tree_map(
                 lambda a: jnp.asarray(np.asarray(a), jnp.float64),
@@ -104,7 +103,7 @@ def check_graph_pretrain_gradients(net, vertex_name: str, xs, *,
     saved_policy = common.get_policy()
     common.set_policy(jnp.float64, jnp.float64, jnp.float64)
     try:
-        with jax_compat.enable_x64(True):
+        with jax.enable_x64(True):
             conf = net.conf
             layer = conf.vertices[vertex_name].layer
             params64 = jax.tree_util.tree_map(
@@ -168,7 +167,7 @@ def _fd_check_subtree(score, params_subtree, *, eps, max_rel_error,
 
 def _check_gradients_x64(net, x, y, *, eps, max_rel_error, min_abs_error, subset,
                          seed, verbose) -> bool:
-    with jax_compat.enable_x64(True):
+    with jax.enable_x64(True):
         params64 = jax.tree_util.tree_map(
             lambda a: jnp.asarray(np.asarray(a), jnp.float64), net.params_list)
         x64 = jnp.asarray(np.asarray(x), jnp.float64)

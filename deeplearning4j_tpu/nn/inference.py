@@ -22,17 +22,33 @@ same rules that shard training (``parallel/partition.py``), cutting resident
 bytes per device by the shard factor, and the program compiles through the
 ``parallel/compile_seam`` jit-with-shardings path.
 
-**The serving bitwise contract.** Distributed *compute* (true Megatron-style
+**The serving equality contract.** Distributed *compute* (true Megatron-style
 tensor parallelism) makes GSPMD insert partial-sum all-reduces that reorder
 f32 accumulation — ~1-ulp accurate, never bitwise (the training suite's
 dp_tp equivalence test uses atol=1e-4 for exactly this reason). Serving
-promises bitwise equality with the single-device program, so the sharded
-path shards params **at rest** and gathers **at use**: the first act inside
-the jitted program is ``with_sharding_constraint(params, replicated)`` — an
-exact all-gather layout change, no arithmetic — and each batch row then
-computes with the identical single-device reduction order. The win is
-resident bytes (serve models bigger than one HBM) and data-axis batch
-scale-out, not distributed matmuls; do not "optimize" the gather away.
+instead shards params **at rest** and gathers **at use**: the first act
+inside the jitted program is ``with_sharding_constraint(params,
+replicated)`` — an exact all-gather layout change, no arithmetic — so there
+is no cross-device arithmetic at all, and each device computes its slice of
+the batch with the single-device program's own operations. What that makes
+equal to what:
+
+- **bitwise**: the sharded output equals the single-device program applied
+  to each device's slice of the batch (same shapes, same kernels) — hence
+  to the whole batch wherever the data axis does not split it (batches it
+  does not divide dispatch replicated);
+- **to float32 rounding** (a few ulp; the tests hold it to rtol 4e-6): the
+  sharded output against the single-device program on the *whole* batch.
+  XLA picks a matmul kernel by the operand's batch extent, so one row
+  computed in a batch of 1 and in a batch of 4 may round differently. The
+  CPU backend of JAX 0.4 happened to be batch-invariant and this read
+  bitwise; JAX 0.9.0's is not (a batch of 4 split over ``data=4`` drifts by
+  1 ulp). The same holds for the MicroBatcher's padded dispatch against a
+  request served alone. Whether a TPU's kernels are batch-invariant is
+  something ``chip_smoke.py``'s serve phase reports, not something promised.
+
+The win is resident bytes (serve models bigger than one HBM) and data-axis
+batch scale-out, not distributed matmuls; do not "optimize" the gather away.
 
 The reference serves via ``KerasModelEndpoint``/``output()`` with no
 donation concept; here the seam must be explicit because the fit path's
@@ -93,7 +109,8 @@ class PredictFn:
     partition rules (params live split across the mesh; int8 composes — the
     codes shard, and the gather moves int8 bytes) and compiles through the
     compile seam. Outputs are fully replicated and bitwise-equal to the
-    single-device program (see the module docstring for why the params are
+    single-device program on each device's slice of the batch (see the
+    module docstring for the equality contract and why the params are
     gathered at use rather than compute-sharded). ``device=`` instead pins
     the snapshot onto one specific device — the ReplicaSet's per-replica
     placement on a multi-chip host.
@@ -170,9 +187,9 @@ class PredictFn:
         @functools.wraps(fn)
         def gathered(params, *rest, **kw):
             # exact all-gather (layout change, no arithmetic): every device
-            # then runs the identical single-device reduction order, which
-            # is what keeps the sharded program bitwise-equal (int8 codes
-            # gather as int8 — 4x cheaper on the wire than f32)
+            # then runs the single-device operations on its slice of the
+            # batch, which is what the equality contract rests on (int8
+            # codes gather as int8 — 4x cheaper on the wire than f32)
             return fn(jax.lax.with_sharding_constraint(params, gather),
                       *rest, **kw)
 

@@ -35,7 +35,7 @@ from .watchdog import (StepWatchdog, install_watchdog, uninstall_watchdog,
                        global_watchdog, beat)
 from .profiler import (TraceSession, StepAnomalyWatcher, global_trace_session,
                        install_anomaly_watcher, uninstall_anomaly_watcher,
-                       note_dispatch, first_healthy_due, mark_first_healthy)
+                       note_dispatch)
 from . import xplane
 
 __all__ = [
@@ -60,5 +60,5 @@ __all__ = [
     "global_watchdog", "beat",
     "TraceSession", "StepAnomalyWatcher", "global_trace_session",
     "install_anomaly_watcher", "uninstall_anomaly_watcher", "note_dispatch",
-    "first_healthy_due", "mark_first_healthy", "xplane",
+    "xplane",
 ]

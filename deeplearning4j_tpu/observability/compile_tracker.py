@@ -49,10 +49,27 @@ STORM_WINDOW_STEPS = 200
 
 _MAX_EVENTS = 1000
 
-#: assumed accelerator peak when nothing is configured and the backend is a
-#: TPU (v4 chip bf16 peak, matching bench.py); on CPU the default is "peak
-#: unknown" and the MFU gauge stays silent
-_DEFAULT_TPU_PEAK_FLOPS = 197e12
+#: published bf16 peak FLOP/s of one chip, by ``jax.Device.device_kind`` —
+#: THE peaks table (bench.py reads it too). A kind that is not here is an
+#: error, never a default: add it with its source.
+PEAK_BF16_FLOPS = {
+    "TPU v5 lite": 197e12,  # Google Cloud documentation, "TPU v5e"
+}
+
+
+def peak_flops_for(device) -> Optional[float]:
+    """bf16 peak of ``device``: None on a CPU (no utilization is reported
+    there), the table's figure on a known accelerator, ValueError on an
+    unknown one."""
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAK_BF16_FLOPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device kind {device.device_kind!r}: add "
+            "it to observability.compile_tracker.PEAK_BF16_FLOPS with its "
+            "source, or set DL4J_PEAK_FLOPS") from None
 
 
 def _abstractify_for_lowering(x: Any) -> Any:
@@ -198,26 +215,19 @@ class CompileTracker:
 
     # ----------------------------------------------------------------- mfu
     def peak_flops(self) -> Optional[float]:
-        """Accelerator peak FLOP/s for MFU: ``DL4J_PEAK_FLOPS`` (or bench's
-        ``BENCH_PEAK_FLOPS``) if set, else a TPU default when the backend is
-        a TPU, else None — on CPU the MFU gauge deliberately stays silent
-        rather than report a meaningless ratio."""
-        env = os.environ.get("DL4J_PEAK_FLOPS") \
-            or os.environ.get("BENCH_PEAK_FLOPS")
+        """Accelerator peak FLOP/s for MFU: ``DL4J_PEAK_FLOPS`` if set, else
+        the default device's entry in :data:`PEAK_BF16_FLOPS`
+        (:func:`peak_flops_for`) — None on CPU, where the MFU gauge
+        deliberately stays silent rather than report a meaningless ratio,
+        and an error on an accelerator the table does not know."""
+        env = os.environ.get("DL4J_PEAK_FLOPS")
         if env:
-            try:
-                return float(env)
-            except ValueError:
-                log.warning("unparseable peak-FLOPS override %r", env)
+            return float(env)
         if not self._backend_peak_resolved:
-            self._backend_peak_resolved = True
-            try:
-                import jax
+            import jax
 
-                if jax.default_backend() == "tpu":
-                    self._backend_peak = _DEFAULT_TPU_PEAK_FLOPS
-            except Exception:  # pragma: no cover - no backend available  # lint: swallowed-exception-ok (MFU stays disabled when the backend is unknown)
-                pass
+            self._backend_peak = peak_flops_for(jax.devices()[0])
+            self._backend_peak_resolved = True
         return self._backend_peak
 
     def note_executable(self, name: str, compiled: Any) -> None:
@@ -227,6 +237,12 @@ class CompileTracker:
         with self._lock:
             self._executables[name] = compiled
             self._cost.pop(name, None)
+
+    def executable(self, name: str) -> Optional[Any]:
+        """The executable last noted for ``name`` (its ``as_text()`` and
+        ``memory_analysis()`` say what the compiler did), or None."""
+        with self._lock:
+            return self._executables.get(name)
 
     def flops_for(self, name: str) -> Optional[float]:
         """FLOPs of ONE training step of the wrapped program ``name``.

@@ -18,12 +18,8 @@ door in front of it:
   phase, fed via :func:`note_dispatch` from the fit loops), and when a step
   exceeds ``k x rolling-p50`` starts a capture over the next dispatches —
   once per cool-down, so a pathological run cannot trace itself to death.
-* ``first-healthy`` — the bench trigger (ROADMAP item 1: capture-first):
-  :func:`first_healthy_due` consults a cross-process marker file so the
-  first healthy relay window after an outage gets an attribution capture,
-  and later windows inside the cool-down don't re-pay the trace overhead.
 
-Env knobs: ``DL4J_PROFILE_TRIGGER`` (off | anomaly | first-healthy),
+Env knobs: ``DL4J_PROFILE_TRIGGER`` (off | anomaly),
 ``DL4J_PROFILE_DIR`` (base directory, default ``profiles/``),
 ``DL4J_PROFILE_ANOMALY_K`` (default 3.0), ``DL4J_PROFILE_COOLDOWN_S``
 (default 600), ``DL4J_PROFILE_WINDOW`` (dispatches per capture, default 2).
@@ -57,7 +53,6 @@ WINDOW_ENV = "DL4J_PROFILE_WINDOW"
 DEFAULT_BASE_DIR = "profiles"
 ATTRIBUTION_FILE = "attribution.json"
 INDEX_DB = "profile_index.db"
-FIRST_HEALTHY_MARKER = ".first_healthy_ts"
 
 #: index keying: one fixed session so every process appends to the same
 #: stream; the worker id is the pid, the row timestamp orders entries
@@ -446,36 +441,3 @@ def note_dispatch(seconds: float) -> None:
         if w is None:
             return
     w.observe(seconds)
-
-
-# ------------------------------------------------------- first-healthy trigger
-def first_healthy_due(base_dir: Optional[str] = None,
-                      cooldown_s: Optional[float] = None) -> bool:
-    """True when ``DL4J_PROFILE_TRIGGER=first-healthy`` and no capture has
-    been marked within the cool-down. The marker file lives under the
-    profile base dir so the state is shared across bench child processes —
-    the FIRST healthy window captures, the rest of the grid doesn't."""
-    if os.environ.get(TRIGGER_ENV, "").strip() != "first-healthy":
-        return False
-    base = base_dir or os.environ.get(DIR_ENV) or DEFAULT_BASE_DIR
-    cd = cooldown_s if cooldown_s is not None \
-        else _env_float(COOLDOWN_ENV, 600.0)
-    try:
-        age = time.time() - os.path.getmtime(
-            os.path.join(base, FIRST_HEALTHY_MARKER))
-    except OSError:
-        return True
-    return age > cd
-
-
-def mark_first_healthy(base_dir: Optional[str] = None) -> None:
-    """Record that a first-healthy capture just happened (touches the
-    cross-process marker)."""
-    base = base_dir or os.environ.get(DIR_ENV) or DEFAULT_BASE_DIR
-    try:
-        os.makedirs(base, exist_ok=True)
-        with open(os.path.join(base, FIRST_HEALTHY_MARKER), "w") as f:
-            f.write(f"{time.time()}\n")
-    except OSError as e:
-        log.warning("could not write first-healthy marker under %s: %r",
-                    base, e)

@@ -1,12 +1,12 @@
 """Stdlib-only XPlane (``.xplane.pb``) parser -> per-op attribution summary.
 
 ``jax.profiler.start_trace`` writes its artifact as an XSpace protobuf
-(``plugins/profile/<ts>/<host>.xplane.pb``), but the jax build on this image
-ships no reader for it (``jax.profiler.ProfileData`` does not exist in
-0.4.37) and TensorBoard is not installed. The trace is useless to the
-framework unless we can read it ourselves — so this module walks the
-protobuf wire format directly: varints and length-delimited submessages,
-nothing else, no generated bindings, no third-party deps.
+(``plugins/profile/<ts>/<host>.xplane.pb``). This module walks the protobuf
+wire format directly: varints and length-delimited submessages, nothing
+else, no generated bindings, no third-party deps. It was written when the
+installed JAX shipped no reader; JAX 0.9.0's ``jax.profiler.ProfileData``
+now reads the same file, and replacing this hand parser with it is a later
+``simplicity`` PR (noted, not done, in PR 21).
 
 Only the fields attribution needs are decoded (verified against traces from
 this jax build; the numbers are the upstream tsl/profiler field ids):

@@ -94,8 +94,10 @@ def _fused_sm_xent_per(labels: Array, preout: Array) -> Array:
 
 
 def _xent_interpret() -> bool:
-    # pallas interpret mode off-TPU (tests exercise the kernel body on CPU)
-    return jax.default_backend() not in ("tpu",)
+    """DL4J_XENT_INTERPRET=1 runs the fused kernel in interpret mode — the
+    CPU test hook (loss code has no kwarg path down to the kernel), like
+    DL4J_LSTM_INTERPRET. Never on by itself."""
+    return os.environ.get("DL4J_XENT_INTERPRET") == "1"
 
 
 def _fused_sm_xent_fwd(labels, preout):
@@ -117,19 +119,18 @@ _fused_sm_xent_per.defvjp(_fused_sm_xent_fwd, _fused_sm_xent_bwd)
 
 
 def _fused_xent_engaged(preout: Array) -> bool:
-    """DL4J_FUSED_XENT=0 disables, =1 forces (interpret mode off-TPU); unset
-    -> engaged exactly when the other pallas kernels are (use_pallas()).
-    Read at call time like every other kill switch in the tree."""
-    env = os.environ.get("DL4J_FUSED_XENT")
-    if env == "0":
+    """Engaged exactly when the other pallas kernels are (use_pallas()), or
+    when a test asked for interpret mode; DL4J_FUSED_XENT=0 disables. Read
+    at call time like every other kill switch in the tree. Whether the
+    kernel itself then runs is the shape gate's call, counted on
+    dl4j_pallas_dispatch_total (pallas_kernels.softmax_cross_entropy)."""
+    if os.environ.get("DL4J_FUSED_XENT") == "0":
         return False
     if preout.dtype not in (jnp.float32, jnp.bfloat16):
         return False  # f64 gradient checks stay on the exact autodiff path
-    if env == "1":
-        return True
     from deeplearning4j_tpu.ops.pallas_kernels import use_pallas
 
-    return use_pallas()
+    return _xent_interpret() or use_pallas()
 
 
 def mcxent(labels: Array, preout: Array, activation, mask=None) -> Array:

@@ -27,9 +27,10 @@ gate at trace time (the round-5 ``DL4J_FLASH_MIN_SEQ`` pattern):
 Dispatch: ``DL4J_LSTM_IMPL=auto|fused|pallas|scan`` (read at trace time, so
 bench A/Bs flip it between traces). ``auto`` engages pallas only past
 ``(hidden, seq)`` thresholds and under the VMEM budget — the ``batch`` axis
-enters through the budget — and falls back to fused everywhere else,
-including on CPU and whenever the cell uses non-tanh/sigmoid activations
-(the hand-derived kernel backward is specific to the standard cell). Every
+enters through the budget — and takes fused everywhere else, including on
+CPU and whenever the cell uses non-tanh/sigmoid activations (the hand-derived
+kernel backward is specific to the standard cell); a forced ``pallas`` that
+cannot run raises, naming the constraint (:func:`pallas_refusal`). Every
 selection increments ``dl4j_lstm_dispatch_total`` and the shared
 ``dl4j_pallas_dispatch_total`` engagement counter.
 """
@@ -48,7 +49,8 @@ from deeplearning4j_tpu.common import accum_dtype, get_policy
 from deeplearning4j_tpu.observability.metrics import global_registry
 from deeplearning4j_tpu.observability.names import (LSTM_DISPATCH_TOTAL,
                                                     LSTM_PALLAS_BLOCK_STEPS)
-from deeplearning4j_tpu.ops.pallas_kernels import _note_dispatch, use_pallas
+from deeplearning4j_tpu.ops.pallas_kernels import (_note_dispatch,
+                                                   pallas_unavailable)
 
 Array = jax.Array
 
@@ -82,7 +84,8 @@ def _min_seq() -> int:
 
 
 def _vmem_budget() -> int:
-    # ~16 MB VMEM/core minus headroom for Mosaic's own pipeline buffers
+    # the compiler's scoped VMEM default is 16 MiB per core; the rest is
+    # headroom for Mosaic's own scratch and for what _vmem_bytes leaves out
     return int(os.environ.get("DL4J_LSTM_VMEM_BUDGET", str(12 * 1024 * 1024)))
 
 
@@ -92,12 +95,16 @@ def _vmem_bytes(bt: int, batch: int, n_in: int, hidden: int,
 
     The backward is the binding constraint: it holds W AND the dW accumulator
     (2x the ``(F+H) x 4H`` weight), streams four double-buffered slabs
-    (x, h_prev, c_prev, dy) plus the dx output slab, and carries dh/dc in
-    f32. The forward fits whenever the backward does.
+    (x, h_prev, c_prev, dy) plus the dx output slab and the lane-padded
+    mask column, and carries dh/dc in f32. The forward fits whenever the
+    backward does. Checked against the v5e compiler's own scoped-allocation
+    figure over 18 (batch, F, H, block, dtype) points: at or above it
+    everywhere but one, which it misses by 0.07 MiB — inside the headroom
+    :func:`_vmem_budget` keeps under the 16 MiB limit.
     """
     fh4 = (n_in + hidden) * 4 * hidden
     w_and_dw = 2 * fh4 * max(itemsize, 4)  # dW accumulates at least f32
-    streams = 2 * bt * batch * (n_in + 3 * hidden) * itemsize
+    streams = 2 * bt * batch * (n_in + 3 * hidden + 128) * itemsize
     dx_out = 2 * bt * batch * n_in * itemsize
     carries = 8 * batch * hidden * 4
     work = batch * (n_in + 9 * hidden) * 4  # xh + z + dz tiles in f32
@@ -124,6 +131,34 @@ def _pick_block(seq: int, batch: int, n_in: int, hidden: int,
     return None
 
 
+def pallas_refusal(hidden: int, seq: int, batch: int, n_in: int, *,
+                   dtype=None, act_name: Optional[str] = "tanh",
+                   gate_name: Optional[str] = "sigmoid",
+                   interpret: bool = False) -> Optional[str]:
+    """Why the Pallas cell cannot run this call, or None when it can — the
+    hard constraints, each read off the device or the shape: a TPU (or a
+    test's interpret mode), the standard tanh/sigmoid cell (the kernel
+    backward is hand-derived for it), a lane-aligned hidden width on real
+    hardware, and a timestep block that fits the VMEM budget."""
+    why = None if interpret else pallas_unavailable()
+    if why is not None:
+        return why
+    if act_name not in (None, "tanh") or gate_name not in (None, "sigmoid"):
+        return f"cell activations {act_name}/{gate_name} are not tanh/sigmoid"
+    if not interpret and hidden % 128:
+        return f"hidden {hidden} is not a multiple of the 128-lane tile"
+    dtype = dtype if dtype is not None else get_policy().compute_dtype
+    if _pick_block(seq, batch, n_in, hidden, dtype) is None:
+        itemsize = jnp.dtype(dtype).itemsize
+        need = min(_vmem_bytes(bt, batch, n_in, hidden, itemsize)
+                   for bt in BLOCK_CHOICES)
+        return (f"resident weights and slabs need {need / 2**20:.1f} MiB of "
+                f"VMEM at batch {batch}, n_in {n_in}, hidden {hidden}, "
+                f"{jnp.dtype(dtype).name}; the budget is "
+                f"{_vmem_budget() / 2**20:.0f} MiB")
+    return None
+
+
 def resolve_impl(hidden: int, seq: int, batch: int, n_in: int, *,
                  dtype=None, act_name: str = "tanh",
                  gate_name: str = "sigmoid", impl: Optional[str] = None,
@@ -132,32 +167,26 @@ def resolve_impl(hidden: int, seq: int, batch: int, n_in: int, *,
 
     One predicate for every caller (layers, bench, tests) so a forward under
     ``jax.grad`` can never take a different path than the plain forward.
-    Hard constraints on pallas — TPU-or-interpret availability, the standard
-    tanh/sigmoid cell (the kernel backward is hand-derived for it), a
-    lane-aligned hidden width on real hardware, and the VMEM budget — hold
-    even when ``DL4J_LSTM_IMPL=pallas`` forces the variant; a forced-but-
-    impossible pallas request degrades to fused, never to a crash."""
+    ``auto`` takes pallas past the ``(hidden, seq)`` thresholds wherever
+    :func:`pallas_refusal` has no objection, and fused everywhere else. A
+    forced ``pallas`` that the hard constraints refuse is an error naming
+    the constraint — a request for the kernel never runs something else."""
     choice = (impl or _requested_impl()).lower()
     if choice not in ("auto", "fused", "pallas", "scan"):
         raise ValueError(f"unknown LSTM impl '{choice}' "
                          "(expected auto|fused|pallas|scan)")
-    if choice == "scan":
-        return "scan", None
-    if choice == "fused":
-        return "fused", None
+    if choice in ("scan", "fused"):
+        return choice, None
     dtype = dtype if dtype is not None else get_policy().compute_dtype
-    pallas_hard_ok = ((use_pallas() or interpret)
-                     and act_name in (None, "tanh")
-                     and gate_name in (None, "sigmoid")
-                     and (interpret or hidden % 128 == 0))
-    bt = (_pick_block(seq, batch, n_in, hidden, dtype)
-          if pallas_hard_ok else None)
-    if choice == "pallas":
-        return ("pallas", bt) if bt is not None else ("fused", None)
-    # auto: calibrated thresholds (hidden, seq); batch enters via the VMEM
-    # budget inside _pick_block
-    if bt is not None and hidden >= _min_hidden() and seq >= _min_seq():
-        return "pallas", bt
+    refusal = pallas_refusal(hidden, seq, batch, n_in, dtype=dtype,
+                             act_name=act_name, gate_name=gate_name,
+                             interpret=interpret)
+    if choice == "pallas" and refusal is not None:
+        raise ValueError(f"LSTM impl 'pallas' was requested but cannot run: "
+                         f"{refusal}")
+    if refusal is None and (choice == "pallas" or (
+            hidden >= _min_hidden() and seq >= _min_seq())):
+        return "pallas", _pick_block(seq, batch, n_in, hidden, dtype)
     return "fused", None
 
 
@@ -327,8 +356,8 @@ def _lstm_fwd_kernel(x_ref, w_ref, b_ref, h0_ref, c0_ref, m_ref, *rest,
 
     def body(t, carry):
         h, c = carry
-        x_t = x_ref[pl.ds(t, 1)][0].astype(wd)            # [B, F]
-        m_t = m_ref[pl.ds(t, 1)][0].astype(wd)[:, None]   # [B, 1]
+        x_t = x_ref[t].astype(wd)                         # [B, F]
+        m_t = m_ref[t].astype(wd)                         # [B, 1]
         xh = jnp.concatenate([x_t, h], axis=-1)           # [B, F+H]
         z = jnp.dot(xh, w, preferred_element_type=wd) + b  # [B, 4H]
         zi = z[:, :hidden]
@@ -348,8 +377,8 @@ def _lstm_fwd_kernel(x_ref, w_ref, b_ref, h0_ref, c0_ref, m_ref, *rest,
         h_new = o * jnp.tanh(c_new)
         h_new = jnp.where(m_t > 0, h_new, h)
         c_new = jnp.where(m_t > 0, c_new, c)
-        ys_ref[pl.ds(t, 1)] = h_new[None].astype(ys_ref.dtype)
-        cs_ref[pl.ds(t, 1)] = c_new[None].astype(cs_ref.dtype)
+        ys_ref[t] = h_new.astype(ys_ref.dtype)
+        cs_ref[t] = c_new.astype(cs_ref.dtype)
         return h_new, c_new
 
     h, c = lax.fori_loop(0, bt, body,
@@ -390,13 +419,13 @@ def _lstm_bwd_kernel(x_ref, hp_ref, cp_ref, dy_ref, w_ref, b_ref,
         p_o = p_ref[2].astype(wd)
 
     def body(j, carry):
-        dh, dc, dw, db, dp = carry
+        dh, dc = carry
         t = bt - 1 - j
-        x_t = x_ref[pl.ds(t, 1)][0].astype(wd)
-        hp = hp_ref[pl.ds(t, 1)][0].astype(wd)
-        cp = cp_ref[pl.ds(t, 1)][0].astype(wd)
-        dy = dy_ref[pl.ds(t, 1)][0].astype(wd)
-        m_t = m_ref[pl.ds(t, 1)][0].astype(wd)[:, None]
+        x_t = x_ref[t].astype(wd)
+        hp = hp_ref[t].astype(wd)
+        cp = cp_ref[t].astype(wd)
+        dy = dy_ref[t].astype(wd)
+        m_t = m_ref[t].astype(wd)                         # [B, 1]
         # forward recompute (one extra matmul per step; W is already here)
         xh = jnp.concatenate([x_t, hp], axis=-1)
         z = jnp.dot(xh, w, preferred_element_type=wd) + b
@@ -435,31 +464,25 @@ def _lstm_bwd_kernel(x_ref, hp_ref, cp_ref, dy_ref, w_ref, b_ref,
         dzg = dg * (1.0 - g * g)
         dz = jnp.concatenate([dzi, dzf, dzg, dzo], axis=-1)  # [B, 4H]
         dxh = jnp.dot(dz, w.T, preferred_element_type=wd)    # [B, F+H]
-        dw = dw + jnp.dot(xh.T, dz, preferred_element_type=wd)
-        db = db + jnp.sum(dz, axis=0)
+        # the weight-sized accumulators live in their VMEM-resident output
+        # blocks, never in the loop carry (a (F+H)x4H carry would spill)
+        dw_ref[...] += jnp.dot(xh.T, dz, preferred_element_type=wd)
+        db_ref[...] += jnp.sum(dz, axis=0, keepdims=True)
         if peephole:
-            dp = dp + jnp.stack([jnp.sum(dzi * cp, axis=0),
-                                 jnp.sum(dzf * cp, axis=0),
-                                 jnp.sum(dzo * c_new, axis=0)])
-        dx_ref[pl.ds(t, 1)] = dxh[None, :, :n_in].astype(dx_ref.dtype)
+            dp_ref[0:1] += jnp.sum(dzi * cp, axis=0, keepdims=True)
+            dp_ref[1:2] += jnp.sum(dzf * cp, axis=0, keepdims=True)
+            dp_ref[2:3] += jnp.sum(dzo * c_new, axis=0, keepdims=True)
+        dx_ref[t] = dxh[:, :n_in].astype(dx_ref.dtype)
         dh_next = dxh[:, n_in:] + dh_skip
         dc_next = dc_t * f + dc_skip
         if peephole:
             dc_next = dc_next + dzi * p_i + dzf * p_f
-        return dh_next, dc_next, dw, db, dp
+        return dh_next, dc_next
 
-    zero_w = jnp.zeros(dw_ref.shape, wd)
-    zero_b = jnp.zeros((4 * hidden,), wd)
-    zero_p = jnp.zeros((3, hidden), wd)
-    dh, dc, dw, db, dp = lax.fori_loop(
-        0, bt, body, (dh_ref[...].astype(wd), dc_ref[...].astype(wd),
-                      zero_w, zero_b, zero_p))
+    dh, dc = lax.fori_loop(
+        0, bt, body, (dh_ref[...].astype(wd), dc_ref[...].astype(wd)))
     dh_ref[...] = dh.astype(dh_ref.dtype)
     dc_ref[...] = dc.astype(dc_ref.dtype)
-    dw_ref[...] = (dw_ref[...].astype(wd) + dw).astype(dw_ref.dtype)
-    db_ref[...] = (db_ref[...].astype(wd) + db[None]).astype(db_ref.dtype)
-    if peephole:
-        dp_ref[...] = (dp_ref[...].astype(wd) + dp).astype(dp_ref.dtype)
 
 
 def _pallas_forward(x_t, wcat, b2, peep, h0, c0, m_t, bt, peephole,
@@ -477,7 +500,7 @@ def _pallas_forward(x_t, wcat, b2, peep, h0, c0, m_t, bt, peephole,
         pl.BlockSpec((1, 4 * H), lambda i: (0, 0)),
         pl.BlockSpec((B, H), lambda i: (0, 0)),
         pl.BlockSpec((B, H), lambda i: (0, 0)),
-        pl.BlockSpec((bt, B), lambda i: (i, 0)),
+        pl.BlockSpec((bt, B, 1), lambda i: (i, 0, 0)),
     ]
     operands = [x_t, wcat, b2, h0, c0, m_t]
     if peephole:
@@ -515,9 +538,6 @@ def _pallas_backward(x_t, hprev, cprev, wcat, b2, peep, dys, dht, dct, m_t,
     def rev3(i):
         return (nb - 1 - i, 0, 0)
 
-    def rev2(i):
-        return (nb - 1 - i, 0)
-
     def const2(i):
         return (0, 0)
 
@@ -530,7 +550,7 @@ def _pallas_backward(x_t, hprev, cprev, wcat, b2, peep, dys, dht, dct, m_t,
         pl.BlockSpec((1, 4 * H), const2),
         pl.BlockSpec((B, H), const2),
         pl.BlockSpec((B, H), const2),
-        pl.BlockSpec((bt, B), rev2),
+        pl.BlockSpec((bt, B, 1), rev3),
     ]
     operands = [x_t, hprev, cprev, dys, wcat, b2, dht, dct, m_t]
     if peephole:
@@ -614,13 +634,16 @@ def _lstm_pallas_seq(params: dict, x: Array, h0: Array, c0: Array,
         peep = jnp.zeros((3, hidden), cd)
     B, T = x.shape[0], x.shape[1]
     x_t = jnp.moveaxis(x, 1, 0).astype(cd)
-    m_t = (jnp.moveaxis(mask, 1, 0).astype(cd) if mask is not None
-           else jnp.ones((T, B), cd))
+    # [T, B, 1]: a step's mask is then one leading-axis read of a [B, 1]
+    # column, whatever the dtype's sublane packing
+    m_t = (jnp.moveaxis(mask, 1, 0).astype(cd)[:, :, None]
+           if mask is not None else jnp.ones((T, B, 1), cd))
     pad = (-T) % bt
     if pad:
         x_t = jnp.concatenate(
             [x_t, jnp.zeros((pad,) + x_t.shape[1:], x_t.dtype)], axis=0)
-        m_t = jnp.concatenate([m_t, jnp.zeros((pad, B), m_t.dtype)], axis=0)
+        m_t = jnp.concatenate([m_t, jnp.zeros((pad, B, 1), m_t.dtype)],
+                              axis=0)
     ys, h, c = _pallas_lstm(bt, peephole, interpret, x_t, wcat, b2, peep,
                             h0.astype(cd), c0.astype(cd), m_t)
     return (jnp.moveaxis(ys[:T], 0, 1).astype(od),

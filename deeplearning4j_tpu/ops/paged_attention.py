@@ -12,8 +12,8 @@ as the int8 matmul (ops/quant.py) and the LSTM engine (ops/lstm.py):
   the DMA engine streams exactly the mapped pages HBM→VMEM — the
   logical view is materialized tile by tile, never as a second dense
   copy in HBM.
-- **XLA fallback** (CPU hosts, kill switch): one fused ``take`` along
-  the page axis.
+- **XLA path** (CPU hosts, a jit that GSPMD partitions, kill switch):
+  one fused ``take`` along the page axis.
 
 Both paths are pure data movement over the same indices, so they are
 bitwise identical by construction — the dispatch gate can never change
@@ -31,11 +31,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fail on builds without the TPU plugin
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - exercised only on minimal builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def resolve_paged_impl(requested=None):
@@ -51,7 +47,7 @@ def resolve_paged_impl(requested=None):
         return "xla", False
     if req == "pallas":
         return "pallas", interpret
-    if pltpu is not None and (use_pallas() or interpret):
+    if use_pallas() or interpret:
         return "pallas", interpret
     return "xla", False
 
@@ -92,7 +88,7 @@ def paged_gather(pool, table, *, impl=None):
     caller's attention mask must never select)."""
     from deeplearning4j_tpu.ops.pallas_kernels import _note_dispatch
     kind, interpret = resolve_paged_impl(impl)
-    if kind == "pallas" and pltpu is not None:
+    if kind == "pallas":
         _note_dispatch("paged_gather", True)
         return _paged_gather_pallas(pool, table, interpret)
     _note_dispatch("paged_gather", False)

@@ -16,7 +16,6 @@ replacement for the reference's Spark cluster setup.
 """
 from __future__ import annotations
 
-import logging
 from typing import Optional, Sequence
 
 import jax
@@ -29,19 +28,6 @@ def init_distributed(coordinator_address: Optional[str] = None,
                      process_id: Optional[int] = None) -> None:
     """Multi-host init (replaces Spark driver/executor RPC + Aeron media driver,
     reference ParameterServerParallelWrapper.java:159-161)."""
-    # CPU cross-process collectives need an explicit implementation: the
-    # jax_cpu_collectives_implementation flag defaults to "none" and (in jax
-    # 0.4.37) is NOT read from the environment, so a multi-process CPU
-    # cluster would form and then fail every collective at compile time with
-    # "Multiprocess computations aren't implemented on the CPU backend".
-    # Must run before the first backend is created; harmless on TPU.
-    try:
-        from jaxlib.xla_client import _xla
-        if hasattr(_xla, "make_gloo_tcp_collectives"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # unknown flag / exotic jaxlib: old behavior
-        logging.getLogger(__name__).debug(
-            "could not enable gloo CPU collectives: %s", e)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes, process_id=process_id)
 

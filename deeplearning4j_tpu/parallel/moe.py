@@ -21,7 +21,6 @@ from jax.sharding import Mesh
 from deeplearning4j_tpu.parallel.partition import (
     pspec as P, named_sharding as _named_sharding,
 )
-from deeplearning4j_tpu.jax_compat import shard_map
 from deeplearning4j_tpu.observability.names import COLLECTIVE_BYTES_PER_STEP
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry,
@@ -140,7 +139,7 @@ def expert_parallel_ffn(layer, params: dict, x: Array, mesh: Mesh,
     # so a placeholder key + train=False keeps the operand list static
     if rng is None:
         rng, train = jax.random.PRNGKey(0), False
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_moe_local, layer=layer, axis_name=axis_name,
                           capacity=capacity, train=train,
                           mean_axes=mean_axes),

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
-from deeplearning4j_tpu import jax_compat
-from deeplearning4j_tpu.jax_compat import shard_map
 from deeplearning4j_tpu.parallel.partition import (
     pspec as P, named_sharding as _named_sharding,
 )
@@ -70,7 +68,7 @@ def _pipeline_local(params_local, xs, *, block_fn, axis_name: str):
 
     # the carried activation/outputs are device-varying (each stage holds a
     # different microbatch) — mark them so the loop carry types line up
-    vary = lambda a: jax_compat.pcast(a, (axis_name,), to="varying")
+    vary = lambda a: jax.lax.pcast(a, (axis_name,), to="varying")
     init = (vary(jnp.zeros_like(xs[0])), vary(jnp.zeros_like(xs)))
     _, outs = lax.fori_loop(0, S + xs.shape[0] - 1, tick, init)
     return outs
@@ -121,7 +119,7 @@ class PipelineParallel:
         # per-stage output buffers stay sharded over the stage axis
         # ([S*M, mb, T, F] stacked); only the last stage's M microbatches
         # are real — slice them out instead of psum-replicating
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_pipeline_local,
                               block_fn=self._stage_fn,
                               axis_name=self.axis_name),

@@ -25,7 +25,6 @@ from jax.sharding import Mesh
 from deeplearning4j_tpu.parallel.partition import (
     pspec as P, named_sharding as _named_sharding,
 )
-from deeplearning4j_tpu.jax_compat import pcast, shard_map
 from deeplearning4j_tpu.observability.names import COLLECTIVE_BYTES_PER_STEP
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry, tree_nbytes as _tree_nbytes,
@@ -96,7 +95,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     # them so the fori_loop carry types line up under shard_map (over the
     # batch axis too when the leading dim is data-sharded)
     axes = (axis_name,) + ((batch_axis,) if batch_axis else ())
-    vary = lambda x: pcast(x, axes, to="varying")
+    vary = lambda x: jax.lax.pcast(x, axes, to="varying")
     m = vary(jnp.full((B, H, Tq), _NEG, q.dtype))
     l = vary(jnp.zeros((B, H, Tq), q.dtype))
     o = jnp.zeros_like(q)
@@ -134,7 +133,7 @@ def ring_attention_sharded(q: Array, k: Array, v: Array, mesh: Mesh,
     _collective_per_step.labels(op="ppermute_kv",
                                 site="ring_attention").set(
         _tree_nbytes((k, v)))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name,
                           causal=causal, batch_axis=batch_axis),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
@@ -191,7 +190,7 @@ def ulysses_attention_sharded(q: Array, k: Array, v: Array, mesh: Mesh,
     # check_vma=False: pallas_call's out_shape carries no varying-mesh-axes
     # annotation, so the flash kernel inside the body can't satisfy the vma
     # checker; correctness is pinned by the =reference tests instead
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=axis_name, causal=causal,
                           interpret=interpret),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -27,28 +27,7 @@ def flatten_params(tree: Any, dtype=None) -> Array:
         return jnp.zeros((0,), dtype or jnp.float32)
     if dtype is None:
         dtype = jnp.result_type(*leaves)
-    if _any_partially_sharded(leaves):
-        # XLA's CPU SPMD partitioner miscompiles the eager mixed-layout
-        # concatenate below when the leaves carry different NamedShardings
-        # on a multi-axis mesh (jax 0.4.37: with a sharded 1-D leaf in the
-        # mix, every segment comes back scaled by a product of mesh axis
-        # sizes). Resolving each leaf to host values first sidesteps the
-        # partitioner entirely; this branch only fires on concrete arrays,
-        # so traced callers are unaffected.
-        return jnp.asarray(np.concatenate(
-            [np.asarray(l).ravel().astype(dtype) for l in leaves]))
     return jnp.concatenate([jnp.ravel(l).astype(dtype) for l in leaves])
-
-
-def _any_partially_sharded(leaves) -> bool:
-    for l in leaves:
-        if isinstance(l, jax.core.Tracer):
-            return False
-        sh = getattr(l, "sharding", None)
-        if (sh is not None and getattr(sh, "num_devices", 1) > 1
-                and not sh.is_fully_replicated):
-            return True
-    return False
 
 
 def unflatten_params(tree_like: Any, flat: Array) -> Any:

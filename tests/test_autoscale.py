@@ -17,8 +17,10 @@ Pinned contracts:
   ``dl4j_serve_shed_total{tenant,priority}`` accounting) while ``high``
   still admits — a high-priority 429 means the queue is hard-full;
 - warm scale-up: with the persistent compile cache populated,
-  ``add_replica()`` resolves every bucket program from disk — zero fresh
-  XLA compiles on a hot scale-up;
+  ``add_replica()`` onto a device that has run the model resolves every
+  bucket program from disk — zero fresh XLA compiles on a hot scale-up;
+  the first replica on a new device compiles for it (an executable is
+  bound to its device);
 - the HTTP front door exposes the autoscaler block and honors the
   priority/tenant headers; the CLI grows the --autoscale axis;
 - ``run_ramp_ab`` produces the full A/B record shape with zero lost
@@ -315,13 +317,18 @@ def test_priority_flows_through_router():
 
 # ----------------------------------------------------------- warm scale-up
 
-def test_scale_out_warm_hits_compile_cache(monkeypatch):
+@pytest.mark.parametrize("same_device", [True, False],
+                         ids=["device-seen-before", "new-device"])
+def test_scale_out_warm_hits_compile_cache(monkeypatch, same_device):
+    import jax
+
     from deeplearning4j_tpu.observability.compile_tracker import (
         global_tracker,
     )
     monkeypatch.setenv("DL4J_COMPILE_CACHE", "1")
+    devs = jax.devices()[:1] if same_device else jax.devices()[:2]
     rs = ReplicaSet(1, max_batch=8, max_latency_s=0.001, max_queue=32,
-                    warmup=True)
+                    warmup=True, devices=devs)
     try:
         # cold: replica 0's warmup populates the persistent cache with
         # every bucket program
@@ -329,12 +336,19 @@ def test_scale_out_warm_hits_compile_cache(monkeypatch):
         n0 = len(global_tracker().snapshot_events())
         r = rs.add_replica(reason="t-warm")
         ev = global_tracker().snapshot_events()[n0:]
-        # the pinned acceptance: a hot scale-up resolves EVERY program from
-        # disk (the fingerprint sheds the ~r<i> decoration) — no fresh XLA
-        # compile stands between the decision and a routable replica
         assert ev, "scale-out must warm every bucket program"
-        assert all(e.get("cache_hit") for e in ev), \
-            f"fresh compile on hot scale-up: {ev}"
+        if same_device:
+            # the pinned acceptance: a hot scale-up onto a device that has
+            # run the model resolves EVERY program from disk (the
+            # fingerprint sheds the ~r<i> decoration) — no fresh XLA
+            # compile stands between the decision and a routable replica
+            assert all(e.get("cache_hit") for e in ev), \
+                f"fresh compile on hot scale-up: {ev}"
+        else:
+            # an executable is bound to the device it was compiled for: the
+            # first replica on a second device compiles for it, once
+            assert not any(e.get("cache_hit") for e in ev), \
+                f"device 0's executable was loaded for device 1: {ev}"
         out = r.batcher.submit("mlp", _x()).result(timeout=30)
         assert np.asarray(out["predictions"]).shape == (2, N_OUT)
     finally:

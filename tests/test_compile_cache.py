@@ -16,9 +16,9 @@ Contracts pinned here:
   ``tracker.wrap(jax.jit(...))`` path: no disk entries, no CachedProgram;
 - the store itself prunes oldest-first to its byte bound.
 
-The autouse conftest fixture points ``DL4J_COMPILE_CACHE_DIR`` at a
-per-test tmp dir, so every test starts cold and cross-test poisoning is
-impossible.
+The autouse conftest fixture points ``JAX_COMPILATION_CACHE_DIR`` at a
+per-test tmp dir (the store is its ``executables/``), so every test starts
+cold and cross-test poisoning is impossible.
 """
 import glob
 import os
@@ -67,8 +67,7 @@ def _xy(n=16, seed=0):
 
 
 def _cache_files():
-    return sorted(glob.glob(os.path.join(
-        os.environ["DL4J_COMPILE_CACHE_DIR"], "*.xc")))
+    return sorted(glob.glob(os.path.join(cc.cache_dir(), "*.xc")))
 
 
 def _events_since(n0):
@@ -204,7 +203,7 @@ def test_cross_process_reuse(tmp_path):
         out = np.asarray(net.output(np.zeros((4, {N_IN}), np.float32)))
         np.save({out_npy!r}, out)
     """)
-    # the child inherits JAX_PLATFORMS / XLA_FLAGS / DL4J_COMPILE_CACHE_DIR
+    # the child inherits JAX_PLATFORMS / XLA_FLAGS / JAX_COMPILATION_CACHE_DIR
     # from this process, so its backend key matches ours
     subprocess.run([sys.executable, "-c", child], check=True, timeout=300)
     assert _cache_files(), "child must have persisted its executable"
@@ -309,3 +308,61 @@ def test_epoch_env_salts_fingerprint(monkeypatch):
     monkeypatch.setenv("DL4J_COMPILE_CACHE_EPOCH", "2")
     b = prog._fp_hex(sig)
     assert a != b, "EPOCH must invalidate without deleting files"
+
+
+# ------------------------------------------------------------- placement
+def test_cache_dir_resolution(monkeypatch, tmp_path):
+    """One knob, JAX's own: with JAX_COMPILATION_CACHE_DIR set the store is
+    its ``executables/`` and JAX's cache is left where the variable put it;
+    unset, both sit under one fixed directory in the checkout — derived from
+    the package's path, never from tempfile, a pid or a time."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax_dir_before = jax.config.jax_compilation_cache_dir
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    monkeypatch.setenv("DL4J_COMPILE_CACHE_DIR", str(tmp_path / "gone"))
+    assert cc.cache_root() == str(tmp_path / "c")
+    assert cc.cache_dir() == str(tmp_path / "c" / "executables")
+    assert cc.global_cache().directory == cc.cache_dir()
+    # nothing in code repointed JAX's own cache
+    assert jax.config.jax_compilation_cache_dir == jax_dir_before
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert cc.cache_root() == os.path.join(repo, ".jax_cache")
+        assert cc.cache_dir() == os.path.join(repo, ".jax_cache",
+                                              "executables")
+        assert cc.cache_root() == cc.cache_root()  # a fixed path
+        assert jax.config.jax_compilation_cache_dir == cc.cache_root()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", jax_dir_before)
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    src = open(cc.__file__).read()
+    assert "gettempdir" not in src and "mkdtemp" not in src \
+        and "getpid" not in src
+
+
+def test_loaded_executable_runs_on_the_devices_it_was_compiled_for():
+    """An entry records its device assignment and is loaded onto exactly
+    those devices (the default, every local device, is what broke every warm
+    start on JAX 0.9); a placement on another device is a sibling entry."""
+    import jax.numpy as jnp
+
+    d0, d3 = jax.devices()[0], jax.devices()[3]
+    x = np.arange(8, dtype=np.float32)
+    outs = {}
+    for round_ in ("cold", "warm"):
+        n0 = _n_events()
+        for dev in (d0, d3):
+            prog = cc.build_program("placed", jax.jit(lambda a: a * 2 + 1))
+            out = prog(jax.device_put(jnp.asarray(x), dev))
+            assert out.devices() == {dev}
+            outs[round_, dev.id] = np.asarray(out)
+        hits = [e.get("cache_hit") for e in _events_since(n0)
+                if e.get("fn") == "placed"]
+        assert hits == [round_ == "warm"] * 2, (round_, hits)
+    assert len(_cache_files()) == 2      # one entry per device
+    for dev in (d0, d3):
+        np.testing.assert_array_equal(outs["cold", dev.id],
+                                      outs["warm", dev.id])

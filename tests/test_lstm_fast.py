@@ -216,9 +216,12 @@ class TestDispatchGate:
         monkeypatch.setenv(eng.IMPL_ENV, forced)
         assert eng.resolve_impl(1024, 1024, 64, 256) == (forced, None)
 
-    def test_forced_pallas_on_cpu_degrades_to_fused(self, monkeypatch):
+    def test_forced_pallas_on_cpu_raises(self, monkeypatch):
+        """A request for the kernel never runs something else: without a
+        TPU (and without a test's interpret mode) it is an error."""
         monkeypatch.setenv(eng.IMPL_ENV, "pallas")
-        assert eng.resolve_impl(1024, 1024, 64, 256) == ("fused", None)
+        with pytest.raises(ValueError, match="no TPU"):
+            eng.resolve_impl(1024, 1024, 64, 256)
 
     def test_forced_pallas_engages_under_interpret(self):
         sel, bt = eng.resolve_impl(H, T, B, F, impl="pallas", interpret=True)
@@ -252,14 +255,20 @@ class TestDispatchGate:
         """The (hidden, seq, batch)-keyed feasibility half of the gate:
         hidden=1024 f32 puts W+dW alone at ~67MB, over any real budget."""
         monkeypatch.setenv("DL4J_LSTM_VMEM_BUDGET", str(1024))
-        assert eng.resolve_impl(8, 16, 2, 4, impl="pallas",
+        with pytest.raises(ValueError, match="VMEM"):
+            eng.resolve_impl(8, 16, 2, 4, impl="pallas", interpret=True)
+        # the same refusal under auto is a choice, not an error
+        monkeypatch.setenv("DL4J_LSTM_PALLAS_MIN_HIDDEN", "8")
+        assert eng.resolve_impl(8, 16, 2, 4, impl="auto",
                                 interpret=True) == ("fused", None)
 
     def test_nonstandard_activation_rules_out_pallas(self):
-        assert eng.resolve_impl(H, 16, B, F, impl="pallas", interpret=True,
-                                act_name="relu") == ("fused", None)
-        assert eng.resolve_impl(H, 16, B, F, impl="pallas", interpret=True,
-                                gate_name="hardsigmoid") == ("fused", None)
+        for kw in ({"act_name": "relu"}, {"gate_name": "hardsigmoid"}):
+            with pytest.raises(ValueError, match="tanh/sigmoid"):
+                eng.resolve_impl(H, 16, B, F, impl="pallas", interpret=True,
+                                 **kw)
+            assert eng.resolve_impl(H, 16, B, F, impl="auto", interpret=True,
+                                    **kw) == ("fused", None)
 
     def test_unknown_impl_raises(self):
         with pytest.raises(ValueError):

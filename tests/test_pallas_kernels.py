@@ -171,8 +171,8 @@ def test_masked_attention_pallas_matches_xla(causal):
 
 
 def test_fused_xent_loss_path_matches_xla():
-    """mcxent through the fused Pallas softmax-xent custom_vjp (forced via
-    DL4J_FUSED_XENT=1, interpret on CPU) must match the XLA autodiff path in
+    """mcxent through the fused Pallas softmax-xent custom_vjp (asked for via
+    DL4J_XENT_INTERPRET=1, interpret on CPU) must match the XLA autodiff path in
     value AND gradient, including masked time-series input — this is the
     production wiring of ops/pallas_kernels.softmax_cross_entropy."""
     import os
@@ -206,10 +206,12 @@ def test_fused_xent_loss_path_matches_xla():
         try:
             os.environ["DL4J_FUSED_XENT"] = "0"
             v_xla, g_xla = run()
-            os.environ["DL4J_FUSED_XENT"] = "1"
+            del os.environ["DL4J_FUSED_XENT"]
+            os.environ["DL4J_XENT_INTERPRET"] = "1"
             v_fused, g_fused = run()
         finally:
             os.environ.pop("DL4J_FUSED_XENT", None)
+            os.environ.pop("DL4J_XENT_INTERPRET", None)
         assert abs(v_xla - v_fused) < 1e-5, (v_xla, v_fused)
         np.testing.assert_allclose(g_fused, g_xla, rtol=1e-4, atol=1e-6)
 
@@ -225,7 +227,7 @@ def test_fused_xent_falls_back_under_shard_map():
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from deeplearning4j_tpu.jax_compat import shard_map
+    from jax import shard_map
 
     from deeplearning4j_tpu.ops import losses
     from deeplearning4j_tpu.parallel.mesh import build_mesh
@@ -239,16 +241,18 @@ def test_fused_xent_falls_back_under_shard_map():
         return losses.mcxent(yy, xx, jax.nn.softmax)[None]
 
     try:
-        os.environ["DL4J_FUSED_XENT"] = "1"
+        os.environ["DL4J_XENT_INTERPRET"] = "1"
         per_shard = jax.jit(shard_map(
             local_loss, mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=P("data")))(x, y)
+        del os.environ["DL4J_XENT_INTERPRET"]
         os.environ["DL4J_FUSED_XENT"] = "0"
         expect = jax.jit(shard_map(
             local_loss, mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=P("data")))(x, y)
     finally:
         os.environ.pop("DL4J_FUSED_XENT", None)
+        os.environ.pop("DL4J_XENT_INTERPRET", None)
     np.testing.assert_allclose(np.asarray(per_shard), np.asarray(expect),
                                rtol=1e-5)
 
@@ -261,7 +265,7 @@ def test_flash_attention_falls_back_under_checked_shard_map():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from deeplearning4j_tpu.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deeplearning4j_tpu.ops import pallas_kernels as pk
@@ -300,7 +304,7 @@ def test_fused_xent_integrations_bf16_and_lbfgs():
     x = rng.normal(size=(32, 6)).astype(np.float32)
     y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, 32)]
     try:
-        os.environ["DL4J_FUSED_XENT"] = "1"
+        os.environ["DL4J_XENT_INTERPRET"] = "1"
         conf = (NeuralNetConfiguration.builder().seed(0).learning_rate(0.1)
                 .dtype("bfloat16_full")
                 .list()
@@ -329,7 +333,7 @@ def test_fused_xent_integrations_bf16_and_lbfgs():
             net2.fit(x, y)
         assert net2.score_value <= s0
     finally:
-        os.environ.pop("DL4J_FUSED_XENT", None)
+        os.environ.pop("DL4J_XENT_INTERPRET", None)
 
 
 def test_pick_blk_divisor_fallback():
@@ -378,7 +382,7 @@ def test_force_pallas_bypasses_length_gate_not_hard_constraints(monkeypatch):
     vma-checked shard_map guard (pallas_call is rejected there outright)."""
     from jax.sharding import PartitionSpec as P
 
-    from deeplearning4j_tpu.jax_compat import shard_map
+    from jax import shard_map
     from deeplearning4j_tpu.ops import pallas_kernels as pk
     from deeplearning4j_tpu.parallel.mesh import build_mesh
 

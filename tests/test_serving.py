@@ -118,9 +118,12 @@ def test_predict_fn_isolated_from_training_donation():
     assert np.array_equal(np.asarray(pf(x)), before)
 
 
-# ------------------------------------------------- bitwise batch semantics
-def test_batched_padded_output_bitwise_equals_per_request():
-    """Across bucket boundaries: coalesced+padded == served alone."""
+# ------------------------------------------------- padded batch semantics
+def test_batched_padded_output_equals_per_request():
+    """Across bucket boundaries: coalesced+padded == served alone, to
+    float32 rounding (XLA's kernels are not batch-size invariant on JAX
+    0.9: a row alone and the same row in a bucket of 4 may differ in the
+    last ulp) — and padding itself is bitwise semantics-free."""
     net = _mlp()
     registry = ModelRegistry()
     mv = registry.register("m", net, version="v1")
@@ -141,8 +144,22 @@ def test_batched_padded_output_bitwise_equals_per_request():
             # coalesced into one padded dispatch — the property under test
             assert max(o["batch_rows"] for o in outs) > 1
         for o, ref in zip(outs, refs):
-            assert np.array_equal(np.asarray(o["predictions"]), ref), \
-                f"bitwise mismatch at k={k}"
+            got = np.asarray(o["predictions"])
+            if o["batch_rows"] == 1:
+                assert np.array_equal(got, ref), f"bitwise mismatch at k={k}"
+            np.testing.assert_allclose(got, ref, rtol=4e-6, atol=1e-7,
+                                       err_msg=f"mismatch at k={k}")
+    # padding adds nothing: 3 rows padded to the bucket of 4 are bitwise the
+    # first 3 rows of the same 4-row batch with the zero row written out
+    x3 = _x(rng, 3)
+    batcher = MicroBatcher(registry, max_batch=boundary, max_latency_s=0.001)
+    try:
+        got = np.asarray(batcher.submit("m", x3).result(timeout=30)
+                         ["predictions"])
+    finally:
+        batcher.close()
+    x4 = np.concatenate([x3, np.zeros_like(x3[:1])])
+    assert np.array_equal(got, np.asarray(mv.predict_fn(x4))[:3])
 
 
 # ------------------------------------------------------ bounded compile cache

@@ -88,10 +88,16 @@ def test_predict_roundtrip_and_status(server):
     out = json.loads(body)
     assert np.asarray(out["predictions"]).shape == (3, N_OUT)
     assert out["model"] == "mlp" and out["version"] == "v1"
-    # per-request vs HTTP-batched: same numbers end to end
-    ref = np.asarray(server.registry.active("mlp").predict_fn(x))
-    assert np.array_equal(np.asarray(out["predictions"], np.float32),
-                          ref.astype(np.float32))
+    # per-request vs HTTP-batched: same numbers end to end. The 3 rows ride
+    # the bucket of 4: bitwise the same 4-row batch with the zero row written
+    # out, and equal to the unpadded 3-row dispatch to float32 rounding
+    # (nn/inference.py, "The serving equality contract")
+    got = np.asarray(out["predictions"], np.float32)
+    predict = server.registry.active("mlp").predict_fn
+    padded = np.concatenate([x, np.zeros_like(x[:1])])
+    assert np.array_equal(got, np.asarray(predict(padded), np.float32)[:3])
+    np.testing.assert_allclose(got, np.asarray(predict(x), np.float32),
+                               rtol=4e-6, atol=1e-7)
 
     status, body = _get(server.port, "/serve/status")
     st = json.loads(body)

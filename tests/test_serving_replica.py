@@ -2,9 +2,12 @@
 
 Pinned contracts:
 - a ``sharding="dp_tp"`` PredictFn on the 8-device virtual mesh is
-  **bitwise-identical** to the single-device program at every batch size,
-  including batches the data axis doesn't divide (gather-at-use: the params
-  shard at rest, the compute keeps the single-device reduction order);
+  **bitwise-identical** to the single-device program applied to each
+  device's slice of the batch, at every batch size including ones the data
+  axis doesn't divide (gather-at-use: the params shard at rest, no
+  cross-device arithmetic), and equal to it on the whole batch to float32
+  rounding — XLA's kernels are not batch-size invariant on JAX 0.9
+  (nn/inference.py, "The serving equality contract");
 - the per-device resident bytes really drop (shard check on the weight
   buffers) and the ``dl4j_sharded_param_bytes_per_device`` gauge agrees
   with ``partition.per_device_bytes``;
@@ -93,7 +96,14 @@ def test_sharded_predict_bitwise_and_per_device_bytes():
         x = rng.normal(size=(n, N_IN)).astype(np.float32)
         a, b = np.asarray(ref(x)), np.asarray(pf(x))
         assert a.shape == (n, N_OUT)
-        assert np.array_equal(a, b), f"sharded output drifted at batch {n}"
+        # bitwise against the single-device program on each device's slice
+        split = partition.batch_spec(mesh, n) != partition.pspec()
+        per = n // 4 if split else n
+        sliced = np.concatenate([np.asarray(ref(x[i:i + per]))
+                                 for i in range(0, n, per)])
+        assert np.array_equal(sliced, b), f"sharded output drifted at batch {n}"
+        # and to float32 rounding against it on the whole batch
+        np.testing.assert_allclose(b, a, rtol=4e-6, atol=1e-7)
     # the params really live split: the 16x32 weight holds half its bytes
     # per device on the model=2 axis
     import jax

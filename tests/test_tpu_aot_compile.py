@@ -1,0 +1,247 @@
+"""The TPU compiler's verdict on every Pallas kernel, without a TPU.
+
+The suite runs on a forced-CPU mesh, where the kernels only ever execute in
+interpret mode — which cannot see what Mosaic refuses (an unaligned slice of
+a packed dtype, a gather, a block that overflows scoped VMEM). libtpu is
+installed, though, and compiles for a *described* ``v5e:2x2`` topology. Each
+case below either compiles a kernel at a real shape and finds the
+``tpu_custom_call`` in the program, or asserts that the kernel's own gate
+refuses the shape — so a default path that does not lower is caught here,
+at no chip time. Nothing runs; a compile that passes is not a chip run.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.ops import lstm as lstm_engine
+from deeplearning4j_tpu.ops import paged_attention, quant
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described (not attached) v5e device, persistent cache off: a
+    compile for a described chip is written to the cache but cannot be read
+    back without one, and the next run would warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this host
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _gates_see_a_tpu(monkeypatch):
+    """The gates ask for the default device's platform, which is this
+    host's CPU; the question here is what they decide on the chip."""
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+
+
+def _n_kernels(chip, f, *shapes):
+    """Compile ``f`` for the described chip; -> number of Pallas kernels in
+    the program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    return jax.jit(f).lower(*args).compile().as_text().count(
+        '"tpu_custom_call"')
+
+
+# ------------------------------------------------------------------- cases
+def _flash(T, dtype, grad):
+    qkv = [((2, T, 8, 64), dtype)] * 3          # B*H = 16, D = 64
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, True)
+
+    def bwd(q, k, v):
+        # the loss value keeps the forward alive where only the chunked
+        # XLA backward follows it
+        return jax.value_and_grad(lambda *a: fwd(*a).astype(F32).sum(),
+                                  argnums=(0, 1, 2))(q, k, v)
+
+    # forward kernel; under grad it is joined by dq + dkv from _PBWD_MIN_SEQ
+    want = (3 if T >= pk._PBWD_MIN_SEQ else 1) if grad else 1
+    return (bwd if grad else fwd), qkv, want
+
+
+def _masked(grad):
+    T = 4096
+    shapes = [((2, T, 8, 64), BF16)] * 3 + [((2, T), F32)]
+
+    def fwd(q, k, v, m):
+        return pk.masked_attention(q, k, v, m)
+
+    def bwd(q, k, v, m):
+        return jax.grad(lambda *a: fwd(*a, m).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), shapes, (3 if grad else 1)
+
+
+def _xent(N, C, dtype):
+    if pk._xent_rows(N, C, dtype) is None:
+        return None
+    return (lambda a, b: pk.softmax_cross_entropy(a, b),
+            [((N, C), dtype)] * 2, 1)
+
+
+def _lstm(dtype, grad, B=32, T=56, F=64, H=512):
+    """char-RNN layer 0 at hidden 512: TBPTT 50 padded to the 8-step block,
+    GravesLSTM peepholes on."""
+    sel, bt = lstm_engine.resolve_impl(H, T, B, F, dtype=dtype, impl="auto")
+    if sel != "pallas":
+        return None
+    shapes = [((T, B, F), dtype), ((F + H, 4 * H), dtype), ((1, 4 * H), dtype),
+              ((3, H), dtype), ((B, H), dtype), ((B, H), dtype),
+              ((T, B, 1), dtype)]
+
+    def fwd(*a):
+        return lstm_engine._pallas_lstm(bt, True, False, *a)
+
+    def bwd(*a):
+        return jax.grad(lambda *d: fwd(*d, *a[4:])[0].astype(F32).sum(),
+                        argnums=(0, 1, 2, 3))(*a[:4])
+
+    return (bwd if grad else fwd), shapes, (2 if grad else 1)
+
+
+def _paged():
+    # 16 slots x 2048-token ceiling in 16-token pages, 8 heads of 64
+    pool, table = ((2049, 16, 8, 64), BF16), ((16, 128), jnp.int32)
+    return (lambda p, t: paged_attention.paged_gather(p, t),
+            [pool, table], 1)
+
+
+def _int8():
+    # one decode step's FFN matmul at width 512: [slots, 512] x [512, 2048]
+    leaf = quant.QuantizedLeaf(jnp.zeros((512, 2048), jnp.int8),
+                               jnp.ones((2048,), F32))
+    assert quant._pallas_int8_ok(jnp.zeros((16, 512)), leaf, False)
+    return (lambda x, q, s: quant._int8_matmul_pallas(x, q, s),
+            [((16, 512), F32), ((512, 2048), jnp.int8), ((2048,), F32)], 1)
+
+
+CASES = {
+    **{f"flash-{'grad' if g else 'fwd'}-T{T}-{jnp.dtype(d).name}":
+       (_flash, (T, d, g))
+       for g in (False, True)
+       for T, d in ((1024, BF16), (4096, BF16), (16384, BF16), (16384, F32))},
+    "masked-fwd-T4096-bfloat16": (_masked, (False,)),
+    "masked-grad-T4096-bfloat16": (_masked, (True,)),
+    **{f"xent-N{N}-C{C}-{jnp.dtype(d).name}": (_xent, (N, C, d))
+       for N, C, d in ((128, 10, F32), (4096, 1000, BF16),
+                       (4096, 32000, BF16), (2048, 50257, F32),
+                       (2048, 50257, BF16))},
+    **{f"lstm-{'grad' if g else 'fwd'}-H512-{jnp.dtype(d).name}":
+       (_lstm, (d, g)) for g in (False, True) for d in (BF16, F32)},
+    "paged-gather": (_paged, ()),
+    "int8-matmul": (_int8, ()),
+}
+
+#: shapes the gates refuse, with the reason each gate gives from the shape
+REFUSED = {
+    "xent-N2048-C50257-bfloat16",   # a 16-row bf16 block of 50,304 lanes
+    "lstm-fwd-H512-float32",        # W + f32 dW alone are 9.4 MiB
+    "lstm-grad-H512-float32",
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e_or_gate_refuses(name, chip):
+    build, args = CASES[name]
+    case = build(*args)
+    if name in REFUSED:
+        assert case is None, f"{name}: the gate was expected to refuse"
+        return
+    assert case is not None, f"{name}: the gate refused a shape it admitted"
+    f, shapes, want = case
+    assert _n_kernels(chip, f, *shapes) == want
+
+
+def test_lstm_refusal_names_the_shape():
+    why = lstm_engine.pallas_refusal(512, 56, 32, 64, dtype=F32)
+    assert why is not None and "VMEM" in why and "hidden 512" in why
+    assert lstm_engine.pallas_refusal(512, 56, 32, 64, dtype=BF16) is None
+    with pytest.raises(ValueError, match="VMEM"):
+        lstm_engine.resolve_impl(512, 56, 32, 64, dtype=F32, impl="pallas")
+
+
+def test_partitioned_jit_leaves_kernels_to_xla_and_compiles_for_four_chips(chip):
+    """GSPMD cannot partition a Mosaic kernel — the TPU lowering refuses the
+    whole program — so under compile_seam's "jit" strategy over more than one
+    device every gate says no, unless a shard_map body holds the call. The
+    sync-DP step of a classifier (whose loss is the fused xent kernel on one
+    chip) must then compile for four described chips: an all-reduce, no
+    kernel."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.nn.conf.builders import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    assert pk.use_pallas()
+    with pk.partitioned_trace(4):
+        assert "GSPMD" in pk.pallas_unavailable()
+        assert "GSPMD" in lstm_engine.pallas_refusal(512, 56, 32, 64,
+                                                     dtype=BF16)
+    from jax.experimental import topologies
+    mesh = Mesh(np.array(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices), ("data",))
+    seen = {}
+
+    def probe(x):
+        with pk.partitioned_trace(4):
+            seen["jit"] = pk.use_pallas()
+
+            def body(y):
+                seen["shard_map"] = pk.use_pallas()
+                return y
+
+            return jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                 out_specs=P("data"))(x)
+
+    jax.jit(probe).lower(jax.ShapeDtypeStruct(
+        (8,), F32, sharding=NamedSharding(mesh, P("data"))))
+    assert seen == {"jit": False, "shard_map": True}
+
+    net = MultiLayerNetwork(
+        NeuralNetConfiguration.builder().seed(1).list()
+        .layer(DenseLayer(n_in=256, n_out=256, activation="relu"))
+        .layer(OutputLayer(n_in=256, n_out=1000, loss="mcxent",
+                           activation="softmax")).build()).init()
+    pw = ParallelWrapper(net, mesh=mesh)
+    pw._drop_stale_programs()
+    step = pw._make_sync_step()
+    repl, split = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+
+    def abstract(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=sharding), tree)
+
+    hlo = step.fn._jitted.lower(
+        abstract(net.params_list, repl), abstract(net.state_list, repl),
+        abstract(net.updater_state, repl),
+        jax.ShapeDtypeStruct((512, 256), F32, sharding=split),
+        jax.ShapeDtypeStruct((512, 1000), F32, sharding=split),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl),
+    ).compile().as_text()
+    assert '"tpu_custom_call"' not in hlo
+    assert " all-reduce(" in hlo or " all-reduce-start(" in hlo
