@@ -1,0 +1,6 @@
+"""memory_stats()["peak_bytes_in_use"] of the fullest chip after the window."""
+
+
+def read(ctx):
+    peak = ctx["device"]["memory_peak_bytes"]
+    return peak / 1e9 if peak else None
