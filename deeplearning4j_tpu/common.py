@@ -182,6 +182,9 @@ def wrap_with_policy(fn, name: str | None):
     def wrapped(*args, **kwargs):
         with override_policy(name):
             return fn(*args, **kwargs)
+    # jax.jit names the compiled module after the function it is given: keep
+    # ``fn``'s name, so a profile shows the program and not "jit_wrapped"
+    wrapped.__name__ = getattr(fn, "__name__", wrapped.__name__)
     return wrapped
 
 

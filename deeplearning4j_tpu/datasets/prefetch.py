@@ -28,16 +28,29 @@ Bounded depth (default 2 = double buffering) caps HBM held by staged batches
 at ``depth * group_bytes``; depth <= 0 degrades to synchronous inline staging
 (the pre-prefetch behavior, used by the numerical-equivalence tests and the
 bench A/B).
+
+Spans. Every staged item gets a process-wide sequence number, its ``group``,
+when the producer starts to pull it; the number travels with the item through
+the queue, and ``current_group()`` answers it on whichever thread is working
+on the item (the producer while it pulls and stages, the fit loop from the
+moment it receives it). The prefetcher writes ``input.pull`` and ``fit.wait``
+into the flight recorder's ring; the stage function writes its own
+(``nn.multilayer.stage_group``: ``input.stack``, ``input.cast``,
+``input.h2d``), and the dispatch its ``fit.dispatch``, all under that number.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
 from typing import Callable, Iterable, Optional
 
+from deeplearning4j_tpu.observability.flight_recorder import (
+    global_recorder as _flight_recorder,
+)
 from deeplearning4j_tpu.observability.names import (
-    PREFETCH_BYTES_TOTAL, PREFETCH_DEPTH, PREFETCH_OVERLAP_RATIO,
+    PREFETCH_BYTES_TOTAL, PREFETCH_DEPTH,
     PREFETCH_STAGING_SECONDS_TOTAL, PREFETCH_WAIT_SECONDS_TOTAL,
 )
 from deeplearning4j_tpu.observability.metrics import (
@@ -60,12 +73,23 @@ _wait_total = _obs_registry().counter(
     PREFETCH_WAIT_SECONDS_TOTAL,
     "consumer seconds blocked waiting for a staged item (staging NOT hidden "
     "behind dispatch), by fit path")
-_overlap_gauge = _obs_registry().gauge(
-    PREFETCH_OVERLAP_RATIO,
-    "1 - wait/staging over this prefetcher's lifetime: fraction of staging "
-    "time hidden behind dispatch (1.0 = fully overlapped)")
 
 _DONE = object()  # queue sentinel: producer finished (or was stopped)
+
+_group_seq = itertools.count()  # next() is one bytecode: atomic under the GIL
+_working_on = threading.local()
+
+
+def begin_group() -> int:
+    """A new group number, made this thread's current one."""
+    _working_on.group = group = next(_group_seq)
+    return group
+
+
+def current_group() -> Optional[int]:
+    """The staged item this thread is working on (module docstring), or None
+    where the thread has touched none."""
+    return getattr(_working_on, "group", None)
 
 
 class DevicePrefetcher:
@@ -84,7 +108,8 @@ class DevicePrefetcher:
     ``wait_series``: optional histogram series (e.g. the fit loops'
     ``dl4j_fit_phase_seconds{phase="staging"}``) observing what the consumer
     actually waited per item — under working overlap it collapses toward 0.
-    ``path=None`` disables all metrics (host-only use, AsyncDataSetIterator).
+    ``path=None`` disables all metrics and spans (host-only use,
+    AsyncDataSetIterator).
     """
 
     def __init__(self, source: Iterable, stage: Optional[Callable] = None,
@@ -94,20 +119,18 @@ class DevicePrefetcher:
         self._stage = stage
         self._depth = depth
         self._wait_series = wait_series
+        self._path = path
         if path is not None:
             self._m_depth = _depth_gauge.labels(path=path)
             self._m_bytes = _bytes_total.labels(path=path)
             self._m_staging = _staging_total.labels(path=path)
             self._m_wait = _wait_total.labels(path=path)
-            self._m_overlap = _overlap_gauge.labels(path=path)
         else:
-            self._m_depth = self._m_bytes = self._m_staging = None
-            self._m_wait = self._m_overlap = None
+            self._m_depth = self._m_bytes = None
+            self._m_staging = self._m_wait = None
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
         self._error: Optional[BaseException] = None
-        self._staged_s = 0.0  # producer-side total (GIL-atomic float adds)
-        self._wait_s = 0.0
         self.thread: Optional[threading.Thread] = None
 
     # ---------------------------------------------------------------- producer
@@ -123,25 +146,36 @@ class DevicePrefetcher:
                 continue
         return False
 
+    def _produce(self, it):
+        """Pull the next item and stage it on the calling thread, under a
+        new group number. Returns ``(group, item, seconds)``; the item is
+        ``_DONE`` at the source's end."""
+        group = begin_group()
+        t0, w0 = time.perf_counter(), time.time_ns()
+        try:
+            item = next(it)
+        except StopIteration:
+            return group, _DONE, 0.0
+        w1 = time.time_ns()
+        if self._stage is not None:
+            item = self._stage(item)
+        dt = time.perf_counter() - t0
+        if self._path is not None:
+            _flight_recorder().record_span("input.pull", w0, w1, group=group,
+                                           path=self._path)
+        if self._m_staging is not None:
+            self._m_staging.inc(dt)
+            nbytes = _tree_nbytes(item)
+            if nbytes:
+                self._m_bytes.inc(nbytes)
+        return group, item, dt
+
     def _run(self) -> None:
         try:
             it = iter(self._source)
             while not self._stop.is_set():
-                t0 = time.perf_counter()
-                try:
-                    item = next(it)
-                except StopIteration:
-                    break
-                if self._stage is not None:
-                    item = self._stage(item)
-                dt = time.perf_counter() - t0
-                self._staged_s += dt
-                if self._m_staging is not None:
-                    self._m_staging.inc(dt)
-                    nbytes = _tree_nbytes(item)
-                    if nbytes:
-                        self._m_bytes.inc(nbytes)
-                if not self._put(item):
+                group, item, _ = self._produce(it)
+                if item is _DONE or not self._put((group, item)):
                     return
                 if self._m_depth is not None:
                     self._m_depth.set(self._q.qsize())
@@ -162,20 +196,21 @@ class DevicePrefetcher:
         self.thread.start()
         try:
             while True:
-                t0 = time.perf_counter()
-                item = self._q.get()
+                t0, w0 = time.perf_counter(), time.time_ns()
+                got = self._q.get()
                 wait = time.perf_counter() - t0
-                if item is _DONE:
+                if got is _DONE:
                     if self._error is not None:
                         raise self._error
                     return
-                self._wait_s += wait
+                group, item = got
+                _working_on.group = group
                 if self._m_wait is not None:
+                    _flight_recorder().record_span(
+                        "fit.wait", w0, time.time_ns(), group=group,
+                        path=self._path)
                     self._m_wait.inc(wait)
                     self._m_depth.set(self._q.qsize())
-                    if self._staged_s > 0.0:
-                        self._m_overlap.set(max(0.0, min(1.0,
-                            1.0 - self._wait_s / self._staged_s)))
                 if self._wait_series is not None:
                     self._wait_series.observe(wait)
                 yield item
@@ -183,18 +218,18 @@ class DevicePrefetcher:
             self.close()
 
     def _iter_sync(self):
-        """depth <= 0: the exact pre-prefetch behavior — stage inline on the
-        consumer thread, full staging cost visible in ``wait_series``."""
-        for item in self._source:
-            t0 = time.perf_counter()
-            if self._stage is not None:
-                item = self._stage(item)
-            dt = time.perf_counter() - t0
-            if self._m_staging is not None:
-                self._m_staging.inc(dt)
-                nbytes = _tree_nbytes(item)
-                if nbytes:
-                    self._m_bytes.inc(nbytes)
+        """depth <= 0: the exact pre-prefetch behavior — pull and stage
+        inline on the consumer thread, the full cost visible in
+        ``wait_series`` and as the group's ``fit.wait``."""
+        it = iter(self._source)
+        while True:
+            w0 = time.time_ns()
+            group, item, dt = self._produce(it)
+            if item is _DONE:
+                return
+            if self._path is not None:
+                _flight_recorder().record_span("fit.wait", w0, time.time_ns(),
+                                               group=group, path=self._path)
             if self._wait_series is not None:
                 self._wait_series.observe(dt)
             yield item

@@ -82,6 +82,14 @@ log = logging.getLogger(__name__)
 MAGIC = b"DL4JXC02"
 _DIGEST_LEN = 32
 
+#: part of every fingerprint; bump it when the seams' programs change in a
+#: way that no argument, option or configuration shows. Operation metadata is
+#: such a change: the ``jax.named_scope`` names a profile's reader looks for
+#: are in neither this store's key nor (by default) JAX's own, so an entry
+#: written before the scopes existed would be loaded for the program that has
+#: them, and every scope would read nothing.
+PROGRAM_REV = "named-scopes-1"
+
 _DEFAULT_MAX_MB = 512.0
 
 
@@ -435,7 +443,7 @@ class CachedProgram:
         # donation, specs) arrive via ``extra``; ``pk`` keeps differently
         # placed (sharded) callers on sibling entries.
         try:
-            material = repr((MAGIC, _backend_key(), _trace_env(),
+            material = repr((MAGIC, PROGRAM_REV, _backend_key(), _trace_env(),
                              os.environ.get("DL4J_COMPILE_CACHE_EPOCH", ""),
                              self._fingerprint_name, sig, pk,
                              self._conf_fp, self._extra))

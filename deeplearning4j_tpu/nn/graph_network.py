@@ -22,8 +22,10 @@ import numpy as np
 from deeplearning4j_tpu import common
 from deeplearning4j_tpu.nn.conf.graphconf import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.vertices import LayerVertex
+from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher, begin_group
 from deeplearning4j_tpu.nn.multilayer import (
-    LazyScore, _updater_spec, _t_staging, _t_dispatch, _t_listeners,
+    LazyScore, _stage_host, _updater_spec, _t_staging, _t_dispatch,
+    _t_listeners, stage_group,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -108,15 +110,19 @@ def graph_forward(conf: ComputationGraphConfiguration, params: dict, states: dic
         if (collect_loss_inputs and name in conf.network_outputs
                 and isinstance(vertex, LayerVertex) and vertex.layer.has_loss()):
             loss_inputs[name] = vins[0]
-        if remat and isinstance(vertex, LayerVertex):
-            # jax.checkpoint per layer vertex: backward recomputes this
-            # vertex's forward instead of holding its activations
-            def f(p, vi, _v=vertex, _s=states.get(name, {}), _r=rngs[i]):
-                return _v.apply(p, _s, vi, train=True, rng=_r, mask=mask)
-            y, ns = jax.checkpoint(f)(params.get(name, {}), vins)
-        else:
-            y, ns = vertex.apply(params.get(name, {}), states.get(name, {}),
-                                 vins, train=train, rng=rngs[i], mask=mask)
+        # the vertex's name is in the op_name of everything it traces,
+        # forward and backward (see multilayer._layer_scope)
+        with jax.named_scope(f"layer/{name}"):
+            if remat and isinstance(vertex, LayerVertex):
+                # jax.checkpoint per layer vertex: backward recomputes this
+                # vertex's forward instead of holding its activations
+                def f(p, vi, _v=vertex, _s=states.get(name, {}), _r=rngs[i]):
+                    return _v.apply(p, _s, vi, train=True, rng=_r, mask=mask)
+                y, ns = jax.checkpoint(f)(params.get(name, {}), vins)
+            else:
+                y, ns = vertex.apply(params.get(name, {}),
+                                     states.get(name, {}), vins, train=train,
+                                     rng=rngs[i], mask=mask)
         acts[name] = y
         new_states[name] = ns
         mask_of[name] = mask
@@ -128,16 +134,21 @@ def graph_loss(conf, params, states, inputs, labels, rng, fmasks=None, lmasks=No
     acts, new_states, loss_inputs = graph_forward(
         conf, params, states, inputs, train=True, rng=rng, masks=fmasks,
         collect_loss_inputs=True)
-    total = jnp.float32(0.0)
-    for i, out_name in enumerate(conf.network_outputs):
-        vertex = conf.vertices[out_name]
-        if not (isinstance(vertex, LayerVertex) and vertex.layer.has_loss()):
-            raise ValueError(f"Output vertex '{out_name}' has no loss function")
-        h = loss_inputs[out_name]
-        lmask = lmasks[i] if lmasks else None
-        total = total + vertex.layer.compute_loss(params[out_name], h, labels[i], lmask)
-    total = total + _aux_losses(conf, new_states)
-    return total + _graph_regularization(conf, params), new_states
+    with jax.named_scope("loss"):
+        total = jnp.float32(0.0)
+        for i, out_name in enumerate(conf.network_outputs):
+            vertex = conf.vertices[out_name]
+            if not (isinstance(vertex, LayerVertex)
+                    and vertex.layer.has_loss()):
+                raise ValueError(
+                    f"Output vertex '{out_name}' has no loss function")
+            h = loss_inputs[out_name]
+            lmask = lmasks[i] if lmasks else None
+            total = total + vertex.layer.compute_loss(
+                params[out_name], h, labels[i], lmask)
+        total = total + _aux_losses(conf, new_states)
+        total = total + _graph_regularization(conf, params)
+    return total, new_states
 
 
 def _aux_losses(conf, new_states):
@@ -164,9 +175,10 @@ def _coerce_graph_batch(ds):
     return [ds.features], [ds.labels], fm, lm
 
 
+@jax.named_scope("update")
 def _apply_graph_updates(conf, params, grads, upd_state, iteration):
     """Per-vertex gradient normalization + updater math (shared by the
-    standard and TBPTT train steps)."""
+    standard and TBPTT train steps), traced under the ``update`` scope."""
     g = conf.global_conf
     grads = grads_to_param_dtype(
         grads, {n: {k: params[n][k] for k in gv} for n, gv in grads.items()})
@@ -338,8 +350,8 @@ def make_graph_multistep_train_step(conf: ComputationGraphConfiguration, *,
     variant's stacked ``(K, 4)`` summary output."""
     step = make_graph_train_step(conf, health=health)
 
-    def multi_step(params, states, upd_state, inputs_stack, labels_stack,
-                   rng, iteration0):
+    def dl4j_train_ksteps(params, states, upd_state, inputs_stack,
+                          labels_stack, rng, iteration0):
         def body(carry, batch):
             p, s, u, it = carry
             xs, ys = batch
@@ -358,7 +370,8 @@ def make_graph_multistep_train_step(conf: ComputationGraphConfiguration, *,
             return p, s, u, losses, hauxs
         return p, s, u, out
 
-    return multi_step
+    # the compiled module's name, as in make_multistep_train_step
+    return dl4j_train_ksteps
 
 
 def _ancestor_set(conf, target: str) -> set:
@@ -569,8 +582,6 @@ class ComputationGraph(LazyScore):
     def _fit_repeated(self, xs, ys, epochs: int) -> None:
         """Repeated steps on one device-resident multi-IO batch, K per
         dispatch (see MultiLayerNetwork._fit_repeated)."""
-        from deeplearning4j_tpu.nn.multilayer import _stage_host
-
         with _t_staging.time():
             xd = [jnp.asarray(_stage_host(a, self.stage_dtype)) for a in xs]
             yd = [jnp.asarray(a) for a in ys]
@@ -580,13 +591,9 @@ class ComputationGraph(LazyScore):
             k = min(self.dispatch_ksteps, remaining)
             xk = [jnp.broadcast_to(a[None], (k,) + a.shape) for a in xd]
             yk = [jnp.broadcast_to(a[None], (k,) + a.shape) for a in yd]
+            begin_group()
             losses = self._run_multistep(xk, yk, k)
-            with _t_listeners.time():
-                for i in range(k):
-                    self.iteration += 1
-                    self.score_value = (lambda ls=losses, j=i: ls[j])
-                    for listener in self.listeners:
-                        listener.iteration_done(self, self.iteration)
+            self._run_listeners(losses, k)
             _wd_beat(self.iteration)
             remaining -= k
 
@@ -634,8 +641,6 @@ class ComputationGraph(LazyScore):
             self.epoch += 1
 
     def _fit_epoch_multistep(self, iterator, k: int) -> None:
-        from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher
-        from deeplearning4j_tpu.nn.multilayer import _stage_host
         from deeplearning4j_tpu.utils.batching import k_step_groups
 
         def to_batch(ds):
@@ -651,12 +656,7 @@ class ComputationGraph(LazyScore):
             kind, item = kind_item
             if kind != "group" or len(item) < 2:
                 return kind_item
-            n_in, n_out = len(item[0][0]), len(item[0][1])
-            xs = [jax.device_put(_stage_host(
-                      np.stack([b[0][i] for b in item]), self.stage_dtype))
-                  for i in range(n_in)]
-            ys = [jax.device_put(np.stack([b[1][i] for b in item]))
-                  for i in range(n_out)]
+            xs, ys = stage_group(item, self.stage_dtype)
             return "staged", (xs, ys, len(item))
 
         pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
@@ -679,16 +679,9 @@ class ComputationGraph(LazyScore):
         if len(batches) == 1:
             self._fit_batch(batches[0][0], batches[0][1])
             return
-        n_in, n_out = len(batches[0][0]), len(batches[0][1])
-
-        from deeplearning4j_tpu.nn.multilayer import _stage_host
-
+        begin_group()
         with _t_staging.time():
-            xs = [jnp.asarray(_stage_host(np.stack([b[0][i] for b in batches]),
-                                          self.stage_dtype))
-                  for i in range(n_in)]
-            ys = [jnp.asarray(np.stack([b[1][i] for b in batches]))
-                  for i in range(n_out)]
+            xs, ys = stage_group(batches, self.stage_dtype)
         self._dispatch_staged(xs, ys, len(batches))
 
     def _dispatch_staged(self, xs, ys, n: int) -> None:
@@ -698,12 +691,7 @@ class ComputationGraph(LazyScore):
         # MultiLayerNetwork._dispatch_staged)
         self.last_batch_size = int(xs[0].shape[1]) if xs else 0
         losses = self._run_multistep(xs, ys, n)
-        with _t_listeners.time():
-            for i in range(n):
-                self.iteration += 1
-                self.score_value = (lambda ls=losses, j=i: ls[j])
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration)
+        self._run_listeners(losses, n)
         _wd_beat(self.iteration)
 
     #: Solver facade instance when optimization_algo != SGD (built lazily)

@@ -26,6 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu import common
+from deeplearning4j_tpu.datasets.prefetch import (
+    DevicePrefetcher, begin_group, current_group,
+)
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
 )
@@ -97,6 +100,13 @@ def _regularization(conf: MultiLayerConfiguration, params_list) -> Array:
     return total
 
 
+def _layer_scope(index: int, layer) -> str:
+    """The ``jax.named_scope`` of one layer's work: it is in the ``op_name``
+    of every operation the layer traces, forward and (inside JAX's
+    ``transpose(jvp(...))``) backward, so device time can be read by layer."""
+    return f"layer/{index}_{type(layer).__name__}"
+
+
 def forward_fn(conf: MultiLayerConfiguration, params_list, state_list, x, *,
                train: bool, rng: Optional[jax.Array], mask: Optional[Array] = None,
                collect: bool = False):
@@ -109,10 +119,11 @@ def forward_fn(conf: MultiLayerConfiguration, params_list, state_list, x, *,
             if rng is not None else [None] * len(conf.layers))
     for i, layer in enumerate(conf.layers):
         pp = conf.preprocessor(i)
-        if pp is not None:
-            h = pp.pre_process(h, mask)
-        h, ns = layer.apply(params_list[i], state_list[i], h,
-                            train=train, rng=rngs[i], mask=mask)
+        with jax.named_scope(_layer_scope(i, layer)):
+            if pp is not None:
+                h = pp.pre_process(h, mask)
+            h, ns = layer.apply(params_list[i], state_list[i], h,
+                                train=train, rng=rngs[i], mask=mask)
         new_states.append(ns)
         if collect:
             acts.append(h)
@@ -139,24 +150,28 @@ def loss_fn(conf: MultiLayerConfiguration, params_list, state_list, x, y, rng,
             if rng is not None else [None] * len(layers))
     for i, layer in enumerate(layers[:-1]):
         pp = conf.preprocessor(i)
-        if pp is not None:
-            h = pp.pre_process(h, fmask)
-        if remat:
-            def f(p, hh, _layer=layer, _s=state_list[i], _r=rngs[i]):
-                return _layer.apply(p, _s, hh, train=True, rng=_r, mask=fmask)
-            h, ns = jax.checkpoint(f)(params_list[i], h)
-        else:
-            h, ns = layer.apply(params_list[i], state_list[i], h,
-                                train=True, rng=rngs[i], mask=fmask)
+        with jax.named_scope(_layer_scope(i, layer)):
+            if pp is not None:
+                h = pp.pre_process(h, fmask)
+            if remat:
+                def f(p, hh, _layer=layer, _s=state_list[i], _r=rngs[i]):
+                    return _layer.apply(p, _s, hh, train=True, rng=_r,
+                                        mask=fmask)
+                h, ns = jax.checkpoint(f)(params_list[i], h)
+            else:
+                h, ns = layer.apply(params_list[i], state_list[i], h,
+                                    train=True, rng=rngs[i], mask=fmask)
         new_states.append(ns)
     pp = conf.preprocessor(len(layers) - 1)
-    if pp is not None:
-        h = pp.pre_process(h, fmask)
-    h = last.apply_dropout(h, rngs[-1], True)
-    loss = last.compute_loss(params_list[-1], h, y, lmask)
-    new_states.append(state_list[-1])
-    loss = loss + _aux_losses(layers, new_states)
-    return loss + _regularization(conf, params_list), new_states
+    with jax.named_scope("loss"):
+        if pp is not None:
+            h = pp.pre_process(h, fmask)
+        h = last.apply_dropout(h, rngs[-1], True)
+        loss = last.compute_loss(params_list[-1], h, y, lmask)
+        new_states.append(state_list[-1])
+        loss = loss + _aux_losses(layers, new_states)
+        loss = loss + _regularization(conf, params_list)
+    return loss, new_states
 
 
 def _aux_losses(layers, new_states):
@@ -168,6 +183,39 @@ def _aux_losses(layers, new_states):
         if isinstance(ns, dict) and "aux_loss" in ns:
             total = total + getattr(layer, "aux_loss_weight", 1.0) * ns["aux_loss"]
     return total
+
+
+def _apply_updates(conf, params_list, grads, upd_state, iteration):
+    """Per-layer gradient normalization + updater math of one train step."""
+    g = conf.global_conf
+    new_params = []
+    new_upd = []
+    for i, layer in enumerate(conf.layers):
+        g_i = grads[i]
+        if not g_i:
+            new_params.append(params_list[i])
+            new_upd.append(upd_state[i])
+            continue
+        g_i = normalize_gradients(g_i, layer.gradient_normalization,
+                                  layer.gradient_normalization_threshold or 1.0)
+        spec = _updater_spec(layer)
+        lr = effective_lr(layer.learning_rate, g.lr_policy, iteration,
+                          g.lr_policy_decay_rate, g.lr_policy_power,
+                          g.lr_policy_steps, g.lr_schedule, g.max_num_iterations)
+        lr_bias = (jnp.float32(layer.bias_learning_rate)
+                   if layer.bias_learning_rate is not None else lr)
+        p_new = {}
+        u_new = {}
+        for name, grad in g_i.items():
+            this_lr = lr_bias if name in ("b", "vb", "beta") else lr
+            step, ustate = updater_step_with_param(
+                spec, grad, params_list[i][name], upd_state[i][name],
+                this_lr, iteration)
+            p_new[name] = params_list[i][name] - step
+            u_new[name] = ustate
+        new_params.append(p_new)
+        new_upd.append(u_new)
+    return new_params, new_upd
 
 
 def make_train_step(conf: MultiLayerConfiguration, loss=None, *,
@@ -196,35 +244,10 @@ def make_train_step(conf: MultiLayerConfiguration, loss=None, *,
         (loss_val, new_states), grads = jax.value_and_grad(
             lambda p: loss(p, state_list, x, y, rng, fmask, lmask),
             has_aux=True)(params_list)
-        grads = grads_to_param_dtype(grads, params_list)
-
-        new_params = []
-        new_upd = []
-        for i, layer in enumerate(conf.layers):
-            g_i = grads[i]
-            if not g_i:
-                new_params.append(params_list[i])
-                new_upd.append(upd_state[i])
-                continue
-            g_i = normalize_gradients(g_i, layer.gradient_normalization,
-                                      layer.gradient_normalization_threshold or 1.0)
-            spec = _updater_spec(layer)
-            lr = effective_lr(layer.learning_rate, g.lr_policy, iteration,
-                              g.lr_policy_decay_rate, g.lr_policy_power,
-                              g.lr_policy_steps, g.lr_schedule, g.max_num_iterations)
-            lr_bias = (jnp.float32(layer.bias_learning_rate)
-                       if layer.bias_learning_rate is not None else lr)
-            p_new = {}
-            u_new = {}
-            for name, grad in g_i.items():
-                this_lr = lr_bias if name in ("b", "vb", "beta") else lr
-                step, ustate = updater_step_with_param(
-                    spec, grad, params_list[i][name], upd_state[i][name],
-                    this_lr, iteration)
-                p_new[name] = params_list[i][name] - step
-                u_new[name] = ustate
-            new_params.append(p_new)
-            new_upd.append(u_new)
+        with jax.named_scope("update"):
+            grads = grads_to_param_dtype(grads, params_list)
+            new_params, new_upd = _apply_updates(conf, params_list, grads,
+                                                 upd_state, iteration)
         if health:
             from deeplearning4j_tpu.observability.health import health_terms
 
@@ -255,7 +278,8 @@ def make_multistep_train_step(conf: MultiLayerConfiguration, *,
     """
     step = make_train_step(conf, health=health)
 
-    def multi_step(params_list, state_list, upd_state, xs, ys, rng, iteration0):
+    def dl4j_train_ksteps(params_list, state_list, upd_state, xs, ys, rng,
+                          iteration0):
         def body(carry, batch):
             p, s, u, it = carry
             x, y = batch
@@ -273,7 +297,9 @@ def make_multistep_train_step(conf: MultiLayerConfiguration, *,
             return p, s, u, losses, hauxs
         return p, s, u, out
 
-    return multi_step
+    # the function's name is the compiled module's (``jit_dl4j_train_ksteps``):
+    # a profile's reader finds the step program by it
+    return dl4j_train_ksteps
 
 
 def _stage_host(x, dtype):
@@ -286,6 +312,35 @@ def _stage_host(x, dtype):
     if isinstance(x, jax.Array):
         return x.astype(dtype)
     return np.asarray(x).astype(dtype, copy=False)
+
+
+def stage_group(batches: list, dtype):
+    """Stage one K-step group of host batches ``[(features, labels), ...]``
+    (arrays, or for a graph one list of arrays per stream): stack each stream
+    to ``(K, B, ...)``, cast the features to ``dtype`` on the host
+    (``_stage_host``), and hand everything to the device with a
+    ``jax.device_put`` that does not wait for the copy. Returns ``(xs, ys)``.
+
+    Writes the group's three stage spans, ``input.stack``, ``input.cast`` and
+    ``input.h2d`` (the submission, with the ``bytes`` submitted), under the
+    calling thread's ``current_group()``; they follow one another without a
+    gap, so with ``input.pull`` they add up to the staging counter."""
+    tree_map = jax.tree_util.tree_map
+    t0 = time.time_ns()
+    xs = tree_map(lambda *a: np.stack(a), *[b[0] for b in batches])
+    ys = tree_map(lambda *a: np.stack(a), *[b[1] for b in batches])
+    t1 = time.time_ns()
+    xs = tree_map(lambda a: _stage_host(a, dtype), xs)
+    t2 = time.time_ns()
+    nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves((xs, ys)))
+    xs, ys = tree_map(jax.device_put, (xs, ys))
+    t3 = time.time_ns()
+    rec, group = _flight_recorder(), current_group()
+    rec.record_span("input.stack", t0, t1, group=group, cause="input.pull")
+    rec.record_span("input.cast", t1, t2, group=group, cause="input.pull")
+    rec.record_span("input.h2d", t2, t3, group=group, cause="input.pull",
+                    bytes=nbytes)
+    return xs, ys
 
 
 class LazyScore:
@@ -406,10 +461,10 @@ class LazyScore:
             name, type(self)._multistep_builder(self.conf,
                                                 health=due_i is not None),
             donate=(0, 1, 2))
-        t0 = time.perf_counter()
+        t0, t0_ns = time.perf_counter(), time.time_ns()
         out = multi(self.params_list, self.state_list, self.updater_state,
                     xs, ys, self._next_rng(), jnp.int32(self.iteration))
-        dt = time.perf_counter() - t0
+        dt, t1_ns = time.perf_counter() - t0, time.time_ns()
         _t_dispatch.observe(dt)
         _profile_note_dispatch(dt)
         if due_i is None:
@@ -423,10 +478,28 @@ class LazyScore:
             hm.offer(hauxs[due_i], self.iteration + due_i)
         wrap_name = f"{type(self).__name__}.{name}"
         _compile_tracker().note_step(n, fn=wrap_name)
-        _flight_recorder().record(
-            "step", path=wrap_name, it=self.iteration, k=n,
+        # the step event is the group's ``fit.dispatch`` span
+        _flight_recorder().record_span(
+            "fit.dispatch", t0_ns, t1_ns, kind="step", group=current_group(),
+            cause="fit.wait", path=wrap_name, it=self.iteration, k=n,
             batch=self.last_batch_size, dispatch_s=dt)
         return losses
+
+    def _run_listeners(self, losses, n: int) -> None:
+        """Advance the iteration over the ``n`` steps of a dispatched group,
+        each with its lazy score, and call the listeners (shared by both
+        network types): the ``listeners`` phase and the group's
+        ``fit.listeners`` span."""
+        t0_ns = time.time_ns()
+        with _t_listeners.time():
+            for i in range(n):
+                self.iteration += 1
+                self.score_value = (lambda ls=losses, j=i: ls[j])
+                for listener in self.listeners:
+                    listener.iteration_done(self, self.iteration)
+        _flight_recorder().record_span(
+            "fit.listeners", t0_ns, time.time_ns(), group=current_group(),
+            cause="fit.dispatch")
 
 
 class MultiLayerNetwork(LazyScore):
@@ -658,13 +731,9 @@ class MultiLayerNetwork(LazyScore):
             k = min(self.dispatch_ksteps, remaining)
             xs = jnp.broadcast_to(xd[None], (k,) + xd.shape)
             ys = jnp.broadcast_to(yd[None], (k,) + yd.shape)
+            begin_group()
             losses = self._run_multistep(xs, ys, k)
-            with _t_listeners.time():
-                for i in range(k):
-                    self.iteration += 1
-                    self.score_value = (lambda ls=losses, j=i: ls[j])
-                    for listener in self.listeners:
-                        listener.iteration_done(self, self.iteration)
+            self._run_listeners(losses, k)
             _wd_beat(self.iteration)
             remaining -= k
 
@@ -731,7 +800,6 @@ class MultiLayerNetwork(LazyScore):
             self.epoch += 1
 
     def _fit_epoch_multistep(self, iterator, k: int) -> None:
-        from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher
         from deeplearning4j_tpu.utils.batching import k_step_groups
 
         def to_batch(ds):
@@ -748,9 +816,7 @@ class MultiLayerNetwork(LazyScore):
             kind, item = kind_item
             if kind != "group" or len(item) < 2:
                 return kind_item
-            xs = jax.device_put(_stage_host(np.stack([b[0] for b in item]),
-                                            self.stage_dtype))
-            ys = jax.device_put(np.stack([b[1] for b in item]))
+            xs, ys = stage_group(item, self.stage_dtype)
             return "staged", (xs, ys, len(item))
 
         pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
@@ -774,10 +840,9 @@ class MultiLayerNetwork(LazyScore):
         if len(batches) == 1:
             self._fit_batch(batches[0][0], batches[0][1])
             return
+        begin_group()
         with _t_staging.time():
-            xs = jnp.asarray(_stage_host(np.stack([b[0] for b in batches]),
-                                         self.stage_dtype))
-            ys = jnp.asarray(np.stack([b[1] for b in batches]))
+            xs, ys = stage_group(batches, self.stage_dtype)
         self._dispatch_staged(xs, ys, len(batches))
 
     def _dispatch_staged(self, xs, ys, n: int) -> None:
@@ -794,12 +859,7 @@ class MultiLayerNetwork(LazyScore):
         alias a buffer the in-flight step is consuming."""
         self.last_batch_size = int(xs.shape[1])
         losses = self._run_multistep(xs, ys, n)
-        with _t_listeners.time():
-            for i in range(n):
-                self.iteration += 1
-                self.score_value = (lambda ls=losses, j=i: ls[j])
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration)
+        self._run_listeners(losses, n)
         _wd_beat(self.iteration)
 
     #: Solver facade instance when optimization_algo != SGD (built lazily)

@@ -14,7 +14,8 @@ SIGUSR1 — ``dump()`` writes a self-contained diagnostic bundle.
 
 Design constraints:
 
-* **Hot-path cost.** ``record()`` is one dict build + a locked deque append
+* **Hot-path cost.** ``record()`` (and ``record_span()``, its form for a
+  finished interval) is one dict build + a locked deque append
   — no registry traffic, no device syncs, no I/O. Call sites record once
   per *dispatch* (per K-step group), not per iteration. Events must carry
   host values only (ints/floats/strings); recording a device array would
@@ -207,11 +208,27 @@ class FlightRecorder:
         strings) — never device arrays; see the module docstring."""
         if not self._enabled:
             return
-        event = {"kind": kind, "ts": time.time(), **fields}
+        self._append({"kind": kind, "ts": time.time(), **fields})
+
+    def _append(self, event: dict) -> None:
         with self._lock:
             if len(self._events) == self._events.maxlen:
                 self._dropped += 1
             self._events.append(event)
+
+    def record_span(self, name: str, t0_ns: int, t1_ns: int, *,
+                    kind: str = "span", **fields) -> None:
+        """Append one finished span, written once, at its end: ``[t0_ns,
+        t1_ns]`` on ``time.time_ns()``'s clock (``CLOCK_REALTIME``, the clock
+        a profiler trace's ``profile_start_time`` is on, so a reader lays the
+        span over the device's timeline with one addition) and the thread
+        it ran on. ``group`` is the staged K-step group the work belongs to,
+        ``cause`` the name of the span of that group it followed from."""
+        if not self._enabled:
+            return
+        self._append({"kind": kind, "ts": t1_ns * 1e-9, "name": name,
+                      "t0_ns": t0_ns, "t1_ns": t1_ns,
+                      "thread": threading.current_thread().name, **fields})
 
     def snapshot(self) -> List[dict]:
         with self._lock:

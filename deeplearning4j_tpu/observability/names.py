@@ -175,7 +175,6 @@ PREFETCH_DEPTH = "dl4j_prefetch_depth"
 PREFETCH_BYTES_TOTAL = "dl4j_prefetch_bytes_total"
 PREFETCH_STAGING_SECONDS_TOTAL = "dl4j_prefetch_staging_seconds_total"
 PREFETCH_WAIT_SECONDS_TOTAL = "dl4j_prefetch_wait_seconds_total"
-PREFETCH_OVERLAP_RATIO = "dl4j_prefetch_overlap_ratio"
 
 #: every registered name, sorted by constant name; the lint rule parses
 #: this module statically, this tuple is for runtime consumers (tests,

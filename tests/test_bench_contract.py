@@ -200,6 +200,11 @@ def test_telemetry_overhead_budget():
                 total += s["count"] if "count" in s else max(s["value"], 1.0)
         return total
 
+    from deeplearning4j_tpu.observability.flight_recorder import (
+        FlightRecorder, global_recorder,
+    )
+
+    global_recorder().clear()
     before = _mutation_count(global_registry())
     n_steps = 12
     data = [DataSet(x, y) for _ in range(n_steps)]
@@ -214,9 +219,15 @@ def test_telemetry_overhead_budget():
     ops_per_step += 2 * len(jax.local_devices()) + 2
     # DevicePrefetcher ops excluded or invisible above, charged per GROUP
     # (k steps): producer staging.inc + bytes.inc + depth.set, consumer
-    # wait.inc + depth.set + overlap.set = 6 (the wait_series observe is a
-    # histogram count, already in the delta).
-    ops_per_step += 6 / ksteps
+    # wait.inc + depth.set = 5 (the wait_series observe is a histogram
+    # count, already in the delta).
+    ops_per_step += 5 / ksteps
+    # the spans the fit path wrote into the flight recorder's ring: seven a
+    # group (input.pull/stack/cast/h2d, fit.wait/dispatch/listeners), each
+    # two clock reads and one record_span
+    spans_per_step = sum("t0_ns" in e
+                         for e in global_recorder().snapshot()) / n_steps
+    assert spans_per_step == 7 / ksteps
     # health gauges excluded above, charged per CHECK: grad/update/nonfinite
     # norm sets + loss-EMA set = 4 (the checks counter inc is a unit counter,
     # already in the delta). The fused K-group path checks at most once per
@@ -233,11 +244,18 @@ def test_telemetry_overhead_budget():
         c.inc()
         h.observe(0.001)
     per_op_s = (time.perf_counter() - t0) / (2 * n_probe)
+    ring = FlightRecorder(capacity=64)
+    t0 = time.perf_counter()
+    for _ in range(n_probe):
+        ring.record_span("probe", time.time_ns(), time.time_ns(), group=1,
+                         cause="probe")
+    per_span_s = (time.perf_counter() - t0) / n_probe
 
-    overhead = ops_per_step * per_op_s
+    overhead = ops_per_step * per_op_s + spans_per_step * per_span_s
     assert overhead <= 0.02 * step_s, (
         f"telemetry budget blown: {ops_per_step:.0f} registry ops/step x "
-        f"{per_op_s * 1e6:.2f}us = {overhead * 1e3:.3f}ms vs step "
+        f"{per_op_s * 1e6:.2f}us + {spans_per_step:.1f} spans/step x "
+        f"{per_span_s * 1e6:.2f}us = {overhead * 1e3:.3f}ms vs step "
         f"{step_s * 1e3:.1f}ms")
 
 

@@ -303,13 +303,16 @@ def test_prefetch_metric_families_exposed():
     snap = global_registry().snapshot()
     for fam in ("dl4j_prefetch_depth", "dl4j_prefetch_bytes_total",
                 "dl4j_prefetch_staging_seconds_total",
-                "dl4j_prefetch_wait_seconds_total",
-                "dl4j_prefetch_overlap_ratio"):
+                "dl4j_prefetch_wait_seconds_total"):
         assert fam in snap, fam
     by_path = {s["labels"].get("path"): s
                for s in snap["dl4j_prefetch_bytes_total"]["series"]}
     assert by_path["multilayer"]["value"] > 0
-    ratios = [s["value"]
-              for s in snap["dl4j_prefetch_overlap_ratio"]["series"]
-              if s["labels"].get("path") == "multilayer"]
-    assert ratios and 0.0 <= ratios[0] <= 1.0
+    # the share of staging hidden behind dispatch is 1 - wait / staging of
+    # the two counters (no gauge of its own)
+    wait, staging = (
+        next(s["value"] for s in snap[fam]["series"]
+             if s["labels"].get("path") == "multilayer")
+        for fam in ("dl4j_prefetch_wait_seconds_total",
+                    "dl4j_prefetch_staging_seconds_total"))
+    assert wait >= 0.0 and staging > 0.0
