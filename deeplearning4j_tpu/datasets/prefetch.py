@@ -16,16 +16,26 @@ Donation safety — the ownership hand-off, explicitly:
   (``donate_argnums=(0, 1, 2)``); batch inputs are never donated, so XLA
   never reuses a staged batch buffer for step outputs.
 * Every staged item is produced from host numpy by ``jax.device_put`` /
-  ``make_array_from_callback`` — a FRESH device buffer per group, never a
-  view of a buffer an in-flight step reads.
+  ``make_array_from_callback`` — a FRESH *device* buffer per group, never a
+  view of a buffer an in-flight step reads. Only the host side is reused:
+  the networks' stage function (``nn.multilayer.stage_group``) writes each
+  group into a slot of a ``HostGroupRing`` and puts the slot. The runtime
+  reads a slot until the copy is done, so a slot is rewritten only after
+  ``block_until_ready()`` on the device arrays last made from it; and where
+  the backend wrapped the slot's memory instead of copying it (the CPU
+  backend does for a 64-byte-aligned buffer) the device arrays own that
+  memory from then on and the ring allocates a new slot in its place.
 * Each queue slot is consumed by exactly one dispatch: the consumer pops an
-  item, hands it to the train step, and drops its reference. The producer
-  holds no reference after ``put``. Nothing ever aliases the donated
+  item, hands it to the train step, and drops its reference. The ring keeps
+  one to the group's device arrays only until their copy has finished (it
+  looks at every staging call). Nothing ever aliases the donated
   params/state buffers, so depth-2 prefetch cannot trigger a
   "deleted buffer" error (pinned by tests/test_prefetch.py).
 
-Bounded depth (default 2 = double buffering) caps HBM held by staged batches
-at ``depth * group_bytes``; depth <= 0 degrades to synchronous inline staging
+Bounded depth (default 2 = double buffering) caps HBM held by queued batches
+at ``depth * group_bytes``; the networks' fit loops keep at most two
+dispatched groups unfinished besides (``LazyScore._dispatch_staged``, span
+``fit.step_wait``). depth <= 0 degrades to synchronous inline staging
 (the pre-prefetch behavior, used by the numerical-equivalence tests and the
 bench A/B).
 
@@ -36,7 +46,8 @@ on the item (the producer while it pulls and stages, the fit loop from the
 moment it receives it). The prefetcher writes ``input.pull`` and ``fit.wait``
 into the flight recorder's ring; the stage function writes its own
 (``nn.multilayer.stage_group``: ``input.stack``, ``input.cast``,
-``input.h2d``), and the dispatch its ``fit.dispatch``, all under that number.
+``input.h2d``), and the dispatch its ``fit.step_wait`` and ``fit.dispatch``,
+all under that number.
 """
 from __future__ import annotations
 
@@ -46,11 +57,15 @@ import threading
 import time
 from typing import Callable, Iterable, Optional
 
+import jax
+import numpy as np
+
 from deeplearning4j_tpu.observability.flight_recorder import (
     global_recorder as _flight_recorder,
 )
 from deeplearning4j_tpu.observability.names import (
     PREFETCH_BYTES_TOTAL, PREFETCH_DEPTH,
+    PREFETCH_SLOT_WAIT_SECONDS_TOTAL, PREFETCH_STAGE_SLOTS_TOTAL,
     PREFETCH_STAGING_SECONDS_TOTAL, PREFETCH_WAIT_SECONDS_TOTAL,
 )
 from deeplearning4j_tpu.observability.metrics import (
@@ -74,6 +89,15 @@ _wait_total = _obs_registry().counter(
     "consumer seconds blocked waiting for a staged item (staging NOT hidden "
     "behind dispatch), by fit path")
 
+_slots_total = _obs_registry().counter(
+    PREFETCH_STAGE_SLOTS_TOTAL,
+    "staged groups by where their host buffers came from: a ring slot written "
+    "before (reused) or a new one (allocated), by fit path")
+_slot_wait_total = _obs_registry().counter(
+    PREFETCH_SLOT_WAIT_SECONDS_TOTAL,
+    "staging seconds blocked until the previous transfer out of a ring slot "
+    "had finished, by fit path")
+
 _DONE = object()  # queue sentinel: producer finished (or was stopped)
 
 _group_seq = itertools.count()  # next() is one bytecode: atomic under the GIL
@@ -90,6 +114,93 @@ def current_group() -> Optional[int]:
     """The staged item this thread is working on (module docstring), or None
     where the thread has touched none."""
     return getattr(_working_on, "group", None)
+
+
+def _reads_host_memory(dev, host) -> bool:
+    """Whether ``dev``, the array a put of ``host`` returned, reads
+    ``host``'s own memory: only a CPU device can, and then its buffer lies
+    inside the numpy array's."""
+    if all(d.platform != "cpu" for d in dev.devices()):
+        return False
+    lo = host.ctypes.data
+    return lo <= dev.unsafe_buffer_pointer() < lo + host.nbytes
+
+
+#: a slot's memory: untouched pages, so a slot costs nothing until a group is
+#: written into it
+_host_buffer = np.empty
+
+
+class _Slot:
+    """One group's host buffers, ``(capacity, *shape)`` per leaf, and the
+    device arrays last made from them whose copy may still be running."""
+    __slots__ = ("buffers", "in_flight")
+
+    def __init__(self, buffers):
+        self.buffers = buffers
+        self.in_flight = []
+
+
+class HostGroupRing:
+    """``size`` reusable host slots for staged K-step groups (donation
+    safety, module docstring). A slot is allocated on first use and holds
+    one group: per leaf an array ``(capacity, *shape)`` of the staged dtype.
+    ``stage`` takes the next slot round the ring, first waiting for the
+    transfer last made from it, has the caller write the group into it, and
+    puts it. A group of another per-batch shape or dtype, or longer than the
+    capacity, drops every slot and starts anew. One stager at a time: a
+    network's only one is its fit loop's producer.
+    """
+
+    def __init__(self, size: int, path: str):
+        self.size = size
+        self._spec = None
+        self._capacity = 0
+        self._slots: list = []
+        self._next = 0
+        self._m_slots = {o: _slots_total.labels(path=path, outcome=o)
+                         for o in ("reused", "allocated")}
+        self._m_wait = _slot_wait_total.labels(path=path)
+
+    def stage(self, spec: tuple, n: int, fill: Callable) -> list:
+        """Stage a group of ``n`` batches; ``spec`` is the ``(shape, dtype)``
+        of each leaf of one batch. ``fill`` is called with the host arrays
+        ``(n, *shape)`` to write the group into, one per leaf. Returns the
+        device arrays put from them, leaf by leaf. A slot whose memory one
+        of them reads is given away."""
+        if spec != self._spec or n > self._capacity:
+            # in-flight transfers keep their buffers alive themselves
+            self._spec, self._capacity = spec, n
+            self._slots = [None] * self.size
+            self._next = 0
+        for slot in self._slots:
+            if slot is not None and slot.in_flight:
+                slot.in_flight = [d for d in slot.in_flight
+                                  if not d.is_ready()]
+        i = self._next
+        self._next = (i + 1) % self.size
+        slot = self._slots[i]
+        if slot is None:
+            slot = self._slots[i] = _Slot(
+                [_host_buffer((self._capacity,) + shape, dtype)
+                 for shape, dtype in spec])
+            self._m_slots["allocated"].inc()
+        else:
+            if slot.in_flight:
+                t0 = time.perf_counter()
+                for d in slot.in_flight:
+                    d.block_until_ready()
+                self._m_wait.inc(time.perf_counter() - t0)
+            self._m_slots["reused"].inc()
+        staged = [b[:n] for b in slot.buffers]
+        fill(staged)
+        device = [jax.device_put(a) for a in staged]
+        if any(_reads_host_memory(d, b)
+               for d, b in zip(device, slot.buffers)):
+            self._slots[i] = None
+        else:
+            slot.in_flight = device
+        return device
 
 
 class DevicePrefetcher:

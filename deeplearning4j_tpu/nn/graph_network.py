@@ -25,7 +25,7 @@ from deeplearning4j_tpu.nn.conf.vertices import LayerVertex
 from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher, begin_group
 from deeplearning4j_tpu.nn.multilayer import (
     LazyScore, _stage_host, _updater_spec, _t_staging, _t_dispatch,
-    _t_listeners, stage_group,
+    _t_listeners,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -619,26 +619,29 @@ class ComputationGraph(LazyScore):
         multistep_ok = (k > 1 and self._uses_sgd()
                         and self.conf.global_conf.iterations <= 1
                         and not self._tbptt_active())
-        for _ in range(epochs):
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_start"):
-                    listener.on_epoch_start(self)
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            if self.conf.pretrain:
-                self.pretrain(iterator)
+        try:
+            for _ in range(epochs):
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_start"):
+                        listener.on_epoch_start(self)
                 if hasattr(iterator, "reset"):
                     iterator.reset()
-            if multistep_ok:
-                self._fit_epoch_multistep(iterator, k)
-            else:
-                for ds in iterator:
-                    xs, ys, fm, lm = _coerce_graph_batch(ds)
-                    self._fit_batch(xs, ys, fm, lm)
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(self)
-            self.epoch += 1
+                if self.conf.pretrain:
+                    self.pretrain(iterator)
+                    if hasattr(iterator, "reset"):
+                        iterator.reset()
+                if multistep_ok:
+                    self._fit_epoch_multistep(iterator, k)
+                else:
+                    for ds in iterator:
+                        xs, ys, fm, lm = _coerce_graph_batch(ds)
+                        self._fit_batch(xs, ys, fm, lm)
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_end"):
+                        listener.on_epoch_end(self)
+                self.epoch += 1
+        finally:
+            self._release_staging()
 
     def _fit_epoch_multistep(self, iterator, k: int) -> None:
         from deeplearning4j_tpu.utils.batching import k_step_groups
@@ -651,12 +654,13 @@ class ComputationGraph(LazyScore):
             return ([np.asarray(x) for x in xs], [np.asarray(y) for y in ys])
 
         def stage(kind_item):
-            # producer thread: per-stream stack + cast + non-blocking
-            # device_put (see MultiLayerNetwork._fit_epoch_multistep)
+            # producer thread: per-stream cast into a host slot +
+            # non-blocking device_put (see
+            # MultiLayerNetwork._fit_epoch_multistep)
             kind, item = kind_item
             if kind != "group" or len(item) < 2:
                 return kind_item
-            xs, ys = stage_group(item, self.stage_dtype)
+            xs, ys = self._stage_group(item, "graph")
             return "staged", (xs, ys, len(item))
 
         pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
@@ -681,18 +685,8 @@ class ComputationGraph(LazyScore):
             return
         begin_group()
         with _t_staging.time():
-            xs, ys = stage_group(batches, self.stage_dtype)
+            xs, ys = self._stage_group(batches, "graph")
         self._dispatch_staged(xs, ys, len(batches))
-
-    def _dispatch_staged(self, xs, ys, n: int) -> None:
-        # donated params/states/updater: in-place XLA update; staged xs/ys
-        # are fresh, non-donated buffers so prefetched groups never alias
-        # what the in-flight step consumes (see
-        # MultiLayerNetwork._dispatch_staged)
-        self.last_batch_size = int(xs[0].shape[1]) if xs else 0
-        losses = self._run_multistep(xs, ys, n)
-        self._run_listeners(losses, n)
-        _wd_beat(self.iteration)
 
     #: Solver facade instance when optimization_algo != SGD (built lazily)
     _solver = None

@@ -27,7 +27,7 @@ import numpy as np
 
 from deeplearning4j_tpu import common
 from deeplearning4j_tpu.datasets.prefetch import (
-    DevicePrefetcher, begin_group, current_group,
+    DevicePrefetcher, HostGroupRing, begin_group, current_group,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -62,8 +62,11 @@ _phase_hist = _obs_registry().histogram(
     FIT_PHASE_SECONDS,
     "host wall seconds per fit-loop phase (staging: host cast+transfer "
     "submit, or with device prefetch the visible wait for the staged batch; "
-    "dispatch: jitted-call submit; listeners: callback overhead)")
+    "device: the wait for the step of the staged group two back before the "
+    "next is dispatched; dispatch: jitted-call submit; listeners: callback "
+    "overhead)")
 _t_staging = _phase_hist.labels(phase="staging")
+_t_device = _phase_hist.labels(phase="device")
 _t_dispatch = _phase_hist.labels(phase="dispatch")
 _t_listeners = _phase_hist.labels(phase="listeners")
 
@@ -302,11 +305,18 @@ def make_multistep_train_step(conf: MultiLayerConfiguration, *,
     return dl4j_train_ksteps
 
 
-def _stage_host(x, dtype):
+def _stage_host(x, dtype, out=None):
     """Cast features to the staging dtype ON THE HOST, before the device
     transfer, so ``stage_dtype`` halves host->device wire bytes on every fit
     path (its documented contract). Device-resident jax Arrays are cast on
-    device instead — pulling them back to host would defeat the point."""
+    device instead — pulling them back to host would defeat the point.
+
+    With ``out`` (one batch's place in a host group buffer of the staged
+    dtype) the cast is fused into the copy there: one pass over the bytes,
+    the same round-to-nearest-even cast as ``astype``, bit for bit."""
+    if out is not None:
+        np.copyto(out, x, casting="unsafe")
+        return out
     if dtype is None:
         return x
     if isinstance(x, jax.Array):
@@ -314,33 +324,58 @@ def _stage_host(x, dtype):
     return np.asarray(x).astype(dtype, copy=False)
 
 
-def stage_group(batches: list, dtype):
+def stage_group(batches: list, dtype, ring: HostGroupRing):
     """Stage one K-step group of host batches ``[(features, labels), ...]``
-    (arrays, or for a graph one list of arrays per stream): stack each stream
-    to ``(K, B, ...)``, cast the features to ``dtype`` on the host
-    (``_stage_host``), and hand everything to the device with a
-    ``jax.device_put`` that does not wait for the copy. Returns ``(xs, ys)``.
+    (arrays, or for a graph one list of arrays per stream) as ``(K, B, ...)``
+    device arrays, the features cast to ``dtype`` on the host (None keeps
+    their own). Returns ``(xs, ys)``.
 
-    Writes the group's three stage spans, ``input.stack``, ``input.cast`` and
-    ``input.h2d`` (the submission, with the ``bytes`` submitted), under the
-    calling thread's ``current_group()``; they follow one another without a
-    gap, so with ``input.pull`` they add up to the staging counter."""
-    tree_map = jax.tree_util.tree_map
-    t0 = time.time_ns()
-    xs = tree_map(lambda *a: np.stack(a), *[b[0] for b in batches])
-    ys = tree_map(lambda *a: np.stack(a), *[b[1] for b in batches])
-    t1 = time.time_ns()
-    xs = tree_map(lambda a: _stage_host(a, dtype), xs)
-    t2 = time.time_ns()
-    nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves((xs, ys)))
-    xs, ys = tree_map(jax.device_put, (xs, ys))
+    One pass over the bytes: every batch is written straight into its place
+    in a slot of ``ring`` (``datasets.prefetch.HostGroupRing``: reused host
+    group buffers of the staged dtype), the cast fused into the copy, and the
+    slot, cut to the group's length, is handed to the device with a
+    ``jax.device_put`` that does not wait for the copy: a fresh device buffer
+    every group, from host pages that are warm. The ring rewrites a slot only
+    when the transfer last made from it has finished.
+
+    Writes the group's three stage spans under the calling thread's
+    ``current_group()``: ``input.stack`` (taking the slot, which includes any
+    wait for its last transfer, and writing the labels), ``input.cast`` (the
+    pass over the features) and ``input.h2d`` (the submission, with the
+    ``bytes`` submitted). They follow one another without a gap, so with
+    ``input.pull`` they add up to the staging counter."""
+    t0 = t1 = t2 = time.time_ns()
+    tree = jax.tree_util
+    columns = list(zip(*(tree.tree_leaves(b) for b in batches)))
+    treedef = tree.tree_structure(batches[0])
+    n_features = treedef.children()[0].num_leaves
+    spec = tuple(
+        (col[0].shape,
+         np.dtype(dtype) if dtype is not None and i < n_features
+         else np.result_type(*(a.dtype for a in col)))
+        for i, col in enumerate(columns))
+
+    def fill(staged: list):
+        nonlocal t1, t2
+
+        def write(leaves: slice):
+            for out, col in zip(staged[leaves], columns[leaves]):
+                for place, a in zip(out, col):
+                    _stage_host(a, out.dtype, out=place)
+
+        write(slice(n_features, None))      # labels
+        t1 = time.time_ns()
+        write(slice(n_features))            # features
+        t2 = time.time_ns()
+
+    device = ring.stage(spec, len(batches), fill)
     t3 = time.time_ns()
     rec, group = _flight_recorder(), current_group()
     rec.record_span("input.stack", t0, t1, group=group, cause="input.pull")
     rec.record_span("input.cast", t1, t2, group=group, cause="input.pull")
     rec.record_span("input.h2d", t2, t3, group=group, cause="input.pull",
-                    bytes=nbytes)
-    return xs, ys
+                    bytes=sum(d.nbytes for d in device))
+    return tree.tree_unflatten(treedef, device)
 
 
 class LazyScore:
@@ -447,13 +482,67 @@ class LazyScore:
     #: shared dispatch helper below can build plain and health variants
     _multistep_builder = None
 
-    def _run_multistep(self, xs, ys, n: int):
+    #: the host slots for staged groups (``HostGroupRing``), made on the
+    #: first staged group; ``fit_iterator`` drops them when it returns
+    _host_ring = None
+
+    def _stage_group(self, batches: list, path: str):
+        """``stage_group`` into this network's ring of ``prefetch_depth + 2``
+        slots: as many groups as are alive at once (one being staged,
+        ``prefetch_depth`` queued, one dispatched) and one more, so that the
+        transfer out of a slot has long finished when its turn comes again."""
+        size = max(0, self.prefetch_depth) + 2
+        ring = self._host_ring
+        if ring is None or ring.size != size:
+            ring = self._host_ring = HostGroupRing(size, path)
+        return stage_group(batches, self.stage_dtype, ring)
+
+    #: loss stacks of the two staged groups dispatched last, older first
+    _staged_losses = (None, None)
+
+    def _release_staging(self) -> None:
+        """Let go of what the staged fit loop keeps between groups: the host
+        slots (``prefetch_depth + 2`` groups of the staged dtype) and the
+        last groups' losses."""
+        self._host_ring = None
+        self._staged_losses = (None, None)
+
+    def _dispatch_staged(self, xs, ys, n: int) -> None:
+        """Run a K-step group whose (K, B, ...) stacks are already device-
+        resident (or in flight — dispatch never blocks on the transfer);
+        shared by both network types.
+
+        Donation hand-off: params/states/updater buffers are DONATED — XLA
+        updates them in place (no 2x param HBM during the step) and the
+        previous arrays are consumed; anyone holding stale references gets a
+        loud "deleted buffer" error, never silent corruption (clone() deep-
+        copies for this reason; donation is a no-op on CPU). The staged
+        xs/ys are NOT in the donated argnums and were freshly created by
+        device_put on the prefetch thread, so a prefetched group can never
+        alias a buffer the in-flight step is consuming.
+
+        Flow control: the group is dispatched once the step of the group two
+        before it has finished, so one group is queued behind the running
+        step and the dispatch call is hidden. Without a bound the producer,
+        where it is the faster side, piles staged groups up in HBM: each
+        dispatched group holds its inputs there until its step has run, and
+        the runtime lets a host run some thirty dispatches ahead."""
+        self.last_batch_size = int(jax.tree_util.tree_leaves(xs)[0].shape[1])
+        two_back, one_back = self._staged_losses
+        losses = self._run_multistep(xs, ys, n, after=two_back)
+        self._staged_losses = (one_back, losses)
+        self._run_listeners(losses, n)
+        _wd_beat(self.iteration)
+
+    def _run_multistep(self, xs, ys, n: int, after=None):
         """Dispatch one K-step fused group (shared by both network types):
         picks the health variant when the attached monitor's cadence falls
         inside the group, times the dispatch, records the flight-recorder
         step event, and advances the step clock with MFU attribution.
         Returns the (K,) per-step loss stack; params/states/updater are
-        updated in place (donated)."""
+        updated in place (donated). ``after``: a device array to wait for
+        first (``_dispatch_staged``; the ``device`` phase and the group's
+        ``fit.step_wait`` span), once everything but the call is done."""
         hm = self.health_monitor
         due_i = hm.due_index(self.iteration, n) if hm is not None else None
         name = "multistep" if due_i is None else "multistep_health"
@@ -461,6 +550,14 @@ class LazyScore:
             name, type(self)._multistep_builder(self.conf,
                                                 health=due_i is not None),
             donate=(0, 1, 2))
+        if after is not None:
+            w0_ns = time.time_ns()
+            with _t_device.time():
+                # lint: host-sync-in-hot-loop-ok (flow control: the one wait per K-step group that bounds staged groups in HBM)
+                after.block_until_ready()
+            _flight_recorder().record_span(
+                "fit.step_wait", w0_ns, time.time_ns(),
+                group=current_group(), cause="fit.dispatch")
         t0, t0_ns = time.perf_counter(), time.time_ns()
         out = multi(self.params_list, self.state_list, self.updater_state,
                     xs, ys, self._next_rng(), jnp.int32(self.iteration))
@@ -744,9 +841,10 @@ class MultiLayerNetwork(LazyScore):
 
     #: optional dtype (e.g. jnp.bfloat16) features are cast to on the host
     #: BEFORE the device transfer in the fused fit path. Halves host->device
-    #: bytes (BASELINE.md round-3 fit-API analysis; to be re-measured on a
-    #: local chip, ROADMAP S1). Labels stay untouched. None
-    #: keeps exact f32 staging.
+    #: bytes (BASELINE.md round-3 fit-API analysis). Labels stay untouched.
+    #: None keeps exact f32 staging. Either way a K-step group is written
+    #: once, batch by batch, into a reused host slot of the staged dtype
+    #: (``stage_group``), never stacked in float32 first.
     stage_dtype = None
 
     #: K-step groups staged + transferred ahead of the dispatch loop on a
@@ -778,26 +876,29 @@ class MultiLayerNetwork(LazyScore):
             and self.conf.global_conf.iterations <= 1
             and not (self.conf.backprop_type == "TruncatedBPTT"
                      and any(isinstance(l, LSTM) for l in self.conf.layers)))
-        for _ in range(epochs):
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_start"):
-                    listener.on_epoch_start(self)
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            if self.conf.pretrain:
-                self.pretrain(iterator)
+        try:
+            for _ in range(epochs):
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_start"):
+                        listener.on_epoch_start(self)
                 if hasattr(iterator, "reset"):
                     iterator.reset()
-            if multistep_ok:
-                self._fit_epoch_multistep(iterator, k)
-            else:
-                for ds in iterator:
-                    self._fit_batch(ds.features, ds.labels, ds.features_mask,
-                                    ds.labels_mask)
-            for listener in self.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(self)
-            self.epoch += 1
+                if self.conf.pretrain:
+                    self.pretrain(iterator)
+                    if hasattr(iterator, "reset"):
+                        iterator.reset()
+                if multistep_ok:
+                    self._fit_epoch_multistep(iterator, k)
+                else:
+                    for ds in iterator:
+                        self._fit_batch(ds.features, ds.labels,
+                                        ds.features_mask, ds.labels_mask)
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_end"):
+                        listener.on_epoch_end(self)
+                self.epoch += 1
+        finally:
+            self._release_staging()
 
     def _fit_epoch_multistep(self, iterator, k: int) -> None:
         from deeplearning4j_tpu.utils.batching import k_step_groups
@@ -809,14 +910,14 @@ class MultiLayerNetwork(LazyScore):
             return np.asarray(ds.features), np.asarray(ds.labels)
 
         def stage(kind_item):
-            # producer thread: stack + cast + NON-BLOCKING device_put — the
-            # (K, B, ...) group is in flight to HBM while the previous
-            # dispatch executes. Singles and len<2 groups pass through to
-            # the host fallback path unchanged.
+            # producer thread: cast into a host slot + NON-BLOCKING
+            # device_put — the (K, B, ...) group is in flight to HBM while
+            # the previous dispatch executes. Singles and len<2 groups pass
+            # through to the host fallback path unchanged.
             kind, item = kind_item
             if kind != "group" or len(item) < 2:
                 return kind_item
-            xs, ys = stage_group(item, self.stage_dtype)
+            xs, ys = self._stage_group(item, "multilayer")
             return "staged", (xs, ys, len(item))
 
         pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
@@ -842,25 +943,8 @@ class MultiLayerNetwork(LazyScore):
             return
         begin_group()
         with _t_staging.time():
-            xs, ys = stage_group(batches, self.stage_dtype)
+            xs, ys = self._stage_group(batches, "multilayer")
         self._dispatch_staged(xs, ys, len(batches))
-
-    def _dispatch_staged(self, xs, ys, n: int) -> None:
-        """Run a K-step group whose (K, B, ...) stacks are already device-
-        resident (or in flight — dispatch never blocks on the transfer).
-
-        Donation hand-off: params/states/updater buffers are DONATED — XLA
-        updates them in place (no 2x param HBM during the step) and the
-        previous arrays are consumed; anyone holding stale references gets a
-        loud "deleted buffer" error, never silent corruption (clone() deep-
-        copies for this reason; donation is a no-op on CPU). The staged
-        xs/ys are NOT in the donated argnums and were freshly created by
-        device_put on the prefetch thread, so a prefetched group can never
-        alias a buffer the in-flight step is consuming."""
-        self.last_batch_size = int(xs.shape[1])
-        losses = self._run_multistep(xs, ys, n)
-        self._run_listeners(losses, n)
-        _wd_beat(self.iteration)
 
     #: Solver facade instance when optimization_algo != SGD (built lazily)
     _solver = None

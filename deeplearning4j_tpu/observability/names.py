@@ -175,6 +175,8 @@ PREFETCH_DEPTH = "dl4j_prefetch_depth"
 PREFETCH_BYTES_TOTAL = "dl4j_prefetch_bytes_total"
 PREFETCH_STAGING_SECONDS_TOTAL = "dl4j_prefetch_staging_seconds_total"
 PREFETCH_WAIT_SECONDS_TOTAL = "dl4j_prefetch_wait_seconds_total"
+PREFETCH_STAGE_SLOTS_TOTAL = "dl4j_prefetch_stage_slots_total"
+PREFETCH_SLOT_WAIT_SECONDS_TOTAL = "dl4j_prefetch_slot_wait_seconds_total"
 
 #: every registered name, sorted by constant name; the lint rule parses
 #: this module statically, this tuple is for runtime consumers (tests,

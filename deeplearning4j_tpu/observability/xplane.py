@@ -250,9 +250,12 @@ def summarize(logdir: str, top: int = 25) -> dict:
 
     out: dict = {"trace": path, "planes": [p["name"] for p in planes]}
     # device planes only ("/device:TPU:0" etc.); fall back to host planes so
-    # the pipeline still summarizes something on CPU-only runs
+    # the pipeline still summarizes something on CPU-only runs, also where
+    # the process has described a TPU it does not run on and its planes are
+    # there, empty
     device = [p for p in planes
-              if any(t in p["name"].lower() for t in ("tpu", "gpu", "device"))]
+              if any(t in p["name"].lower() for t in ("tpu", "gpu", "device"))
+              and any(line["events"] for line in p["lines"])]
     summarized = device or planes
     out["summarized_planes"] = [p["name"] for p in summarized]
 

@@ -223,11 +223,13 @@ def test_telemetry_overhead_budget():
     # count, already in the delta).
     ops_per_step += 5 / ksteps
     # the spans the fit path wrote into the flight recorder's ring: seven a
-    # group (input.pull/stack/cast/h2d, fit.wait/dispatch/listeners), each
-    # two clock reads and one record_span
+    # group (input.pull/stack/cast/h2d, fit.wait/dispatch/listeners) and
+    # from the call's third group on its fit.step_wait, each two clock reads
+    # and one record_span
     spans_per_step = sum("t0_ns" in e
                          for e in global_recorder().snapshot()) / n_steps
-    assert spans_per_step == 7 / ksteps
+    groups = n_steps // ksteps
+    assert spans_per_step == (7 * groups + groups - 2) / n_steps
     # health gauges excluded above, charged per CHECK: grad/update/nonfinite
     # norm sets + loss-EMA set = 4 (the checks counter inc is a unit counter,
     # already in the delta). The fused K-group path checks at most once per

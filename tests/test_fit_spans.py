@@ -1,7 +1,7 @@
 """The training path names its work: one span per stage of every staged
 K-step group in the flight recorder's ring (``input.pull/stack/cast/h2d`` on
-the producer, ``fit.wait/dispatch/listeners`` on the fit loop, sharing the
-group's number, on ``time.time_ns()``'s clock), and a ``jax.named_scope`` per
+the producer, ``fit.wait/step_wait/dispatch/listeners`` on the fit loop,
+sharing the group's number, on ``time.time_ns()``'s clock), and a ``jax.named_scope`` per
 layer and phase in the K-step program, whose module has a name of its own."""
 import time
 
@@ -84,7 +84,10 @@ def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
     net.fit_iterator(ListDataSetIterator(batches(3 * K)))
     groups = spans_by_group()
     assert len(groups) == 3
-    for group, by_name in groups.items():
+    for i, (group, by_name) in enumerate(sorted(groups.items())):
+        # the fit loop waits for the step two groups back, from the third on
+        step_wait = by_name.pop("fit.step_wait", None)
+        assert (step_wait is not None) == (i >= 2)
         assert set(by_name) == set(STAGES + FIT), by_name.keys()
         pull, stack, cast, h2d = (by_name[n] for n in STAGES)
         wait, dispatch, listeners = (by_name[n] for n in FIT)
@@ -108,6 +111,11 @@ def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
         assert {s["thread"] for s in (pull, stack, cast, h2d)} == {producer}
         assert {s["thread"] for s in (wait, dispatch, listeners)} == {
             "MainThread"}
+        if step_wait:
+            assert (wait["t1_ns"] <= step_wait["t0_ns"] <= step_wait["t1_ns"]
+                    <= dispatch["t0_ns"])
+            assert step_wait["cause"] == "fit.dispatch"
+            assert step_wait["thread"] == "MainThread"
     steps = [e for e in global_recorder().snapshot() if e["kind"] == "step"]
     assert len(steps) == 3
 
@@ -128,6 +136,17 @@ def test_stage_spans_add_up_to_the_staging_counter(ring, kind, depth):
                 if e.get("name") in STAGES) / 1e9
     assert counted > 0.01
     assert spans == pytest.approx(counted, rel=0.01)
+    # every group still has its three stage spans, none empty, one after the
+    # other: taking a slot and writing the labels, the pass over the
+    # features, the submission (a reader of a span that is absent reads null)
+    groups = spans_by_group()
+    assert len(groups) == 3
+    for by_name in groups.values():
+        stack, cast, h2d = (by_name[n] for n in STAGES[1:])
+        assert stack["t0_ns"] < stack["t1_ns"] == cast["t0_ns"]
+        assert cast["t0_ns"] < cast["t1_ns"] == h2d["t0_ns"] < h2d["t1_ns"]
+        # one pass: the features take longer than the slot and the labels
+        assert cast["t1_ns"] - cast["t0_ns"] > stack["t1_ns"] - stack["t0_ns"]
 
 
 @pytest.mark.parametrize("kind", ["multilayer", "graph"])

@@ -11,6 +11,7 @@ plus the AsyncDataSetIterator producer-thread-leak regression and the
 prefetch metric families.
 """
 import threading
+import types
 
 import jax
 import numpy as np
@@ -20,12 +21,14 @@ from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterators import (
     AsyncDataSetIterator, ListDataSetIterator,
 )
-from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher
+from deeplearning4j_tpu.datasets import prefetch as prefetch_mod
+from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher, HostGroupRing
 from deeplearning4j_tpu.nn.conf.builders import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.conf.layers import (
     DenseLayer, GravesLSTM, OutputLayer, RnnOutputLayer,
 )
-from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.nn import multilayer as multilayer_mod
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, stage_group
 from deeplearning4j_tpu.observability.metrics import global_registry
 
 
@@ -316,3 +319,282 @@ def test_prefetch_metric_families_exposed():
         for fam in ("dl4j_prefetch_wait_seconds_total",
                     "dl4j_prefetch_staging_seconds_total"))
     assert wait >= 0.0 and staging > 0.0
+
+
+# ------------------------------------------------------- host ring (stage_group)
+def _host_group(kind, n, seed=0, batch=6):
+    """``n`` distinct host batches: arrays, or a graph's lists of streams
+    (two feature streams, one label stream)."""
+    rng = np.random.default_rng(seed)
+
+    def one():
+        x = rng.normal(size=(batch, 5)).astype(np.float32) * 3.0
+        x[0, 0] = np.inf
+        y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, batch)]
+        if kind == "array":
+            return x, y
+        x2 = rng.integers(-9, 9, size=(batch, 2, 3)).astype(np.int32)
+        return [x, x2], [y]
+
+    return [one() for _ in range(n)]
+
+
+def _as_bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint8).reshape(a.shape + (a.dtype.itemsize,))
+
+
+def _slot_counts(path):
+    out = {"reused": 0, "allocated": 0, "wait": 0.0}
+    snap = global_registry().snapshot()
+    for s in snap.get("dl4j_prefetch_stage_slots_total", {"series": []})[
+            "series"]:
+        if s["labels"].get("path") == path:
+            out[s["labels"]["outcome"]] = s["value"]
+    for s in snap.get("dl4j_prefetch_slot_wait_seconds_total",
+                      {"series": []})["series"]:
+        if s["labels"].get("path") == path:
+            out["wait"] = s["value"]
+    return out
+
+
+def _buffer_at(offset):
+    """An allocator of memory ``offset`` bytes past a 64-byte boundary: at 0
+    the CPU backend wraps it without a copy, at 16 it has to copy."""
+    def host_buffer(shape, dtype):
+        dtype = np.dtype(dtype)
+        nbytes = int(np.prod(shape)) * dtype.itemsize
+        raw = np.empty(nbytes + 128, np.uint8)
+        start = -raw.ctypes.data % 64 + offset
+        return raw[start:start + nbytes].view(dtype).reshape(shape)
+    return host_buffer
+
+
+@pytest.fixture
+def copied_slots(monkeypatch):
+    """Slots the CPU backend copies out of, as a TPU does (for arrays as
+    small as a test's, whether ``np.empty``'s memory gets wrapped is
+    chance)."""
+    monkeypatch.setattr(prefetch_mod, "_host_buffer", _buffer_at(16))
+
+
+@pytest.mark.parametrize("n", [4, 2])
+@pytest.mark.parametrize("kind", ["array", "streams"])
+@pytest.mark.parametrize("dtype", ["bfloat16", None])
+def test_stage_group_bits_equal_stack_then_astype(copied_slots, dtype, kind,
+                                                  n):
+    """One pass into a reused slot gives the bytes ``np.stack(...).astype``
+    gave: full group, then a short one (``buf[:n]``) into the same slots,
+    then round the ring so that every slot is written a second time."""
+    import jax.numpy as jnp
+
+    dtype = getattr(jnp, dtype) if dtype else None
+    ring = HostGroupRing(2, "test_bits")
+    before = _slot_counts("test_bits")
+    for turn, length in enumerate([4, n, 4, n]):
+        group = _host_group(kind, length, seed=turn)
+        xs, ys = stage_group(group, dtype, ring)
+        tree = jax.tree_util
+        want_x = tree.tree_map(lambda *a: np.stack(a), *[b[0] for b in group])
+        want_y = tree.tree_map(lambda *a: np.stack(a), *[b[1] for b in group])
+        if dtype is not None:
+            want_x = tree.tree_map(lambda a: a.astype(dtype), want_x)
+        assert tree.tree_structure((xs, ys)) == tree.tree_structure(
+            (want_x, want_y))
+        for got, want in zip(tree.tree_leaves((xs, ys)),
+                             tree.tree_leaves((want_x, want_y))):
+            assert isinstance(got, jax.Array)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.array_equal(_as_bits(got), _as_bits(want))
+    after = _slot_counts("test_bits")
+    assert after["allocated"] - before["allocated"] == 2
+    assert after["reused"] - before["reused"] == 2
+
+
+@pytest.mark.parametrize("aligned", [False, True])
+@pytest.mark.parametrize("depth", [2, 0])
+def test_slot_reuse_trains_the_same_as_fresh_slots(monkeypatch, depth,
+                                                   aligned):
+    """Every slot of the ring is rewritten twice while earlier groups are
+    queued, dispatched or still being copied (the CPU runtime reads a put's
+    host buffer after ``device_put`` has returned, and wraps an aligned one
+    for good): params bit for bit as with a stager that never reuses one."""
+    import jax.numpy as jnp
+
+    k = 2
+    data = _batches((2 * (depth + 2) + 1) * k, seed=4)
+
+    def run(reuse):
+        monkeypatch.setattr(prefetch_mod, "_host_buffer",
+                            _buffer_at(0 if aligned else 16))
+        if not reuse:  # a ring that never comes round
+            monkeypatch.setattr(
+                multilayer_mod, "HostGroupRing",
+                lambda size, path: HostGroupRing(10 ** 6, path))
+        net = _mlp_net(seed=11)
+        net.dispatch_ksteps = k
+        net.prefetch_depth = depth
+        net.stage_dtype = jnp.bfloat16
+        before = _slot_counts("multilayer")
+        net.fit_iterator(ListDataSetIterator(data))
+        after = _slot_counts("multilayer")
+        monkeypatch.undo()
+        return _leaves(net), {o: after[o] - before[o] for o in after}
+
+    fresh, fresh_counts = run(reuse=False)
+    reused, counts = run(reuse=True)
+    groups = 2 * (depth + 2) + 1
+    assert fresh_counts["reused"] == 0 and fresh_counts["allocated"] == groups
+    if aligned:
+        # the backend wrapped every slot, so each was given away
+        assert counts["reused"] == 0 and counts["allocated"] == groups
+    else:
+        assert counts["allocated"] == depth + 2
+        assert counts["reused"] == groups - (depth + 2)
+    for a, b in zip(fresh, reused):
+        assert np.array_equal(a, b)
+
+
+class _StubDeviceArray:
+    """What ``device_put`` returns, for a ring that cannot tell: a transfer
+    that has not finished until someone waits for it, on a device whose
+    memory is its own (``platform``) or the host's."""
+
+    def __init__(self, host, log, platform="tpu"):
+        self.host, self.log, self.platform = host, log, platform
+        self.sent = host.copy()
+        self.ready = False
+        self.nbytes, self.shape, self.dtype = host.nbytes, host.shape, host.dtype
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        # the runtime was still reading: the slot must hold what was put
+        self.log.append(("waited", np.array_equal(_as_bits(self.host),
+                                                  _as_bits(self.sent))))
+        self.ready = True
+        return self
+
+    def devices(self):
+        return [types.SimpleNamespace(platform=self.platform)]
+
+    def unsafe_buffer_pointer(self):
+        return self.host.ctypes.data
+
+
+def test_slot_is_not_rewritten_before_its_transfer_has_finished(monkeypatch):
+    log = []
+    monkeypatch.setattr(
+        jax, "device_put", lambda a, *r, **kw: _StubDeviceArray(a, log))
+    ring = HostGroupRing(2, "test_wait")
+    before = _slot_counts("test_wait")
+    first = stage_group(_host_group("array", 3, seed=1), None, ring)
+    stage_group(_host_group("array", 3, seed=2), None, ring)
+    assert log == []                      # two slots, nothing to wait for
+    third = stage_group(_host_group("array", 3, seed=3), None, ring)
+    # both transfers out of slot 0 (features, labels) were waited for, and
+    # at that moment the slot still held the first group
+    assert log == [("waited", True), ("waited", True)]
+    assert all(d.ready for d in first) and not any(d.ready for d in third)
+    assert first[0].host.ctypes.data == third[0].host.ctypes.data
+    after = _slot_counts("test_wait")
+    assert after["reused"] - before["reused"] == 1
+    assert after["wait"] >= before["wait"]
+    # a transfer seen finished at a later staging call is let go of unwaited
+    for d in third:
+        d.ready = True
+    stage_group(_host_group("array", 3, seed=4), None, ring)   # slot 1
+    stage_group(_host_group("array", 3, seed=5), None, ring)   # slot 0
+    assert len(log) == 4                  # slot 1's two, none for slot 0
+
+
+def test_aliased_put_gives_the_slot_away(monkeypatch):
+    log = []
+    monkeypatch.setattr(
+        jax, "device_put",
+        lambda a, *r, **kw: _StubDeviceArray(a, log, platform="cpu"))
+    ring = HostGroupRing(1, "test_alias")
+    before = _slot_counts("test_alias")
+    staged = [stage_group(_host_group("streams", 2, seed=s), None, ring)
+              for s in range(3)]
+    after = _slot_counts("test_alias")
+    # a ring of one would have reused its slot twice; every put read the
+    # slot's own memory, so each group got memory of its own and none waited
+    assert after["allocated"] - before["allocated"] == 3
+    assert after["reused"] == before["reused"] and log == []
+    firsts = [jax.tree_util.tree_leaves(s)[0] for s in staged]
+    assert len({d.host.ctypes.data for d in firsts}) == 3
+    for s, d in enumerate(firsts):        # and nobody wrote over it
+        want = np.stack([b[0][0] for b in _host_group("streams", 2, seed=s)])
+        assert np.array_equal(_as_bits(d.host), _as_bits(want))
+
+
+def test_slot_counters_follow_a_known_sequence(copied_slots):
+    import jax.numpy as jnp
+
+    ring = HostGroupRing(2, "test_counts")
+    seen = []
+
+    def stage(group, dtype=jnp.bfloat16):
+        before = _slot_counts("test_counts")
+        out = stage_group(group, dtype, ring)
+        after = _slot_counts("test_counts")
+        seen.extend(o for o in ("reused", "allocated")
+                    if after[o] != before[o])
+        return out
+
+    def bf16_bits(group):
+        return _as_bits(np.stack([b[0] for b in group]).astype(jnp.bfloat16))
+
+    a = lambda s: _host_group("array", 4, seed=s)                 # noqa: E731
+    stage(a(0)); stage(a(1)); stage(a(2))        # two slots, then round
+    stage(a(3)[:2])                              # short: same slots
+    stage(_host_group("array", 4, seed=4, batch=3))   # ragged tail: new ring
+    stage(a(5))                                  # back: the ring was dropped
+    # one path whatever the leaf: a jax.Array and an object array are cast
+    # into the slot like any other
+    xs, _ = stage([(jnp.asarray(x), y) for x, y in a(6)])
+    assert xs.dtype == jnp.bfloat16 and xs.shape == (4, 6, 5)
+    assert np.array_equal(_as_bits(xs), bf16_bits(a(6)))
+    xs, _ = stage([(x.astype(object), y) for x, y in a(7)])
+    assert np.array_equal(_as_bits(xs), bf16_bits(a(7)))
+    stage(a(8), dtype=None)                      # another staged dtype
+    assert seen == ["allocated", "allocated", "reused", "reused",
+                    "allocated", "allocated", "allocated", "reused",
+                    "allocated"]
+
+
+def test_fit_loop_keeps_one_group_queued_behind_the_running_step(monkeypatch):
+    """Flow control: a staged group is dispatched once the step of the group
+    two before it has finished, so staged groups cannot pile up in HBM and
+    the dispatch call is still hidden behind a running step. What the loop
+    kept between groups goes when ``fit_iterator`` returns."""
+    order = []
+
+    class Losses:
+        def __init__(self, i, real):
+            self.i, self.real = i, real
+
+        def block_until_ready(self):
+            order.append(("waited", self.i))
+
+        def __getitem__(self, j):
+            return self.real[j]
+
+    net = _mlp_net(seed=2)
+    net.dispatch_ksteps = 2
+    run = type(net)._run_multistep
+
+    def spy(self, xs, ys, n, after=None):
+        i = sum(1 for o in order if o[0] == "dispatch")
+        order.append(("dispatch", i, after.i if after else None))
+        assert self._host_ring is not None
+        return Losses(i, run(self, xs, ys, n, after=after))
+
+    monkeypatch.setattr(type(net), "_run_multistep", spy)
+    net.fit_iterator(ListDataSetIterator(_batches(8)))
+    assert order == [("dispatch", 0, None), ("dispatch", 1, None),
+                     ("dispatch", 2, 0), ("waited", 0),
+                     ("dispatch", 3, 1), ("waited", 1)]
+    assert net._host_ring is None and net._staged_losses == (None, None)

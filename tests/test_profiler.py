@@ -125,6 +125,23 @@ def test_truncated_and_malformed_proto_error_record(tmp_path):
         xplane.parse_planes(data[:len(data) // 2])
 
 
+def test_empty_device_planes_fall_back_to_host_planes(tmp_path, monkeypatch):
+    """A process that has described a TPU it does not run on (an AOT compile
+    for a topology, tests/test_tpu_aot_compile.py) finds that device's
+    planes in its later profiles, without an event: they are not the device."""
+    trace = tmp_path / "t" / "host.xplane.pb"
+    trace.parent.mkdir()
+    trace.write_bytes(b"")
+    planes = [
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": []}]},
+        {"name": "/host:CPU", "lines": [{"name": "tf_XLACpuClient", "events": [
+            ("%dot.1 = f32[8]{0} dot(f32[8]{0} %a, f32[8]{0} %b)", 5000)]}]}]
+    monkeypatch.setattr(xplane, "parse_planes", lambda data: planes)
+    s = xplane.summarize(str(tmp_path / "t"))
+    assert s["summarized_planes"] == ["/device:TPU:0", "/host:CPU"]
+    assert s["categories_pct"] == {"matmul/custom": 100.0}
+
+
 def test_summarize_empty_dir_error(tmp_path):
     s = xplane.summarize(str(tmp_path))
     assert "error" in s and "no xplane.pb" in s["error"]
