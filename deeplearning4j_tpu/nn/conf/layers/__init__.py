@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
 # imported for registration side effects too: a saved MoE model zip must
 # restore without the caller having imported the module first
 from deeplearning4j_tpu.nn.conf.layers.moe import MoELayer, MoETransformerBlock
+from deeplearning4j_tpu.nn.conf.layers.decoder import DecoderBlock, RMSNormLayer
 
 __all__ = [
     "Layer", "FeedForwardLayer", "PretrainLayer",
@@ -42,4 +43,5 @@ __all__ = [
     "BatchNormalization", "LocalResponseNormalization",
     "GravesLSTM", "LSTM", "GravesBidirectionalLSTM", "RnnOutputLayer",
     "VariationalAutoencoder", "SelfAttentionLayer", "TransformerBlock", "MoELayer", "MoETransformerBlock",
+    "DecoderBlock", "RMSNormLayer",
 ]

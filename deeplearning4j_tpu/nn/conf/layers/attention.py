@@ -12,9 +12,12 @@ Layout: [batch, time, features] like the recurrent layers.
 from __future__ import annotations
 
 import dataclasses
+import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.common import accum_dtype, at_least_f32, get_policy
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -24,7 +27,8 @@ from deeplearning4j_tpu.nn.conf.serde import register_config
 Array = jax.Array
 
 
-def attend(q: Array, k: Array, v: Array, causal: bool, mask=None) -> Array:
+def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
+           scale=None) -> Array:
     """The ONE attention-core dispatch every attention-bearing layer uses.
 
     Single device (no active ParallelContext): flash_attention (Pallas on
@@ -36,7 +40,9 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None) -> Array:
     ParallelWrapper.java:44 wraps any net without touching model code.
     Masked (variable-length) batches fall back to the dense masked kernel:
     correctness over parallelism, mirroring ParallelWrapper's own fallback
-    for semantics its sharded step doesn't cover.
+    for semantics its sharded step doesn't cover. ``v`` may have another
+    width than ``q`` and ``k``, and ``scale`` (None: ``Dk ** -0.5``) another
+    value: latent attention's core.
     """
     from deeplearning4j_tpu.ops.pallas_kernels import (
         flash_attention, masked_attention,
@@ -44,6 +50,15 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None) -> Array:
     from deeplearning4j_tpu.parallel import context as pctx
 
     ctx = pctx.current()
+    if scale is not None or v.shape[-1] != q.shape[-1]:
+        # latent attention (values narrower than keys, a score scale of its
+        # own): the single-device core only; neither the sequence-parallel
+        # bodies nor the key-masked kernel know those shapes yet
+        if mask is not None or (ctx is not None and ctx.seq_axis is not None):
+            raise NotImplementedError(
+                "attention with its own scale or value width runs unmasked "
+                "on one device only")
+        return flash_attention(q, k, v, causal, False, False, scale)
     if ctx is not None and ctx.seq_axis is not None and mask is None:
         from deeplearning4j_tpu.parallel.ring_attention import (
             ring_attention_sharded, ulysses_attention_sharded)
@@ -56,6 +71,61 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None) -> Array:
     if mask is not None:
         return masked_attention(q, k, v, mask, causal)
     return flash_attention(q, k, v, causal)
+
+
+def rms_norm(x: Array, g: Array, eps: float = 1e-6) -> Array:
+    """``x * rsqrt(mean(x^2) + eps) * g`` over the last axis, the statistic
+    in at least float32 whatever dtype the activations flow in."""
+    xf = x.astype(at_least_f32(x.dtype))
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * inv).astype(x.dtype) * g.astype(x.dtype)
+
+
+def rope_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """The ``dim // 2`` rotary frequencies. ``scaling`` None is plain RoPE;
+    ``{"type": "yarn", "factor", "original_max_position_embeddings",
+    "beta_fast", "beta_slow"}`` blends interpolated and extrapolated
+    frequencies with YaRN's linear ramp (Peng et al., arXiv:2309.00071, as
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` computes them)."""
+    pos = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** pos
+    if not scaling:
+        return extra
+    if scaling.get("type") != "yarn":
+        raise ValueError(f"unknown rope scaling {scaling.get('type')!r}")
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x: Array, inv_freq) -> Array:
+    """Rotate ``x`` [B, T, H, D] by position: the pair ``(x[2i], x[2i+1])``
+    turns by ``t * inv_freq[i]``, and the result comes out as DeepSeek-V2's
+    ``apply_rotary_pos_emb`` leaves it, every first element before every
+    second (queries and keys alike, so their products are untouched).
+    Angles and the rotation are float32."""
+    t = jnp.arange(x.shape[1], dtype=jnp.float32)
+    ang = t[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    xf = x.astype(at_least_f32(x.dtype))
+    a, b = xf[..., 0::2], xf[..., 1::2]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 @register_config("SelfAttention")

@@ -33,6 +33,8 @@ def _dense(params: dict, x: Array) -> Array:
     w = params["W"].astype(pol.compute_dtype)
     out = jnp.matmul(x.astype(pol.compute_dtype), w,
                      preferred_element_type=accum_dtype(pol.compute_dtype))
+    if "b" not in params:       # a layer built with has_bias=False
+        return out.astype(pol.output_dtype)
     return (out.astype(pol.compute_dtype)
             + params["b"].astype(pol.compute_dtype)).astype(pol.output_dtype)
 
@@ -129,18 +131,23 @@ class EmbeddingLayer(FeedForwardLayer):
     expects integer-index input, mathematically a one-hot matmul but implemented as a
     gather — on TPU a gather from an [vocab, dim] table in HBM)."""
 
+    #: False: the table alone, no "b" leaf (current language models)
+    has_bias: bool = True
+
     def init_params(self, key, itype: InputType) -> dict:
-        return {"W": self._init_w(key, (self.n_in, self.n_out)),
-                "b": self._init_b((self.n_out,))}
+        p = {"W": self._init_w(key, (self.n_in, self.n_out))}
+        if self.has_bias:
+            p["b"] = self._init_b((self.n_out,))
+        return p
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        # one-hot input: rank >= 3 ([B, T, V] sequences), or a floating-point
-        # [B, V] matrix — integer-id input is never mistaken for one-hot even
-        # when a sequence length coincides with the vocab size
-        one_hot = (x.shape[-1] == self.n_in and self.n_in > 1
+        # integer [B] or [B, T] input is ids, taken as they are: no guess.
+        # Otherwise one-hot input: rank >= 3 ([B, T, V] sequences), or a
+        # floating-point [B, V] matrix
+        ids = jnp.issubdtype(x.dtype, jnp.integer) and x.ndim <= 2
+        one_hot = (not ids and x.shape[-1] == self.n_in and self.n_in > 1
                    and (x.ndim >= 3
-                        or (x.ndim == 2
-                            and jnp.issubdtype(x.dtype, jnp.floating))))
+                        or jnp.issubdtype(x.dtype, jnp.floating)))
         if one_hot:
             idx = jnp.argmax(x, axis=-1).astype(jnp.int32)
         else:
@@ -148,8 +155,10 @@ class EmbeddingLayer(FeedForwardLayer):
             if idx.ndim > 1 and idx.shape[-1] == 1:
                 idx = idx[..., 0]
         pol = get_policy()
-        emb = (params["W"][idx] + params["b"]).astype(pol.output_dtype)
-        return self.act_fn()(emb), state
+        emb = params["W"][idx]
+        if "b" in params:
+            emb = emb + params["b"]
+        return self.act_fn()(emb.astype(pol.output_dtype)), state
 
 
 @register_config("AutoEncoder")

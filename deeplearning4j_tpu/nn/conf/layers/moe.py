@@ -14,15 +14,223 @@ Params: "Wg" [F, E] router; experts batched on the leading axis —
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.common import accum_dtype, get_policy
+from deeplearning4j_tpu.common import accum_dtype, at_least_f32, get_policy
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.serde import register_config
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_pair_rows(x2d, head, inv, n_routed, k: int):
+    """``rows[i] = x2d[head[i] % S]``: the token of the i-th sorted (token,
+    choice) pair, for the first ``len(head)`` pairs of the sorted order
+    (those routed here come first). Pairs are numbered choice-major, ``j * S
+    + s``, so a token's k pairs lie S apart and their sum is a sum of k
+    ``[S, F]`` slabs (token-major, the sum would run over a second-minor axis
+    of k, which the TPU's tiling pads and relays out). ``inv`` [S * k] is
+    the order's inverse, so the backward is a gather too (a routed pair's
+    row back to its place, then the slabs' sum), not a scatter-add.
+    Cotangent rows from ``n_routed`` on belong to no pair routed here (a
+    grouped product leaves them unwritten): they are dropped, not summed."""
+    return x2d[head % x2d.shape[0]]
+
+
+def _take_fwd(x2d, head, inv, n_routed, k):
+    return _take_pair_rows(x2d, head, inv, n_routed, k), (inv, n_routed)
+
+
+def _take_bwd(k, res, g):
+    inv, n_routed = res
+    g = _rows_of_pairs(g, inv, n_routed)
+    summed = jnp.sum(g.reshape(k, -1, g.shape[-1]).astype(jnp.float32),
+                     axis=0)
+    return summed.astype(g.dtype), None, None, None
+
+
+_take_pair_rows.defvjp(_take_fwd, _take_bwd)
+
+
+def _rows_of_pairs(rows, inv, n_routed):
+    """``out[p] = rows[inv[p]]`` where pair p was routed here (its place in
+    the sorted order lies below ``n_routed``, which never exceeds
+    ``len(rows)``), else 0."""
+    at = jnp.minimum(inv, rows.shape[0] - 1)
+    return jnp.where((inv < n_routed)[:, None], rows[at], 0)
+
+
+@jax.custom_vjp
+def _untake_pair_rows(rows, head, inv, n_routed):
+    """Sorted rows back in (token, choice) order, 0 for a pair not routed
+    here (``_rows_of_pairs``); the backward gathers with ``head`` and drops
+    the rows that carry no pair."""
+    return _rows_of_pairs(rows, inv, n_routed)
+
+
+def _untake_bwd(res, g):
+    head, n_routed = res
+    live = jnp.arange(head.shape[0])[:, None] < n_routed
+    return jnp.where(live, g[head], 0), None, None, None
+
+
+_untake_pair_rows.defvjp(
+    lambda rows, head, inv, n_routed: (
+        _untake_pair_rows(rows, head, inv, n_routed), (head, n_routed)),
+    _untake_bwd)
+
+
+#: row tile of the grouped products: a group's rows are computed in whole
+#: tiles, so ``computed rows`` counts up to one tile of padding a group
+GROUP_ROW_TILE = 512
+
+
+def _kernel_engaged(m: int, dtype) -> bool:
+    """Whether the grouped products of an ``m``-row buffer run as the Pallas
+    kernel: on a TPU, outside a partitioned jit, bfloat16 or float32, and a
+    row count the tile divides."""
+    from deeplearning4j_tpu.ops.pallas_kernels import use_pallas
+
+    return (use_pallas() and m % GROUP_ROW_TILE == 0
+            and dtype in (jnp.bfloat16, jnp.float32))
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, kernel: bool):
+    """``lhs[rows of group g] @ rhs[g]`` for every group: [M, K] x [G, K, N]
+    -> [M, N], rows sorted by group, float32 accumulation, output in
+    ``lhs``'s dtype. ``kernel``: the Pallas grouped product (JAX's megablox
+    ``gmm``: it visits only the row tiles that hold a group's rows, and its
+    backward is two more grouped products); else ``jax.lax.ragged_dot``.
+    Rows past ``sum(group_sizes)`` are not computed by either: the kernel
+    leaves them unwritten, ``ragged_dot`` zero."""
+    if kernel:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        # tiles sized for the 16 MiB of scoped VMEM: two buffers each of
+        # an lhs, an rhs and an out tile, and the float32 accumulator
+        k, n = lhs.shape[1], rhs.shape[-1]
+        tk = 1024 if lhs.dtype.itemsize == 2 else 512
+        return gmm(lhs, rhs, group_sizes, lhs.dtype,
+                   (GROUP_ROW_TILE, min(k, tk), min(n, 1024)))
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=accum_dtype(lhs.dtype)
+                              ).astype(lhs.dtype)
+
+
+def _tiled_rows(group_sizes):
+    """Rows the kernel runs over, padding included: every row tile a
+    non-empty group touches, counted once per group that touches it (as the
+    kernel visits them). Equal to the routed rows only where every group
+    starts and ends on a tile boundary."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    t = GROUP_ROW_TILE
+    tiles = jnp.where(group_sizes > 0, (ends + t - 1) // t - starts // t, 0)
+    return jnp.sum(tiles) * t
+
+
+def _usual_bound(pairs: int) -> int:
+    """The smaller of the dispatch buffer's two static sizes: a quarter of
+    all pairs in whole row tiles (with an even router and an eighth of the
+    experts held, twice what is routed here), or all of them where that is
+    less than a tile."""
+    quarter = -(-pairs // (4 * GROUP_ROW_TILE)) * GROUP_ROW_TILE
+    return quarter if GROUP_ROW_TILE <= quarter < pairs else pairs
+
+
+def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
+                       first_held: int = 0):
+    """The routed experts' part of a top-k expert layer for the experts held
+    here, dropless: ``y[t] = sum over t's choices e held here of
+    weight[t, e] * E_e(x[t])``, ``E_e`` a gated SiLU feed-forward
+    ``(silu(x Wg_e) * (x Wu_e)) Wd_e``.
+
+    ``x2d`` [S, F]; ``choice`` [S, k] int32 expert ids over ALL experts;
+    ``weight`` [S, k]; ``w_gate``/``w_up`` [G, F, H] and ``w_down`` [G, H, F]
+    hold experts ``first_held .. first_held + G - 1``. A choice outside that
+    range adds nothing here (another chip's part). Returns ``(y [S, F],
+    rows)`` with ``rows`` int32 [3]: the (token, choice) pairs routed here,
+    the rows the grouped products ran over (padding included), the largest
+    expert's rows.
+
+    **Sort and group.** The ``S * k`` pairs (numbered choice-major, see
+    ``_take_pair_rows``) are sorted by expert, pairs for absent experts
+    last; each sorted row takes its token's activations, the
+    three products run grouped over the G experts (``_grouped_matmul``), and
+    the rows go back to pair order, are weighted and summed per token.
+
+    **No pair is dropped whatever the imbalance.** The row buffer has one of
+    two static sizes, chosen on the device from the count of pairs routed
+    here (``lax.cond``): ``_usual_bound`` (a quarter of all pairs) where
+    they fit in it, else all ``S * k`` rows, which is every pair there is
+    (one expert may take them all). Rows past the routed count belong to no
+    expert held here: the grouped products do not compute them, the combine
+    replaces them with 0 before weighting, their cotangent is dropped on the
+    way back, and they are not counted as work (``rows[1]`` stops at the
+    last group's last tile)."""
+    S, k = choice.shape
+    G = w_gate.shape[0]
+    pairs = S * k
+    pol = get_policy()
+    with jax.named_scope("moe/dispatch"):
+        # choice-major pairs: pair j * S + s is token s's j-th choice
+        local = choice.T.reshape(pairs) - first_held
+        key = jnp.where((local >= 0) & (local < G), local, G).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(pairs, dtype=jnp.int32))
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32)
+        n_routed = jnp.sum(group_sizes)
+
+    def run(bound: int):
+        """The layer over a buffer of ``bound`` rows (>= the routed count)."""
+        from deeplearning4j_tpu.ops.pallas_kernels import _note_dispatch
+
+        kernel = _kernel_engaged(bound, jnp.dtype(pol.compute_dtype))
+        _note_dispatch("grouped_matmul", kernel)
+
+        def layer(x2d, weight, wg, wu, wd):
+            head = order[:bound]
+            with jax.named_scope("moe/dispatch"):
+                rows = _take_pair_rows(x2d, head, inv, n_routed, k)
+            with jax.named_scope("moe/experts"):
+                rows = rows.astype(pol.compute_dtype)
+                gate = _grouped_matmul(rows, wg, group_sizes, kernel)
+                up = _grouped_matmul(rows, wu, group_sizes, kernel)
+                act = (jax.nn.silu(gate.astype(at_least_f32(gate.dtype)))
+                       .astype(gate.dtype) * up)
+                out = _grouped_matmul(act, wd, group_sizes, kernel).astype(
+                    pol.output_dtype)
+            with jax.named_scope("moe/dispatch"):
+                out = _untake_pair_rows(out, head, inv, n_routed)
+                w = weight.T.astype(at_least_f32(out.dtype))[..., None]
+                y = jnp.sum(out.reshape(k, S, -1).astype(w.dtype) * w,
+                            axis=0).astype(out.dtype)
+            return y, _tiled_rows(group_sizes) if kernel else n_routed
+
+        return layer
+
+    operands = (x2d, weight) + tuple(
+        w.astype(pol.compute_dtype) for w in (w_gate, w_up, w_down))
+    usual = _usual_bound(pairs)
+    if usual < pairs:
+        # each branch is rematerialised: the cond's backward then needs the
+        # operands alone, not both branches' intermediates (the full
+        # buffer's would be written as zeros whenever the usual one ran)
+        y, computed = jax.lax.cond(
+            n_routed <= usual, jax.checkpoint(run(usual)),
+            jax.checkpoint(run(pairs)), *operands)
+    else:
+        y, computed = run(pairs)(*operands)
+    stats = jnp.stack([n_routed, computed,
+                       jnp.max(group_sizes)]).astype(jnp.int32)
+    return y, stats
 
 
 @register_config("MoE")

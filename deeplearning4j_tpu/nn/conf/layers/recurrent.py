@@ -153,6 +153,8 @@ class RnnOutputLayer(FeedForwardLayer):
     dense applied at every timestep of [B,T,F], loss masked by the time-series mask."""
 
     loss: str = "mcxent"
+    #: False: logits = h W, no "b" leaf (current language-model heads)
+    has_bias: bool = True
 
     def has_loss(self) -> bool:
         return True
@@ -162,8 +164,10 @@ class RnnOutputLayer(FeedForwardLayer):
             self.n_in = itype.size if itype.kind == "recurrent" else itype.flat_size()
 
     def init_params(self, key, itype: InputType) -> dict:
-        return {"W": self._init_w(key, (self.n_in, self.n_out)),
-                "b": self._init_b((self.n_out,))}
+        p = {"W": self._init_w(key, (self.n_in, self.n_out))}
+        if self.has_bias:
+            p["b"] = self._init_b((self.n_out,))
+        return p
 
     def output_type(self, itype: InputType) -> InputType:
         return InputType.recurrent(self.n_out, itype.timesteps)

@@ -36,7 +36,10 @@ from deeplearning4j_tpu.observability.flight_recorder import (
     dump_on_unhandled as _dump_on_unhandled,
     global_recorder as _flight_recorder,
 )
-from deeplearning4j_tpu.observability.names import FIT_PHASE_SECONDS
+from deeplearning4j_tpu.observability.names import (
+    FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
+    MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
+)
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry,
 )
@@ -69,6 +72,24 @@ _t_staging = _phase_hist.labels(phase="staging")
 _t_device = _phase_hist.labels(phase="device")
 _t_dispatch = _phase_hist.labels(phase="dispatch")
 _t_listeners = _phase_hist.labels(phase="listeners")
+
+
+_moe_tokens = _obs_registry().counter(
+    MOE_TOKENS_TOTAL, "tokens that went through the expert layers of "
+    "dispatched K-step groups (each counted once, not once per layer)")
+_moe_routed = _obs_registry().counter(
+    MOE_ROUTED_ROWS_TOTAL, "(token, choice) pairs routed to an expert this "
+    "chip holds, by expert layer")
+_moe_computed = _obs_registry().counter(
+    MOE_COMPUTED_ROWS_TOTAL, "rows the grouped expert products ran over, "
+    "padding to whole row tiles included, by expert layer")
+_moe_rows_max = _obs_registry().gauge(
+    MOE_EXPERT_ROWS_MAX, "rows of the busiest held expert in one step, the "
+    "largest of the last group read, by expert layer")
+_moe_rows_max_total = _obs_registry().counter(
+    MOE_EXPERT_ROWS_MAX_TOTAL, "rows of the busiest held expert, summed over "
+    "the steps (over the routed rows / experts held of the same steps: how "
+    "uneven the routing was), by expert layer")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -278,6 +299,11 @@ def make_multistep_train_step(conf: MultiLayerConfiguration, *,
     ``health=True`` threads the per-step health vector through the scan and
     returns it stacked as ``(K, 4)`` after the losses; the dispatcher picks
     the row for the cadence-due iteration (a lazy device gather, no sync).
+
+    Where expert layers publish ``moe_rows`` in their state (DecoderBlock),
+    every step's rows come back last, stacked ``(K, layers, 3)`` int32: the
+    fit loop reads them on the host once they are ready, beside the score
+    (``LazyScore._note_moe_rows``).
     """
     step = make_train_step(conf, health=health)
 
@@ -287,18 +313,16 @@ def make_multistep_train_step(conf: MultiLayerConfiguration, *,
             p, s, u, it = carry
             x, y = batch
             key = jax.random.fold_in(rng, it)
-            if health:
-                p, s, u, loss, haux = step(p, s, u, x, y, key, it)
-                return (p, s, u, it + 1), (loss, haux)
-            p, s, u, loss = step(p, s, u, x, y, key, it)
-            return (p, s, u, it + 1), loss
+            p, s, u, *out = step(p, s, u, x, y, key, it)
+            rows = [st["moe_rows"] for st in s
+                    if isinstance(st, dict) and "moe_rows" in st]
+            if rows:
+                out.append(jnp.stack(rows))
+            return (p, s, u, it + 1), tuple(out)
 
         (p, s, u, _), out = jax.lax.scan(
             body, (params_list, state_list, upd_state, iteration0), (xs, ys))
-        if health:
-            losses, hauxs = out
-            return p, s, u, losses, hauxs
-        return p, s, u, out
+        return (p, s, u) + out
 
     # the function's name is the compiled module's (``jit_dl4j_train_ksteps``):
     # a profile's reader finds the step program by it
@@ -319,16 +343,28 @@ def _stage_host(x, dtype, out=None):
         return out
     if dtype is None:
         return x
-    if isinstance(x, jax.Array):
-        return x.astype(dtype)
-    return np.asarray(x).astype(dtype, copy=False)
+    on_device = isinstance(x, jax.Array)
+    if not on_device:
+        x = np.asarray(x)
+    if not _castable(x.dtype, dtype):
+        return x        # an integer leaf (token ids) is left as it is
+    return x.astype(dtype) if on_device else x.astype(dtype, copy=False)
+
+
+def _castable(leaf_dtype, stage_dtype) -> bool:
+    """Whether staging may cast a feature leaf of ``leaf_dtype`` to
+    ``stage_dtype``: never an integer leaf (token ids, class ids) to a float
+    type, which cannot hold them (bfloat16 is exact only up to 256)."""
+    return not (np.issubdtype(np.dtype(leaf_dtype), np.integer)
+                and not np.issubdtype(np.dtype(stage_dtype), np.integer))
 
 
 def stage_group(batches: list, dtype, ring: HostGroupRing):
     """Stage one K-step group of host batches ``[(features, labels), ...]``
     (arrays, or for a graph one list of arrays per stream) as ``(K, B, ...)``
     device arrays, the features cast to ``dtype`` on the host (None keeps
-    their own). Returns ``(xs, ys)``.
+    their own, and an integer leaf always keeps its own: ``_castable``).
+    Returns ``(xs, ys)``.
 
     One pass over the bytes: every batch is written straight into its place
     in a slot of ``ring`` (``datasets.prefetch.HostGroupRing``: reused host
@@ -352,6 +388,7 @@ def stage_group(batches: list, dtype, ring: HostGroupRing):
     spec = tuple(
         (col[0].shape,
          np.dtype(dtype) if dtype is not None and i < n_features
+         and _castable(col[0].dtype, dtype)
          else np.result_type(*(a.dtype for a in col)))
         for i, col in enumerate(columns))
 
@@ -409,10 +446,37 @@ class LazyScore:
         if callable(raw):
             raw = float(raw())
             self._score_raw = raw
+            self._note_moe_rows()
         elif not isinstance(raw, float):
             raw = float(raw)
             self._score_raw = raw
         return raw
+
+    #: ``(rows (K, layers, 3) on the device, tokens)`` of the dispatched
+    #: groups whose expert-layer rows the host has not read yet
+    _pending_moe_rows: tuple = ()
+
+    def _note_moe_rows(self) -> None:
+        """Book the expert layers' rows of every dispatched group whose step
+        has finished (``dl4j_moe_*``), oldest first, and never wait for one:
+        called after each dispatch, where flow control has just waited for
+        the group two back, and when a score has been read."""
+        pending = self._pending_moe_rows
+        if not pending:
+            return
+        layers = [str(i) for i, st in enumerate(self.state_list)
+                  if isinstance(st, dict) and "moe_rows" in st]
+        while pending and pending[0][0].is_ready():
+            (rows, tokens), pending = pending[0], pending[1:]
+            self._pending_moe_rows = pending
+            rows = np.asarray(rows)           # (K, layers, 3), finished
+            _moe_tokens.inc(tokens)
+            for j, layer in enumerate(layers):
+                _moe_routed.labels(layer=layer).inc(int(rows[:, j, 0].sum()))
+                _moe_computed.labels(layer=layer).inc(int(rows[:, j, 1].sum()))
+                _moe_rows_max.labels(layer=layer).set(int(rows[:, j, 2].max()))
+                _moe_rows_max_total.labels(layer=layer).inc(
+                    int(rows[:, j, 2].sum()))
 
     @score_value.setter
     def score_value(self, value) -> None:
@@ -564,15 +628,17 @@ class LazyScore:
         dt, t1_ns = time.perf_counter() - t0, time.time_ns()
         _t_dispatch.observe(dt)
         _profile_note_dispatch(dt)
-        if due_i is None:
-            (self.params_list, self.state_list, self.updater_state,
-             losses) = out
-        else:
-            (self.params_list, self.state_list, self.updater_state,
-             losses, hauxs) = out
+        (self.params_list, self.state_list, self.updater_state,
+         losses, *rest) = out
+        if due_i is not None:
             # lazy device gather of the due step's packed health vector — the
             # monitor parks it; the host sync happens at poll() time
-            hm.offer(hauxs[due_i], self.iteration + due_i)
+            hm.offer(rest.pop(0)[due_i], self.iteration + due_i)
+        if rest:
+            tokens = math.prod(jax.tree_util.tree_leaves(xs)[0].shape[:3])
+            self._pending_moe_rows = (*self._pending_moe_rows,
+                                      (rest[0], tokens))
+            self._note_moe_rows()
         wrap_name = f"{type(self).__name__}.{name}"
         _compile_tracker().note_step(n, fn=wrap_name)
         # the step event is the group's ``fit.dispatch`` span

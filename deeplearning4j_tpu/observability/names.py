@@ -44,6 +44,13 @@ SHARDED_PARAM_BYTES_PER_DEVICE = "dl4j_sharded_param_bytes_per_device"
 # --- kernel dispatch (ops/pallas_kernels.py) -------------------------------
 PALLAS_DISPATCH_TOTAL = "dl4j_pallas_dispatch_total"
 
+# --- expert layers (nn/conf/layers/decoder.py, read out by nn/multilayer.py) -
+MOE_TOKENS_TOTAL = "dl4j_moe_tokens_total"
+MOE_ROUTED_ROWS_TOTAL = "dl4j_moe_routed_rows_total"
+MOE_COMPUTED_ROWS_TOTAL = "dl4j_moe_computed_rows_total"
+MOE_EXPERT_ROWS_MAX = "dl4j_moe_expert_rows_max"
+MOE_EXPERT_ROWS_MAX_TOTAL = "dl4j_moe_expert_rows_max_total"
+
 # --- recurrent engine (ops/lstm.py) ----------------------------------------
 LSTM_DISPATCH_TOTAL = "dl4j_lstm_dispatch_total"
 LSTM_PALLAS_BLOCK_STEPS = "dl4j_lstm_pallas_block_steps"

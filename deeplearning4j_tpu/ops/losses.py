@@ -133,10 +133,60 @@ def _fused_xent_engaged(preout: Array) -> bool:
     return _xent_interpret() or use_pallas()
 
 
+@jax.custom_vjp
+def sparse_softmax_xent(logits: Array, labels: Array) -> Array:
+    """Per-row softmax cross entropy against integer class ids, with no
+    one-hot of the classes anywhere: ``logits`` (..., C) in any float dtype,
+    ``labels`` (...) integers -> (...) float32.
+
+    The logits stay in the dtype they arrive in (bfloat16 under
+    ``bfloat16_full``: 16,384 x 12,800 float32 logits would be 0.84 GB);
+    the max, the sum of exponentials and the picked logit are float32
+    reductions over them, and the backward writes ``(softmax - onehot) * ct``
+    straight back in the logits' dtype from the saved log-sum-exp."""
+    return _sparse_xent_fwd(logits, labels)[0]
+
+
+def _sparse_xent_fwd(logits, labels):
+    f32 = jnp.promote_types(logits.dtype, jnp.float32)
+    m = jnp.max(logits, axis=-1, keepdims=True).astype(f32)
+    z = jnp.sum(jnp.exp(logits.astype(f32) - m), axis=-1)
+    lse = m[..., 0] + jnp.log(z)
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return lse - picked.astype(f32), (logits, labels, lse)
+
+
+def _sparse_xent_bwd(res, ct):
+    logits, labels, lse = res
+    f32 = lse.dtype
+    p = jnp.exp(logits.astype(f32) - lse[..., None])
+    hit = labels[..., None] == jax.lax.broadcasted_iota(
+        labels.dtype, logits.shape, logits.ndim - 1)
+    d = (p - hit.astype(f32)) * ct[..., None].astype(f32)
+    return d.astype(logits.dtype), None
+
+
+sparse_softmax_xent.defvjp(_sparse_xent_fwd, _sparse_xent_bwd)
+
+
+def _is_class_ids(labels: Array, preout: Array) -> bool:
+    """Integer labels one rank below the logits: class ids, not one-hot."""
+    return (jnp.issubdtype(labels.dtype, jnp.integer)
+            and labels.ndim == preout.ndim - 1)
+
+
 def mcxent(labels: Array, preout: Array, activation, mask=None) -> Array:
     """Multi-class cross entropy (reference LossMCXENT). Fused log-softmax when the
     output activation is softmax (the common OutputLayer pairing); on TPU the
-    per-row loss+gradient ride the fused Pallas kernel via custom_vjp."""
+    per-row loss+gradient ride the fused Pallas kernel via custom_vjp.
+    Integer ``labels`` one rank below ``preout`` are class ids: the loss is
+    :func:`sparse_softmax_xent` and no one-hot is built."""
+    if _is_class_ids(labels, preout):
+        if not _is_softmax(activation):
+            raise ValueError("integer class-id labels need a softmax output "
+                             "activation (mcxent over logits)")
+        per = sparse_softmax_xent(preout, labels.astype(jnp.int32))
+        return _reduce(per[..., None], mask)
     if _is_softmax(activation):
         if _fused_xent_engaged(preout):
             C = preout.shape[-1]
@@ -245,8 +295,13 @@ def _f32_entry(fn: Callable) -> Callable:
         return a
 
     def wrapped(labels, preout, activation, mask=None):
-        return fn(_upcast(jnp.asarray(labels)),
-                  _upcast(jnp.asarray(preout)), activation, mask)
+        labels, preout = jnp.asarray(labels), jnp.asarray(preout)
+        if (fn in (mcxent, negativeloglikelihood)
+                and _is_class_ids(labels, preout)):
+            # class ids: the loss reduces in float32 itself and never
+            # materialises float32 logits (sparse_softmax_xent)
+            return fn(labels, preout, activation, mask)
+        return fn(_upcast(labels), _upcast(preout), activation, mask)
     return wrapped
 
 

@@ -121,6 +121,21 @@ def use_pallas() -> bool:
 
 
 # ---------------------------------------------------------------- flash attention
+def _dot(a, b, contract_a: int, contract_b: int):
+    """``a`` x ``b`` over the given axes, in the operands' own dtype on the
+    MXU (bfloat16 stays bfloat16: an upcast product takes several passes)
+    with float32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _causal_block_live(qi, kj, blk_q: int, blk_k: int):
+    """Whether the (q-block, k-block) tile holds any unmasked score under a
+    causal mask; a dead tile adds exactly nothing and is skipped."""
+    return kj * blk_k <= qi * blk_q + (blk_q - 1)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
                       blk_k: int, scale: float, has_mask: bool):
     """One (batch*head, q-block, k-block) program of the online softmax.
@@ -130,11 +145,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
     ``m``, normalizer ``l`` and unnormalized output ``acc`` ride VMEM scratch
     from the first k-block (init) to the last (finalize) — so the VMEM a
     program holds is a function of the tile sizes only, never of the
-    sequence length. q_ref/o_ref: (1, blk_q, D); k_ref/v_ref: (1, blk_k, D);
+    sequence length. q_ref: (1, blk_q, Dk); k_ref: (1, blk_k, Dk); v_ref:
+    (1, blk_k, Dv); o_ref: (1, blk_q, Dv) — the value width may differ from
+    the query/key width (latent attention: 192 and 128);
     lse_ref: (1, blk_q, 1) log-sum-exp of the scaled scores per query row —
     saved so the backward can recompute P = exp(S - lse) without a second
     online-softmax pass. With has_mask, a (1, blk_k, 1) {0,1} key-padding
-    mask block precedes the outputs: masked keys get -inf logits.
+    mask block precedes the outputs: masked keys get -inf logits. Under a
+    causal mask a tile wholly above the diagonal is skipped.
     """
     if has_mask:
         km_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
@@ -149,23 +167,28 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    q = q_ref[0].astype(jnp.float32) * scale      # block is (1, blk_q, D)
-    k_blk = k_ref[0].astype(jnp.float32)
-    v_blk = v_ref[0].astype(jnp.float32)
-    s = q @ k_blk.T                                   # (blk_q, blk_k)
-    if has_mask:
-        km_blk = km_ref[0, :, 0].astype(jnp.float32)
-        s = jnp.where(km_blk[None, :] > 0, s, _NEG)
+    def _tile():
+        v_blk = v_ref[0]
+        s = _dot(q_ref[0], k_ref[0], 1, 1) * scale        # (blk_q, blk_k)
+        if has_mask:
+            km_blk = km_ref[0, :, 0].astype(jnp.float32)
+            s = jnp.where(km_blk[None, :] > 0, s, _NEG)
+        if causal:
+            s = _causal_mask(s, qi * blk_q, kj * blk_k)
+        m = m_sc[...]                                     # (blk_q, 1)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(s <= _NEG, 0.0, p)
+        alpha = jnp.exp(m - m_new)
+        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v_blk.dtype),
+                                                 v_blk, 1, 0)
+        m_sc[...] = m_new
+
     if causal:
-        s = _causal_mask(s, qi * blk_q, kj * blk_k)
-    m = m_sc[...]                                     # (blk_q, 1)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(s <= _NEG, 0.0, p)
-    alpha = jnp.exp(m - m_new)
-    l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_sc[...] = acc_sc[...] * alpha + p @ v_blk
-    m_sc[...] = m_new
+        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
+    else:
+        _tile()
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
@@ -187,62 +210,75 @@ def _bh_mask(key_mask: Array, H: int) -> Array:
                             (B, H, Tk)).reshape(B * H, Tk, 1)
 
 
+def _kv_block(causal: bool, blk_q: int, blk_k: int):
+    """Index map of a K/V block on a (bh, q-block, k-block) grid. Under a
+    causal mask a tile above the diagonal is never computed
+    (``_causal_block_live``); naming the diagonal's block again there keeps
+    the pipeline from fetching one that nobody reads."""
+    if not causal:
+        return lambda bh, i, j: (bh, j, 0)
+    return lambda bh, i, j: (
+        bh, jnp.minimum(j, (i * blk_q + (blk_q - 1)) // blk_k), 0)
+
+
 def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
                    blk_q: int = None, blk_k: int = None,
-                   interpret: bool = False, key_mask: Array = None):
-    """q,k,v: (B, T, H, D) -> (out (B, T, H, D), lse (B*H, Tq) f32). None
-    block sizes -> env-tunable module defaults (_BLK_Q/_BLK_K). key_mask:
-    optional [B, Tk] {0,1} key-padding mask."""
+                   interpret: bool = False, key_mask: Array = None,
+                   scale: float = None):
+    """q,k: (B, T, H, Dk), v: (B, T, H, Dv) -> (out (B, T, H, Dv), lse
+    (B*H, Tq) f32). None block sizes -> env-tunable module defaults
+    (_BLK_Q/_BLK_K). key_mask: optional [B, Tk] {0,1} key-padding mask.
+    ``scale`` None is ``Dk ** -0.5``."""
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     blk_q = min(blk_q, Tq) if blk_q else _pick_blk(Tq, _BLK_Q)
     blk_k = min(blk_k, Tk) if blk_k else _pick_blk(Tk, _BLK_K)
     if not blk_q or not blk_k or Tq % blk_q or Tk % blk_k:
         raise ValueError(f"sequence lengths ({Tq},{Tk}) must be divisible by "
                          f"block sizes ({blk_q},{blk_k})")
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     has_mask = key_mask is not None
 
     kernel = functools.partial(_flash_fwd_kernel, causal=causal, blk_q=blk_q,
                                blk_k=blk_k, scale=scale, has_mask=has_mask)
+    kv = _kv_block(causal, blk_q, blk_k)
     in_specs = [
         pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, blk_k, D), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, blk_k, D), lambda bh, i, j: (bh, j, 0)),
+        pl.BlockSpec((1, blk_k, D), kv),
+        pl.BlockSpec((1, blk_k, Dv), kv),
     ]
     operands = [qr, kr, vr]
     if has_mask:
-        in_specs.append(pl.BlockSpec((1, blk_k, 1),
-                                     lambda bh, i, j: (bh, j, 0)))
+        in_specs.append(pl.BlockSpec((1, blk_k, 1), kv))
         operands.append(_bh_mask(key_mask, H))
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, Tq // blk_q, Tk // blk_k),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, blk_q, Dv), lambda bh, i, j: (bh, i, 0)),
             # trailing singleton: see _bh_mask on Mosaic block-layout rules
             pl.BlockSpec((1, blk_q, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, 1), jnp.float32),
-                        pltpu.VMEM((blk_q, D), jnp.float32)],
+                        pltpu.VMEM((blk_q, Dv), jnp.float32)],
         compiler_params=_STREAMED,
         interpret=interpret,
     )(*operands)
     return _unflatten_heads(out, B, H), lse[:, :, 0]
 
 
-def _attention_xla(q, k, v, causal):
+def _attention_xla(q, k, v, causal, scale=None):
     # Single source of truth for the reference math (also the ring-attention
     # correctness oracle) — keep one copy so masking/scaling can't diverge.
     from deeplearning4j_tpu.parallel.ring_attention import attention_reference
-    return attention_reference(q, k, v, causal).astype(q.dtype)
+    return attention_reference(q, k, v, causal, scale).astype(q.dtype)
 
 
 def _in_shard_map() -> bool:
@@ -432,12 +468,15 @@ def _masked_bwd_rule(causal, interpret, force, res, g):
 _masked_attention_vjp.defvjp(_masked_fwd_rule, _masked_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
                     interpret: bool = False,
-                    force_pallas: bool = False) -> Array:
+                    force_pallas: bool = False,
+                    scale: float = None) -> Array:
     """Tiled attention: pallas forward on TPU (shapes that don't tile fall
     back to the identical XLA math rather than erroring), XLA elsewhere.
+    ``v`` may be narrower or wider than ``q`` and ``k`` (latent attention:
+    192-wide keys, 128-wide values); ``scale`` None is ``Dk ** -0.5``.
     Backward is tiled pallas too (dQ + dK/dV kernels recomputing P from the
     saved logsumexp — flash-attention practice: trade FLOPs for HBM; peak
     extra memory O(blk·T), never O(Tq·Tk)); set DL4J_FLASH_PALLAS_BWD=0 to
@@ -463,8 +502,9 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     ok = _pallas_ok(q, k, interpret, force_pallas)
     _note_dispatch("flash_attention", ok)
     if ok:
-        return _flash_forward(q, k, v, causal, interpret=interpret)[0]
-    return _attention_xla(q, k, v, causal)
+        return _flash_forward(q, k, v, causal, interpret=interpret,
+                              scale=scale)[0]
+    return _attention_xla(q, k, v, causal, scale)
 
 
 # -------------------------------------------------- pallas backward kernels
@@ -473,7 +513,7 @@ def _recomputed_probs(q, k_blk, lse_ref, km_ref, causal, q0, k0, scale):
     logsumexp — ONE copy for both backward kernels. Masked entries clamp to
     P = 0 rather than exp(S − lse): for a fully key-masked row lse is ~_NEG
     and the exponent would overflow."""
-    s = (q @ k_blk.T) * scale
+    s = _dot(q, k_blk, 1, 1) * scale
     if km_ref is not None:
         km_blk = km_ref[0, :, 0].astype(jnp.float32)
         s = jnp.where(km_blk[None, :] > 0, s, _NEG)
@@ -488,7 +528,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          scale: float, has_mask: bool = False):
     """dQ program per (batch*head, q-block, k-block); the k-block axis is
     sequential and dQ accumulates in VMEM scratch across it (same streaming
-    shape as the forward).
+    shape as the forward, dead causal tiles skipped alike).
 
     dS = P ∘ (dP − delta) with P = exp(S − lse), dP = dO·Vᵀ,
     delta = rowsum(dO ∘ O); dQ = dS·K·scale.
@@ -502,16 +542,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
 
-    q = q_ref[0].astype(jnp.float32)              # (blk_q, D)
-    do = do_ref[0].astype(jnp.float32)
-    delta = delta_ref[0].astype(jnp.float32)      # (blk_q, 1)
-    k_blk = k_ref[0].astype(jnp.float32)
-    v_blk = v_ref[0].astype(jnp.float32)
-    p = _recomputed_probs(q, k_blk, lse_ref, km_ref, causal,
-                          qi * blk_q, kj * blk_k, scale)
-    dp = do @ v_blk.T
-    ds = p * (dp - delta) * scale
-    dq_sc[...] += ds @ k_blk
+    def _tile():
+        k_blk = k_ref[0]                              # (blk_k, Dk)
+        delta = delta_ref[0].astype(jnp.float32)      # (blk_q, 1)
+        p = _recomputed_probs(q_ref[0], k_blk, lse_ref, km_ref, causal,
+                              qi * blk_q, kj * blk_k, scale)
+        dp = _dot(do_ref[0], v_ref[0], 1, 1)
+        ds = p * (dp - delta) * scale
+        dq_sc[...] += _dot(ds.astype(k_blk.dtype), k_blk, 1, 0)
+
+    if causal:
+        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
+    else:
+        _tile()
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
@@ -537,17 +580,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
 
-    k_blk = k_ref[0].astype(jnp.float32)          # (blk_k, D)
-    v_blk = v_ref[0].astype(jnp.float32)
-    q_blk = q_ref[0].astype(jnp.float32)          # (blk_q, D)
-    do_blk = do_ref[0].astype(jnp.float32)
-    delta_blk = delta_ref[0].astype(jnp.float32)  # (blk_q, 1)
-    p = _recomputed_probs(q_blk, k_blk, lse_ref, km_ref, causal,
-                          qi * blk_q, kj * blk_k, scale)
-    dv_sc[...] += p.T @ do_blk
-    dp = do_blk @ v_blk.T
-    ds = p * (dp - delta_blk) * scale
-    dk_sc[...] += ds.T @ q_blk
+    def _tile():
+        q_blk = q_ref[0]                              # (blk_q, Dk)
+        do_blk = do_ref[0]                            # (blk_q, Dv)
+        delta_blk = delta_ref[0].astype(jnp.float32)  # (blk_q, 1)
+        p = _recomputed_probs(q_blk, k_ref[0], lse_ref, km_ref, causal,
+                              qi * blk_q, kj * blk_k, scale)
+        dv_sc[...] += _dot(p.astype(do_blk.dtype), do_blk, 0, 0)
+        dp = _dot(do_blk, v_ref[0], 1, 1)
+        ds = p * (dp - delta_blk) * scale
+        dk_sc[...] += _dot(ds.astype(q_blk.dtype), q_blk, 0, 0)
+
+    if causal:
+        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
+    else:
+        _tile()
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finalize():
@@ -557,17 +604,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     blk_k: int = None, interpret: bool = False,
-                    key_mask: Array = None):
+                    key_mask: Array = None, scale: float = None):
     """Tiled pallas backward from the saved forward logsumexp. key_mask:
-    optional [B, Tk] {0,1} key-padding mask, same semantics as forward."""
+    optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
+    ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``."""
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[-1]
     blk_q = min(blk_q, Tq) if blk_q else _pick_blk(Tq, _BLK_Q)
     blk_k = min(blk_k, Tk) if blk_k else _pick_blk(Tk, _BLK_K)
     if not blk_q or not blk_k or Tq % blk_q or Tk % blk_k:
         raise ValueError(f"sequence lengths ({Tq},{Tk}) must be divisible by "
                          f"block sizes ({blk_q},{blk_k})")
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     gr, outr = _flatten_heads(g), _flatten_heads(out)
     # delta = rowsum(dO ∘ O): one cheap fused elementwise+reduce in XLA;
@@ -586,16 +634,26 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         """Block specs for (q, k, v, dO, lse, delta[, mask]) on a
         (bh, a, b) grid whose axis ``q_pos`` (1 or 2) walks the q-blocks
         and whose other block axis walks the k-blocks."""
+        # under a causal mask a dead tile (``_causal_block_live``) names the
+        # nearest live tile's streamed block again, so nothing is fetched
+        # for it: the k-block is held at the diagonal where the k axis
+        # streams (dQ), the q-block at the first live one where q does
         def q_map(*g):
-            return (g[0], g[q_pos], 0)
+            i, j = g[q_pos], g[3 - q_pos]
+            if causal and q_pos == 2:
+                i = jnp.maximum(i, (j * blk_k) // blk_q)
+            return (g[0], i, 0)
 
         def k_map(*g):
-            return (g[0], g[3 - q_pos], 0)
+            i, j = g[q_pos], g[3 - q_pos]
+            if causal and q_pos == 1:
+                j = jnp.minimum(j, (i * blk_q + (blk_q - 1)) // blk_k)
+            return (g[0], j, 0)
 
         out = [pl.BlockSpec((1, blk_q, D), q_map),
                pl.BlockSpec((1, blk_k, D), k_map),
-               pl.BlockSpec((1, blk_k, D), k_map),
-               pl.BlockSpec((1, blk_q, D), q_map),
+               pl.BlockSpec((1, blk_k, Dv), k_map),
+               pl.BlockSpec((1, blk_q, Dv), q_map),
                pl.BlockSpec((1, blk_q, 1), q_map),
                pl.BlockSpec((1, blk_q, 1), q_map)]
         if has_mask:
@@ -619,14 +677,14 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         in_specs=specs(2),
         out_specs=[
             pl.BlockSpec((1, blk_k, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda bh, j, i: (bh, j, 0)),
+            pl.BlockSpec((1, blk_k, Dv), lambda bh, j, i: (bh, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Tk, Dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
-                        pltpu.VMEM((blk_k, D), jnp.float32)],
+                        pltpu.VMEM((blk_k, Dv), jnp.float32)],
         compiler_params=_STREAMED,
         interpret=interpret,
     )(*operands)
@@ -635,7 +693,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
             _unflatten_heads(dv, B, H))
 
 
-def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None):
+def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
+                           scale: float = None):
     """Chunked attention backward: lax.scan over query blocks, recomputing the
     (blk_q, Tk) score tile per step. dK/dV accumulate in f32 in the carry.
 
@@ -647,8 +706,8 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None):
     """
     blk_q = blk_q or _BLK_Q
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    scale = 1.0 / (D ** 0.5)
+    Tk, Dv = k.shape[1], v.shape[-1]
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     blk_q = min(blk_q, Tq)
     pad = (-Tq) % blk_q
     qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else q
@@ -656,7 +715,7 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None):
     n = (Tq + pad) // blk_q
     # (n, B, blk_q, H, D) chunk-major for scan
     qs = qp.reshape(B, n, blk_q, H, D).transpose(1, 0, 2, 3, 4)
-    gs = gp.reshape(B, n, blk_q, H, D).transpose(1, 0, 2, 3, 4)
+    gs = gp.reshape(B, n, blk_q, H, Dv).transpose(1, 0, 2, 3, 4)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
 
@@ -702,24 +761,25 @@ def _pallas_bwd_enabled(seq_k: int = None, force: bool = False) -> bool:
     return force or seq_k is None or seq_k >= _PBWD_MIN_SEQ
 
 
-def _flash_fwd_rule(q, k, v, causal, interpret, force):
+def _flash_fwd_rule(q, k, v, causal, interpret, force, scale):
     tiled_bwd = (_pallas_ok(q, k, interpret, force)
                  and _pallas_bwd_enabled(k.shape[1], force))
     _note_dispatch("flash_attention_bwd", tiled_bwd)
     if tiled_bwd:
         _note_dispatch("flash_attention", True)
-        out, lse = _flash_forward(q, k, v, causal, interpret=interpret)
+        out, lse = _flash_forward(q, k, v, causal, interpret=interpret,
+                                  scale=scale)
         return out, (q, k, v, out, lse)
-    return (flash_attention(q, k, v, causal, interpret, force),
+    return (flash_attention(q, k, v, causal, interpret, force, scale),
             (q, k, v, None, None))
 
 
-def _flash_bwd_rule(causal, interpret, force, res, g):
+def _flash_bwd_rule(causal, interpret, force, scale, res, g):
     q, k, v, out, lse = res
     if lse is not None:
         return _flash_backward(q, k, v, out, lse, g, causal,
-                               interpret=interpret)
-    return _attention_bwd_chunked(q, k, v, g, causal)
+                               interpret=interpret, scale=scale)
+    return _attention_bwd_chunked(q, k, v, g, causal, scale=scale)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -42,13 +42,16 @@ _collective_per_step = _obs_registry().gauge(
     "static shapes at trace time, by op and site")
 
 
-def attention_reference(q: Array, k: Array, v: Array, causal: bool = False) -> Array:
+def attention_reference(q: Array, k: Array, v: Array, causal: bool = False,
+                        scale=None) -> Array:
     """Plain full-sequence softmax attention (the correctness oracle).
 
-    Shapes: q,k,v = (B, T, H, D) -> (B, T, H, D).
+    Shapes: q,k = (B, T, H, Dk), v = (B, T, H, Dv) -> (B, T, H, Dv);
+    ``scale`` None is ``Dk ** -0.5``.
     """
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(d))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    s = s / jnp.sqrt(jnp.float32(d)) if scale is None else s * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]

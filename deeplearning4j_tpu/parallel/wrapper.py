@@ -464,12 +464,38 @@ class ParallelWrapper:
         return jax.make_array_from_callback(arr.shape, sharding,
                                             lambda idx: arr[idx])
 
+    def _place_state(self) -> None:
+        """Lay the model's parameters, layer states and updater state out as
+        the sync step's out_specs leave them. A network comes from ``init()``
+        on one device; dispatched from there, the step is compiled for that
+        placement, its outputs come back laid out over the mesh, and the
+        second dispatch compiles the same step again for those. Placed up
+        front the step compiles once; on a later ``fit`` the state is where
+        it belongs already and this moves nothing. Single process only: a
+        cluster's processes hand their state over as the jit takes it."""
+        if jax.process_count() != 1:
+            return
+        net = self.model
+
+        def place(tree, specs):
+            if isinstance(specs, P):
+                return jax.device_put(tree, _named_sharding(self.mesh, specs))
+            return jax.tree_util.tree_map(
+                lambda sp, sub: place(sub, sp), specs, tree,
+                is_leaf=lambda sp: isinstance(sp, P))
+
+        par_sp, upd_sp = self._spec_trees()
+        net.params_list = place(net.params_list, par_sp)
+        net.state_list = place(net.state_list, P())
+        net.updater_state = place(net.updater_state, upd_sp)
+
     def _fit_sync(self, iterator, epochs: int) -> None:
         net = self.model
         self._drop_stale_programs()
         if self._sync_step is None:
             self._sync_step = self._make_sync_step()
             self._sync_multi = self._make_sync_multistep()
+        self._place_state()
         from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM
         from deeplearning4j_tpu.nn.graph_network import (
             ComputationGraph, _coerce_graph_batch)

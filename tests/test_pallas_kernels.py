@@ -393,7 +393,8 @@ def test_force_pallas_bypasses_length_gate_not_hard_constraints(monkeypatch):
 
     calls = []
 
-    def fake_forward(qq, kk, vv, causal, interpret=False, key_mask=None):
+    def fake_forward(qq, kk, vv, causal, interpret=False, key_mask=None,
+                     scale=None):
         calls.append(1)
         if key_mask is not None:
             return pk._masked_attention_xla(qq, kk, vv, key_mask, causal), None

@@ -69,6 +69,28 @@ def test_sync_dp_adam_equals_single_device():
                                np.asarray(dp_net.params()), atol=2e-6)
 
 
+def test_sync_dp_compiles_its_step_once():
+    """A network fresh from ``init()`` lies on one device; the wrapper lays
+    its state out over the mesh before the first dispatch, so the second
+    group of K steps meets the placement the first left and compiles
+    nothing (handed over as it was, the K-step program compiled twice)."""
+    net = MultiLayerNetwork(_conf()).init()
+    net.dispatch_ksteps = 2
+    pw = (ParallelWrapper.builder(net)
+          .workers(8).prefetch_buffer(0).averaging_frequency(1).build())
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: compiles.append(event)
+        if event.endswith("backend_compile_duration") else None)
+    pw.fit(ListDataSetIterator(_batches(2)))
+    first = len(compiles)
+    assert first > 0
+    pw.fit(ListDataSetIterator(_batches(4, seed=1)))
+    assert len(compiles) == first
+    for leaf in jax.tree_util.tree_leaves(net.params_list):
+        assert len(leaf.sharding.device_set) == 8
+
+
 def test_local_sgd_averaging():
     """averaging_frequency=4 local-SGD: runs, stays finite, and final params are
     synchronized across replicas (reference ParallelWrapper averaging :179-212)."""

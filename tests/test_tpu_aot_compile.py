@@ -135,7 +135,50 @@ def _int8():
             [((16, 512), F32), ((512, 2048), jnp.int8), ((2048,), F32)], 1)
 
 
+def _latent(grad):
+    """Latent attention's core at DeepSeek-V2-Lite's widths: 192-wide
+    queries and keys, 128-wide values, 4 sequences of 4,096, 16 heads."""
+    shapes = [((4, 4096, 16, d), BF16) for d in (192, 192, 128)]
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, True, False, False, 0.1147)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), shapes, (3 if grad else 1)
+
+
+def _grouped(grad, policy="bfloat16_full"):
+    """The dropless expert dispatch at DeepSeek-V2-Lite's widths: 16,384
+    tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408."""
+    from deeplearning4j_tpu import common
+    from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
+
+    S, k, F, H, G = 16384, 6, 2048, 1408, 8
+    shapes = [((S, F), BF16), ((S, k), jnp.int32), ((S, k), F32),
+              ((G, F, H), BF16), ((G, F, H), BF16), ((G, H, F), BF16)]
+
+    def fwd(x, c, w, g, u, d):
+        with common.override_policy(policy):
+            return grouped_expert_ffn(x, c, w, g, u, d, 0)[0]
+
+    def bwd(x, c, w, g, u, d):
+        return jax.grad(lambda *a: fwd(a[0], c, *a[1:]).astype(F32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(x, w, g, u, d)
+
+    # three grouped products forward, each with two more backward, in both
+    # of the buffer's sizes (the cond's two branches)
+    return (bwd if grad else fwd), shapes, (18 if grad else 6)
+
+
 CASES = {
+    "latent-fwd-T4096-bfloat16": (_latent, (False,)),
+    "latent-grad-T4096-bfloat16": (_latent, (True,)),
+    "grouped-fwd-S16384-bfloat16": (_grouped, (False,)),
+    "grouped-grad-S16384-bfloat16": (_grouped, (True,)),
+    "grouped-grad-S16384-float32": (_grouped, (True, "float32")),
     **{f"flash-{'grad' if g else 'fwd'}-T{T}-{jnp.dtype(d).name}":
        (_flash, (T, d, g))
        for g in (False, True)
