@@ -25,6 +25,14 @@ Donation safety — the ownership hand-off, explicitly:
   the backend wrapped the slot's memory instead of copying it (the CPU
   backend does for a 64-byte-aligned buffer) the device arrays own that
   memory from then on and the ring allocates a new slot in its place.
+  ``ParallelWrapper``'s synchronous loop stages through the same function
+  with the put laid out over its mesh: every device is sent its shard as a
+  view of the slot, so the slot belongs to every shard of the arrays made
+  from it. It is rewritten only when all of them, on every device, are
+  ready (``block_until_ready`` of the whole array), and given away if any
+  shard reads its memory. The wrapper keeps its ring between ``fit`` calls
+  and drains it when one returns (``drain``), so the ring holds host
+  memory then and no device array.
 * Each queue slot is consumed by exactly one dispatch: the consumer pops an
   item, hands it to the train step, and drops its reference. The ring keeps
   one to the group's device arrays only until their copy has finished (it
@@ -33,11 +41,11 @@ Donation safety — the ownership hand-off, explicitly:
   "deleted buffer" error (pinned by tests/test_prefetch.py).
 
 Bounded depth (default 2 = double buffering) caps HBM held by queued batches
-at ``depth * group_bytes``; the networks' fit loops keep at most two
-dispatched groups unfinished besides (``LazyScore._dispatch_staged``, span
-``fit.step_wait``). depth <= 0 degrades to synchronous inline staging
-(the pre-prefetch behavior, used by the numerical-equivalence tests and the
-bench A/B).
+at ``depth * group_bytes``; the networks' fit loops and the wrapper's keep
+at most two dispatched groups unfinished besides
+(``nn.multilayer.wait_for_step``, span ``fit.step_wait``). depth <= 0
+degrades to synchronous inline staging (the pre-prefetch behavior, used by
+the numerical-equivalence tests and the bench A/B).
 
 Spans. Every staged item gets a process-wide sequence number, its ``group``,
 when the producer starts to pull it; the number travels with the item through
@@ -118,12 +126,15 @@ def current_group() -> Optional[int]:
 
 def _reads_host_memory(dev, host) -> bool:
     """Whether ``dev``, the array a put of ``host`` returned, reads
-    ``host``'s own memory: only a CPU device can, and then its buffer lies
-    inside the numpy array's."""
+    ``host``'s own memory: only a CPU device can, and then its buffer, or
+    that of one of its shards, lies inside the numpy array's."""
     if all(d.platform != "cpu" for d in dev.devices()):
         return False
     lo = host.ctypes.data
-    return lo <= dev.unsafe_buffer_pointer() < lo + host.nbytes
+    parts = ([dev] if len(dev.devices()) == 1
+             else [s.data for s in dev.addressable_shards])
+    return any(lo <= a.unsafe_buffer_pointer() < lo + host.nbytes
+               for a in parts)
 
 
 #: a slot's memory: untouched pages, so a slot costs nothing until a group is
@@ -162,11 +173,16 @@ class HostGroupRing:
                          for o in ("reused", "allocated")}
         self._m_wait = _slot_wait_total.labels(path=path)
 
-    def stage(self, spec: tuple, n: int, fill: Callable) -> list:
+    def stage(self, spec: tuple, n: int, fill: Callable,
+              shardings: Optional[list] = None) -> list:
         """Stage a group of ``n`` batches; ``spec`` is the ``(shape, dtype)``
         of each leaf of one batch. ``fill`` is called with the host arrays
         ``(n, *shape)`` to write the group into, one per leaf. Returns the
-        device arrays put from them, leaf by leaf. A slot whose memory one
+        device arrays put from them, leaf by leaf: on the default device,
+        or with ``shardings`` (one ``Sharding`` a leaf) laid out over a
+        mesh, every addressable device sent its shard as a view of the
+        filled array (nothing lands whole on one device first, and the
+        processes of a cluster exchange nothing). A slot whose memory one
         of them reads is given away."""
         if spec != self._spec or n > self._capacity:
             # in-flight transfers keep their buffers alive themselves
@@ -194,13 +210,27 @@ class HostGroupRing:
             self._m_slots["reused"].inc()
         staged = [b[:n] for b in slot.buffers]
         fill(staged)
-        device = [jax.device_put(a) for a in staged]
+        if shardings is None:
+            device = [jax.device_put(a) for a in staged]
+        else:
+            device = [jax.make_array_from_callback(a.shape, s, a.__getitem__)
+                      for a, s in zip(staged, shardings)]
         if any(_reads_host_memory(d, b)
                for d, b in zip(device, slot.buffers)):
             self._slots[i] = None
         else:
             slot.in_flight = device
         return device
+
+    def drain(self) -> None:
+        """Wait for every transfer still running out of a slot and let go of
+        the device arrays: for an owner that keeps the ring between fits,
+        which would else hold the last groups' device memory through it."""
+        for slot in self._slots:
+            if slot is not None:
+                for d in slot.in_flight:
+                    d.block_until_ready()
+                slot.in_flight = []
 
 
 class DevicePrefetcher:
@@ -261,7 +291,10 @@ class DevicePrefetcher:
         """Pull the next item and stage it on the calling thread, under a
         new group number. Returns ``(group, item, seconds)``; the item is
         ``_DONE`` at the source's end."""
-        group = begin_group()
+        # a prefetcher without a path (AsyncDataSetIterator) numbers nothing:
+        # it runs inside a fit loop's pull, on that loop's producer thread,
+        # whose current group it must leave alone
+        group = begin_group() if self._path is not None else None
         t0, w0 = time.perf_counter(), time.time_ns()
         try:
             item = next(it)
@@ -315,8 +348,8 @@ class DevicePrefetcher:
                         raise self._error
                     return
                 group, item = got
-                _working_on.group = group
                 if self._m_wait is not None:
+                    _working_on.group = group
                     _flight_recorder().record_span(
                         "fit.wait", w0, time.time_ns(), group=group,
                         path=self._path)

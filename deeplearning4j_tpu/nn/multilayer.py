@@ -359,7 +359,7 @@ def _castable(leaf_dtype, stage_dtype) -> bool:
                 and not np.issubdtype(np.dtype(stage_dtype), np.integer))
 
 
-def stage_group(batches: list, dtype, ring: HostGroupRing):
+def stage_group(batches: list, dtype, ring: HostGroupRing, sharding=None):
     """Stage one K-step group of host batches ``[(features, labels), ...]``
     (arrays, or for a graph one list of arrays per stream) as ``(K, B, ...)``
     device arrays, the features cast to ``dtype`` on the host (None keeps
@@ -373,6 +373,11 @@ def stage_group(batches: list, dtype, ring: HostGroupRing):
     ``jax.device_put`` that does not wait for the copy: a fresh device buffer
     every group, from host pages that are warm. The ring rewrites a slot only
     when the transfer last made from it has finished.
+
+    ``sharding`` lays the group out over a mesh (``ParallelWrapper``): called
+    with a leaf of one batch, it returns the ``Sharding`` of that leaf's
+    stacked ``(K, B, ...)`` array, and every device is sent its shard
+    straight from the slot. Left out, the default device.
 
     Writes the group's three stage spans under the calling thread's
     ``current_group()``: ``input.stack`` (taking the slot, which includes any
@@ -405,7 +410,9 @@ def stage_group(batches: list, dtype, ring: HostGroupRing):
         write(slice(n_features))            # features
         t2 = time.time_ns()
 
-    device = ring.stage(spec, len(batches), fill)
+    device = ring.stage(
+        spec, len(batches), fill,
+        None if sharding is None else [sharding(col[0]) for col in columns])
     t3 = time.time_ns()
     rec, group = _flight_recorder(), current_group()
     rec.record_span("input.stack", t0, t1, group=group, cause="input.pull")
@@ -413,6 +420,23 @@ def stage_group(batches: list, dtype, ring: HostGroupRing):
     rec.record_span("input.h2d", t2, t3, group=group, cause="input.pull",
                     bytes=sum(d.nbytes for d in device))
     return tree.tree_unflatten(treedef, device)
+
+
+def wait_for_step(losses) -> None:
+    """Flow control's one wait a staged group (``LazyScore._dispatch_staged``,
+    ``ParallelWrapper``'s synchronous loop): block until ``losses``, the loss
+    stack of the group dispatched two before, is ready; None (the first two
+    groups) waits for nothing. The ``device`` phase and the calling thread's
+    group's ``fit.step_wait`` span."""
+    if losses is None:
+        return
+    w0_ns = time.time_ns()
+    with _t_device.time():
+        # lint: host-sync-in-hot-loop-ok (flow control: the one wait per K-step group that bounds staged groups in HBM)
+        losses.block_until_ready()
+    _flight_recorder().record_span(
+        "fit.step_wait", w0_ns, time.time_ns(),
+        group=current_group(), cause="fit.dispatch")
 
 
 class LazyScore:
@@ -614,14 +638,7 @@ class LazyScore:
             name, type(self)._multistep_builder(self.conf,
                                                 health=due_i is not None),
             donate=(0, 1, 2))
-        if after is not None:
-            w0_ns = time.time_ns()
-            with _t_device.time():
-                # lint: host-sync-in-hot-loop-ok (flow control: the one wait per K-step group that bounds staged groups in HBM)
-                after.block_until_ready()
-            _flight_recorder().record_span(
-                "fit.step_wait", w0_ns, time.time_ns(),
-                group=current_group(), cause="fit.dispatch")
+        wait_for_step(after)
         t0, t0_ns = time.perf_counter(), time.time_ns()
         out = multi(self.params_list, self.state_list, self.updater_state,
                     xs, ys, self._next_rng(), jnp.int32(self.iteration))

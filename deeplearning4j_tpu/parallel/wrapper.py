@@ -184,6 +184,24 @@ class ParallelWrapperBuilder:
 
 
 class ParallelWrapper:
+    """Data-parallel ``fit`` over the devices of a mesh (module docstring).
+
+    The synchronous loop (``averaging_frequency`` 1) stages its K-step groups
+    as the networks' own fit loops do (``nn.multilayer.stage_group``): each
+    batch is cast to the model's ``stage_dtype`` straight into a reused host
+    slot, every device is sent its shard of the slot without waiting (laid
+    out per ``_batch_spec``; nothing lands whole on one device), and a group
+    is dispatched once the step of the group two before it has finished.
+
+    The slots (a ``HostGroupRing`` of ``prefetch + 2``) live as long as the
+    wrapper, not one ``fit`` call: a trainer's epochs are several ``fit``
+    calls, and the first touch of a slot's pages costs about a second a GB.
+    The wrapper therefore holds up to ``prefetch + 2`` groups of host memory,
+    each ``K`` global batches of the staged dtype; the ring starts anew when
+    the batch's shape or dtype changes, and goes with the wrapper. Device
+    memory is not held between fits (the ring is drained when ``fit``
+    returns)."""
+
     def __init__(self, model, workers: Optional[int] = None, prefetch: int = 2,
                  averaging_frequency: int = 1, average_updaters: bool = True,
                  report_score: bool = False, mesh: Optional[Mesh] = None,
@@ -277,6 +295,10 @@ class ParallelWrapper:
         self._local_step = None
         self._avg_fn = None
         self._local = None  # stacked per-replica (params, states, upd) for local-SGD
+        # the synchronous loop's host slots for staged K-step groups (class
+        # docstring) and the loss stacks of the two groups dispatched last
+        self._host_ring = None
+        self._staged_losses = (None, None)
         # dtype policy the cached jitted programs were traced under; they are
         # rebuilt when it changes (the policy is read at trace time)
         self._traced_policy = None
@@ -497,10 +519,17 @@ class ParallelWrapper:
             self._sync_multi = self._make_sync_multistep()
         self._place_state()
         from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM
+        from deeplearning4j_tpu.datasets.prefetch import (
+            DevicePrefetcher, HostGroupRing)
         from deeplearning4j_tpu.nn.graph_network import (
             ComputationGraph, _coerce_graph_batch)
+        from deeplearning4j_tpu.nn.multilayer import (
+            stage_group, wait_for_step)
         from deeplearning4j_tpu.utils.batching import k_step_groups
 
+        if self._host_ring is None:
+            self._host_ring = HostGroupRing(self.prefetch + 2, "wrapper_sync")
+        ring = self._host_ring
         is_graph = isinstance(net, ComputationGraph)
         iters_cfg = max(1, net.conf.global_conf.iterations)
         tbptt_lstm = (not is_graph
@@ -562,13 +591,19 @@ class ParallelWrapper:
                     listener.iteration_done(net, net.iteration)
             _wd_beat(net.iteration)
 
-        def stack_spec(arr):
-            # stacked (K, B, ...) batches: batch spec shifted one axis right
-            return P(None, *self._batch_spec(arr[0]))
+        def group_sharding(leaf):
+            # a stacked (K, B, ...) group: the batch spec shifted one axis right
+            return _named_sharding(self.mesh,
+                                   P(None, *self._batch_spec(leaf)))
 
         def dispatch(xs, ys, n):
             if not is_graph:
                 net.last_batch_size = int(xs.shape[1])
+            # flow control, as LazyScore._dispatch_staged has it: one group
+            # queued behind the running step, and no more staged groups in
+            # HBM than the ring has slots
+            two_back, one_back = self._staged_losses
+            wait_for_step(two_back)
             t0 = _time.perf_counter()
             (net.params_list, net.state_list, net.updater_state,
              losses) = \
@@ -577,6 +612,7 @@ class ParallelWrapper:
                                  net._next_rng(),
                                  jnp.int32(net.iteration))
             dt = _time.perf_counter() - t0
+            self._staged_losses = (one_back, losses)
             _t_dispatch.observe(dt)
             _compile_tracker().note_step(n, fn="ParallelWrapper.sync_multistep")
             psum_bytes.inc(param_bytes * n)
@@ -593,11 +629,11 @@ class ParallelWrapper:
             _wd_beat(net.iteration)
 
         def stage(kind_item):
-            # producer thread: the sharded version of the single-chip stage —
-            # stack + non-blocking device_put laid out per _batch_spec (or
-            # per-process shards via make_array_from_callback), so the
-            # sharded (K, B, ...) group is in flight while the previous
-            # dispatch executes. Singles fall through to the host fallback.
+            # producer thread: a group is staged as the networks' own loops
+            # stage theirs (stage_group: cast into a reused host slot, put
+            # without waiting), laid out per _batch_spec, so the sharded
+            # (K, B, ...) group is in flight while the previous dispatch
+            # executes. Singles fall through to the host fallback.
             kind, item = kind_item
             if kind != "group":
                 return kind_item
@@ -612,35 +648,31 @@ class ParallelWrapper:
                     x = self._stage(x, self._batch_spec(x))
                     y = self._stage(y, self._batch_spec(y))
                 return "staged1", (x, y, bs)
-            if is_graph:
-                xs = [self._stage(a, stack_spec(a))
-                      for a in (np.stack([b[0][i] for b in item])
-                                for i in range(len(item[0][0])))]
-                ys = [self._stage(a, stack_spec(a))
-                      for a in (np.stack([b[1][i] for b in item])
-                                for i in range(len(item[0][1])))]
-            else:
-                xs = np.stack([b[0] for b in item])
-                xs = self._stage(xs, stack_spec(xs))
-                ys = np.stack([b[1] for b in item])
-                ys = self._stage(ys, stack_spec(ys))
+            xs, ys = stage_group(item, getattr(net, "stage_dtype", None),
+                                 ring, group_sharding)
             return "stagedK", (xs, ys, len(item))
 
-        from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher
-
-        for _ in range(epochs):
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
-                                  depth=self.prefetch, path="wrapper_sync",
-                                  wait_series=_t_staging)
-            for kind, item in pf:
-                if kind == "single":
-                    fallback(item)
-                elif kind == "staged1":
-                    dispatch_one(*item)
-                else:
-                    dispatch(*item)
+        try:
+            for _ in range(epochs):
+                if hasattr(iterator, "reset"):
+                    iterator.reset()
+                # closed on the way out, so that no producer is staging into
+                # the ring when it is drained
+                with DevicePrefetcher(k_step_groups(iterator, k, to_batch),
+                                      stage, depth=self.prefetch,
+                                      path="wrapper_sync",
+                                      wait_series=_t_staging) as pf:
+                    for kind, item in pf:
+                        if kind == "single":
+                            fallback(item)
+                        elif kind == "staged1":
+                            dispatch_one(*item)
+                        else:
+                            dispatch(*item)
+        finally:
+            # the ring outlives the fit; the device memory of the groups last
+            # put from it must not
+            ring.drain()
 
     # --------------------------------------------------- local SGD (freq=N>1)
     def _make_local_sgd_fns(self):

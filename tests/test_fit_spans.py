@@ -120,6 +120,71 @@ def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
     assert len(steps) == 3
 
 
+@pytest.mark.parametrize("kind", ["multilayer", "graph"])
+def test_wrapper_groups_carry_the_stage_spans_and_one_step_wait(ring, kind):
+    """``ParallelWrapper``'s synchronous loop stages through ``stage_group``
+    and dispatches under the networks' bound: every group has the producer's
+    four spans and the loop's wait under its number, and from the third on a
+    ``fit.step_wait`` for the group two before, which ends before the step
+    is dispatched. The bound reaches across ``fit`` calls on one wrapper."""
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    net = make_net(kind)
+    net.stage_dtype = jnp.bfloat16
+    wrapper = ParallelWrapper.builder(net).workers(4).prefetch_buffer(2).build()
+    order = []
+    make = ParallelWrapper._make_sync_multistep
+
+    class Losses:
+        def __init__(self, i, real):
+            self.i, self.real = i, real
+
+        def block_until_ready(self):
+            order.append(("waited", self.i))
+
+        def __getitem__(self, j):
+            return self.real[j]
+
+    def multi(params, states, upd, xs, ys, rng, it):
+        i = sum(1 for o in order if o[0] == "dispatch")
+        order.append(("dispatch", i))
+        *state, losses = real(params, states, upd, xs, ys, rng, it)
+        return (*state, Losses(i, losses))
+
+    real = make(wrapper)
+    wrapper._make_sync_multistep = lambda: multi
+    wrapper.fit(ListDataSetIterator(batches(4 * K)))
+    assert order == [("dispatch", 0), ("dispatch", 1), ("waited", 0),
+                     ("dispatch", 2), ("waited", 1), ("dispatch", 3)]
+    groups = spans_by_group()
+    assert len(groups) == 4
+    for i, (group, by_name) in enumerate(sorted(groups.items())):
+        step_wait = by_name.pop("fit.step_wait", None)
+        assert (step_wait is not None) == (i >= 2)
+        assert set(by_name) == set(STAGES + ("fit.wait",)), by_name.keys()
+        pull, stack, cast, h2d = (by_name[n] for n in STAGES)
+        assert (pull["t1_ns"] <= stack["t0_ns"] <= stack["t1_ns"]
+                == cast["t0_ns"] <= cast["t1_ns"] == h2d["t0_ns"]
+                <= h2d["t1_ns"] <= by_name["fit.wait"]["t1_ns"])
+        assert h2d["bytes"] == K * 8 * (16 * 2 + 3 * 4)   # bf16 in, f32 labels
+        assert {s["thread"] for s in (pull, stack, cast, h2d)} == {
+            "dl4j-prefetch-staging"}
+        if step_wait:
+            assert by_name["fit.wait"]["t1_ns"] <= step_wait["t0_ns"]
+            assert step_wait["cause"] == "fit.dispatch"
+            assert step_wait["thread"] == "MainThread"
+        # the wait is over before the step is dispatched
+        step = next(e for e in global_recorder().snapshot()
+                    if e["kind"] == "step" and e["it"] == i * K)
+        assert step["k"] == K
+        if step_wait:
+            assert step_wait["t1_ns"] / 1e9 <= step["ts"]
+    # a second fit on the wrapper goes on where the first stopped
+    del order[:]
+    wrapper.fit(ListDataSetIterator(batches(K)))
+    assert order == [("waited", 2), ("dispatch", 0)]
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 @pytest.mark.parametrize("kind", ["multilayer", "graph"])
 def test_stage_spans_add_up_to_the_staging_counter(ring, kind, depth):

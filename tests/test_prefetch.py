@@ -378,22 +378,46 @@ def copied_slots(monkeypatch):
     monkeypatch.setattr(prefetch_mod, "_host_buffer", _buffer_at(16))
 
 
+def _group_sharding(mesh_shape):
+    """What ``ParallelWrapper._fit_sync`` hands ``stage_group``: a batch's
+    leaf to the sharding of its stacked group, on a mesh ``(data,)`` or
+    ``(data, sp)`` of the virtual devices (with ``sp``, a ``[B, T, ...]``
+    leaf's time axis is split too, as ``_batch_spec`` does)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    count = int(np.prod(mesh_shape))
+    mesh = Mesh(np.array(jax.devices()[:count]).reshape(mesh_shape),
+                ("data", "sp")[:len(mesh_shape)])
+
+    def sharding(leaf):
+        seq = len(mesh_shape) == 2 and np.ndim(leaf) == 3
+        return NamedSharding(mesh, P(None, "data", "sp") if seq
+                             else P(None, "data"))
+    return sharding
+
+
 @pytest.mark.parametrize("n", [4, 2])
 @pytest.mark.parametrize("kind", ["array", "streams"])
 @pytest.mark.parametrize("dtype", ["bfloat16", None])
-def test_stage_group_bits_equal_stack_then_astype(copied_slots, dtype, kind,
-                                                  n):
+@pytest.mark.parametrize("mesh_shape", [None, (4,), (2, 2)])
+def test_stage_group_bits_equal_stack_then_astype(copied_slots, mesh_shape,
+                                                  dtype, kind, n):
     """One pass into a reused slot gives the bytes ``np.stack(...).astype``
     gave: full group, then a short one (``buf[:n]``) into the same slots,
-    then round the ring so that every slot is written a second time."""
+    then round the ring so that every slot is written a second time. On the
+    default device, and laid out over a mesh as the wrapper's groups are
+    (every device sent its shard as a view of the slot): over the batch
+    alone, and with the sequence axis or replicas besides."""
     import jax.numpy as jnp
 
     dtype = getattr(jnp, dtype) if dtype else None
+    sharding = _group_sharding(mesh_shape) if mesh_shape else None
     ring = HostGroupRing(2, "test_bits")
     before = _slot_counts("test_bits")
     for turn, length in enumerate([4, n, 4, n]):
-        group = _host_group(kind, length, seed=turn)
-        xs, ys = stage_group(group, dtype, ring)
+        group = _host_group(kind, length, seed=turn,
+                            batch=8 if mesh_shape else 6)
+        xs, ys = stage_group(group, dtype, ring, sharding)
         tree = jax.tree_util
         want_x = tree.tree_map(lambda *a: np.stack(a), *[b[0] for b in group])
         want_y = tree.tree_map(lambda *a: np.stack(a), *[b[1] for b in group])
@@ -409,6 +433,10 @@ def test_stage_group_bits_equal_stack_then_astype(copied_slots, dtype, kind,
             assert isinstance(got, jax.Array)
             assert got.dtype == want.dtype and got.shape == want.shape
             assert np.array_equal(_as_bits(got), _as_bits(want))
+            if sharding is not None:
+                assert got.sharding.is_equivalent_to(sharding(want[0]),
+                                                     want.ndim)
+                assert len(got.sharding.device_set) == 4
     after = _slot_counts("test_bits")
     assert after["allocated"] - before["allocated"] == 2
     assert after["reused"] - before["reused"] == 2
@@ -486,16 +514,31 @@ class _StubDeviceArray:
         return self.host.ctypes.data
 
 
-def test_slot_is_not_rewritten_before_its_transfer_has_finished(monkeypatch):
+@pytest.mark.parametrize("sharded", [False, True])
+def test_slot_is_not_rewritten_before_its_transfer_has_finished(monkeypatch,
+                                                                sharded):
+    """``sharded``: the group is laid out over a mesh, as the wrapper's are;
+    the slot then belongs to an array with a shard on every device, which is
+    waited for as a whole."""
     log = []
     monkeypatch.setattr(
         jax, "device_put", lambda a, *r, **kw: _StubDeviceArray(a, log))
+    monkeypatch.setattr(
+        jax, "make_array_from_callback",
+        lambda shape, sharding, shard_of: _StubDeviceArray(
+            shard_of((slice(None),) * len(shape)), log))
+    sharding = _group_sharding((4,)) if sharded else None
+
+    def stage(seed):
+        return stage_group(_host_group("array", 3, seed=seed, batch=8),
+                           None, ring, sharding)
+
     ring = HostGroupRing(2, "test_wait")
     before = _slot_counts("test_wait")
-    first = stage_group(_host_group("array", 3, seed=1), None, ring)
-    stage_group(_host_group("array", 3, seed=2), None, ring)
+    first = stage(1)
+    stage(2)
     assert log == []                      # two slots, nothing to wait for
-    third = stage_group(_host_group("array", 3, seed=3), None, ring)
+    third = stage(3)
     # both transfers out of slot 0 (features, labels) were waited for, and
     # at that moment the slot still held the first group
     assert log == [("waited", True), ("waited", True)]
@@ -507,9 +550,32 @@ def test_slot_is_not_rewritten_before_its_transfer_has_finished(monkeypatch):
     # a transfer seen finished at a later staging call is let go of unwaited
     for d in third:
         d.ready = True
-    stage_group(_host_group("array", 3, seed=4), None, ring)   # slot 1
-    stage_group(_host_group("array", 3, seed=5), None, ring)   # slot 0
+    stage(4)   # slot 1
+    fifth = stage(5)   # slot 0
     assert len(log) == 4                  # slot 1's two, none for slot 0
+    # an owner that keeps the ring between fits drains it: every transfer
+    # waited for, no device array kept
+    assert not any(d.ready for d in fifth)
+    ring.drain()
+    assert all(d.ready for d in fifth) and len(log) == 8
+    assert all(slot.in_flight == [] for slot in ring._slots)
+
+
+def test_a_shard_that_reads_the_slot_is_seen_on_every_device():
+    """A slot laid out over a mesh is given away if ANY device's shard reads
+    its memory: the CPU backend wraps an aligned buffer whole for every
+    device of a replicated leaf, and copies out of an unaligned one."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    everywhere = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("data",)),
+                               P())
+    for offset, aliased in ((0, True), (16, False)):
+        host = _buffer_at(offset)((4, 64), np.float32)
+        host[:] = 1.0
+        dev = jax.make_array_from_callback(host.shape, everywhere,
+                                           host.__getitem__)
+        assert len(dev.addressable_shards) == 4
+        assert prefetch_mod._reads_host_memory(dev, host) == aliased
 
 
 def test_aliased_put_gives_the_slot_away(monkeypatch):
@@ -601,3 +667,98 @@ def test_fit_loop_keeps_one_group_queued_behind_the_running_step(monkeypatch):
                      ("dispatch", 2, 0), ("waited", 0),
                      ("dispatch", 3, 1), ("waited", 1)]
     assert net._host_ring is None and net._staged_losses == (None, None)
+
+
+# ------------------------------------ ParallelWrapper's synchronous loop (ring)
+def _wrapped_net(kind, seed=7):
+    """A network of either type under a 4-device synchronous wrapper,
+    ``prefetch`` 2 (a ring of four slots), K=2, staging cast on."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.graph_network import ComputationGraph
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    b = NeuralNetConfiguration.builder().seed(seed).learning_rate(0.1)
+    dense = DenseLayer(n_in=4, n_out=8, activation="tanh")
+    out = OutputLayer(n_in=8, n_out=3, loss="mcxent", activation="softmax")
+    if kind == "multilayer":
+        net = MultiLayerNetwork(b.list().layer(dense).layer(out).build())
+    else:
+        net = ComputationGraph(
+            b.graph_builder().add_inputs("in")
+            .add_layer("dense", dense, "in").add_layer("out", out, "dense")
+            .set_outputs("out").build())
+    net = net.init(seed=seed)
+    net.dispatch_ksteps = 2
+    net.stage_dtype = jnp.bfloat16
+    wrapper = (ParallelWrapper.builder(net).workers(4).prefetch_buffer(2)
+               .averaging_frequency(1).build())
+    return net, wrapper
+
+
+@pytest.mark.parametrize("kind", ["multilayer", "graph"])
+def test_wrapper_slot_reuse_trains_the_same_as_fresh_slots(monkeypatch, kind):
+    """The wrapper's groups go through ``stage_group`` into a ring of
+    ``prefetch + 2`` slots that outlives ``fit``: cast to ``stage_dtype``,
+    laid out over the mesh's batch axis, every slot rewritten while earlier
+    groups are queued or dispatched, and params bit for bit as with a stager
+    that never reuses a slot. A second ``fit`` allocates nothing, and no
+    device array is kept between the two."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    k, slots = 2, 4
+    groups = 2 * slots + 1
+    data = _batches(groups * k, seed=4)
+    make = ParallelWrapper._make_sync_multistep
+
+    def run(reuse):
+        monkeypatch.setattr(prefetch_mod, "_host_buffer", _buffer_at(16))
+        if not reuse:  # a ring that never comes round
+            monkeypatch.setattr(
+                prefetch_mod, "HostGroupRing",
+                lambda size, path: HostGroupRing(10 ** 6, path))
+        staged = []
+
+        def spying(self):
+            multi = make(self)
+
+            def spy(params, states, upd, xs, ys, rng, it):
+                staged.append((xs, ys))
+                return multi(params, states, upd, xs, ys, rng, it)
+            return spy
+
+        monkeypatch.setattr(ParallelWrapper, "_make_sync_multistep", spying)
+        net, wrapper = _wrapped_net(kind)
+        counts = [_slot_counts("wrapper_sync")]
+        for _ in range(2):
+            wrapper.fit(ListDataSetIterator(data))
+            counts.append(_slot_counts("wrapper_sync"))
+            assert all(slot is None or slot.in_flight == []
+                       for slot in wrapper._host_ring._slots)
+        monkeypatch.undo()
+        deltas = [{o: b[o] - a[o] for o in ("allocated", "reused")}
+                  for a, b in zip(counts, counts[1:])]
+        return _leaves(net), deltas, staged
+
+    fresh, fresh_counts, _ = run(reuse=False)
+    reused, counts, staged = run(reuse=True)
+    assert fresh_counts == [{"allocated": groups, "reused": 0}] * 2
+    assert counts == [{"allocated": slots, "reused": groups - slots},
+                      {"allocated": 0, "reused": groups}]
+    for a, b in zip(fresh, reused):
+        assert np.array_equal(a, b)
+    assert len(staged) == 2 * groups
+    for i, (xs, ys) in enumerate(staged):
+        want = data[(i % groups) * k:(i % groups + 1) * k]
+        for got, host, dtype in (
+                (xs, [d.features for d in want], jnp.bfloat16),
+                (ys, [d.labels for d in want], np.float32)):
+            got, = jax.tree_util.tree_leaves(got)
+            assert got.dtype == dtype and got.shape == (k, 8) + host[0].shape[1:]
+            assert got.sharding.spec == P(None, "data")
+            assert len(got.sharding.device_set) == 4
+            assert np.array_equal(_as_bits(got),
+                                  _as_bits(np.stack(host).astype(dtype)))
