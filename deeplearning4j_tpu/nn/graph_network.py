@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Optional
 
 import jax
@@ -22,10 +21,8 @@ import numpy as np
 from deeplearning4j_tpu import common
 from deeplearning4j_tpu.nn.conf.graphconf import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.vertices import LayerVertex
-from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher, begin_group
 from deeplearning4j_tpu.nn.multilayer import (
-    LazyScore, _stage_host, _updater_spec, _t_staging, _t_dispatch,
-    _t_listeners,
+    LazyScore, _batch_size, _updater_spec,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -164,15 +161,6 @@ def _aux_losses(conf, new_states):
             w = getattr(getattr(vertex, "layer", None), "aux_loss_weight", 1.0)
             total = total + w * ns["aux_loss"]
     return total
-
-
-def _coerce_graph_batch(ds):
-    """Normalize a DataSet or MultiDataSet into (xs, ys, fmasks, lmasks) lists."""
-    if isinstance(ds, MultiDataSet):
-        return ds.features, ds.labels, ds.features_masks, ds.labels_masks
-    fm = [ds.features_mask] if ds.features_mask is not None else None
-    lm = [ds.labels_mask] if ds.labels_mask is not None else None
-    return [ds.features], [ds.labels], fm, lm
 
 
 @jax.named_scope("update")
@@ -443,7 +431,9 @@ def make_graph_pretrain_step(conf: ComputationGraphConfiguration, name: str):
 class ComputationGraph(LazyScore):
     """Stateful shell (reference nn/graph/ComputationGraph.java)."""
 
+    _step_builder = staticmethod(make_graph_train_step)
     _multistep_builder = staticmethod(make_graph_multistep_train_step)
+    _fit_path = "graph"
 
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
@@ -541,214 +531,34 @@ class ComputationGraph(LazyScore):
         return total + _graph_regularization(conf, params)
 
     # ------------------------------------------------------------------ training
-    def _next_rng(self):
-        self._require_init()
-        if self._rng is None:
-            raise RuntimeError(self.NOT_INITIALIZED_MSG)
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
-
     @_dump_on_unhandled("ComputationGraph.fit")
     def fit(self, data, labels=None, *, epochs: int = 1) -> None:
         """Fit on a MultiDataSet, DataSet, iterator, or (inputs, labels) lists
-        (reference fit:670/747)."""
+        (reference fit:670/747); the loop itself is ``LazyScore``'s."""
         from deeplearning4j_tpu.datasets.dataset import DataSet
 
         if isinstance(data, (MultiDataSet, DataSet)):
-            xs, ys, fm, lm = _coerce_graph_batch(data)
-            if epochs > 1 and fm is None and lm is None \
-                    and self._repeat_multistep_ok():
-                self._fit_repeated(xs, ys, epochs)
-            else:
-                for _ in range(epochs):
-                    self._fit_batch(xs, ys, fm, lm)
-            return
-        if labels is not None:
+            self._fit_arrays(*self._batch_of(data), epochs)
+        elif labels is not None:
             xs = list(data if isinstance(data, (list, tuple)) else [data])
             ys = list(labels if isinstance(labels, (list, tuple)) else [labels])
-            if epochs > 1 and self._repeat_multistep_ok():
-                self._fit_repeated(xs, ys, epochs)
-            else:
-                for _ in range(epochs):
-                    self._fit_batch(xs, ys)
-            return
-        self.fit_iterator(data, epochs=epochs)
+            self._fit_arrays(xs, ys, None, None, epochs)
+        else:
+            self.fit_iterator(data, epochs=epochs)
 
-    def _repeat_multistep_ok(self) -> bool:
-        return (self.dispatch_ksteps > 1 and self._uses_sgd()
-                and self.conf.global_conf.iterations <= 1
-                and not self._tbptt_active())
-
-    def _fit_repeated(self, xs, ys, epochs: int) -> None:
-        """Repeated steps on one device-resident multi-IO batch, K per
-        dispatch (see MultiLayerNetwork._fit_repeated)."""
-        with _t_staging.time():
-            xd = [jnp.asarray(_stage_host(a, self.stage_dtype)) for a in xs]
-            yd = [jnp.asarray(a) for a in ys]
-        self.last_batch_size = int(xd[0].shape[0]) if xd and xd[0].ndim else 0
-        remaining = epochs
-        while remaining > 0:
-            k = min(self.dispatch_ksteps, remaining)
-            xk = [jnp.broadcast_to(a[None], (k,) + a.shape) for a in xd]
-            yk = [jnp.broadcast_to(a[None], (k,) + a.shape) for a in yd]
-            begin_group()
-            losses = self._run_multistep(xk, yk, k)
-            self._run_listeners(losses, k)
-            _wd_beat(self.iteration)
-            remaining -= k
-
-    #: train steps fused per host dispatch in fit_iterator (see
-    #: MultiLayerNetwork.dispatch_ksteps); 1 disables the K-step path
-    dispatch_ksteps: int = 8
-
-    #: host-side feature staging dtype for the fused fit path (see
-    #: MultiLayerNetwork.stage_dtype); None keeps exact f32 staging
-    stage_dtype = None
-
-    #: staged K-groups prefetched ahead of the dispatch loop (see
-    #: MultiLayerNetwork.prefetch_depth); 0 = synchronous staging
-    prefetch_depth: int = 2
-
-    @_dump_on_unhandled("ComputationGraph.fit_iterator")
-    def fit_iterator(self, iterator, epochs: int = 1,
-                     ksteps: Optional[int] = None) -> None:
-        """Iterator fit with K-step fused dispatch (TPU fast path — see
-        MultiLayerNetwork.fit_iterator; reference fit(DataSetIterator):747).
-        Falls back to per-batch dispatch for masked or ragged batches."""
-        k = self.dispatch_ksteps if ksteps is None else max(1, ksteps)
-        multistep_ok = (k > 1 and self._uses_sgd()
-                        and self.conf.global_conf.iterations <= 1
-                        and not self._tbptt_active())
-        try:
-            for _ in range(epochs):
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_start"):
-                        listener.on_epoch_start(self)
-                if hasattr(iterator, "reset"):
-                    iterator.reset()
-                if self.conf.pretrain:
-                    self.pretrain(iterator)
-                    if hasattr(iterator, "reset"):
-                        iterator.reset()
-                if multistep_ok:
-                    self._fit_epoch_multistep(iterator, k)
-                else:
-                    for ds in iterator:
-                        xs, ys, fm, lm = _coerce_graph_batch(ds)
-                        self._fit_batch(xs, ys, fm, lm)
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_end"):
-                        listener.on_epoch_end(self)
-                self.epoch += 1
-        finally:
-            self._release_staging()
-
-    def _fit_epoch_multistep(self, iterator, k: int) -> None:
-        from deeplearning4j_tpu.utils.batching import k_step_groups
-
-        def to_batch(ds):
-            xs, ys, fm, lm = _coerce_graph_batch(ds)
-            if fm is not None or lm is not None:
-                return None  # masked -> per-batch fallback
-            # lint: host-sync-in-hot-loop-ok (producer-thread host staging of iterator output, not a device sync)
-            return ([np.asarray(x) for x in xs], [np.asarray(y) for y in ys])
-
-        def stage(kind_item):
-            # producer thread: per-stream cast into a host slot +
-            # non-blocking device_put (see
-            # MultiLayerNetwork._fit_epoch_multistep)
-            kind, item = kind_item
-            if kind != "group" or len(item) < 2:
-                return kind_item
-            xs, ys = self._stage_group(item, "graph")
-            return "staged", (xs, ys, len(item))
-
-        pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
-                              depth=self.prefetch_depth, path="graph",
-                              wait_series=_t_staging)
-        for kind, item in pf:
-            if kind == "single":
-                self._fit_batch(*_coerce_graph_batch(item))
-            elif kind == "group":
-                if item:
-                    self._fit_batch(item[0][0], item[0][1])
-            else:
-                self._dispatch_staged(*item)
-
-    def _dispatch_multistep(self, batches: list) -> None:
-        """Synchronous-staging compatibility path (prefetch_depth=0 semantics
-        for a pre-built group)."""
-        if not batches:
-            return
-        if len(batches) == 1:
-            self._fit_batch(batches[0][0], batches[0][1])
-            return
-        begin_group()
-        with _t_staging.time():
-            xs, ys = self._stage_group(batches, "graph")
-        self._dispatch_staged(xs, ys, len(batches))
-
-    #: Solver facade instance when optimization_algo != SGD (built lazily)
-    _solver = None
-
-    def _uses_sgd(self) -> bool:
-        algo = self.conf.global_conf.optimization_algo
-        return algo in (None, "stochastic_gradient_descent")
+    def _batch_of(self, ds) -> tuple:
+        """A DataSet or MultiDataSet as (xs, ys, fmasks, lmasks) lists."""
+        if isinstance(ds, MultiDataSet):
+            return (list(ds.features), list(ds.labels), ds.features_masks,
+                    ds.labels_masks)
+        fm = [ds.features_mask] if ds.features_mask is not None else None
+        lm = [ds.labels_mask] if ds.labels_mask is not None else None
+        return [ds.features], [ds.labels], fm, lm
 
     def _tbptt_active(self) -> bool:
         return (self.conf.backprop_type == "TruncatedBPTT"
                 and any(_is_streaming_lstm(v)
                         for v in self.conf.vertices.values()))
-
-    def _fit_batch(self, xs, ys, fmasks=None, lmasks=None) -> None:
-        if not self._uses_sgd():
-            # honor optimization_algo (reference Solver.java:55); see
-            # MultiLayerNetwork._fit_batch
-            from deeplearning4j_tpu.optimize.solvers import Solver
-
-            if self._solver is None:
-                self._solver = Solver(self)
-            self._solver.optimize(list(xs), list(ys))
-            return
-        if self._tbptt_active():
-            self._fit_tbptt(xs, ys, fmasks, lmasks)
-            return
-        with _t_staging.time():
-            xs = [jnp.asarray(x) for x in xs]
-            ys = [jnp.asarray(y) for y in ys]
-            fmasks = [jnp.asarray(m) for m in fmasks] if fmasks else None
-            lmasks = [jnp.asarray(m) for m in lmasks] if lmasks else None
-        self.last_batch_size = int(xs[0].shape[0]) if xs and xs[0].ndim else 0
-        for _ in range(max(1, self.conf.global_conf.iterations)):
-            hm = self.health_monitor
-            use_health = hm is not None and hm.due(self.iteration)
-            name = "train_step_health" if use_health else "train_step"
-            step = self._jit(name, make_graph_train_step(self.conf,
-                                                         health=use_health))
-            t0 = time.perf_counter()
-            out = step(self.params_list, self.state_list,
-                       self.updater_state, xs, ys, self._next_rng(),
-                       jnp.int32(self.iteration), fmasks, lmasks)
-            dt = time.perf_counter() - t0
-            _t_dispatch.observe(dt)
-            if use_health:
-                (self.params_list, self.state_list, self.updater_state,
-                 loss, haux) = out
-                hm.offer(haux, self.iteration)
-            else:
-                (self.params_list, self.state_list, self.updater_state,
-                 loss) = out
-            wrap_name = f"{type(self).__name__}.{name}"
-            _compile_tracker().note_step(fn=wrap_name)
-            _flight_recorder().record(
-                "step", path=wrap_name, it=self.iteration,
-                batch=self.last_batch_size, dispatch_s=dt)
-            self.score_value = loss  # device scalar; synced lazily (LazyScore)
-            self.iteration += 1
-            with _t_listeners.time():
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration)
-            _wd_beat(self.iteration)
 
     # ------------------------------------------------------------------ pretrain
     def pretrain(self, iterator) -> None:
@@ -785,7 +595,7 @@ class ComputationGraph(LazyScore):
         if hasattr(iterator, "reset"):
             iterator.reset()
         for ds in iterator:
-            xs, _, _, _ = _coerce_graph_batch(ds)
+            xs, _, _, _ = self._batch_of(ds)
             xs = [jnp.asarray(x) for x in xs]
             (self.params_list[name], self.updater_state[name], loss) = step(
                 self.params_list, self.state_list, self.updater_state[name],
@@ -809,7 +619,7 @@ class ComputationGraph(LazyScore):
         if hasattr(iterator, "reset"):
             iterator.reset()
         for ds in iterator:
-            feats, labels, fmasks, lmasks = _coerce_graph_batch(ds)
+            feats, labels, fmasks, lmasks = self._batch_of(ds)
             outs = self._output_for_eval(feats, fmasks)
             n_cls = np.asarray(labels[0]).shape[-1]
             for i, out in enumerate(outs):
@@ -853,6 +663,7 @@ class ComputationGraph(LazyScore):
         across chunks via stop_gradient (the truncation). Time axis = 1."""
         xs = [jnp.asarray(x) for x in xs]
         ys = [jnp.asarray(y) for y in ys]
+        self.last_batch_size = _batch_size(xs)
         T = xs[0].shape[1]
         L = self.conf.tbptt_fwd_length
         n_chunks = max(1, math.ceil(T / L))
@@ -936,7 +747,7 @@ class ComputationGraph(LazyScore):
         Feature masks route through the forward walk, label masks weight
         each example's own loss — as in fit()."""
         self._require_init()
-        xs, ys, fms, lms = _coerce_graph_batch(data)
+        xs, ys, fms, lms = self._batch_of(data)
         asarray_opt = lambda m: jnp.asarray(m) if m is not None else None
         fn = self._jit("score_examples", self._score_examples_pure)
         per = fn(self.params_list, self.state_list,

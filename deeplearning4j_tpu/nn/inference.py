@@ -1,7 +1,7 @@
 """Non-donated compiled inference: the serving seam.
 
 Training dispatches donate params/states/updater into XLA
-(``LazyScore._run_multistep`` jits with ``donate_argnums=(0, 1, 2)``) — the
+(``LazyScore._program`` jits with ``donate_argnums=(0, 1, 2)``) — the
 buffers are consumed in place, which is exactly right for a fit loop and
 exactly wrong for serving, where the same parameters must survive millions
 of forward passes. :func:`make_predict_fn` pins a **snapshot** of a

@@ -13,13 +13,19 @@ collapses into that fused step; listeners observe from the host side.
 Mutable-object API (net.fit(...), net.output(...)) is preserved as a thin stateful shell
 over the pure functions so reference users feel at home; the pure train_step itself is
 exposed for ParallelWrapper/pjit composition (see deeplearning4j_tpu.parallel).
+
+This module is also the home of the fit loop. ``LazyScore``, the base of
+MultiLayerNetwork and ComputationGraph, holds the one copy of ``fit_iterator``, the
+staged K-step epoch, the per-batch step and the record of a dispatched step;
+ParallelWrapper's synchronous loop runs the same code as a ``LoopOwner``.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import time
-from typing import Iterable, Optional
+from typing import Callable, Iterable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -439,10 +445,45 @@ def wait_for_step(losses) -> None:
         group=current_group(), cause="fit.dispatch")
 
 
-class LazyScore:
-    """`score_value` that syncs device->host only when actually read.
+def _batch_size(x, axis: int = 0) -> int:
+    """Examples in a batch (arrays, or a list of arrays per stream), read off
+    its first leaf; ``axis`` 1 for a stacked (K, B, ...) group."""
+    leaves = jax.tree_util.tree_leaves(x)
+    return int(leaves[0].shape[axis]) if leaves and leaves[0].ndim > axis else 0
 
-    The reference's fit loop computes `score` eagerly every iteration
+
+@dataclasses.dataclass(frozen=True)
+class LoopOwner:
+    """What the staged fit loop (``LazyScore._fit_epoch``) takes from a caller
+    that drives a network through programs of its own: ``ParallelWrapper``'s
+    synchronous loop, compiled over its mesh. A network fitting itself passes
+    none: its own programs, the default device, its ``prefetch_depth`` and a
+    ring it drops when ``fit_iterator`` returns."""
+
+    #: ``path`` label of the loop's ``dl4j_prefetch_*`` series
+    path: str
+    #: groups staged ahead of the dispatch loop
+    depth: int
+    #: host slots of the staged groups, the owner's to keep and to drain
+    ring: HostGroupRing
+    #: host leaf of a lone batch -> its device array
+    put: Callable
+    #: leaf of one batch -> the ``Sharding`` of its stacked (K, B, ...) group
+    group_sharding: Callable
+    #: ``{"train_step" | "multistep": (compiled program, its name in the
+    #: compile tracker)}``, called as the networks' own are, without masks
+    programs: dict
+    #: called with the steps a dispatch ran (the owner's own accounting);
+    #: returns further fields of the dispatch's step record
+    note_steps: Callable
+
+
+class LazyScore:
+    """The networks' shared base: the lazily read score, the per-network
+    program cache (``_jit``) and the fit loop (below, "the fit loop").
+
+    `score_value` syncs device->host only when actually read. The
+    reference's fit loop computes `score` eagerly every iteration
     (MultiLayerNetwork.java:1807 computeGradientAndScore) because its
     listeners observe synchronously. On TPU `float(loss)` is a full host
     round-trip that drains the dispatch queue, so the training loops
@@ -565,28 +606,221 @@ class LazyScore:
                 extra=("donate", donate) + tuple(extra) + tuple(pol))
         return self._jit_cache[key]
 
-    #: hook: the module-level K-step builder for this network type
-    #: (make_multistep_train_step / make_graph_multistep_train_step) so the
-    #: shared dispatch helper below can build plain and health variants
+    # ------------------------------------------------------------ the fit loop
+    # One copy of the staged K-step loop and of the per-batch step, for
+    # MultiLayerNetwork, ComputationGraph and (through a ``LoopOwner``)
+    # ParallelWrapper's synchronous loop. A batch is arrays for the one and a
+    # list of arrays per stream for the other: everything below maps over
+    # leaves, and the hooks name what a network type brings.
+
+    #: hooks: the module-level builders of this network type's one-step and
+    #: K-step programs (make_train_step / make_graph_train_step and their
+    #: ``multistep`` twins); both take ``health=`` for the monitored variant
+    _step_builder = None
     _multistep_builder = None
+
+    #: hook: this network type's ``path`` label on the ``dl4j_prefetch_*``
+    #: series ("multilayer" / "graph")
+    _fit_path = None
+
+    def _batch_of(self, ds) -> tuple:
+        """Hook: ``(features, labels, feature masks, label masks)`` of a
+        dataset in this network type's tree shape; absent masks are None."""
+        raise NotImplementedError
+
+    def _tbptt_active(self) -> bool:
+        """Hook: whether a batch goes through the type's own ``_fit_tbptt``
+        (truncated BPTT over layers that carry a streaming state)."""
+        raise NotImplementedError
+
+    #: train steps fused per host dispatch in fit_iterator (lax.scan); 1
+    #: disables the K-step path. Calibrated 2026-07-31 on a v5e, record not
+    #: kept; re-derive in a cell.
+    dispatch_ksteps: int = 8
+
+    #: optional dtype (e.g. jnp.bfloat16) features are cast to on the host
+    #: BEFORE the device transfer in the fused fit path. Halves host->device
+    #: bytes (BASELINE.md round-3 fit-API analysis). Labels stay untouched.
+    #: None keeps exact f32 staging. Either way a K-step group is written
+    #: once, batch by batch, into a reused host slot of the staged dtype
+    #: (``stage_group``), never stacked in float32 first.
+    stage_dtype = None
+
+    #: K-step groups staged + transferred ahead of the dispatch loop on a
+    #: background thread (datasets.prefetch.DevicePrefetcher): 2 = double
+    #: buffering (batch n+1 in flight to HBM while step n executes), 0 =
+    #: synchronous staging (the pre-prefetch behavior; bit-identical params
+    #: either way — tests/test_prefetch.py).
+    prefetch_depth: int = 2
+
+    #: Solver facade instance when optimization_algo != SGD (built lazily)
+    _solver = None
 
     #: the host slots for staged groups (``HostGroupRing``), made on the
     #: first staged group; ``fit_iterator`` drops them when it returns
     _host_ring = None
 
-    def _stage_group(self, batches: list, path: str):
-        """``stage_group`` into this network's ring of ``prefetch_depth + 2``
-        slots: as many groups as are alive at once (one being staged,
-        ``prefetch_depth`` queued, one dispatched) and one more, so that the
-        transfer out of a slot has long finished when its turn comes again."""
+    #: loss stacks of the two staged groups dispatched last, older first
+    _staged_losses = (None, None)
+
+    def _next_rng(self):
+        self._require_init()
+        if self._rng is None:
+            raise RuntimeError(self.NOT_INITIALIZED_MSG)
+        self._rng, sub = jax.random.split(self._rng)
+        return sub
+
+    def _uses_sgd(self) -> bool:
+        algo = self.conf.global_conf.optimization_algo
+        return algo in (None, "stochastic_gradient_descent")
+
+    def _fused_ok(self) -> bool:
+        """Whether the fused step programs (one step, or K in a scan) train
+        this configuration: SGD, one iteration a batch, no streaming state to
+        thread through truncated BPTT. The one predicate behind ``fit``,
+        ``fit_iterator`` and ``ParallelWrapper``'s synchronous loop; what it
+        declines goes batch by batch through ``_fit_batch``'s routes."""
+        return (self._uses_sgd()
+                and self.conf.global_conf.iterations <= 1
+                and not self._tbptt_active())
+
+    def _fit_arrays(self, x, y, fmask, lmask, epochs: int) -> None:
+        """``epochs`` steps on one batch (``fit`` on arrays or a dataset):
+        unmasked and ``_fused_ok``, K per dispatch from a batch staged once
+        (``_fit_repeated``), else one ``_fit_batch`` each."""
+        if (epochs > 1 and fmask is None and lmask is None
+                and self.dispatch_ksteps > 1 and self._fused_ok()):
+            self._fit_repeated(x, y, epochs)
+            return
+        for _ in range(epochs):
+            begin_group()
+            self._fit_batch(x, y, fmask, lmask)
+
+    def _fit_repeated(self, x, y, epochs: int) -> None:
+        """``epochs`` repeated steps on one device-resident batch, K per
+        dispatch via the scanned train step (broadcast along the scan axis —
+        XLA reads the same HBM buffer each step, no K-fold staging)."""
+        tree_map = jax.tree_util.tree_map
+        with _t_staging.time():
+            xd = tree_map(
+                lambda a: jnp.asarray(_stage_host(a, self.stage_dtype)), x)
+            yd = tree_map(jnp.asarray, y)
+        self.last_batch_size = _batch_size(xd)
+        remaining = epochs
+        while remaining > 0:
+            k = min(self.dispatch_ksteps, remaining)
+            xs, ys = tree_map(
+                lambda a: jnp.broadcast_to(a[None], (k,) + a.shape), (xd, yd))
+            begin_group()
+            self._run_steps("multistep", k, xs, ys)
+            remaining -= k
+
+    @_dump_on_unhandled("{cls}.fit_iterator")
+    def fit_iterator(self, iterator: Iterable, epochs: int = 1,
+                     ksteps: Optional[int] = None) -> None:
+        """Fit from a DataSetIterator (reference MultiLayerNetwork
+        fit(DataSetIterator):978, ComputationGraph fit:747).
+
+        TPU fast path: accumulates up to ``ksteps`` host-staged minibatches,
+        stages them as one (K, B, ...) device transfer, and runs all K
+        train steps inside ONE XLA dispatch (the type's ``multistep``
+        program) — the per-minibatch host round-trip of the reference's fit
+        loop is paid once per K steps. Listeners still observe every
+        iteration; reading `score_value` lazily indexes the on-device loss
+        stack (LazyScore), so a listener firing every N iterations costs
+        ~K*N fewer syncs. Falls back to per-batch dispatch for TBPTT, masked
+        batches, iterations>1 configs, or ragged batch shapes.
+        """
+        k = self.dispatch_ksteps if ksteps is None else max(1, ksteps)
+        try:
+            for _ in range(epochs):
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_start"):
+                        listener.on_epoch_start(self)
+                if hasattr(iterator, "reset"):
+                    iterator.reset()
+                if self.conf.pretrain:
+                    self.pretrain(iterator)
+                    if hasattr(iterator, "reset"):
+                        iterator.reset()
+                self._fit_epoch(iterator, k)
+                for listener in self.listeners:
+                    if hasattr(listener, "on_epoch_end"):
+                        listener.on_epoch_end(self)
+                self.epoch += 1
+        finally:
+            self._release_staging()
+
+    def _fit_epoch(self, iterator, k: int, owner=None) -> None:
+        """One pass over ``iterator``: through the staged loop where the
+        fused programs train this configuration and there is something to
+        stage for (K > 1, or an owner's mesh), else batch by batch."""
+        if self._fused_ok() and (k > 1 or owner is not None):
+            self._fit_epoch_staged(iterator, k, owner)
+            return
+        for ds in iterator:
+            begin_group()
+            self._fit_batch(*self._batch_of(ds))
+
+    def _fit_epoch_staged(self, iterator, k: int, owner=None) -> None:
+        """The staged K-step loop: pull up to ``k`` unmasked batches of one
+        shape, stage the group on the producer thread, dispatch it once the
+        step two groups back has finished, run the listeners. Masked batches
+        and lone batches (a ragged tail, K = 1) take the per-batch step; only
+        an unmasked lone batch takes the owner's."""
+        from deeplearning4j_tpu.utils.batching import k_step_groups
+
+        tree_map = jax.tree_util.tree_map
+        path, depth, put = (
+            (self._fit_path, self.prefetch_depth, jnp.asarray)
+            if owner is None else (owner.path, owner.depth, owner.put))
+
+        def to_batch(ds):
+            x, y, fmask, lmask = self._batch_of(ds)
+            if fmask is not None or lmask is not None:
+                return None  # masked -> per-batch fallback
+            return tree_map(np.asarray, (x, y))   # host staging, no device sync
+
+        def stage(kind_item):
+            # producer thread: cast into a host slot + NON-BLOCKING put — the
+            # (K, B, ...) group is in flight to HBM while the previous
+            # dispatch executes; a lone batch is put as it is. Singles pass
+            # through to the host fallback path unchanged.
+            kind, item = kind_item
+            if kind != "group":
+                return kind_item
+            if len(item) == 1:
+                return kind, [tree_map(put, item[0])]
+            xs, ys = self._stage_group(item, owner)
+            return "staged", (xs, ys, len(item))
+
+        # closed on the way out, so that no producer is staging into a ring
+        # when its owner drains it
+        with DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
+                              depth=depth, path=path,
+                              wait_series=_t_staging) as pf:
+            for kind, item in pf:
+                if kind == "single":
+                    self._fit_batch(*self._batch_of(item))
+                elif kind == "group":
+                    self._fit_batch(*item[0], owner=owner)
+                else:
+                    self._dispatch_staged(*item, owner=owner)
+
+    def _stage_group(self, batches: list, owner=None):
+        """``stage_group`` into the loop's ring. A network's own has
+        ``prefetch_depth + 2`` slots: as many groups as are alive at once
+        (one being staged, ``prefetch_depth`` queued, one dispatched) and one
+        more, so that the transfer out of a slot has long finished when its
+        turn comes again. An owner brings its ring and its layout."""
+        if owner is not None:
+            return stage_group(batches, self.stage_dtype, owner.ring,
+                               owner.group_sharding)
         size = max(0, self.prefetch_depth) + 2
         ring = self._host_ring
         if ring is None or ring.size != size:
-            ring = self._host_ring = HostGroupRing(size, path)
+            ring = self._host_ring = HostGroupRing(size, self._fit_path)
         return stage_group(batches, self.stage_dtype, ring)
-
-    #: loss stacks of the two staged groups dispatched last, older first
-    _staged_losses = (None, None)
 
     def _release_staging(self) -> None:
         """Let go of what the staged fit loop keeps between groups: the host
@@ -595,19 +829,18 @@ class LazyScore:
         self._host_ring = None
         self._staged_losses = (None, None)
 
-    def _dispatch_staged(self, xs, ys, n: int) -> None:
+    def _dispatch_staged(self, xs, ys, n: int, owner=None) -> None:
         """Run a K-step group whose (K, B, ...) stacks are already device-
-        resident (or in flight — dispatch never blocks on the transfer);
-        shared by both network types.
+        resident (or in flight — dispatch never blocks on the transfer).
 
-        Donation hand-off: params/states/updater buffers are DONATED — XLA
-        updates them in place (no 2x param HBM during the step) and the
-        previous arrays are consumed; anyone holding stale references gets a
-        loud "deleted buffer" error, never silent corruption (clone() deep-
-        copies for this reason; donation is a no-op on CPU). The staged
-        xs/ys are NOT in the donated argnums and were freshly created by
-        device_put on the prefetch thread, so a prefetched group can never
-        alias a buffer the in-flight step is consuming.
+        Donation hand-off: a network's own K-step program DONATES params/
+        states/updater buffers — XLA updates them in place (no 2x param HBM
+        during the step) and the previous arrays are consumed; anyone holding
+        stale references gets a loud "deleted buffer" error, never silent
+        corruption (clone() deep-copies for this reason; donation is a no-op
+        on CPU). The staged xs/ys are NOT in the donated argnums and were
+        freshly created by device_put on the prefetch thread, so a prefetched
+        group can never alias a buffer the in-flight step is consuming.
 
         Flow control: the group is dispatched once the step of the group two
         before it has finished, so one group is queued behind the running
@@ -615,77 +848,127 @@ class LazyScore:
         where it is the faster side, piles staged groups up in HBM: each
         dispatched group holds its inputs there until its step has run, and
         the runtime lets a host run some thirty dispatches ahead."""
-        self.last_batch_size = int(jax.tree_util.tree_leaves(xs)[0].shape[1])
+        self.last_batch_size = _batch_size(xs, axis=1)
         two_back, one_back = self._staged_losses
-        losses = self._run_multistep(xs, ys, n, after=two_back)
+        losses = self._run_steps("multistep", n, xs, ys, after=two_back,
+                                 owner=owner)
         self._staged_losses = (one_back, losses)
-        self._run_listeners(losses, n)
-        _wd_beat(self.iteration)
 
-    def _run_multistep(self, xs, ys, n: int, after=None):
-        """Dispatch one K-step fused group (shared by both network types):
-        picks the health variant when the attached monitor's cadence falls
-        inside the group, times the dispatch, records the flight-recorder
-        step event, and advances the step clock with MFU attribution.
-        Returns the (K,) per-step loss stack; params/states/updater are
-        updated in place (donated). ``after``: a device array to wait for
-        first (``_dispatch_staged``; the ``device`` phase and the group's
-        ``fit.step_wait`` span), once everything but the call is done."""
-        hm = self.health_monitor
+    def _fit_batch(self, x, y, fmask=None, lmask=None, owner=None) -> None:
+        """One batch through the per-batch step: the solver for a
+        configuration that asks for one, the type's ``_fit_tbptt`` under
+        truncated BPTT, else ``iterations`` dispatches of the one-step
+        program (an owner's takes no masks: masked batches are not its)."""
+        if not self._uses_sgd():
+            # honor optimization_algo: LBFGS/CG/line-GD configs route through
+            # the Solver facade (reference Solver.java:55 getOptimizer
+            # dispatch) instead of silently training with SGD
+            from deeplearning4j_tpu.optimize.solvers import Solver
+
+            if self._solver is None:
+                self._solver = Solver(self)
+            self._solver.optimize(x, y)
+            return
+        if self._tbptt_active():
+            self._fit_tbptt(x, y, fmask, lmask)
+            return
+        with _t_staging.time():
+            x, y, fmask, lmask = jax.tree_util.tree_map(
+                jnp.asarray, (x, y, fmask, lmask))
+        self.last_batch_size = _batch_size(x)
+        masks = (fmask, lmask) if owner is None else ()
+        for _ in range(max(1, self.conf.global_conf.iterations)):
+            self._run_steps("train_step", 1, x, y, *masks, owner=owner)
+
+    def _program(self, kind: str, health: bool, owner):
+        """The step program of ``kind`` ("train_step" / "multistep") and its
+        name in the compile tracker: the owner's, else this network's own,
+        jitted on first use (the K-step program donates the state)."""
+        if owner is not None:
+            return owner.programs[kind]
+        name = kind + "_health" if health else kind
+        multi = kind == "multistep"
+        build = (type(self)._multistep_builder if multi
+                 else type(self)._step_builder)
+        program = self._jit(name, build(self.conf, health=health),
+                            donate=(0, 1, 2) if multi else None)
+        return program, f"{type(self).__name__}.{name}"
+
+    def _run_steps(self, kind: str, n: int, x, y, *masks, after=None,
+                   owner=None):
+        """Dispatch one call of a step program and book its ``n`` steps:
+        ``kind`` "train_step" (one batch, ``n`` 1, returns the loss) or
+        "multistep" ((K, B, ...) stacks, returns the (K,) loss stack). Picks
+        the health variant when the attached monitor's cadence falls inside
+        the call's steps (an owner's programs have none); params/states/
+        updater are replaced by the program's outputs. ``after``: a device
+        array to wait for first (``_dispatch_staged``; the ``device`` phase
+        and the group's ``fit.step_wait`` span), once everything but the call
+        is done."""
+        multi = kind == "multistep"
+        hm = self.health_monitor if owner is None else None
         due_i = hm.due_index(self.iteration, n) if hm is not None else None
-        name = "multistep" if due_i is None else "multistep_health"
-        multi = self._jit(
-            name, type(self)._multistep_builder(self.conf,
-                                                health=due_i is not None),
-            donate=(0, 1, 2))
+        program, name = self._program(kind, due_i is not None, owner)
         wait_for_step(after)
         t0, t0_ns = time.perf_counter(), time.time_ns()
-        out = multi(self.params_list, self.state_list, self.updater_state,
-                    xs, ys, self._next_rng(), jnp.int32(self.iteration))
+        out = program(self.params_list, self.state_list, self.updater_state,
+                      x, y, self._next_rng(), jnp.int32(self.iteration),
+                      *masks)
         dt, t1_ns = time.perf_counter() - t0, time.time_ns()
-        _t_dispatch.observe(dt)
-        _profile_note_dispatch(dt)
         (self.params_list, self.state_list, self.updater_state,
          losses, *rest) = out
         if due_i is not None:
-            # lazy device gather of the due step's packed health vector — the
-            # monitor parks it; the host sync happens at poll() time
-            hm.offer(rest.pop(0)[due_i], self.iteration + due_i)
+            # the due step's packed health vector (out of a group's (K, 4) a
+            # lazy device gather) — the monitor parks it; the host sync
+            # happens at poll() time
+            haux = rest.pop(0)
+            hm.offer(haux[due_i] if multi else haux, self.iteration + due_i)
         if rest:
-            tokens = math.prod(jax.tree_util.tree_leaves(xs)[0].shape[:3])
+            tokens = math.prod(jax.tree_util.tree_leaves(x)[0].shape[:3])
             self._pending_moe_rows = (*self._pending_moe_rows,
                                       (rest[0], tokens))
             self._note_moe_rows()
-        wrap_name = f"{type(self).__name__}.{name}"
-        _compile_tracker().note_step(n, fn=wrap_name)
-        # the step event is the group's ``fit.dispatch`` span
-        _flight_recorder().record_span(
-            "fit.dispatch", t0_ns, t1_ns, kind="step", group=current_group(),
-            cause="fit.wait", path=wrap_name, it=self.iteration, k=n,
-            batch=self.last_batch_size, dispatch_s=dt)
+        fields = {} if owner is None else owner.note_steps(n)
+        self._book_steps(name, n, losses if multi else [losses], t0_ns,
+                         t1_ns, dt, **fields)
         return losses
 
-    def _run_listeners(self, losses, n: int) -> None:
-        """Advance the iteration over the ``n`` steps of a dispatched group,
-        each with its lazy score, and call the listeners (shared by both
-        network types): the ``listeners`` phase and the group's
-        ``fit.listeners`` span."""
-        t0_ns = time.time_ns()
+    def _book_steps(self, name: str, n: int, scores, t0_ns: int, t1_ns: int,
+                    dt: float, **fields) -> None:
+        """The one record of "``n`` steps were dispatched": by the program
+        ``name``, in a call from ``t0_ns`` to ``t1_ns`` that took ``dt``
+        seconds; ``scores[i]`` is step i's loss on the device. The
+        ``dispatch`` phase, the profiler's and the step clock's note, the
+        calling thread's group's ``fit.dispatch`` span (the ``step`` event,
+        with ``fields``), then per step the iteration count, its lazy score
+        and the listeners (the ``listeners`` phase, ``fit.listeners``), and
+        the watchdog's beat."""
+        _t_dispatch.observe(dt)
+        _profile_note_dispatch(dt)
+        _compile_tracker().note_step(n, fn=name)
+        rec, group = _flight_recorder(), current_group()
+        rec.record_span(
+            "fit.dispatch", t0_ns, t1_ns, kind="step", group=group,
+            cause="fit.wait", path=name, it=self.iteration, k=n,
+            batch=self.last_batch_size, dispatch_s=dt, **fields)
+        l0_ns = time.time_ns()
         with _t_listeners.time():
             for i in range(n):
                 self.iteration += 1
-                self.score_value = (lambda ls=losses, j=i: ls[j])
+                self.score_value = (lambda ls=scores, j=i: ls[j])
                 for listener in self.listeners:
                     listener.iteration_done(self, self.iteration)
-        _flight_recorder().record_span(
-            "fit.listeners", t0_ns, time.time_ns(), group=current_group(),
-            cause="fit.dispatch")
+        rec.record_span("fit.listeners", l0_ns, time.time_ns(), group=group,
+                        cause="fit.dispatch")
+        _wd_beat(self.iteration)
 
 
 class MultiLayerNetwork(LazyScore):
     """Stateful convenience shell over the pure functions above."""
 
+    _step_builder = staticmethod(make_train_step)
     _multistep_builder = staticmethod(make_multistep_train_step)
+    _fit_path = "multilayer"
 
     def __init__(self, conf: MultiLayerConfiguration):
         self.conf = conf
@@ -859,13 +1142,6 @@ class MultiLayerNetwork(LazyScore):
         return self.evaluate(x, y).f1()
 
     # ------------------------------------------------------------------ training
-    def _next_rng(self):
-        self._require_init()
-        if self._rng is None:
-            raise RuntimeError(self.NOT_INITIALIZED_MSG)
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
-
     @_dump_on_unhandled("MultiLayerNetwork.fit")
     def fit(self, x, y=None, *, epochs: int = 1, fmask=None, lmask=None) -> None:
         """Fit on arrays, a DataSet, or a DataSetIterator (reference fit:978).
@@ -877,216 +1153,21 @@ class MultiLayerNetwork(LazyScore):
         from deeplearning4j_tpu.datasets.dataset import DataSet
 
         if y is None and isinstance(x, DataSet):
-            self.fit(x.features, x.labels, epochs=epochs,
-                     fmask=x.features_mask, lmask=x.labels_mask)
-            return
-        if y is None and hasattr(x, "__iter__") and not isinstance(x, (jnp.ndarray, np.ndarray)):
+            self._fit_arrays(*self._batch_of(x), epochs)
+        elif y is None and hasattr(x, "__iter__") and not isinstance(x, (jnp.ndarray, np.ndarray)):
             self.fit_iterator(x, epochs=epochs)
-            return
-        if (epochs > 1 and fmask is None and lmask is None
-                and self._repeat_multistep_ok()):
-            self._fit_repeated(x, y, epochs)
-            return
-        for _ in range(epochs):
-            self._fit_batch(x, y, fmask, lmask)
+        else:
+            # the loop maps over leaves: a nested list is one array here
+            x, y = (a if hasattr(a, "shape") else jnp.asarray(a)
+                    for a in (x, y))
+            self._fit_arrays(x, y, fmask, lmask, epochs)
 
-    def _repeat_multistep_ok(self) -> bool:
-        return (self.dispatch_ksteps > 1
-                and self._uses_sgd()
-                and self.conf.global_conf.iterations <= 1
-                and not (self.conf.backprop_type == "TruncatedBPTT"
-                         and any(isinstance(l, LSTM)
-                                 for l in self.conf.layers)))
+    def _batch_of(self, ds) -> tuple:
+        return ds.features, ds.labels, ds.features_mask, ds.labels_mask
 
-    def _fit_repeated(self, x, y, epochs: int) -> None:
-        """``epochs`` repeated steps on one device-resident batch, K per
-        dispatch via the scanned train step (broadcast along the scan axis —
-        XLA reads the same HBM buffer each step, no K-fold staging)."""
-        with _t_staging.time():
-            xd = jnp.asarray(_stage_host(x, self.stage_dtype))
-            yd = jnp.asarray(y)
-        self.last_batch_size = int(np.shape(x)[0]) if np.ndim(x) else 0
-        remaining = epochs
-        while remaining > 0:
-            k = min(self.dispatch_ksteps, remaining)
-            xs = jnp.broadcast_to(xd[None], (k,) + xd.shape)
-            ys = jnp.broadcast_to(yd[None], (k,) + yd.shape)
-            begin_group()
-            losses = self._run_multistep(xs, ys, k)
-            self._run_listeners(losses, k)
-            _wd_beat(self.iteration)
-            remaining -= k
-
-    #: train steps fused per host dispatch in fit_iterator (lax.scan); 1
-    #: disables the K-step path. Calibrated 2026-07-31 on a v5e, record not
-    #: kept; re-derive in a cell.
-    dispatch_ksteps: int = 8
-
-    #: optional dtype (e.g. jnp.bfloat16) features are cast to on the host
-    #: BEFORE the device transfer in the fused fit path. Halves host->device
-    #: bytes (BASELINE.md round-3 fit-API analysis). Labels stay untouched.
-    #: None keeps exact f32 staging. Either way a K-step group is written
-    #: once, batch by batch, into a reused host slot of the staged dtype
-    #: (``stage_group``), never stacked in float32 first.
-    stage_dtype = None
-
-    #: K-step groups staged + transferred ahead of the dispatch loop on a
-    #: background thread (datasets.prefetch.DevicePrefetcher): 2 = double
-    #: buffering (batch n+1 in flight to HBM while step n executes), 0 =
-    #: synchronous staging (the pre-prefetch behavior; bit-identical params
-    #: either way — tests/test_prefetch.py).
-    prefetch_depth: int = 2
-
-    @_dump_on_unhandled("MultiLayerNetwork.fit_iterator")
-    def fit_iterator(self, iterator: Iterable, epochs: int = 1,
-                     ksteps: Optional[int] = None) -> None:
-        """Fit from a DataSetIterator (reference fit(DataSetIterator):978).
-
-        TPU fast path: accumulates up to ``ksteps`` host-staged minibatches,
-        stacks them into one (K, B, ...) device transfer, and runs all K
-        train steps inside ONE XLA dispatch (make_multistep_train_step) —
-        the per-minibatch host round-trip of the reference's fit loop is paid
-        once per K steps. Listeners still observe every iteration; reading
-        `score_value` lazily indexes the on-device loss stack (LazyScore), so
-        a listener firing every N iterations costs ~K*N fewer syncs.
-        Falls back to per-batch dispatch for TBPTT, masked batches,
-        iterations>1 configs, or ragged batch shapes.
-        """
-        k = self.dispatch_ksteps if ksteps is None else max(1, ksteps)
-        multistep_ok = (
-            k > 1
-            and self._uses_sgd()
-            and self.conf.global_conf.iterations <= 1
-            and not (self.conf.backprop_type == "TruncatedBPTT"
-                     and any(isinstance(l, LSTM) for l in self.conf.layers)))
-        try:
-            for _ in range(epochs):
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_start"):
-                        listener.on_epoch_start(self)
-                if hasattr(iterator, "reset"):
-                    iterator.reset()
-                if self.conf.pretrain:
-                    self.pretrain(iterator)
-                    if hasattr(iterator, "reset"):
-                        iterator.reset()
-                if multistep_ok:
-                    self._fit_epoch_multistep(iterator, k)
-                else:
-                    for ds in iterator:
-                        self._fit_batch(ds.features, ds.labels,
-                                        ds.features_mask, ds.labels_mask)
-                for listener in self.listeners:
-                    if hasattr(listener, "on_epoch_end"):
-                        listener.on_epoch_end(self)
-                self.epoch += 1
-        finally:
-            self._release_staging()
-
-    def _fit_epoch_multistep(self, iterator, k: int) -> None:
-        from deeplearning4j_tpu.utils.batching import k_step_groups
-
-        def to_batch(ds):
-            if ds.features_mask is not None or ds.labels_mask is not None:
-                return None  # masked -> per-batch fallback
-            # lint: host-sync-in-hot-loop-ok (producer-thread host staging of iterator output, not a device sync)
-            return np.asarray(ds.features), np.asarray(ds.labels)
-
-        def stage(kind_item):
-            # producer thread: cast into a host slot + NON-BLOCKING
-            # device_put — the (K, B, ...) group is in flight to HBM while
-            # the previous dispatch executes. Singles and len<2 groups pass
-            # through to the host fallback path unchanged.
-            kind, item = kind_item
-            if kind != "group" or len(item) < 2:
-                return kind_item
-            xs, ys = self._stage_group(item, "multilayer")
-            return "staged", (xs, ys, len(item))
-
-        pf = DevicePrefetcher(k_step_groups(iterator, k, to_batch), stage,
-                              depth=self.prefetch_depth, path="multilayer",
-                              wait_series=_t_staging)
-        for kind, item in pf:
-            if kind == "single":
-                self._fit_batch(item.features, item.labels,
-                                item.features_mask, item.labels_mask)
-            elif kind == "group":
-                if item:
-                    self._fit_batch(item[0][0], item[0][1])
-            else:
-                self._dispatch_staged(*item)
-
-    def _dispatch_multistep(self, batches: list) -> None:
-        """Synchronous-staging compatibility path (prefetch_depth=0 semantics
-        for a pre-built group)."""
-        if not batches:
-            return
-        if len(batches) == 1:
-            self._fit_batch(batches[0][0], batches[0][1])
-            return
-        begin_group()
-        with _t_staging.time():
-            xs, ys = self._stage_group(batches, "multilayer")
-        self._dispatch_staged(xs, ys, len(batches))
-
-    #: Solver facade instance when optimization_algo != SGD (built lazily)
-    _solver = None
-
-    def _uses_sgd(self) -> bool:
-        algo = self.conf.global_conf.optimization_algo
-        return algo in (None, "stochastic_gradient_descent")
-
-    def _fit_batch(self, x, y, fmask=None, lmask=None) -> None:
-        if not self._uses_sgd():
-            # honor optimization_algo: LBFGS/CG/line-GD configs route through
-            # the Solver facade (reference Solver.java:55 getOptimizer
-            # dispatch) instead of silently training with SGD
-            from deeplearning4j_tpu.optimize.solvers import Solver
-
-            if self._solver is None:
-                self._solver = Solver(self)
-            self._solver.optimize(x, y)
-            return
-        if (self.conf.backprop_type == "TruncatedBPTT"
-                and any(isinstance(l, LSTM) for l in self.conf.layers)):
-            self._fit_tbptt(x, y, fmask, lmask)
-            return
-        with _t_staging.time():
-            x, y = jnp.asarray(x), jnp.asarray(y)
-            fmask = jnp.asarray(fmask) if fmask is not None else None
-            lmask = jnp.asarray(lmask) if lmask is not None else None
-        self.last_batch_size = int(x.shape[0]) if x.ndim else 0
-        for _ in range(max(1, self.conf.global_conf.iterations)):
-            hm = self.health_monitor
-            use_health = hm is not None and hm.due(self.iteration)
-            name = "train_step_health" if use_health else "train_step"
-            step = self._jit(name, make_train_step(self.conf,
-                                                   health=use_health))
-            t0 = time.perf_counter()
-            out = step(self.params_list, self.state_list,
-                       self.updater_state, x, y, self._next_rng(),
-                       jnp.int32(self.iteration), fmask, lmask)
-            dt = time.perf_counter() - t0
-            _t_dispatch.observe(dt)
-            _profile_note_dispatch(dt)
-            if use_health:
-                (self.params_list, self.state_list, self.updater_state,
-                 loss, haux) = out
-                hm.offer(haux, self.iteration)
-            else:
-                (self.params_list, self.state_list, self.updater_state,
-                 loss) = out
-            wrap_name = f"{type(self).__name__}.{name}"
-            _compile_tracker().note_step(fn=wrap_name)
-            _flight_recorder().record(
-                "step", path=wrap_name, it=self.iteration,
-                batch=self.last_batch_size, dispatch_s=dt)
-            self.score_value = loss  # device scalar; synced lazily (LazyScore)
-            self.iteration += 1
-            with _t_listeners.time():
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration)
-            _wd_beat(self.iteration)
+    def _tbptt_active(self) -> bool:
+        return (self.conf.backprop_type == "TruncatedBPTT"
+                and any(isinstance(l, LSTM) for l in self.conf.layers))
 
     # ------------------------------------------------------------------ TBPTT
     def _fit_tbptt(self, x, y, fmask=None, lmask=None) -> None:

@@ -354,7 +354,9 @@ def dump_on_unhandled(site: str):
     """Decorator for the fit entry points: an exception escaping the wrapped
     call records an event and (when a dump dir is configured) writes one
     bundle, then re-raises. Nested decorated frames (fit -> fit_iterator)
-    dump once — the exception object is marked after the first bundle."""
+    dump once — the exception object is marked after the first bundle.
+    ``{cls}`` in ``site`` is the class of the object the method was called
+    on, for a method that several classes share."""
 
     def deco(fn):
         @functools.wraps(fn)
@@ -362,7 +364,9 @@ def dump_on_unhandled(site: str):
             try:
                 return fn(*args, **kwargs)
             except Exception as e:
-                _note_unhandled(site, e)
+                _note_unhandled(
+                    site.format(cls=type(args[0]).__name__) if args else site,
+                    e)
                 raise
         return wrapper
 

@@ -33,7 +33,7 @@ from deeplearning4j_tpu.observability.flight_recorder import (
 from deeplearning4j_tpu.observability.watchdog import beat as _wd_beat
 from deeplearning4j_tpu.parallel.mesh import build_mesh
 from deeplearning4j_tpu.parallel.pipeline import PipelineParallel
-from deeplearning4j_tpu.parallel.wrapper import (
+from deeplearning4j_tpu.nn.multilayer import (
     _t_staging, _t_dispatch, _t_listeners,
 )
 

@@ -34,17 +34,11 @@ import numpy as np
 from jax.sharding import Mesh
 
 from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
-from deeplearning4j_tpu.observability.compile_tracker import (
-    global_tracker as _compile_tracker,
-)
+from deeplearning4j_tpu.nn.multilayer import LoopOwner, _t_staging
 from deeplearning4j_tpu.observability.flight_recorder import (
     dump_on_unhandled as _dump_on_unhandled,
-    global_recorder as _flight_recorder,
 )
-from deeplearning4j_tpu.observability.watchdog import beat as _wd_beat
-from deeplearning4j_tpu.observability.names import (
-    COLLECTIVE_BYTES_TOTAL, FIT_PHASE_SECONDS,
-)
+from deeplearning4j_tpu.observability.names import COLLECTIVE_BYTES_TOTAL
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry, tree_nbytes as _tree_nbytes,
 )
@@ -55,18 +49,10 @@ from deeplearning4j_tpu.parallel.partition import (
     rules_for,
 )
 
-# step-time attribution shares the fit-phase histogram with the single-chip
-# loops; the collective counter sizes DP traffic host-side per dispatch (the
+# the collective counter sizes DP traffic host-side per dispatch (the
 # gradient psum moves ~param bytes per step; traced collectives inside
-# ring/ulysses/moe report trace-time per-step gauges instead)
-_phase_hist = _obs_registry().histogram(
-    FIT_PHASE_SECONDS,
-    "host wall seconds per fit-loop phase (staging: host cast+transfer "
-    "submit, or with device prefetch the visible wait for the staged batch; "
-    "dispatch: jitted-call submit; listeners: callback overhead)")
-_t_staging = _phase_hist.labels(phase="staging")
-_t_dispatch = _phase_hist.labels(phase="dispatch")
-_t_listeners = _phase_hist.labels(phase="listeners")
+# ring/ulysses/moe report trace-time per-step gauges instead); step-time
+# attribution is the networks' loop's (``LazyScore._book_steps``)
 _collective_bytes = _obs_registry().counter(
     COLLECTIVE_BYTES_TOTAL,
     "bytes moved by host-dispatched collectives, by op and site")
@@ -186,12 +172,15 @@ class ParallelWrapperBuilder:
 class ParallelWrapper:
     """Data-parallel ``fit`` over the devices of a mesh (module docstring).
 
-    The synchronous loop (``averaging_frequency`` 1) stages its K-step groups
-    as the networks' own fit loops do (``nn.multilayer.stage_group``): each
-    batch is cast to the model's ``stage_dtype`` straight into a reused host
-    slot, every device is sent its shard of the slot without waiting (laid
-    out per ``_batch_spec``; nothing lands whole on one device), and a group
-    is dispatched once the step of the group two before it has finished.
+    The synchronous loop (``averaging_frequency`` 1) is the networks' own
+    staged loop (``nn.multilayer.LazyScore._fit_epoch``), run with the
+    wrapper as its ``LoopOwner`` (``_fit_sync``): each batch is cast to the
+    model's ``stage_dtype`` straight into a reused host slot, every device is
+    sent its shard of the slot without waiting (laid out per ``_batch_spec``;
+    nothing lands whole on one device), a group is dispatched through the
+    wrapper's program once the step of the group two before it has finished,
+    and every dispatch is booked as the networks' are (``fit.dispatch`` with
+    the all-reduce's ``collective_bytes``, ``fit.listeners``).
 
     The slots (a ``HostGroupRing`` of ``prefetch + 2``) live as long as the
     wrapper, not one ``fit`` call: a trainer's epochs are several ``fit``
@@ -296,9 +285,8 @@ class ParallelWrapper:
         self._avg_fn = None
         self._local = None  # stacked per-replica (params, states, upd) for local-SGD
         # the synchronous loop's host slots for staged K-step groups (class
-        # docstring) and the loss stacks of the two groups dispatched last
+        # docstring)
         self._host_ring = None
-        self._staged_losses = (None, None)
         # dtype policy the cached jitted programs were traced under; they are
         # rebuilt when it changes (the policy is read at trace time)
         self._traced_policy = None
@@ -410,15 +398,9 @@ class ParallelWrapper:
 
     # ------------------------------------------------------- synchronous DP (freq=1)
     def _make_sync_step(self):
-        from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, make_train_step
-
         net = self.model
         mesh = self.mesh
-        if isinstance(net, MultiLayerNetwork):
-            base = make_train_step(net.conf)
-        else:
-            from deeplearning4j_tpu.nn.graph_network import make_graph_train_step
-            base = make_graph_train_step(net.conf)
+        base = type(net)._step_builder(net.conf)
 
         def step(params, states, upd, x, y, rng, it):
             with self._trace_ctx():
@@ -445,18 +427,10 @@ class ParallelWrapper:
         """K-step scanned train step with the stacked batch axis sharded over
         'data' (stack axis unsharded): one host dispatch drives K synchronous
         DP steps, so dispatch latency amortizes exactly as in the single-chip
-        fast path (MultiLayerNetwork.fit_iterator)."""
-        from deeplearning4j_tpu.nn.multilayer import (
-            MultiLayerNetwork, make_multistep_train_step)
-
+        fast path (the networks' fit_iterator)."""
         net = self.model
         mesh = self.mesh
-        if isinstance(net, MultiLayerNetwork):
-            base = make_multistep_train_step(net.conf)
-        else:
-            from deeplearning4j_tpu.nn.graph_network import (
-                make_graph_multistep_train_step)
-            base = make_graph_multistep_train_step(net.conf)
+        base = type(net)._multistep_builder(net.conf)
 
         def multi(params, states, upd, xs, ys, rng, it0):
             with self._trace_ctx():
@@ -512,185 +486,65 @@ class ParallelWrapper:
         net.updater_state = place(net.updater_state, upd_sp)
 
     def _fit_sync(self, iterator, epochs: int) -> None:
+        """The networks' staged loop (``LazyScore._fit_epoch``), handed what
+        is the wrapper's own: the programs compiled over the mesh, how a
+        batch and a group are laid out over it, the ring, the prefetch depth
+        and the gradient all-reduce's accounting. What the sharded step does
+        not implement (masks, iterations>1, TBPTT, a solver) falls back to
+        the model's own per-batch path, unsharded: correctness over
+        parallelism for those batches."""
+        from deeplearning4j_tpu.datasets.prefetch import HostGroupRing
+
         net = self.model
         self._drop_stale_programs()
         if self._sync_step is None:
             self._sync_step = self._make_sync_step()
             self._sync_multi = self._make_sync_multistep()
         self._place_state()
-        from deeplearning4j_tpu.nn.conf.layers.recurrent import LSTM
-        from deeplearning4j_tpu.datasets.prefetch import (
-            DevicePrefetcher, HostGroupRing)
-        from deeplearning4j_tpu.nn.graph_network import (
-            ComputationGraph, _coerce_graph_batch)
-        from deeplearning4j_tpu.nn.multilayer import (
-            stage_group, wait_for_step)
-        from deeplearning4j_tpu.utils.batching import k_step_groups
-
         if self._host_ring is None:
             self._host_ring = HostGroupRing(self.prefetch + 2, "wrapper_sync")
-        ring = self._host_ring
-        is_graph = isinstance(net, ComputationGraph)
-        iters_cfg = max(1, net.conf.global_conf.iterations)
-        tbptt_lstm = (not is_graph
-                      and net.conf.backprop_type == "TruncatedBPTT"
-                      and any(isinstance(l, LSTM) for l in net.conf.layers))
-        k = max(1, getattr(net, "dispatch_ksteps", 8))
-
-        def to_batch(ds):
-            # Fall back to the model's own per-batch path for semantics the
-            # sharded standard step doesn't implement: masks, iterations>1,
-            # TBPTT state threading. Fallback runs unsharded — correctness
-            # over parallelism for these batches.
-            if tbptt_lstm or iters_cfg > 1:
-                return None
-            if is_graph:
-                xs, ys, fm, lm = _coerce_graph_batch(ds)
-                if fm is not None or lm is not None:
-                    return None
-                return ([np.asarray(a) for a in xs],  # lint: host-sync-in-hot-loop-ok (host staging in to_batch)
-                        [np.asarray(a) for a in ys])  # lint: host-sync-in-hot-loop-ok (host staging in to_batch)
-            if ds.features_mask is not None or ds.labels_mask is not None:
-                return None
-            # lint: host-sync-in-hot-loop-ok (host staging of iterator output, not a device sync)
-            return np.asarray(ds.features), np.asarray(ds.labels)
-
-        def fallback(ds):
-            if is_graph:
-                net._fit_batch(*_coerce_graph_batch(ds))
-            else:
-                net._fit_batch(ds.features, ds.labels, ds.features_mask,
-                               ds.labels_mask)
-
         # DP gradient psum moves ~param bytes per executed train step; sized
         # host-side here because the collective itself is inside the jit
         param_bytes = _tree_nbytes(net.params_list)
         psum_bytes = _collective_bytes.labels(op="psum_grad",
                                               site="wrapper_sync")
 
-        def dispatch_one(x, y, batch_size):
-            if not is_graph:
-                net.last_batch_size = batch_size
-            t0 = _time.perf_counter()
-            (net.params_list, net.state_list, net.updater_state, loss) = \
-                self._sync_step(net.params_list, net.state_list,
-                                net.updater_state, x, y, net._next_rng(),
-                                jnp.int32(net.iteration))
-            dt = _time.perf_counter() - t0
-            _t_dispatch.observe(dt)
-            _compile_tracker().note_step(fn="ParallelWrapper.sync_step")
-            psum_bytes.inc(param_bytes)
-            _flight_recorder().record(
-                "step", path="ParallelWrapper.sync_step", it=net.iteration,
-                batch=batch_size, dispatch_s=dt,
-                collective_bytes=param_bytes)
-            net.score_value = loss  # synced lazily (LazyScore)
-            net.iteration += 1
-            with _t_listeners.time():
-                for listener in net.listeners:
-                    listener.iteration_done(net, net.iteration)
-            _wd_beat(net.iteration)
-
-        def group_sharding(leaf):
-            # a stacked (K, B, ...) group: the batch spec shifted one axis right
-            return _named_sharding(self.mesh,
-                                   P(None, *self._batch_spec(leaf)))
-
-        def dispatch(xs, ys, n):
-            if not is_graph:
-                net.last_batch_size = int(xs.shape[1])
-            # flow control, as LazyScore._dispatch_staged has it: one group
-            # queued behind the running step, and no more staged groups in
-            # HBM than the ring has slots
-            two_back, one_back = self._staged_losses
-            wait_for_step(two_back)
-            t0 = _time.perf_counter()
-            (net.params_list, net.state_list, net.updater_state,
-             losses) = \
-                self._sync_multi(net.params_list, net.state_list,
-                                 net.updater_state, xs, ys,
-                                 net._next_rng(),
-                                 jnp.int32(net.iteration))
-            dt = _time.perf_counter() - t0
-            self._staged_losses = (one_back, losses)
-            _t_dispatch.observe(dt)
-            _compile_tracker().note_step(n, fn="ParallelWrapper.sync_multistep")
+        def note_steps(n):
             psum_bytes.inc(param_bytes * n)
-            _flight_recorder().record(
-                "step", path="ParallelWrapper.sync_multistep",
-                it=net.iteration, k=n, batch=net.last_batch_size,
-                dispatch_s=dt, collective_bytes=param_bytes * n)
-            with _t_listeners.time():
-                for i in range(n):
-                    net.iteration += 1
-                    net.score_value = (lambda ls=losses, j=i: ls[j])
-                    for listener in net.listeners:
-                        listener.iteration_done(net, net.iteration)
-            _wd_beat(net.iteration)
+            return {"collective_bytes": param_bytes * n}
 
-        def stage(kind_item):
-            # producer thread: a group is staged as the networks' own loops
-            # stage theirs (stage_group: cast into a reused host slot, put
-            # without waiting), laid out per _batch_spec, so the sharded
-            # (K, B, ...) group is in flight while the previous dispatch
-            # executes. Singles fall through to the host fallback.
-            kind, item = kind_item
-            if kind != "group":
-                return kind_item
-            if len(item) == 1:
-                x, y = item[0]
-                if is_graph:
-                    bs = int(np.shape(x[0])[0]) if x else 0
-                    x = [self._stage(a, self._batch_spec(a)) for a in x]
-                    y = [self._stage(a, self._batch_spec(a)) for a in y]
-                else:
-                    bs = int(np.shape(x)[0])
-                    x = self._stage(x, self._batch_spec(x))
-                    y = self._stage(y, self._batch_spec(y))
-                return "staged1", (x, y, bs)
-            xs, ys = stage_group(item, getattr(net, "stage_dtype", None),
-                                 ring, group_sharding)
-            return "stagedK", (xs, ys, len(item))
-
+        owner = LoopOwner(
+            path="wrapper_sync", depth=self.prefetch, ring=self._host_ring,
+            put=lambda a: self._stage(a, self._batch_spec(a)),
+            # a stacked (K, B, ...) group: the batch spec shifted one axis right
+            group_sharding=lambda leaf: _named_sharding(
+                self.mesh, P(None, *self._batch_spec(leaf))),
+            programs={
+                "train_step": (self._sync_step, "ParallelWrapper.sync_step"),
+                "multistep": (self._sync_multi,
+                              "ParallelWrapper.sync_multistep")},
+            note_steps=note_steps)
         try:
             for _ in range(epochs):
                 if hasattr(iterator, "reset"):
                     iterator.reset()
-                # closed on the way out, so that no producer is staging into
-                # the ring when it is drained
-                with DevicePrefetcher(k_step_groups(iterator, k, to_batch),
-                                      stage, depth=self.prefetch,
-                                      path="wrapper_sync",
-                                      wait_series=_t_staging) as pf:
-                    for kind, item in pf:
-                        if kind == "single":
-                            fallback(item)
-                        elif kind == "staged1":
-                            dispatch_one(*item)
-                        else:
-                            dispatch(*item)
+                net._fit_epoch(iterator, max(1, net.dispatch_ksteps), owner)
         finally:
             # the ring outlives the fit; the device memory of the groups last
             # put from it must not
-            ring.drain()
+            self._host_ring.drain()
 
     # --------------------------------------------------- local SGD (freq=N>1)
     def _make_local_sgd_fns(self):
         """shard_map local step over stacked per-replica params + psum-mean averager
         (reference averaging loop ParallelWrapper.java:179-212)."""
-        from deeplearning4j_tpu.nn.graph_network import ComputationGraph, make_graph_train_step
-        from deeplearning4j_tpu.nn.multilayer import make_train_step
-
         net = self.model
         mesh = self.mesh
-        if isinstance(net, ComputationGraph):
-            # multi-IO supported: xs/ys arrive as lists of arrays; the
-            # shard_map in_specs below are pytree prefixes so P("data")
-            # applies to every input/label leaf (reference ParallelWrapper
-            # handles MultiDataSet fit the same way, ParallelWrapper.java:117)
-            base = make_graph_train_step(net.conf)
-        else:
-            base = make_train_step(net.conf)
+        # multi-IO supported: a graph's xs/ys arrive as lists of arrays; the
+        # shard_map in_specs below are pytree prefixes so P("data") applies
+        # to every input/label leaf (reference ParallelWrapper handles
+        # MultiDataSet fit the same way, ParallelWrapper.java:117)
+        base = type(net)._step_builder(net.conf)
         stacked = P("data")
         repl = P()
 
@@ -757,10 +611,6 @@ class ParallelWrapper:
         states = stack(net.state_list)
         upd = stack(net.updater_state)
         batch_sh = _named_sharding(self.mesh, P("data"))
-        from deeplearning4j_tpu.nn.graph_network import (
-            ComputationGraph, _coerce_graph_batch)
-
-        is_graph = isinstance(net, ComputationGraph)
         # each psum-mean resync moves ~per-replica param bytes across the ring
         avg_bytes = _collective_bytes.labels(op="parameter_average",
                                              site="wrapper_local_sgd")
@@ -769,14 +619,9 @@ class ParallelWrapper:
         def stage(ds):
             # producer thread: sharded non-blocking transfer of the next
             # batch while the current local step runs
-            if is_graph:
-                xs, ys, _, _ = _coerce_graph_batch(ds)
-                x = [jax.device_put(a, batch_sh) for a in xs]
-                y = [jax.device_put(a, batch_sh) for a in ys]
-                return x, y, 0
-            bs = int(np.shape(ds.features)[0])
-            return (jax.device_put(ds.features, batch_sh),
-                    jax.device_put(ds.labels, batch_sh), bs)
+            xs, ys, _, _ = net._batch_of(ds)
+            return jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, batch_sh), (xs, ys))
 
         from deeplearning4j_tpu.datasets.prefetch import DevicePrefetcher
 
@@ -787,30 +632,23 @@ class ParallelWrapper:
             pf = DevicePrefetcher(iterator, stage, depth=self.prefetch,
                                   path="wrapper_local_sgd",
                                   wait_series=_t_staging)
-            for x, y, bs in pf:
-                if not is_graph:
-                    net.last_batch_size = bs
-                t0 = _time.perf_counter()
+            for x, y in pf:
+                net.last_batch_size = int(
+                    jax.tree_util.tree_leaves(x)[0].shape[0])
+                t0, t0_ns = _time.perf_counter(), _time.time_ns()
                 params, states, upd, loss = self._local_step(
                     params, states, upd, x, y, net._next_rng(),
                     jnp.int32(net.iteration))
-                dt = _time.perf_counter() - t0
-                _t_dispatch.observe(dt)
-                _compile_tracker().note_step(fn="ParallelWrapper.local_step")
-                _flight_recorder().record(
-                    "step", path="ParallelWrapper.local_step",
-                    it=net.iteration, batch=bs, dispatch_s=dt)
-                net.score_value = loss  # synced lazily (LazyScore)
-                net.iteration += 1
+                dt, t1_ns = _time.perf_counter() - t0, _time.time_ns()
+                # the replicas' params live in this loop until its end: a
+                # listener sees the same model before and after an averaging
+                net._book_steps("ParallelWrapper.local_step", 1, [loss],
+                                t0_ns, t1_ns, dt)
                 since_avg += 1
                 if since_avg >= self.averaging_frequency:
                     params, upd, states = self._avg_fn(params, upd, states)
                     avg_bytes.inc(param_bytes)
                     since_avg = 0
-                with _t_listeners.time():
-                    for listener in net.listeners:
-                        listener.iteration_done(net, net.iteration)
-                _wd_beat(net.iteration)
         # final sync + unstack back into the model
         params, upd, states = self._avg_fn(params, upd, states)
         unstack = functools.partial(jax.tree_util.tree_map, lambda a: a[0])

@@ -1,12 +1,11 @@
 """K-step batch grouping for fused-dispatch training loops.
 
-One shared state machine for the three fused fit loops
-(MultiLayerNetwork.fit_iterator, ComputationGraph.fit_iterator,
-ParallelWrapper._fit_sync): accumulate up to ``k`` same-shape host-staged
-minibatches, emit them as a group for one stacked (K, B, ...) device
-dispatch, and route batches the caller declines (masked, ragged tail) to the
-per-batch fallback. Keeping this in one place prevents the three loops from
-drifting on flush ordering / fallback semantics.
+The state machine under the one staged fit loop
+(``nn.multilayer.LazyScore._fit_epoch_staged``, which both networks'
+``fit_iterator`` and ``ParallelWrapper``'s synchronous loop run): accumulate
+up to ``k`` same-shape host-staged minibatches, emit them as a group for one
+stacked (K, B, ...) device dispatch, and route batches the caller declines
+(masked, ragged tail) to the per-batch fallback.
 """
 from __future__ import annotations
 

@@ -251,13 +251,13 @@ def test_integer_ids_above_256_reach_the_step_unchanged(monkeypatch):
     net = _net(ref.init(7, TINY), dispatch_ksteps=4,
                stage_dtype=jnp.bfloat16)
     seen = []
-    run = type(net)._run_multistep
+    run = type(net)._run_steps
 
-    def spy(self, xs, ys, n, after=None):
+    def spy(self, kind, n, xs, ys, **kw):
         seen.append((np.asarray(xs), np.asarray(ys)))
-        return run(self, xs, ys, n, after=after)
+        return run(self, kind, n, xs, ys, **kw)
 
-    monkeypatch.setattr(type(net), "_run_multistep", spy)
+    monkeypatch.setattr(type(net), "_run_steps", spy)
     net.fit_iterator([DataSet(x, y) for x, y in batches])
     (xs, ys), = seen
     assert xs.dtype == np.int32 and ys.dtype == np.int32

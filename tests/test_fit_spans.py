@@ -74,14 +74,33 @@ def ring():
     rec.clear()
 
 
+def fit_through(entry, net, data, depth=2):
+    """Fit ``data`` through one of the staged loop's entry points: the
+    network's own ``fit_iterator``, or a synchronous ``ParallelWrapper`` over
+    four devices."""
+    if entry == "wrapper":
+        from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+        wrapper = (ParallelWrapper.builder(net).workers(4)
+                   .prefetch_buffer(depth).build())
+        wrapper.fit(ListDataSetIterator(data))
+        return wrapper
+    net.prefetch_depth = depth
+    net.fit_iterator(ListDataSetIterator(data))
+    return net
+
+
 @pytest.mark.parametrize("depth", [0, 2])
 @pytest.mark.parametrize("kind", ["multilayer", "graph"])
-def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
+@pytest.mark.parametrize("entry", ["fit_iterator", "wrapper"])
+def test_spans_of_a_group_share_it_and_nest_in_time(ring, entry, kind, depth):
+    """One loop, one span set: the wrapper's groups carry what the networks'
+    do, their dispatch under the wrapper's program name with the bytes of the
+    gradient all-reduce."""
     net = make_net(kind)
-    net.prefetch_depth = depth
     net.stage_dtype = jnp.bfloat16
     before = time.time_ns()
-    net.fit_iterator(ListDataSetIterator(batches(3 * K)))
+    fit_through(entry, net, batches(3 * K), depth)
     groups = spans_by_group()
     assert len(groups) == 3
     for i, (group, by_name) in enumerate(sorted(groups.items())):
@@ -104,8 +123,16 @@ def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
         assert listeners["cause"] == "fit.dispatch"
         # the step event is the dispatch span, not a second record
         assert dispatch["kind"] == "step" and dispatch["k"] == K
+        assert dispatch["it"] == i * K and dispatch["batch"] == 8
         assert dispatch["dispatch_s"] == pytest.approx(
             (dispatch["t1_ns"] - dispatch["t0_ns"]) / 1e9, abs=1e-3)
+        if entry == "wrapper":
+            assert dispatch["path"] == "ParallelWrapper.sync_multistep"
+            assert dispatch["collective_bytes"] == K * sum(
+                p.nbytes for p in jax.tree_util.tree_leaves(net.params_list))
+        else:
+            assert dispatch["path"] == f"{type(net).__name__}.multistep"
+            assert "collective_bytes" not in dispatch
         assert h2d["bytes"] == K * 8 * (16 * 2 + 3 * 4)   # bf16 in, f32 labels
         producer = "MainThread" if depth == 0 else "dl4j-prefetch-staging"
         assert {s["thread"] for s in (pull, stack, cast, h2d)} == {producer}
@@ -122,11 +149,11 @@ def test_spans_of_a_group_share_it_and_nest_in_time(ring, kind, depth):
 
 @pytest.mark.parametrize("kind", ["multilayer", "graph"])
 def test_wrapper_groups_carry_the_stage_spans_and_one_step_wait(ring, kind):
-    """``ParallelWrapper``'s synchronous loop stages through ``stage_group``
-    and dispatches under the networks' bound: every group has the producer's
-    four spans and the loop's wait under its number, and from the third on a
-    ``fit.step_wait`` for the group two before, which ends before the step
-    is dispatched. The bound reaches across ``fit`` calls on one wrapper."""
+    """``ParallelWrapper``'s synchronous loop dispatches under the networks'
+    bound (their spans: the test above): from the third group on it waits for
+    the loss stack of the group two before, and the wait is over before the
+    step is dispatched. The bound reaches across ``fit`` calls on one
+    wrapper."""
     from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
 
     net = make_net(kind)
@@ -161,24 +188,11 @@ def test_wrapper_groups_carry_the_stage_spans_and_one_step_wait(ring, kind):
     for i, (group, by_name) in enumerate(sorted(groups.items())):
         step_wait = by_name.pop("fit.step_wait", None)
         assert (step_wait is not None) == (i >= 2)
-        assert set(by_name) == set(STAGES + ("fit.wait",)), by_name.keys()
-        pull, stack, cast, h2d = (by_name[n] for n in STAGES)
-        assert (pull["t1_ns"] <= stack["t0_ns"] <= stack["t1_ns"]
-                == cast["t0_ns"] <= cast["t1_ns"] == h2d["t0_ns"]
-                <= h2d["t1_ns"] <= by_name["fit.wait"]["t1_ns"])
-        assert h2d["bytes"] == K * 8 * (16 * 2 + 3 * 4)   # bf16 in, f32 labels
-        assert {s["thread"] for s in (pull, stack, cast, h2d)} == {
-            "dl4j-prefetch-staging"}
+        assert set(by_name) == set(STAGES + FIT), by_name.keys()
+        assert by_name["fit.dispatch"]["it"] == i * K
         if step_wait:
-            assert by_name["fit.wait"]["t1_ns"] <= step_wait["t0_ns"]
-            assert step_wait["cause"] == "fit.dispatch"
-            assert step_wait["thread"] == "MainThread"
-        # the wait is over before the step is dispatched
-        step = next(e for e in global_recorder().snapshot()
-                    if e["kind"] == "step" and e["it"] == i * K)
-        assert step["k"] == K
-        if step_wait:
-            assert step_wait["t1_ns"] / 1e9 <= step["ts"]
+            assert (by_name["fit.wait"]["t1_ns"] <= step_wait["t0_ns"]
+                    <= step_wait["t1_ns"] <= by_name["fit.dispatch"]["t0_ns"])
     # a second fit on the wrapper goes on where the first stopped
     del order[:]
     wrapper.fit(ListDataSetIterator(batches(K)))

@@ -8,8 +8,8 @@ Contracts pinned here:
   touching the math: a fork mid-page diverges correctly and never
   corrupts the donor session's stream;
 - page refcounts never leak: 1k churned sessions leave pool bytes flat
-  (``jax.live_arrays`` idiom), every page back on the free list and the
-  prefix registry empty;
+  (the device arrays the engine holds, after a garbage collection), every
+  page back on the free list and the prefix registry empty;
 - speculative decode emits the EXACT greedy stream at every acceptance
   rate — identical draft (acceptance == 1.0 by construction), a real
   partial-acceptance draft, and a sign-flipped near-zero draft;
@@ -22,8 +22,10 @@ Contracts pinned here:
   bytes billed to dl4j_decode_state_copy_bytes_total are the small host
   scheduling arrays, orders of magnitude under the device blocks.
 """
+import gc
 import os
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -63,8 +65,26 @@ def _run(eng, prompts, budgets):
     return sessions
 
 
-def _live_device_bytes() -> int:
-    return sum(a.nbytes for a in jax.live_arrays() if not a.is_deleted())
+def _device_bytes_held_by(root) -> int:
+    """Bytes of every live device array reachable from ``root`` through
+    instances and containers: an engine's blocks, pool and tables, and
+    whatever a leak would leave hanging on it (a session kept, a cache that
+    grows). Not the process's ``jax.live_arrays()``: other tests' threads in
+    the same worker, and the engine thread's own temporaries of its last
+    step, come and go between two readings."""
+    skip = (type, types.ModuleType, types.FunctionType,
+            types.BuiltinFunctionType, types.CodeType, types.FrameType)
+    seen, stack, total = set(), [root], 0
+    while stack:
+        obj = stack.pop()
+        if id(obj) in seen or isinstance(obj, skip):
+            continue
+        seen.add(id(obj))
+        if isinstance(obj, jax.Array):
+            total += 0 if obj.is_deleted() else obj.nbytes
+        else:
+            stack.extend(gc.get_referents(obj))
+    return total
 
 
 # ----------------------------------------------- paged == dense, bitwise
@@ -197,13 +217,16 @@ def test_pool_refcounts_drain_after_1k_session_churn():
         prompts, budgets = _workload(8, rng, lo=2, hi=4)
         _run(eng, prompts, budgets)
         baseline_state = eng.state_bytes()
-        baseline_live = _live_device_bytes()
+        gc.collect()
+        baseline_held = _device_bytes_held_by(eng)
+        assert baseline_held >= baseline_state > 0
         prompts = [[int(rng.integers(0, V))] for _ in range(1000)]
         budgets = [2] * 1000
         _run(eng, prompts, budgets)
         st = eng.stats()
         assert eng.state_bytes() == baseline_state
-        grown = _live_device_bytes() - baseline_live
+        gc.collect()
+        grown = _device_bytes_held_by(eng) - baseline_held
         assert grown <= 0, f"device bytes grew by {grown} after 1k sessions"
     finally:
         eng.close()

@@ -653,15 +653,16 @@ def test_fit_loop_keeps_one_group_queued_behind_the_running_step(monkeypatch):
 
     net = _mlp_net(seed=2)
     net.dispatch_ksteps = 2
-    run = type(net)._run_multistep
+    run = type(net)._run_steps
 
-    def spy(self, xs, ys, n, after=None):
+    def spy(self, kind, n, xs, ys, after=None, **kw):
+        assert kind == "multistep"
         i = sum(1 for o in order if o[0] == "dispatch")
         order.append(("dispatch", i, after.i if after else None))
         assert self._host_ring is not None
-        return Losses(i, run(self, xs, ys, n, after=after))
+        return Losses(i, run(self, kind, n, xs, ys, after=after, **kw))
 
-    monkeypatch.setattr(type(net), "_run_multistep", spy)
+    monkeypatch.setattr(type(net), "_run_steps", spy)
     net.fit_iterator(ListDataSetIterator(_batches(8)))
     assert order == [("dispatch", 0, None), ("dispatch", 1, None),
                      ("dispatch", 2, 0), ("waited", 0),
@@ -762,3 +763,70 @@ def test_wrapper_slot_reuse_trains_the_same_as_fresh_slots(monkeypatch, kind):
             assert len(got.sharding.device_set) == 4
             assert np.array_equal(_as_bits(got),
                                   _as_bits(np.stack(host).astype(dtype)))
+
+
+# ------------------------------------------------- one staged loop, three doors
+@pytest.mark.parametrize("entry", ["multilayer", "graph", "wrapper"])
+def test_every_entry_point_runs_the_one_staged_epoch(monkeypatch, entry):
+    """Both networks' ``fit_iterator`` and ``ParallelWrapper``'s synchronous
+    loop pass through ``LazyScore._fit_epoch_staged``, the wrapper as a
+    ``LoopOwner``. For the networks the loop is what it was: the type's
+    K-step program over each stacked, cast group (its one-step program for
+    the lone ragged batch), one rng split and K iterations a dispatch —
+    replayed here by hand, the parameters agree bit for bit."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.multilayer import LazyScore
+
+    k, epochs = 2, 2
+    data = _batches(5, seed=8) + _batches(1, batch=5, seed=9)
+    calls = []
+    staged_epoch = LazyScore._fit_epoch_staged
+
+    def spy(self, iterator, k, owner=None):
+        calls.append((type(self).__name__, k, owner and owner.path))
+        return staged_epoch(self, iterator, k, owner)
+
+    monkeypatch.setattr(LazyScore, "_fit_epoch_staged", spy)
+    kind = "multilayer" if entry == "wrapper" else entry
+    net, wrapper = _wrapped_net(kind)
+    name = type(net).__name__
+    if entry == "wrapper":
+        # the wrapper's loop needs batches its four devices divide
+        wrapper.fit(ListDataSetIterator(data[:5]), epochs=epochs)
+        assert calls == [(name, k, "wrapper_sync")] * epochs
+        assert net.iteration == 5 * epochs
+        return
+    net.fit_iterator(ListDataSetIterator(data), epochs=epochs)
+    assert calls == [(name, k, None)] * epochs
+
+    twin, _ = _wrapped_net(kind)
+    multi = jax.jit(type(twin)._multistep_builder(twin.conf))
+    step = jax.jit(type(twin)._step_builder(twin.conf))
+    tree = (lambda a: a) if kind == "multilayer" else (lambda a: [a])
+    state = (twin.params_list, twin.state_list, twin.updater_state)
+    it = 0
+    for _ in range(epochs):
+        for group in (data[0:2], data[2:4], data[4:5], data[5:6]):
+            x = np.stack([d.features for d in group])
+            y = np.stack([d.labels for d in group])
+            if len(group) == 1:      # a lone batch is not cast: _fit_batch
+                *state, _ = step(*state, tree(jnp.asarray(x[0])),
+                                 tree(jnp.asarray(y[0])), twin._next_rng(),
+                                 jnp.int32(it), None, None)
+            else:
+                *state, _ = multi(*state, tree(x.astype(jnp.bfloat16)),
+                                  tree(y), twin._next_rng(), jnp.int32(it))
+            it += len(group)
+    assert net.iteration == it
+    for a, b in zip(_leaves(net), jax.tree_util.tree_leaves(state[0])):
+        assert np.array_equal(a, np.asarray(b))
+
+
+def test_wrapper_sets_last_batch_size_for_a_graph():
+    """The loop is the networks': a ``ComputationGraph`` under the wrapper
+    knows its batch size like any other (PerformanceListener reads it)."""
+    net, wrapper = _wrapped_net("graph")
+    assert net.last_batch_size == 0
+    wrapper.fit(ListDataSetIterator(_batches(3)))   # a group of 2, a lone one
+    assert net.last_batch_size == 8
