@@ -601,41 +601,60 @@ def bench_attention(batch: int, iters: int, ksteps: int, warmup: int = 2,
         "flops_source": "xla_cost" if xla_feasible else "analytic",
     }
 
-    # DL4J_FLASH_SWEEP=1: time the pallas kernel across tile configs so one
-    # run finds the best DL4J_FLASH_BLK_Q/K for this chip (VERDICT
-    # round-3 item 2's "tile sweep" candidate). Globals are read at trace
-    # time; each timing call builds a fresh jit program.
+    # DL4J_FLASH_SWEEP=1: time the pallas kernels, forward and backward,
+    # across explicit tile shapes, so one run says whether the tiles the
+    # kernels choose from the shapes (pk._flash_tiles) are this chip's best
+    # at this shape. Each timing call builds a fresh jit program.
     if pallas_engaged and os.environ.get("DL4J_FLASH_SWEEP") == "1":
         rec.update(_sweep_tiles(
-            lambda: time_path(
-                lambda q, k, v: pk.flash_attention(q, k, v, True))[0],
-            seq))
+            lambda bq, bk: time_path(_flash_at_tiles(bq, bk))[0], seq))
+        chosen = pk._flash_tiles(seq, seq, dim, dim, q.dtype, backward=True)
+        rec["chosen_tiles"] = "%dx%d" % chosen if chosen else None
     flops_per_sec = flops_per_step / t_prod if flops_per_step else 0.0
     rec["tflops_per_sec"] = round(flops_per_sec / 1e12, 4)
     rec["mfu"] = _mfu(flops_per_sec)
     return rec
 
 
-def _sweep_tiles(time_once, seq: int) -> dict:
-    """Sweep flash tile configs through ``time_once`` (which must read the
-    module tile globals at trace time). Per-config failures (e.g. VMEM
-    overflow) are isolated into the record — this runs unattended in the
-    auto-capture window and must never kill the surrounding bench."""
+def _flash_at_tiles(blk_q: int, blk_k: int):
+    """Causal flash attention with the given tiles in the forward and the
+    backward kernels (the one-kernel backward where the shape takes it),
+    whatever the length gates of ``pk.flash_attention`` say."""
+    import jax
+
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
+    tiles = {"blk_q": blk_q, "blk_k": blk_k}
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return pk._flash_forward(q, k, v, True, **tiles)[0]
+
+    def fwd(q, k, v):
+        out, lse = pk._flash_forward(q, k, v, True, **tiles)
+        return out, (q, k, v, out, lse)
+
+    def bwd(res, g):
+        return pk._flash_backward(*res, g, True, **tiles)
+
+    attend.defvjp(fwd, bwd)
+    return attend
+
+
+def _sweep_tiles(time_tiles, seq: int) -> dict:
+    """Sweep flash tile shapes through ``time_tiles(blk_q, blk_k)`` (seconds
+    a step). Per-tile failures (e.g. VMEM overflow) are isolated into the
+    record — this runs unattended in the auto-capture window and must never
+    kill the surrounding bench."""
     sweep = {}
-    saved = pk._BLK_Q, pk._BLK_K
-    for bq, bk in ((64, 128), (128, 128), (128, 256), (256, 128),
-                   (256, 256), (128, 512)):
+    for bq, bk in ((128, 512), (256, 256), (512, 512), (512, 1024),
+                   (1024, 512), (1024, 1024)):
         if seq % bq or seq % bk:
             continue
-        pk._BLK_Q, pk._BLK_K = bq, bk
         try:
-            sweep[f"{bq}x{bk}"] = round(time_once() * 1000, 3)
+            sweep[f"{bq}x{bk}"] = round(time_tiles(bq, bk) * 1000, 3)
         except Exception as e:
             sweep[f"{bq}x{bk}"] = f"error: {e}"[:100]
-        finally:
-            pk._BLK_Q, pk._BLK_K = saved
     out = {"tile_sweep_ms": sweep}
     timed = {k: v for k, v in sweep.items() if isinstance(v, float)}
     if timed:

@@ -8,7 +8,8 @@ XLA path runs. Tests exercise the kernels in interpret mode on CPU.
 
 Kernels:
 * flash_attention — tiled online-softmax attention (forward), custom VJP with
-  a recompute backward (standard flash-attention practice: trade FLOPs for HBM).
+  a recompute backward (standard flash-attention practice: trade FLOPs for
+  HBM); tiles and the one-kernel backward are chosen from the shapes.
 * softmax_cross_entropy — fused row-softmax + NLL loss per row.
 
 Sharding interactions:
@@ -43,27 +44,29 @@ from jax.experimental.pallas import tpu as pltpu
 Array = jax.Array
 _NEG = -1e30
 
-# Flash-attention tile sizes: MXU/VMEM-friendly defaults, overridable for
-# on-chip sweeps (DL4J_FLASH_BLK_Q / DL4J_FLASH_BLK_K).
-_BLK_Q = int(os.environ.get("DL4J_FLASH_BLK_Q", "128"))
-_BLK_K = int(os.environ.get("DL4J_FLASH_BLK_K", "512"))
+#: bytes of VMEM one kernel program may plan for by default: the compiler's
+#: scoped default is 16 MiB per core; the rest is headroom for Mosaic's own
+#: scratch
+_VMEM_BUDGET = 14 * 1024 * 1024
+
+#: the most the TILES of a flash program may plan for (``_flash_vmem_bytes``
+#: as ``_flash_tiles`` counts it: scores, operand blocks, accumulators, dQ at
+#: ``blk_q`` rows), where the count passes the scoped default and the kernel
+#: asks for what it says (``vmem_limit_bytes``): a quarter of a v5e core's
+#: 128 MiB. The one-kernel backward adds a head's whole dQ, which
+#: ``_fused_bwd_fits`` holds to ``_VMEM_BUDGET`` apart: a program plans for at
+#: most the two together, 46 MiB, and asks for at most 61.5 (``_flash_params``)
+_VMEM_CEILING = 32 * 1024 * 1024
 
 
-#: grid semantics shared by the three flash kernels: (batch*head, outer
-#: block) programs are independent, the innermost block axis carries the
-#: VMEM accumulators and must run in order
-_STREAMED = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
-def _causal_mask(s, q0, k0):
-    """Mask score tile ``s`` [blk_q, blk_k] to q_pos >= k_pos, where the tile
-    starts at absolute positions (q0, k0). ONE shared convention for the
-    forward and both backward kernels — they must never disagree."""
-    blk_q, blk_k = s.shape
-    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG)
+def _causal_mask(s, q0, k0, q_axis: int = 0):
+    """Mask a score tile to q_pos >= k_pos, where the tile starts at absolute
+    positions (q0, k0) and its queries run along ``q_axis`` (0: S, the
+    forward's; 1: S transposed, the backward's). ONE shared convention for
+    the forward and the backward kernel — they must never disagree."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    return jnp.where(ahead >= k0 - q0, s, _NEG)
 
 
 def _flatten_heads(a):
@@ -136,6 +139,99 @@ def _causal_block_live(qi, kj, blk_q: int, blk_k: int):
     return kj * blk_k <= qi * blk_q + (blk_q - 1)
 
 
+def _diagonal_k_block(qi, blk_q: int, blk_k: int):
+    """The last k-block a q-block's tiles are live in under a causal mask.
+    An index map names it again for the dead tiles beyond it, so the
+    pipeline fetches nothing nobody reads."""
+    return (qi * blk_q + (blk_q - 1)) // blk_k
+
+
+def _causal_block_crossed(qi, kj, blk_q: int, blk_k: int):
+    """Whether the diagonal crosses the tile: its last key lies beyond its
+    first query, so some score of it is masked. A tile that is not crossed
+    is live and wholly below the diagonal: it needs no mask."""
+    return kj * blk_k + (blk_k - 1) > qi * blk_q
+
+
+#: side from which a square tile on the diagonal is computed as quarters
+#: (``_per_causal_tile``): a quarter keeps at least 256 rows for the MXU
+_QUARTERED_FROM = 512
+
+
+def _per_causal_tile(tile, causal: bool, qi, kj, blk_q: int, blk_k: int):
+    """Run ``tile(masked, rows, cols)`` over one (q-block, k-block) tile of a
+    flash kernel, forward or backward; ``rows`` and ``cols`` are the static
+    slices of the tile's queries and keys a call covers. A dead tile is
+    skipped, a tile wholly below the diagonal takes one call without the
+    mask's passes over its scores, and a tile the diagonal crosses takes the
+    masked body. Square tiles are crossed where q-block == k-block, corner
+    to corner: from ``_QUARTERED_FROM`` rows such a tile runs as three
+    quarters (two masked ones on the diagonal, the plain one below it) and
+    its dead quarter is left out."""
+    whole = pl.ds(0, blk_q), pl.ds(0, blk_k)
+    if not causal:
+        tile(False, *whole)
+        return
+    crossed = _causal_block_crossed(qi, kj, blk_q, blk_k)
+    pl.when(jnp.logical_not(crossed))(lambda: tile(False, *whole))
+    on_diagonal = jnp.logical_and(
+        crossed, _causal_block_live(qi, kj, blk_q, blk_k))
+    if blk_q == blk_k and blk_q >= _QUARTERED_FROM:
+        half = blk_q // 2
+        lo, hi = pl.ds(0, half), pl.ds(half, half)
+
+        @pl.when(on_diagonal)
+        def _quarters():
+            tile(True, lo, lo)
+            tile(False, hi, lo)
+            tile(True, hi, hi)
+    else:
+        pl.when(on_diagonal)(lambda: tile(True, *whole))
+
+
+def _lanes(d: int) -> int:
+    """``d`` rounded up to the 128 lanes a VMEM tile is wide."""
+    return -(-d // 128) * 128
+
+
+def _dq_bytes(rows: int, dk: int, itemsize: int) -> int:
+    """VMEM of ``rows`` rows of dQ: the float32 accumulator and the
+    double-buffered output block."""
+    return rows * _lanes(dk) * (4 + 2 * itemsize)
+
+
+def _flash_vmem_bytes(blk_q: int, blk_k: int, dk: int, dv: int,
+                      itemsize: int, backward: bool = False,
+                      dq_rows: int = 0) -> int:
+    """Bytes of VMEM one program of a flash kernel plans for, from its
+    shapes: the float32 score tile and the tiles derived from it that are
+    alive beside it (P forward; P, dP and dS backward), their copies in the
+    operands' dtype for the MXU, the float32 accumulators, the pipeline's
+    double-buffered operand and output blocks, and, where the backward keeps
+    a head's whole dQ (``dq_rows`` = Tq), that scratch and its output."""
+    dk, dv = _lanes(dk), _lanes(dv)
+    tile = blk_q * blk_k
+    if not backward:
+        scores = tile * (2 * 4 + itemsize)
+        blocks = 2 * itemsize * (blk_q * (dk + dv) + blk_k * (dk + dv))
+        accum = blk_q * (dv + 2 * 128) * 4
+        return scores + blocks + accum
+    scores = tile * (4 * 4 + 2 * itemsize)
+    blocks = 2 * itemsize * (blk_q * (dk + dv) + 2 * blk_k * (dk + dv))
+    accum = blk_k * (dk + dv) * 4
+    return scores + blocks + accum + _dq_bytes(dq_rows or blk_q, dk, itemsize)
+
+
+def _flash_params(semantics, need: int):
+    """Compiler parameters of a flash kernel whose program plans for
+    ``need`` bytes of VMEM: where that passes what the compiler's scoped
+    default leaves, ask for what the count says (and room for Mosaic's own
+    scratch)."""
+    limit = None if need <= _VMEM_BUDGET else need + need // 4 + (4 << 20)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
                       blk_k: int, scale: float, has_mask: bool):
     """One (batch*head, q-block, k-block) program of the online softmax.
@@ -152,7 +248,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
     saved so the backward can recompute P = exp(S - lse) without a second
     online-softmax pass. With has_mask, a (1, blk_k, 1) {0,1} key-padding
     mask block precedes the outputs: masked keys get -inf logits. Under a
-    causal mask a tile wholly above the diagonal is skipped.
+    causal mask a tile wholly above the diagonal is skipped and only a tile
+    the diagonal crosses is masked (``_per_causal_tile``).
     """
     if has_mask:
         km_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
@@ -167,28 +264,31 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    def _tile():
-        v_blk = v_ref[0]
-        s = _dot(q_ref[0], k_ref[0], 1, 1) * scale        # (blk_q, blk_k)
+    def _tile(masked: bool, rows, cols):
+        v_blk = v_ref[0, cols, :]
+        s = _dot(q_ref[0, rows, :], k_ref[0, cols, :], 1, 1) * scale
         if has_mask:
-            km_blk = km_ref[0, :, 0].astype(jnp.float32)
+            km_blk = km_ref[0, cols, 0].astype(jnp.float32)
             s = jnp.where(km_blk[None, :] > 0, s, _NEG)
-        if causal:
-            s = _causal_mask(s, qi * blk_q, kj * blk_k)
-        m = m_sc[...]                                     # (blk_q, 1)
+        if masked:
+            s = _causal_mask(s, qi * blk_q + rows.start,
+                             kj * blk_k + cols.start)
+        m = m_sc[rows, :]                                 # (rows, 1)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(s <= _NEG, 0.0, p)
+        if has_mask:
+            # a row whose keys so far are ALL masked has m_new = _NEG and
+            # would read exp(0); a causal row always holds key 0, in its
+            # first tile, so without a key mask exp underflows to 0 itself
+            p = jnp.where(s <= _NEG, 0.0, p)
         alpha = jnp.exp(m - m_new)
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * alpha + _dot(p.astype(v_blk.dtype),
-                                                 v_blk, 1, 0)
-        m_sc[...] = m_new
+        l_sc[rows, :] = l_sc[rows, :] * alpha + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+        acc_sc[rows, :] = acc_sc[rows, :] * alpha + _dot(
+            p.astype(v_blk.dtype), v_blk, 1, 0)
+        m_sc[rows, :] = m_new
 
-    if causal:
-        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
-    else:
-        _tile()
+    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
@@ -200,11 +300,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
 def _bh_mask(key_mask: Array, H: int) -> Array:
     """[B, Tk] {0,1} key mask -> (B*H, Tk, 1) f32 kernel operand.
 
-    The trailing singleton is Mosaic block-layout armor shared by every
-    per-row vector the flash kernels touch (mask, lse, delta): a (1, blk)
-    block on a (B*H, X) array has sublane size 1, which the TPU lowering
-    rejects unless it equals the array dim; as (B*H, X, 1) the block
-    (1, blk, 1) is legal — blk is 8-divisible and the lane dim matches."""
+    The trailing singleton is Mosaic block-layout armor for a per-key
+    column: a (1, blk) block on a (B*H, X) array has sublane size 1, which
+    the TPU lowering rejects unless it equals the array dim; as (B*H, X, 1)
+    the block (1, blk, 1) is legal — blk is 8-divisible and the lane dim
+    matches. (The backward's per-query rows, lse and delta, travel as
+    (B*H, 1, T): lane-dense, and a row is what S transposed wants.)"""
     B, Tk = key_mask.shape
     return jnp.broadcast_to(key_mask.astype(jnp.float32)[:, None, :],
                             (B, H, Tk)).reshape(B * H, Tk, 1)
@@ -213,12 +314,47 @@ def _bh_mask(key_mask: Array, H: int) -> Array:
 def _kv_block(causal: bool, blk_q: int, blk_k: int):
     """Index map of a K/V block on a (bh, q-block, k-block) grid. Under a
     causal mask a tile above the diagonal is never computed
-    (``_causal_block_live``); naming the diagonal's block again there keeps
-    the pipeline from fetching one that nobody reads."""
+    (``_causal_block_live``) and names the diagonal's block again
+    (``_diagonal_k_block``)."""
     if not causal:
         return lambda bh, i, j: (bh, j, 0)
     return lambda bh, i, j: (
-        bh, jnp.minimum(j, (i * blk_q + (blk_q - 1)) // blk_k), 0)
+        bh, jnp.minimum(j, _diagonal_k_block(i, blk_q, blk_k)), 0)
+
+
+def _flash_tiles(tq: int, tk: int, dk: int, dv: int, dtype,
+                 blk_q: int = None, blk_k: int = None,
+                 backward: bool = False):
+    """(blk_q, blk_k) of a flash kernel from the operands' shape: the
+    widest standard tiles (``_TILE_SIZES``) that divide the sequences and
+    whose program (``_flash_vmem_bytes``) fits ``_VMEM_CEILING``. A wider
+    query tile streams K/V less often (a 128-row one re-reads every live K/V
+    block from HBM at half the chip's ridge intensity), a wider key tile
+    spreads the per-row softmax statistics over more scores, and both pay a
+    tile's fixed cost over more arithmetic: on a v5e that outweighs the dead
+    half a wide tile on the causal diagonal computes, down to one tile a
+    sequence (T = 1,024 and 2,048 at 64 wide, PERF.md §6, PR 31). An
+    explicit size is taken as given (capped at the sequence); None where a
+    sequence has no standard divisor."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for size in _TILE_SIZES:
+        bq = min(blk_q, tq) if blk_q else _pick_blk(tq, size)
+        bk = min(blk_k, tk) if blk_k else _pick_blk(tk, size)
+        if not bq or not bk:
+            return None
+        if (blk_q and blk_k) or _flash_vmem_bytes(
+                bq, bk, dk, dv, itemsize, backward) <= _VMEM_CEILING:
+            return bq, bk
+    return None
+
+
+def _tiles_or_raise(tq: int, tk: int, *args, **kwargs):
+    """``_flash_tiles``, or a ValueError where the sequences do not tile."""
+    tiles = _flash_tiles(tq, tk, *args, **kwargs)
+    if not tiles or tq % tiles[0] or tk % tiles[1]:
+        raise ValueError(f"sequence lengths ({tq},{tk}) must be divisible by "
+                         f"block sizes {tiles}")
+    return tiles
 
 
 def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
@@ -226,16 +362,12 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
                    interpret: bool = False, key_mask: Array = None,
                    scale: float = None):
     """q,k: (B, T, H, Dk), v: (B, T, H, Dv) -> (out (B, T, H, Dv), lse
-    (B*H, Tq) f32). None block sizes -> env-tunable module defaults
-    (_BLK_Q/_BLK_K). key_mask: optional [B, Tk] {0,1} key-padding mask.
+    (B*H, Tq) f32). None block sizes -> chosen from the shapes
+    (``_flash_tiles``). key_mask: optional [B, Tk] {0,1} key-padding mask.
     ``scale`` None is ``Dk ** -0.5``."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
-    blk_q = min(blk_q, Tq) if blk_q else _pick_blk(Tq, _BLK_Q)
-    blk_k = min(blk_k, Tk) if blk_k else _pick_blk(Tk, _BLK_K)
-    if not blk_q or not blk_k or Tq % blk_q or Tk % blk_k:
-        raise ValueError(f"sequence lengths ({Tq},{Tk}) must be divisible by "
-                         f"block sizes ({blk_q},{blk_k})")
+    blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     has_mask = key_mask is not None
@@ -268,7 +400,11 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
         scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, Dv), jnp.float32)],
-        compiler_params=_STREAMED,
+        # (batch*head, q-block) programs are independent; the k-block axis
+        # carries the VMEM accumulators and must run in order
+        compiler_params=_flash_params(
+            ("parallel", "parallel", "arbitrary"),
+            _flash_vmem_bytes(blk_q, blk_k, D, Dv, q.dtype.itemsize)),
         interpret=interpret,
     )(*operands)
     return _unflatten_heads(out, B, H), lse[:, :, 0]
@@ -379,22 +515,24 @@ def _pallas_ok(q, k, interpret: bool, force: bool = False) -> bool:
     return force or interpret or max(q.shape[1], k.shape[1]) >= _MIN_SEQ
 
 
+#: standard tile sides of the flash kernels, widest first: ``_flash_tiles``
+#: takes the first whose program fits. 1,024 square measured fastest in
+#: ``deepseek-v2-lite-ep8-train-seq4096`` (PERF.md §6, PR 31).
+_TILE_SIZES = (1024, 512, 256, 128)
+
+
 def _pick_blk(t: int, pref: int):
-    """Largest supported block size dividing ``t`` (pref first, then the
-    smaller standard tiles). Without the fallback, raising the default
-    K-block to 512 would silently drop 128-divisible-but-not-512-divisible
-    lengths (1280, 3200, ...) to the O(T^2) XLA path."""
+    """Largest standard block size (``_TILE_SIZES``) up to ``pref`` that
+    divides ``t``. Without the fallback a 512-row preference would silently
+    drop 128-divisible-but-not-512-divisible lengths (1280, 3200, ...) to
+    the O(T^2) XLA path."""
     if t <= 128:
         return t
-    for b in sorted({pref, 256, 128}, reverse=True):
-        if b <= t and t % b == 0:
-            return b
-    return None
+    return next((b for b in _TILE_SIZES if b <= pref and t % b == 0), None)
 
 
-def _tileable(tq: int, tk: int, blk_q: int = None, blk_k: int = None) -> bool:
-    return (_pick_blk(tq, blk_q or _BLK_Q) is not None
-            and _pick_blk(tk, blk_k or _BLK_K) is not None)
+def _tileable(tq: int, tk: int) -> bool:
+    return _pick_blk(tq, 128) is not None and _pick_blk(tk, 128) is not None
 
 
 def _masked_attention_xla(q: Array, k: Array, v: Array, key_mask: Array,
@@ -477,12 +615,20 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     back to the identical XLA math rather than erroring), XLA elsewhere.
     ``v`` may be narrower or wider than ``q`` and ``k`` (latent attention:
     192-wide keys, 128-wide values); ``scale`` None is ``Dk ** -0.5``.
-    Backward is tiled pallas too (dQ + dK/dV kernels recomputing P from the
-    saved logsumexp — flash-attention practice: trade FLOPs for HBM; peak
-    extra memory O(blk·T), never O(Tq·Tk)); set DL4J_FLASH_PALLAS_BWD=0 to
-    use the XLA chunked-scan backward instead.
+    Backward is tiled pallas too, recomputing P from the saved logsumexp
+    (flash-attention practice: trade FLOPs for HBM; peak extra memory
+    O(blk·T), never O(Tq·Tk)): ONE kernel for dQ, dK and dV where a head's
+    dQ fits VMEM (``_fused_bwd_fits``), so each score tile is recomputed
+    once, else a dQ and a dK/dV kernel. Set DL4J_FLASH_PALLAS_BWD=0 to use
+    the XLA chunked-scan backward instead.
 
-    Dispatch thresholds (calibrated 2026-07-31 on a v5e, record not kept):
+    Tiles are chosen from the operands' shape (``_flash_tiles``): up to
+    1,024 square, smaller where the sequence or VMEM says so. Under a causal mask a dead tile is skipped and
+    only a tile the diagonal crosses is masked.
+
+    Dispatch thresholds (set for kernels since replaced, ROADMAP S5/D3; the
+    one shape measured since is T = 4,096 at 192/128 wide, which engages
+    forward and backward):
 
     * The forward kernel engages only at ``max(Tq, Tk) >=`` **_MIN_SEQ**
       (default 1024, env ``DL4J_FLASH_MIN_SEQ``). Shorter sequences run
@@ -490,7 +636,7 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
       barrier, so neighbouring projections lose their epilogues.
     * The tiled pallas backward engages only at ``Tk >=`` **_PBWD_MIN_SEQ**
       (default 4096, env ``DL4J_FLASH_PBWD_MIN_SEQ``); below that the
-      chunked lax.scan backward wins. ``DL4J_FLASH_PALLAS_BWD=0/1``
+      chunked lax.scan backward runs. ``DL4J_FLASH_PALLAS_BWD=0/1``
       overrides unconditionally.
 
     ``force_pallas=True`` is the per-call opt-in that bypasses both length
@@ -507,188 +653,215 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     return _attention_xla(q, k, v, causal, scale)
 
 
-# -------------------------------------------------- pallas backward kernels
-def _recomputed_probs(q, k_blk, lse_ref, km_ref, causal, q0, k0, scale):
-    """P = exp(S − lse) for one (blk_q, blk_k) tile from the saved forward
-    logsumexp — ONE copy for both backward kernels. Masked entries clamp to
-    P = 0 rather than exp(S − lse): for a fully key-masked row lse is ~_NEG
-    and the exponent would overflow."""
-    s = _dot(q, k_blk, 1, 1) * scale
-    if km_ref is not None:
-        km_blk = km_ref[0, :, 0].astype(jnp.float32)
-        s = jnp.where(km_blk[None, :] > 0, s, _NEG)
-    if causal:
-        s = _causal_mask(s, q0, k0)
-    return jnp.where(s <= _NEG, 0.0,
-                     jnp.exp(s - lse_ref[0].astype(jnp.float32)))
+# --------------------------------------------------- pallas backward kernel
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                      causal: bool, blk_q: int, blk_k: int, scale: float,
+                      has_mask: bool, want_dq: bool, want_dkv: bool):
+    """One (q-block, k-block) tile of the backward, for whichever of dQ and
+    dK/dV the call wants — ONE body, so each score tile is recomputed, masked
+    and exponentiated once for every gradient it feeds:
+
+    * ``want_dkv`` alone: grid (batch*head, k-block, q-block); Q/dO/lse/delta
+      stream one block per program, dK/dV accumulate in VMEM across the
+      q-blocks.
+    * ``want_dq`` alone: grid (batch*head, q-block, k-block); K/V stream, dQ
+      accumulates across the k-blocks (the forward's streaming shape).
+    * both (the fused backward): the dK/dV grid, and dQ for the WHOLE head
+      in a (Tq, Dk) float32 scratch, each tile adding to its rows, written
+      out on the head's last tile.
+
+    The tile is S TRANSPOSED, (blk_k, blk_q): P^T = exp(S^T - lse) with
+    S^T = K Q^T, dP^T = V dO^T, dS^T = P^T o (dP^T - delta), so lse and delta
+    are lane-dense (1, blk_q) rows broadcast down the sublanes, dV += P^T dO
+    and dK += dS^T Q are plain products, and only dQ += (dS^T)^T K contracts
+    the sublane axis. The score scale of dS is applied once to the float32
+    accumulators instead of to every score. Dead causal tiles are skipped
+    and only tiles the diagonal crosses are masked, as in the forward."""
+    rest = list(rest)
+    km_ref = rest.pop(0) if has_mask else None
+    n_out = want_dq + 2 * want_dkv
+    outs, scratch = rest[:n_out], rest[n_out:]
+    fused = want_dq and want_dkv
+    q_axis = 2 if want_dkv else 1
+    qi = pl.program_id(q_axis)
+    kj = pl.program_id(3 - q_axis)
+    first_q, last_q = qi == 0, qi == pl.num_programs(q_axis) - 1
+    first_k, last_k = kj == 0, kj == pl.num_programs(3 - q_axis) - 1
+    if want_dq:
+        dq_ref, dq_sc = outs[0], scratch[0]
+
+        @pl.when(jnp.logical_and(first_k, first_q) if fused else first_k)
+        def _init_dq():
+            dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+
+    if want_dkv:
+        (dk_ref, dv_ref), (dk_sc, dv_sc) = outs[-2:], scratch[-2:]
+
+        @pl.when(first_q)
+        def _init():
+            dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+            dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    def _tile(masked: bool, rows, cols):
+        q_blk, do_blk = q_ref[0, rows, :], do_ref[0, rows, :]
+        k_blk = k_ref[0, cols, :]
+        st = _dot(k_blk, q_blk, 1, 1) * scale             # (cols, rows)
+        if has_mask:
+            st = jnp.where(km_ref[0, cols, :] > 0, st, _NEG)   # a column
+        if masked:
+            st = _causal_mask(st, qi * blk_q + rows.start,
+                              kj * blk_k + cols.start, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, :, rows])            # a (1, rows) row
+        if has_mask:
+            # masked entries clamp to P = 0 rather than exp(S - lse): for a
+            # fully key-masked row lse is ~_NEG and the exponent would
+            # overflow. A causal row's lse is finite: exp underflows to 0
+            pt = jnp.where(st <= _NEG, 0.0, pt)
+        if want_dkv:
+            dv_sc[cols, :] += _dot(pt.astype(do_blk.dtype), do_blk, 1, 0)
+        dpt = _dot(v_ref[0, cols, :], do_blk, 1, 1)       # (cols, rows)
+        dst = (pt * (dpt - delta_ref[0, :, rows])).astype(q_blk.dtype)
+        if want_dkv:
+            dk_sc[cols, :] += _dot(dst, q_blk, 1, 0)
+        if want_dq:
+            # the fused kernel's scratch holds every row of the head
+            at = pl.ds(pl.multiple_of(qi * blk_q, blk_q) + rows.start,
+                       rows.size) if fused else rows
+            dq_sc[at, :] += _dot(dst, k_blk, 0, 0)        # (rows, Dk)
+
+    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k)
+
+    if want_dq:
+        @pl.when(jnp.logical_and(last_k, last_q) if fused else last_k)
+        def _finalize_dq():
+            dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
+
+    if want_dkv:
+        @pl.when(last_q)
+        def _finalize_dkv():
+            dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *rest, causal: bool, blk_q: int, blk_k: int,
-                         scale: float, has_mask: bool = False):
-    """dQ program per (batch*head, q-block, k-block); the k-block axis is
-    sequential and dQ accumulates in VMEM scratch across it (same streaming
-    shape as the forward, dead causal tiles skipped alike).
-
-    dS = P ∘ (dP − delta) with P = exp(S − lse), dP = dO·Vᵀ,
-    delta = rowsum(dO ∘ O); dQ = dS·K·scale.
-    """
-    km_ref = rest[0] if has_mask else None
-    dq_ref, dq_sc = rest[-2:]
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
-
-    def _tile():
-        k_blk = k_ref[0]                              # (blk_k, Dk)
-        delta = delta_ref[0].astype(jnp.float32)      # (blk_q, 1)
-        p = _recomputed_probs(q_ref[0], k_blk, lse_ref, km_ref, causal,
-                              qi * blk_q, kj * blk_k, scale)
-        dp = _dot(do_ref[0], v_ref[0], 1, 1)
-        ds = p * (dp - delta) * scale
-        dq_sc[...] += _dot(ds.astype(k_blk.dtype), k_blk, 1, 0)
-
-    if causal:
-        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
-    else:
-        _tile()
-
-    @pl.when(kj == pl.num_programs(2) - 1)
-    def _finalize():
-        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          *rest, causal: bool, blk_q: int, blk_k: int,
-                          scale: float, has_mask: bool = False):
-    """dK/dV program per (batch*head, k-block, q-block); the q-block axis is
-    sequential, Q/dO/lse/delta stream one block per program and dK/dV
-    accumulate in VMEM scratch across it.
-
-    dV = Pᵀ·dO accumulated over q-blocks; dK = dSᵀ·Q·scale.
-    """
-    km_ref = rest[0] if has_mask else None
-    dk_ref, dv_ref, dk_sc, dv_sc = rest[-4:]
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
-        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
-
-    def _tile():
-        q_blk = q_ref[0]                              # (blk_q, Dk)
-        do_blk = do_ref[0]                            # (blk_q, Dv)
-        delta_blk = delta_ref[0].astype(jnp.float32)  # (blk_q, 1)
-        p = _recomputed_probs(q_blk, k_ref[0], lse_ref, km_ref, causal,
-                              qi * blk_q, kj * blk_k, scale)
-        dv_sc[...] += _dot(p.astype(do_blk.dtype), do_blk, 0, 0)
-        dp = _dot(do_blk, v_ref[0], 1, 1)
-        ds = p * (dp - delta_blk) * scale
-        dk_sc[...] += _dot(ds.astype(q_blk.dtype), q_blk, 0, 0)
-
-    if causal:
-        pl.when(_causal_block_live(qi, kj, blk_q, blk_k))(_tile)
-    else:
-        _tile()
-
-    @pl.when(qi == pl.num_programs(2) - 1)
-    def _finalize():
-        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+def _fused_bwd_fits(tq: int, dk: int, dtype) -> bool:
+    """Whether ONE backward kernel serves the shape: a head's whole dQ (the
+    Tq x Dk float32 accumulator and its double-buffered output block) has to
+    sit in VMEM beside the tiles, and may take what the compiler's scoped
+    default leaves a program. The tiles come on top, held to
+    ``_VMEM_CEILING`` by ``_flash_tiles`` whether or not this says yes, and
+    the kernel asks for the sum (34 MiB planned at 4,096 x 192/128 with
+    1,024-square tiles). 4,096 x 192 in bfloat16 is 8 MiB and fits; 16,384
+    rows do not, and keep the dQ + dK/dV pair, whose VMEM does not grow with
+    the sequence."""
+    return _dq_bytes(tq, dk, jnp.dtype(dtype).itemsize) <= _VMEM_BUDGET
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     blk_k: int = None, interpret: bool = False,
-                    key_mask: Array = None, scale: float = None):
+                    key_mask: Array = None, scale: float = None,
+                    fused: bool = None):
     """Tiled pallas backward from the saved forward logsumexp. key_mask:
     optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
-    ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``."""
+    ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``.
+    ``fused`` None: one kernel where a head's dQ fits VMEM
+    (``_fused_bwd_fits``), else the dQ + dK/dV pair."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
-    blk_q = min(blk_q, Tq) if blk_q else _pick_blk(Tq, _BLK_Q)
-    blk_k = min(blk_k, Tk) if blk_k else _pick_blk(Tk, _BLK_K)
-    if not blk_q or not blk_k or Tq % blk_q or Tk % blk_k:
-        raise ValueError(f"sequence lengths ({Tq},{Tk}) must be divisible by "
-                         f"block sizes ({blk_q},{blk_k})")
+    blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k,
+                                   backward=True)
+    if fused is None:
+        fused = _fused_bwd_fits(Tq, D, q.dtype)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     gr, outr = _flatten_heads(g), _flatten_heads(out)
     # delta = rowsum(dO ∘ O): one cheap fused elementwise+reduce in XLA;
-    # lse/delta carry a trailing singleton for the kernels (see _bh_mask)
+    # lse and delta travel as lane-dense (B*H, 1, Tq) rows
     delta = jnp.sum(gr.astype(jnp.float32) * outr.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    lse3 = lse[:, :, None]
+                    axis=-1)[:, None, :]
     has_mask = key_mask is not None
-    km = _bh_mask(key_mask, H) if has_mask else None
+    operands = [qr, kr, vr, gr, lse[:, None, :], delta] + (
+        [_bh_mask(key_mask, H)] if has_mask else [])
+    nq, nk = Tq // blk_q, Tk // blk_k
 
-    kw = dict(causal=causal, blk_q=blk_q, blk_k=blk_k, scale=scale,
-              has_mask=has_mask)
-    operands = [qr, kr, vr, gr, lse3, delta] + ([km] if has_mask else [])
+    def call(want_dq: bool, want_dkv: bool):
+        """One pallas_call of ``_flash_bwd_kernel`` on a (bh, a, b) grid
+        whose axis ``q_pos`` walks the q-blocks and whose other block axis
+        walks the k-blocks."""
+        q_pos = 2 if want_dkv else 1
+        whole = want_dq and want_dkv   # the fused kernel: a head's dQ at once
 
-    def specs(q_pos):
-        """Block specs for (q, k, v, dO, lse, delta[, mask]) on a
-        (bh, a, b) grid whose axis ``q_pos`` (1 or 2) walks the q-blocks
-        and whose other block axis walks the k-blocks."""
         # under a causal mask a dead tile (``_causal_block_live``) names the
         # nearest live tile's streamed block again, so nothing is fetched
         # for it: the k-block is held at the diagonal where the k axis
-        # streams (dQ), the q-block at the first live one where q does
-        def q_map(*g):
+        # streams (dQ alone), the q-block at the first live one where q does
+        def q_idx(*g):
             i, j = g[q_pos], g[3 - q_pos]
             if causal and q_pos == 2:
                 i = jnp.maximum(i, (j * blk_k) // blk_q)
-            return (g[0], i, 0)
+            return i
 
-        def k_map(*g):
+        def k_idx(*g):
             i, j = g[q_pos], g[3 - q_pos]
             if causal and q_pos == 1:
-                j = jnp.minimum(j, (i * blk_q + (blk_q - 1)) // blk_k)
-            return (g[0], j, 0)
+                j = jnp.minimum(j, _diagonal_k_block(i, blk_q, blk_k))
+            return j
 
-        out = [pl.BlockSpec((1, blk_q, D), q_map),
-               pl.BlockSpec((1, blk_k, D), k_map),
-               pl.BlockSpec((1, blk_k, Dv), k_map),
-               pl.BlockSpec((1, blk_q, Dv), q_map),
-               pl.BlockSpec((1, blk_q, 1), q_map),
-               pl.BlockSpec((1, blk_q, 1), q_map)]
+        def q_map(*g):
+            return (g[0], q_idx(*g), 0)
+
+        def k_map(*g):
+            return (g[0], k_idx(*g), 0)
+
+        def row_map(*g):
+            return (g[0], 0, q_idx(*g))
+
+        in_specs = [pl.BlockSpec((1, blk_q, D), q_map),
+                    pl.BlockSpec((1, blk_k, D), k_map),
+                    pl.BlockSpec((1, blk_k, Dv), k_map),
+                    pl.BlockSpec((1, blk_q, Dv), q_map),
+                    pl.BlockSpec((1, 1, blk_q), row_map),
+                    pl.BlockSpec((1, 1, blk_q), row_map)]
         if has_mask:
-            out.append(pl.BlockSpec((1, blk_k, 1), k_map))
-        return out
+            in_specs.append(pl.BlockSpec((1, blk_k, 1), k_map))
+        out_specs, out_shape, scratch = [], [], []
+        if want_dq:
+            out_specs.append(
+                pl.BlockSpec((1, Tq, D), lambda bh, a, b: (bh, 0, 0)) if whole
+                else pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)))
+            out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype))
+            scratch.append(pltpu.VMEM((Tq if whole else blk_q, D),
+                                      jnp.float32))
+        if want_dkv:
+            out_specs += [
+                pl.BlockSpec((1, blk_k, D), lambda bh, j, i: (bh, j, 0)),
+                pl.BlockSpec((1, blk_k, Dv), lambda bh, j, i: (bh, j, 0))]
+            out_shape += [jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
+                          jax.ShapeDtypeStruct((B * H, Tk, Dv), v.dtype)]
+            scratch += [pltpu.VMEM((blk_k, D), jnp.float32),
+                        pltpu.VMEM((blk_k, Dv), jnp.float32)]
+        # batch*head programs are independent; a block axis that carries an
+        # accumulator runs in order: both of them where dQ spans the head
+        semantics = ("parallel",
+                     "arbitrary" if whole else "parallel",
+                     "arbitrary")
+        return pl.pallas_call(
+            functools.partial(
+                _flash_bwd_kernel, causal=causal, blk_q=blk_q, blk_k=blk_k,
+                scale=scale, has_mask=has_mask, want_dq=want_dq,
+                want_dkv=want_dkv),
+            grid=(B * H, nk, nq) if want_dkv else (B * H, nq, nk),
+            in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=_flash_params(semantics, _flash_vmem_bytes(
+                blk_q, blk_k, D, Dv, q.dtype.itemsize, True,
+                dq_rows=Tq if whole else 0)),
+            interpret=interpret,
+        )(*operands)
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **kw),
-        grid=(B * H, Tq // blk_q, Tk // blk_k),
-        in_specs=specs(1),
-        out_specs=pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=_STREAMED,
-        interpret=interpret,
-    )(*operands)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **kw),
-        grid=(B * H, Tk // blk_k, Tq // blk_q),
-        in_specs=specs(2),
-        out_specs=[
-            pl.BlockSpec((1, blk_k, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, blk_k, Dv), lambda bh, j, i: (bh, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, Dv), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
-                        pltpu.VMEM((blk_k, Dv), jnp.float32)],
-        compiler_params=_STREAMED,
-        interpret=interpret,
-    )(*operands)
-
+    if fused:
+        dq, dk, dv = call(True, True)
+    else:
+        (dq,), (dk, dv) = call(True, False), call(False, True)
     return (_unflatten_heads(dq, B, H), _unflatten_heads(dk, B, H),
             _unflatten_heads(dv, B, H))
 
@@ -702,9 +875,9 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
     dV = Pᵀ dO, dP = dO Vᵀ, dS = P ∘ (dP − rowsum(P ∘ dP)), dQ = dS·K·scale,
     dK = dSᵀ·Q·scale. Query rows padded up to a block multiple carry dO = 0,
     which makes their dS exactly 0, so padding contributes nothing.
-    None blk_q -> env-tunable module default (_BLK_Q).
+    None blk_q -> 128 rows.
     """
-    blk_q = blk_q or _BLK_Q
+    blk_q = blk_q or 128
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / (D ** 0.5) if scale is None else scale
@@ -765,6 +938,9 @@ def _flash_fwd_rule(q, k, v, causal, interpret, force, scale):
     tiled_bwd = (_pallas_ok(q, k, interpret, force)
                  and _pallas_bwd_enabled(k.shape[1], force))
     _note_dispatch("flash_attention_bwd", tiled_bwd)
+    # which backward the program holds: one kernel, or the dQ + dK/dV pair
+    _note_dispatch("flash_attention_bwd_fused", tiled_bwd and _fused_bwd_fits(
+        q.shape[1], q.shape[-1], q.dtype))
     if tiled_bwd:
         _note_dispatch("flash_attention", True)
         out, lse = _flash_forward(q, k, v, causal, interpret=interpret,
@@ -796,11 +972,6 @@ def _sm_xent_kernel(logits_ref, labels_ref, loss_ref, grad_ref):
     logp = x - m - jnp.log(z)
     loss_ref[:] = -jnp.sum(y * logp, axis=1, keepdims=True).astype(loss_ref.dtype)
     grad_ref[:] = (e / z - y).astype(grad_ref.dtype)
-
-
-#: bytes of VMEM one kernel program may plan for: the compiler's scoped
-#: default is 16 MiB per core; the rest is headroom for Mosaic's own scratch
-_VMEM_BUDGET = 14 * 1024 * 1024
 
 
 def _xent_rows(n: int, c: int, dtype):

@@ -64,26 +64,67 @@ def test_timed_out_run_exits_nonzero_with_an_error_record():
 
 def test_tile_sweep_isolates_failures_and_picks_best():
     """The flash tile sweep runs unattended in the auto-capture window: a
-    failing config must record an error string (not kill the bench), the
-    best config is the fastest timed one, and the module tile globals are
-    restored afterwards."""
+    failing tile shape must record an error string (not kill the bench), the
+    best shape is the fastest timed one, and a shape that does not divide
+    the sequence is left out."""
     import bench
-    from deeplearning4j_tpu.ops import pallas_kernels as pk
 
     calls = []
 
-    def fake_time_once():
-        calls.append((pk._BLK_Q, pk._BLK_K))
-        if pk._BLK_Q == 256 and pk._BLK_K == 128:
+    def fake_time_tiles(bq, bk):
+        calls.append((bq, bk))
+        if (bq, bk) == (512, 512):
             raise RuntimeError("VMEM OOM")
-        return 0.001 * pk._BLK_Q / pk._BLK_K  # fastest: 128x512
+        return 0.001 * bq / bk  # fastest: 128x512
 
-    saved = pk._BLK_Q, pk._BLK_K
-    out = bench._sweep_tiles(fake_time_once, seq=2048)
-    assert (pk._BLK_Q, pk._BLK_K) == saved  # globals restored
+    out = bench._sweep_tiles(fake_time_tiles, seq=2048)
     assert out["best_tiles"] == "128x512"  # smallest bq/bk ratio timed
-    assert out["tile_sweep_ms"]["256x128"].startswith("error:")
-    assert len(calls) == 6  # every config visited despite the failure
+    assert out["best_tiles_ms"] == out["tile_sweep_ms"]["128x512"] == 0.25
+    assert out["tile_sweep_ms"]["512x512"].startswith("error:")
+    assert len(calls) == 6  # every shape visited despite the failure
+    assert set(bench._sweep_tiles(fake_time_tiles, seq=1536)[
+        "tile_sweep_ms"]) == {"128x512", "256x256", "512x512"}
+
+
+def test_swept_tiles_reach_both_kernels():
+    """``_flash_at_tiles`` hands its tiles to the forward and the backward
+    kernels, and its value and gradients are the XLA math's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import bench
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    seen = []
+    real_fwd, real_bwd = pk._flash_forward, pk._flash_backward
+
+    def fwd(*a, **kw):
+        seen.append(("fwd", kw["blk_q"], kw["blk_k"]))
+        return real_fwd(*a, interpret=True, **kw)
+
+    def bwd(*a, **kw):
+        seen.append(("bwd", kw["blk_q"], kw["blk_k"]))
+        return real_bwd(*a, interpret=True, **kw)
+
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 256, 2, 16)), jnp.float32)
+               for _ in range(3))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    want = jax.grad(loss(lambda q, k, v: pk._attention_xla(q, k, v, True)),
+                    argnums=(0, 1, 2))(q, k, v)
+    try:
+        pk._flash_forward, pk._flash_backward = fwd, bwd
+        got = jax.grad(loss(bench._flash_at_tiles(128, 64)),
+                       argnums=(0, 1, 2))(q, k, v)
+    finally:
+        pk._flash_forward, pk._flash_backward = real_fwd, real_bwd
+    assert ("fwd", 128, 64) in seen and ("bwd", 128, 64) in seen
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
 
 
 def test_reduction_dtype_config_resolution():

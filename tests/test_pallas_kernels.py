@@ -136,6 +136,269 @@ def test_pallas_backward_cross_attention_lengths():
                                    rtol=5e-4, atol=5e-5, err_msg=name)
 
 
+def _latent_operands(T, seed, B=2, H=2, Dk=24, Dv=16):
+    """q, k (Dk wide), v and a cotangent (Dv wide): latent attention's
+    192/128 scaled down by 8."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=(B, T, H, d)).astype(np.float32))
+                 for d in (Dk, Dk, Dv, Dv))
+
+
+def _tail_mask(B, T):
+    """A padded tail in the first sequence, every key of the last masked."""
+    mask = np.ones((B, T), np.float32)
+    mask[0, T - T // 3:] = 0.0
+    mask[-1, :] = 0.0
+    return jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("tiles", [(64, 32), (32, 32), (32, 64)],
+                         ids=lambda t: f"q{t[0]}k{t[1]}")
+@pytest.mark.parametrize("key_mask", [False, True], ids=["nomask", "keymask"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_fused_backward_matches_pair_and_reference(causal, key_mask, tiles):
+    """ONE backward kernel (dQ for the whole head in VMEM beside dK/dV's
+    accumulators) gives what the dQ + dK/dV pair gives and what the plain
+    math gives (`_attention_bwd_chunked`; under a key mask the XLA masked
+    attention's gradient), at Dk != Dv, with the query tile larger than,
+    equal to and smaller than the key tile."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        _attention_bwd_chunked, _flash_backward, _flash_forward,
+        _masked_attention_xla)
+    T, scale = 128, 24 ** -0.5
+    q, k, v, g = _latent_operands(T, seed=11)
+    km = _tail_mask(q.shape[0], T) if key_mask else None
+    bq, bk = tiles
+    out, lse = _flash_forward(q, k, v, causal, blk_q=bq, blk_k=bk,
+                              interpret=True, key_mask=km)
+    fused, pair = (
+        _flash_backward(q, k, v, out, lse, g, causal, blk_q=bq, blk_k=bk,
+                        interpret=True, key_mask=km, fused=f)
+        for f in (True, False))
+    if key_mask:
+        _, vjp = jax.vjp(
+            lambda a, b, c: _masked_attention_xla(a, b, c, km, causal),
+            q, k, v)
+        expect = vjp(g)
+    else:
+        expect = _attention_bwd_chunked(q, k, v, g, causal, blk_q=32,
+                                        scale=scale)
+    for name, a, b, c in zip(("dq", "dk", "dv"), fused, pair, expect):
+        assert np.all(np.isfinite(np.asarray(a))), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=5e-4, atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("tiles", [(48, 32), (32, 48), (16, 96), (96, 16),
+                                   (24, 16)],
+                         ids=lambda t: f"q{t[0]}k{t[1]}")
+def test_diagonal_crosses_tiles_at_every_offset(tiles):
+    """Only a tile the diagonal crosses is masked, a tile wholly below it
+    takes the body without the mask and a dead one is skipped: with a query
+    tile that is no multiple of the key tile (and the reverse) the diagonal
+    enters tiles at every offset, and forward and both backwards still give
+    the masked reference's result."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        _causal_block_crossed, _causal_block_live, _flash_backward,
+        _flash_forward)
+    T, scale = 96, 0.17
+    q, k, v, g = _latent_operands(T, seed=12, B=1)
+    bq, bk = tiles
+    # the predicates against the positions they stand for
+    for qi in range(T // bq):
+        for kj in range(T // bk):
+            qs, ks = range(qi * bq, (qi + 1) * bq), range(kj * bk,
+                                                          (kj + 1) * bk)
+            assert _causal_block_live(qi, kj, bq, bk) == (ks[0] <= qs[-1])
+            assert _causal_block_crossed(qi, kj, bq, bk) == (ks[-1] > qs[0])
+    out, lse = _flash_forward(q, k, v, True, blk_q=bq, blk_k=bk,
+                              interpret=True, scale=scale)
+    ref, vjp = jax.vjp(
+        lambda a, b, c: attention_reference(a, b, c, True, scale), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    for fused in (True, False):
+        got = _flash_backward(q, k, v, out, lse, g, True, blk_q=bq, blk_k=bk,
+                              interpret=True, scale=scale, fused=fused)
+        for name, a, b in zip(("dq", "dk", "dv"), got, vjp(g)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
+                err_msg=f"{name} fused={fused}")
+
+
+@pytest.mark.parametrize("key_mask", [False, True], ids=["nomask", "keymask"])
+def test_square_diagonal_tile_runs_as_three_quarters(key_mask, monkeypatch):
+    """From `_QUARTERED_FROM` rows a square tile on the diagonal is computed
+    as its two masked quarters and the plain one below them; the dead
+    quarter is left out. Forward and both backwards give what the whole
+    masked tile gives, and the reference."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    T = 128
+    q, k, v, g = _latent_operands(T, seed=15)
+    km = _tail_mask(q.shape[0], T) if key_mask else None
+    masks = []
+    real = pk._causal_mask
+    monkeypatch.setattr(pk, "_causal_mask",
+                        lambda *a, **kw: masks.append(1) or real(*a, **kw))
+
+    def run():
+        out, lse = pk._flash_forward(q, k, v, True, blk_q=64, blk_k=64,
+                                     interpret=True, key_mask=km)
+        grads = [pk._flash_backward(q, k, v, out, lse, g, True, blk_q=64,
+                                    blk_k=64, interpret=True, key_mask=km,
+                                    fused=f) for f in (True, False)]
+        return [out, *grads[0], *grads[1]]
+
+    whole = run()
+    assert len(masks) == 4          # forward, fused, dQ, dK/dV: one each
+    monkeypatch.setattr(pk, "_QUARTERED_FROM", 64)
+    del masks[:]
+    quartered = run()
+    assert len(masks) == 8          # two masked quarters a kernel
+    for a, b in zip(quartered, whole):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    if not key_mask:
+        ref, vjp = jax.vjp(
+            lambda a, b, c: attention_reference(a, b, c, True), q, k, v)
+        for a, b in zip(quartered[:4], (ref, *vjp(g))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-4, atol=5e-5)
+
+
+def test_tiles_and_backward_follow_the_shape():
+    """No switch chooses the tiles or the backward: the operands' shape
+    does. The language-model cell's core (4,096 x 192/128, bfloat16) takes
+    1,024-square tiles and the one-kernel backward; at 16,384 a head's dQ
+    does not fit beside the tiles and the pair stays; lengths that a tile
+    does not divide keep a smaller standard one."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    bf16 = jnp.bfloat16
+    for backward in (False, True):
+        assert pk._flash_tiles(4096, 4096, 192, 128, bf16,
+                               backward=backward) == (1024, 1024)
+    assert pk._fused_bwd_fits(4096, 192, bf16)
+    assert pk._fused_bwd_fits(4096, 64, jnp.float32)
+    assert not pk._fused_bwd_fits(16384, 64, bf16)
+    assert not pk._fused_bwd_fits(16384, 192, bf16)
+    # the widest tile that divides, down to one a sequence: measured
+    # faster than four a side at T = 1,024 and 2,048 (PERF.md §6, PR 31)
+    assert pk._flash_tiles(1024, 1024, 64, 64, bf16) == (1024, 1024)
+    assert pk._flash_tiles(2048, 2048, 64, 64, bf16,
+                           backward=True) == (1024, 1024)
+    # float32 operands: the 1,024-square backward passes the ceiling
+    assert pk._flash_tiles(4096, 4096, 192, 128, jnp.float32,
+                           backward=True) == (512, 512)
+    assert pk._flash_tiles(1280, 3200, 64, 64, bf16) == (256, 128)
+    assert pk._flash_tiles(64, 2048, 64, 64, bf16) == (64, 1024)
+    assert pk._flash_tiles(2048, 1000, 64, 64, bf16) is None
+    # an explicit size is taken as given, capped at the sequence
+    assert pk._flash_tiles(4096, 256, 64, 64, bf16, 128, 512) == (
+        128, 256)
+    # the count grows with the tile and with a head's dQ
+    small = pk._flash_vmem_bytes(128, 512, 192, 128, 2, True)
+    assert small < pk._flash_vmem_bytes(512, 512, 192, 128, 2, True)
+    assert pk._flash_vmem_bytes(512, 512, 192, 128, 2, True, dq_rows=4096) \
+        == pk._flash_vmem_bytes(512, 512, 192, 128, 2, True) \
+        + (4096 - 512) * 256 * 8
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128),
+                                    (256, 256)], ids=str)
+def test_one_kernel_backward_plans_within_tiles_plus_dq(widths, dtype):
+    """``_VMEM_CEILING`` holds the tiles and ``_VMEM_BUDGET`` a head's whole
+    dQ, each checked apart: whatever shape takes the one-kernel backward
+    plans for at most their sum, and asks the compiler for under half a
+    core's 128 MiB."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    dk, dv = widths
+    itemsize = jnp.dtype(dtype).itemsize
+    fused = 0
+    for t in (1024, 1280, 2048, 4096, 8192, 16384):
+        bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
+        tiles = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True)
+        assert tiles <= pk._VMEM_CEILING
+        if pk._fused_bwd_fits(t, dk, dtype):
+            fused += 1
+            need = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True,
+                                        dq_rows=t)
+            assert need <= pk._VMEM_CEILING + pk._VMEM_BUDGET
+            limit = pk._flash_params(("parallel",), need).vmem_limit_bytes
+            assert limit is None or need < limit <= 64 << 20
+    assert fused  # some length of every width takes the one kernel
+
+
+def _dispatch_counts():
+    from deeplearning4j_tpu.observability.metrics import global_registry
+    snap = global_registry().snapshot().get(
+        "dl4j_pallas_dispatch_total", {"series": []})
+    return {(s["labels"]["kernel"], s["labels"]["engaged"]): s["value"]
+            for s in snap["series"]}
+
+
+def _count_delta(before, kernel):
+    now = _dispatch_counts()
+    return tuple(now.get((kernel, e), 0) - before.get((kernel, e), 0)
+                 for e in ("true", "false"))
+
+
+@pytest.mark.parametrize("fits", [True, False], ids=["fused", "pair"])
+def test_traced_gradient_notes_which_backward_it_holds(fits, monkeypatch):
+    """A traced gradient of flash_attention notes
+    `flash_attention_bwd_fused` engaged or not beside `flash_attention_bwd`:
+    a run says which backward its program holds."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    if not fits:     # a budget no head's dQ fits under
+        monkeypatch.setattr(pk, "_VMEM_BUDGET", 16 * 1024)
+    q, k, v, g = _latent_operands(64, seed=13, B=1)
+    before = _dispatch_counts()
+    grads = jax.grad(lambda a, b, c: jnp.sum(
+        pk.flash_attention(a, b, c, True, True, True) * g), argnums=(0, 1, 2))(
+            q, k, v)
+    assert _count_delta(before, "flash_attention_bwd") == (1, 0)
+    assert _count_delta(before, "flash_attention_bwd_fused") == (
+        (1, 0) if fits else (0, 1))
+    _, vjp = jax.vjp(lambda a, b, c: attention_reference(a, b, c, True),
+                     q, k, v)
+    for a, b in zip(grads, vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-5)
+
+
+def test_store_replays_the_backward_a_loaded_program_holds():
+    """A program loaded from the executable store is never traced: the
+    store replays the notes of the trace it serialized, the fused backward's
+    among them, so a warm process counts what it runs."""
+    from deeplearning4j_tpu.nn import compile_cache as cc
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    q, k, v, g = _latent_operands(64, seed=14, B=1)
+
+    def grad_fn(a, b, c):
+        return jax.grad(lambda *t: jnp.sum(
+            pk.flash_attention(*t, True, True, True) * g))(a, b, c)
+
+    outs = []
+    for round_ in ("cold", "warm"):
+        before = _dispatch_counts()
+        prog = cc.build_program("flash_grad", jax.jit(grad_fn))
+        outs.append(np.asarray(prog(q, k, v)))
+        assert prog.cache_hit is (round_ == "warm"), round_
+        for kernel in ("flash_attention", "flash_attention_bwd",
+                       "flash_attention_bwd_fused"):
+            assert _count_delta(before, kernel) == (1, 0), (round_, kernel)
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_masked_attention_pallas_matches_xla(causal):
     """masked_attention's tiled pallas path (interpret=True) == the XLA
@@ -337,9 +600,9 @@ def test_fused_xent_integrations_bf16_and_lbfgs():
 
 
 def test_pick_blk_divisor_fallback():
-    """Round-5 calibration raised the default K block to 512; _pick_blk must
-    fall back to smaller standard tiles for 128-divisible-but-not-512-
-    divisible lengths instead of silently dropping to the O(T^2) XLA path."""
+    """The tiles prefer 512 rows; _pick_blk must fall back to smaller
+    standard tiles for 128-divisible-but-not-512-divisible lengths instead
+    of silently dropping to the O(T^2) XLA path."""
     from deeplearning4j_tpu.ops.pallas_kernels import _pick_blk, _tileable
 
     assert _pick_blk(2048, 512) == 512

@@ -73,8 +73,12 @@ def _flash(T, dtype, grad):
         return jax.value_and_grad(lambda *a: fwd(*a).astype(F32).sum(),
                                   argnums=(0, 1, 2))(q, k, v)
 
-    # forward kernel; under grad it is joined by dq + dkv from _PBWD_MIN_SEQ
-    want = (3 if T >= pk._PBWD_MIN_SEQ else 1) if grad else 1
+    # forward kernel; under grad it is joined from _PBWD_MIN_SEQ by the one
+    # backward kernel where a head's dQ fits VMEM, else by the dq + dkv pair
+    # (T = 16,384)
+    want = 1
+    if grad and T >= pk._PBWD_MIN_SEQ:
+        want = 2 if pk._fused_bwd_fits(T, 64, dtype) else 3
     return (bwd if grad else fwd), qkv, want
 
 
@@ -89,7 +93,7 @@ def _masked(grad):
         return jax.grad(lambda *a: fwd(*a, m).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    return (bwd if grad else fwd), shapes, (3 if grad else 1)
+    return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
 def _xent(N, C, dtype):
@@ -135,10 +139,13 @@ def _int8():
             [((16, 512), F32), ((512, 2048), jnp.int8), ((2048,), F32)], 1)
 
 
-def _latent(grad):
+def _latent(grad, T=4096):
     """Latent attention's core at DeepSeek-V2-Lite's widths: 192-wide
-    queries and keys, 128-wide values, 4 sequences of 4,096, 16 heads."""
-    shapes = [((4, 4096, 16, d), BF16) for d in (192, 192, 128)]
+    queries and keys, 128-wide values, 4 sequences of 4,096, 16 heads: the
+    forward at the tiles the shape chooses, and under grad the ONE backward
+    kernel (a head's dQ, 4,096 x 192, fits VMEM). At 16,384 it does not,
+    and the dq + dkv pair stays."""
+    shapes = [((4 * 4096 // T, T, 16, d), BF16) for d in (192, 192, 128)]
 
     def fwd(q, k, v):
         return pk.flash_attention(q, k, v, True, False, False, 0.1147)
@@ -147,7 +154,9 @@ def _latent(grad):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    return (bwd if grad else fwd), shapes, (3 if grad else 1)
+    assert pk._fused_bwd_fits(T, 192, BF16) == (T == 4096)
+    return (bwd if grad else fwd), shapes, (
+        (2 if T == 4096 else 3) if grad else 1)
 
 
 def _grouped(grad, policy="bfloat16_full"):
@@ -176,6 +185,7 @@ def _grouped(grad, policy="bfloat16_full"):
 CASES = {
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
+    "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
     "grouped-fwd-S16384-bfloat16": (_grouped, (False,)),
     "grouped-grad-S16384-bfloat16": (_grouped, (True,)),
     "grouped-grad-S16384-float32": (_grouped, (True, "float32")),
