@@ -28,7 +28,7 @@ Array = jax.Array
 
 
 def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
-           scale=None) -> Array:
+           scale=None, window=None) -> Array:
     """The ONE attention-core dispatch every attention-bearing layer uses.
 
     Single device (no active ParallelContext): flash_attention (Pallas on
@@ -42,7 +42,10 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
     correctness over parallelism, mirroring ParallelWrapper's own fallback
     for semantics its sharded step doesn't cover. ``v`` may have another
     width than ``q`` and ``k``, and ``scale`` (None: ``Dk ** -0.5``) another
-    value: latent attention's core.
+    value: latent attention's core. ``window`` (a query sees the ``window``
+    keys ending at itself) and fewer key/value heads than query heads
+    (``k``, ``v`` [B, T, G, D]: query head h reads head ``h // (H // G)``)
+    reach the single-device core as they are.
     """
     from deeplearning4j_tpu.ops.pallas_kernels import (
         flash_attention, masked_attention,
@@ -50,15 +53,18 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
     from deeplearning4j_tpu.parallel import context as pctx
 
     ctx = pctx.current()
-    if scale is not None or v.shape[-1] != q.shape[-1]:
+    if (scale is not None or v.shape[-1] != q.shape[-1]
+            or window is not None or k.shape[2] != q.shape[2]):
         # latent attention (values narrower than keys, a score scale of its
-        # own): the single-device core only; neither the sequence-parallel
-        # bodies nor the key-masked kernel know those shapes yet
+        # own), a window, grouped key/value heads: the single-device core
+        # only; neither the sequence-parallel bodies nor the key-masked
+        # kernel know those shapes yet
         if mask is not None or (ctx is not None and ctx.seq_axis is not None):
             raise NotImplementedError(
-                "attention with its own scale or value width runs unmasked "
-                "on one device only")
-        return flash_attention(q, k, v, causal, False, False, scale)
+                "attention with its own scale or value width (latent "
+                "attention), a window, or fewer key/value heads than query "
+                "heads runs unmasked on one device only")
+        return flash_attention(q, k, v, causal, False, False, scale, window)
     if ctx is not None and ctx.seq_axis is not None and mask is None:
         from deeplearning4j_tpu.parallel.ring_attention import (
             ring_attention_sharded, ulysses_attention_sharded)
@@ -113,17 +119,21 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x: Array, inv_freq) -> Array:
+def apply_rope(x: Array, inv_freq, halves: bool = False) -> Array:
     """Rotate ``x`` [B, T, H, D] by position: the pair ``(x[2i], x[2i+1])``
     turns by ``t * inv_freq[i]``, and the result comes out as DeepSeek-V2's
     ``apply_rotary_pos_emb`` leaves it, every first element before every
     second (queries and keys alike, so their products are untouched).
-    Angles and the rotation are float32."""
+    ``halves``: the pair is ``(x[i], x[i + D/2])`` instead and stays where
+    it was (``x * cos + rotate_half(x) * sin``). Angles and the rotation are
+    float32."""
     t = jnp.arange(x.shape[1], dtype=jnp.float32)
     ang = t[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
     cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
     xf = x.astype(at_least_f32(x.dtype))
-    a, b = xf[..., 0::2], xf[..., 1::2]
+    half = x.shape[-1] // 2
+    a, b = ((xf[..., :half], xf[..., half:]) if halves
+            else (xf[..., 0::2], xf[..., 1::2]))
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)
 

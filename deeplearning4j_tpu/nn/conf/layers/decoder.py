@@ -1,36 +1,52 @@
-"""One pre-norm decoder block whose parts are fields, and a norm layer.
+"""One decoder block whose parts are fields, and a norm layer.
 
 ``DecoderBlock`` is ``h = x + A(N(x)); y = h + F(N(h))`` with the norm ``N``,
 the attention ``A`` and the feed-forward ``F`` each chosen by a field, so a
 current language model is a list of these blocks with its published sizes
 and not a class of its own:
 
-* ``norm``: ``"rms"`` (no bias).
+* ``norm``: ``"rms"`` (no bias). ``norm_placement``: ``"pre"`` as above, or
+  ``"sandwich"``, a norm before *and after* each branch, ``h = x +
+  N(A(N(x))); y = h + N(F(N(h)))``, four scales of their own.
 * ``attention``: ``"mla"``, latent attention: keys and values come from a
   ``kv_rank``-wide normed latent, every head's key carries one shared rotary
   part, queries and keys are ``qk_nope_dim + qk_rope_dim`` wide and values
   ``v_dim``; causal, through ``attention.attend``. ``rope_theta`` turns the
-  rotary embedding on.
+  rotary embedding on. ``"gqa"``: separate query, key and value projections
+  to ``n_heads`` query and ``n_kv_heads`` key/value heads ``head_dim`` wide
+  (query head h reads key/value head ``h // (n_heads // n_kv_heads)``), an
+  RMS norm over each head's query and key (one scale vector for all heads),
+  the rotary embedding on the whole head in halves where ``rope_theta`` is
+  set, a causal mask cut to the last ``window`` keys where that is set, and
+  an output gate ``sigmoid(u Wz)`` on the core's result.
 * ``ffn``: ``"swiglu"`` (``(silu(u Wg) * (u Wu)) Wd``, no biases) or
-  ``"moe"``: softmax routing over ``n_experts`` router outputs, the
-  ``experts_per_token`` largest taken greedily and weighted by their
-  probability (not renormalised), a shared expert computed for every token,
-  and the routed experts this chip holds (``experts_held``, a ``[first,
-  end)`` range of expert ids; None: all) through the dropless grouped
-  dispatch of ``moe.grouped_expert_ffn``. What absent experts would add is
-  left out: a chip's share of an expert-parallel layer, without the
-  exchange.
+  ``"moe"``: routing over ``n_experts`` router outputs, a shared expert
+  computed for every token, and the routed experts this chip holds
+  (``experts_held``, a ``[first, end)`` range of expert ids; None: all)
+  through the dropless grouped dispatch of ``moe.grouped_expert_ffn``. What
+  absent experts would add is left out: a chip's share of an
+  expert-parallel layer, without the exchange. ``router``: ``"softmax"``,
+  the ``experts_per_token`` largest probabilities taken greedily and
+  weighted by themselves (not renormalised), with an auxiliary loss; or
+  ``"sigmoid_bias"``: sigmoid scores, the largest of ``score + bias`` taken,
+  weighted by their scores (without the bias) renormalised to
+  ``route_scale``, no auxiliary loss, and the bias moved against each
+  output's load after every training step.
 
-``norm`` and ``attention`` know one value each, the one a model here uses:
-a second is a branch in ``_norm`` or ``attention_part`` beside its first
-caller, not before it (``TransformerBlock`` is the layer-norm, one-head-width
-block).
+Each field's second value is a branch in ``_norm``, ``attention_part`` or
+``route`` beside its first caller, not a class of its own
+(``TransformerBlock`` is the layer-norm, one-head-width block).
 
-An expert layer's state carries ``aux_loss`` (the sequence-wise balance term
-of DeepSeek-V2's ``seq_aux`` branch, over all router outputs; the fit loop
-adds ``aux_loss_weight`` times it) and ``moe_rows`` (int32 [3]: pairs routed
-here, rows the grouped products ran over, the largest expert's rows), which
-the K-step program hands back with its losses.
+An expert layer's state carries ``moe_rows`` (int32 [3]: pairs routed here,
+rows the grouped products ran over, the largest expert's rows), which the
+K-step program hands back with its losses, and by its router ``aux_loss``
+(the sequence-wise balance term of DeepSeek-V2's ``seq_aux`` branch, over all
+router outputs; the fit loop adds ``aux_loss_weight`` times it) or
+``router_bias`` (float32 [n_experts], from 0): state a step changes without
+a gradient, as batch norm's running statistics are. After a training step
+``bias += d - mean(d)`` with ``d = bias_update_rate * sign(mean(c) - c)``
+and ``c`` the step's (token, choice) pairs on each output, over this chip's
+tokens (a deployment sums ``c`` over its chips first).
 """
 from __future__ import annotations
 
@@ -49,7 +65,8 @@ from deeplearning4j_tpu.nn.conf.layers.feedforward import _dense
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
 
-_NORMS, _ATTENTIONS, _FFNS = ("rms",), ("mla",), ("swiglu", "moe")
+_NORMS, _ATTENTIONS, _FFNS = ("rms",), ("mla", "gqa"), ("swiglu", "moe")
+_PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
 
 
 def _mm(x, w):
@@ -95,8 +112,14 @@ class RMSNormLayer(FeedForwardLayer):
 class DecoderBlock(FeedForwardLayer):
     norm: str = "rms"
     norm_eps: float = 1e-6
+    norm_placement: str = "pre"
     attention: str = "mla"
     n_heads: int = 4
+    #: "gqa": key/value heads, the heads' width, the keys a query sees
+    #: counting itself (None: all before it)
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    window: Optional[int] = None
     #: latent width, query/key widths without and with rotation,
     #: value width
     kv_rank: int = 0
@@ -117,7 +140,11 @@ class DecoderBlock(FeedForwardLayer):
     expert_hidden: int = 0
     shared_hidden: int = 0
     experts_held: Optional[list] = None
+    router: str = "softmax"
     aux_loss_weight: float = 0.001
+    #: "sigmoid_bias": what the chosen scores add up to, and the bias's step
+    route_scale: float = 1.0
+    bias_update_rate: float = 0.001
     #: residual-stream blocks take no output nonlinearity (see
     #: MoETransformerBlock.activation)
     activation: Optional[str] = "identity"
@@ -125,7 +152,8 @@ class DecoderBlock(FeedForwardLayer):
     # ------------------------------------------------------------ geometry
     def __post_init__(self):
         for field, known in (("norm", _NORMS), ("attention", _ATTENTIONS),
-                             ("ffn", _FFNS)):
+                             ("ffn", _FFNS), ("router", _ROUTERS),
+                             ("norm_placement", _PLACEMENTS)):
             if getattr(self, field) not in known:
                 raise ValueError(f"DecoderBlock.{field} = "
                                  f"{getattr(self, field)!r}; known: {known}")
@@ -148,6 +176,8 @@ class DecoderBlock(FeedForwardLayer):
         return int(first), int(end)
 
     def _qk_dim(self) -> int:
+        if self.attention == "gqa":
+            return self.head_dim
         return self.qk_nope_dim + self.qk_rope_dim
 
     def _score_scale(self) -> float:
@@ -163,13 +193,24 @@ class DecoderBlock(FeedForwardLayer):
         ks = iter(jax.random.split(key, 16))
         w = lambda *shape: self._init_w(next(ks), shape)
         p = {}
-        for n in ("norm1", "norm2"):
+        for n in ("norm1", "norm2") + (
+                ("post1", "post2") if self.norm_placement == "sandwich"
+                else ()):
             p[n + "_g"] = jnp.ones((F,), jnp.float32)
         p["Wq"] = w(F, H * self._qk_dim())
-        p["Wkva"] = w(F, self.kv_rank + self.qk_rope_dim)
-        p["kv_norm_g"] = jnp.ones((self.kv_rank,), jnp.float32)
-        p["Wkvb"] = w(self.kv_rank, H * (self.qk_nope_dim + self.v_dim))
-        p["Wo"] = w(H * self.v_dim, F)
+        if self.attention == "gqa":
+            D, G = self.head_dim, self.n_kv_heads
+            if not G or H % G:
+                raise ValueError(f"{H} query heads over {G} key/value heads")
+            p["Wk"], p["Wv"] = w(F, G * D), w(F, G * D)
+            p["Wz"], p["Wo"] = w(F, H * D), w(H * D, F)
+            p["q_norm_g"] = jnp.ones((D,), jnp.float32)
+            p["k_norm_g"] = jnp.ones((D,), jnp.float32)
+        else:
+            p["Wkva"] = w(F, self.kv_rank + self.qk_rope_dim)
+            p["kv_norm_g"] = jnp.ones((self.kv_rank,), jnp.float32)
+            p["Wkvb"] = w(self.kv_rank, H * (self.qk_nope_dim + self.v_dim))
+            p["Wo"] = w(H * self.v_dim, F)
         if self.ffn == "swiglu":
             p["Wg"], p["Wu"] = w(F, self.ffn_hidden), w(F, self.ffn_hidden)
             p["Wd"] = w(self.ffn_hidden, F)
@@ -188,21 +229,43 @@ class DecoderBlock(FeedForwardLayer):
         return p
 
     def regularizable_params(self):
-        return ("Wq", "Wkva", "Wkvb", "Wo", "Wg", "Wu", "Wd", "Eg",
-                "Eu", "Ed", "Sg", "Su", "Sd")
+        return ("Wq", "Wkva", "Wkvb", "Wk", "Wv", "Wz", "Wo", "Wg", "Wu",
+                "Wd", "Eg", "Eu", "Ed", "Sg", "Su", "Sd")
 
     def init_state(self, itype: InputType) -> dict:
         if self.ffn != "moe":
             return {}
-        return {"aux_loss": jnp.zeros((), jnp.float32),
-                "moe_rows": jnp.zeros((3,), jnp.int32)}
+        rows = {"moe_rows": jnp.zeros((3,), jnp.int32)}
+        if self.router == "sigmoid_bias":
+            return {"router_bias": jnp.zeros((self.n_experts,), jnp.float32),
+                    **rows}
+        return {"aux_loss": jnp.zeros((), jnp.float32), **rows}
 
     # --------------------------------------------------------------- parts
     def _norm(self, params, name, x):
         return rms_norm(x, params[name + "_g"], self.norm_eps)
 
+    def _gqa_part(self, params, u, mask):
+        B, T, _ = u.shape
+        H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
+        q = rms_norm(_mm(u, params["Wq"]).reshape(B, T, H, D),
+                     params["q_norm_g"], self.norm_eps)
+        k = rms_norm(_mm(u, params["Wk"]).reshape(B, T, G, D),
+                     params["k_norm_g"], self.norm_eps)
+        v = _mm(u, params["Wv"]).reshape(B, T, G, D)
+        z = _mm(u, params["Wz"])
+        if self.rope_theta:
+            freq = rope_inv_freq(D, self.rope_theta, self.rope_scaling)
+            q, k = apply_rope(q, freq, True), apply_rope(k, freq, True)
+        with jax.named_scope("core"):
+            o = attend(q, k, v, True, mask, window=self.window)
+        gate = jax.nn.sigmoid(z.astype(at_least_f32(z.dtype))).astype(z.dtype)
+        return _mm(o.reshape(B, T, H * D) * gate, params["Wo"])
+
     def attention_part(self, params, u, mask=None):
         """``A(u)``: u [B, T, F] normed input -> [B, T, F]."""
+        if self.attention == "gqa":
+            return self._gqa_part(params, u, mask)
         B, T, _ = u.shape
         H = self.n_heads
         dn, dr, dv, r = (self.qk_nope_dim, self.qk_rope_dim, self.v_dim,
@@ -224,16 +287,36 @@ class DecoderBlock(FeedForwardLayer):
             o = attend(q, k, v, True, mask, scale=self._score_scale())
         return _mm(o.reshape(B, T, H * dv), params["Wo"])
 
-    def route(self, params, u):
+    def route(self, params, u, bias=None):
         """-> (choice [B, T, k] int32 over all experts, weight [B, T, k],
         probs [B, T, E] float32): softmax over every router output, the k
-        largest taken greedily, each weighted by its own probability."""
+        largest taken greedily, each weighted by its own probability. With
+        the ``"sigmoid_bias"`` router: sigmoid scores, the k largest of
+        ``score + bias`` taken, weighted by their scores renormalised to
+        ``route_scale`` (the bias chooses and weighs nothing)."""
         f32 = at_least_f32(u.dtype)
         logits = jnp.matmul(u.astype(f32), params["Wr"].astype(f32),
                             precision=jax.lax.Precision.HIGHEST)
+        if self.router == "sigmoid_bias":
+            scores = jax.nn.sigmoid(logits)
+            _, choice = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias),
+                self.experts_per_token)
+            weight = jnp.take_along_axis(scores, choice, axis=-1)
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
+                               + 1e-20) * self.route_scale
+            return choice.astype(jnp.int32), weight, scores
         probs = jax.nn.softmax(logits, axis=-1)
         weight, choice = jax.lax.top_k(probs, self.experts_per_token)
         return choice.astype(jnp.int32), weight, probs
+
+    def next_bias(self, bias, choice):
+        """The ``"sigmoid_bias"`` router's bias after a step that made
+        ``choice``: against each output's load, re-centred."""
+        load = jnp.sum(jax.nn.one_hot(choice.reshape(-1), self.n_experts,
+                                      dtype=jnp.float32), axis=0)
+        d = self.bias_update_rate * jnp.sign(jnp.mean(load) - load)
+        return bias + d - jnp.mean(d)
 
     def seq_aux_term(self, choice, probs):
         """DeepSeek-V2's ``seq_aux`` balance term before its weight: per
@@ -260,17 +343,22 @@ class DecoderBlock(FeedForwardLayer):
     # --------------------------------------------------------------- apply
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         B, T, F = x.shape
+        sandwich = self.norm_placement == "sandwich"
         with jax.named_scope("attn"):
-            h = x + self.attention_part(params, self._norm(params, "norm1", x),
-                                        mask)
+            a = self.attention_part(params, self._norm(params, "norm1", x),
+                                    mask)
+            h = x + (self._norm(params, "post1", a) if sandwich else a)
         u = self._norm(params, "norm2", h)
         if self.ffn == "swiglu":
             with jax.named_scope("ffn"):
-                y = h + swiglu(u, params["Wg"], params["Wu"], params["Wd"])
+                f = swiglu(u, params["Wg"], params["Wu"], params["Wd"])
+                y = h + (self._norm(params, "post2", f) if sandwich else f)
             return self.act_fn()(y), state
         with jax.named_scope("moe/router"):
-            choice, weight, probs = self.route(params, u)
-            aux = self.seq_aux_term(choice, probs)
+            choice, weight, probs = self.route(params, u,
+                                               state.get("router_bias"))
+            if self.router == "softmax":
+                aux = self.seq_aux_term(choice, probs)
         k = self.experts_per_token
         routed, rows = self.routed_part(params, u.reshape(B * T, F),
                                         choice.reshape(B * T, k),
@@ -279,6 +367,28 @@ class DecoderBlock(FeedForwardLayer):
         if self.shared_hidden:
             with jax.named_scope("moe/shared"):
                 f = f + self.shared_part(params, u)
-        new_state = {"aux_loss": aux if train else jnp.zeros_like(aux),
-                     "moe_rows": rows}
+        if sandwich:
+            f = self._norm(params, "post2", f)
+        if self.router == "sigmoid_bias":
+            bias = state["router_bias"]
+            if train:
+                with jax.named_scope("update"):
+                    bias = self.next_bias(bias, choice)
+            new_state = {"router_bias": bias, "moe_rows": rows}
+        else:
+            new_state = {"aux_loss": aux if train else jnp.zeros_like(aux),
+                         "moe_rows": rows}
         return self.act_fn()(h + f), new_state
+
+    def attn_score_entries(self, batch: int, seq: int, dtype) -> tuple:
+        """``(computed, visible)`` score entries of one step's forward core
+        over ``batch`` sequences of ``seq`` tokens in ``dtype``: what the
+        flash kernel's plan computes under this block's mask and what the
+        mask leaves visible (``pallas_kernels.flash_score_entries``)."""
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_score_entries
+
+        dv = self.head_dim if self.attention == "gqa" else self.v_dim
+        computed, visible = flash_score_entries(seq, self._qk_dim(), dv,
+                                                dtype, self.window)
+        heads = batch * self.n_heads
+        return heads * computed, heads * visible

@@ -133,6 +133,9 @@ class EmbeddingLayer(FeedForwardLayer):
 
     #: False: the table alone, no "b" leaf (current language models)
     has_bias: bool = True
+    #: factor on the looked-up vector (``sqrt(n_out)`` where a model scales
+    #: its embedding's output), applied in the table's dtype
+    output_scale: float = 1.0
 
     def init_params(self, key, itype: InputType) -> dict:
         p = {"W": self._init_w(key, (self.n_in, self.n_out))}
@@ -158,6 +161,8 @@ class EmbeddingLayer(FeedForwardLayer):
         emb = params["W"][idx]
         if "b" in params:
             emb = emb + params["b"]
+        if self.output_scale != 1.0:
+            emb = emb * jnp.asarray(self.output_scale, emb.dtype)
         return self.act_fn()(emb.astype(pol.output_dtype)), state
 
 

@@ -43,6 +43,7 @@ from deeplearning4j_tpu.observability.flight_recorder import (
     global_recorder as _flight_recorder,
 )
 from deeplearning4j_tpu.observability.names import (
+    ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
     FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
     MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
 )
@@ -96,6 +97,15 @@ _moe_rows_max_total = _obs_registry().counter(
     MOE_EXPERT_ROWS_MAX_TOTAL, "rows of the busiest held expert, summed over "
     "the steps (over the routed rows / experts held of the same steps: how "
     "uneven the routing was), by expert layer")
+
+
+_attn_computed = _obs_registry().counter(
+    ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, "attention score entries the forward "
+    "cores of dispatched steps computed (the flash kernel's tiles x their "
+    "area, or the whole square on the XLA path), by decoder block")
+_attn_visible = _obs_registry().counter(
+    ATTN_SCORE_ENTRIES_VISIBLE_TOTAL, "attention score entries the masks of "
+    "the same cores leave visible, by decoder block")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -517,6 +527,31 @@ class LazyScore:
             self._score_raw = raw
         return raw
 
+    def _note_attn_entries(self, steps: int, x) -> None:
+        """Book ``steps`` dispatched steps' attention score entries
+        (``dl4j_attn_score_entries_*``) for the decoder blocks: a function
+        of the batch's shape and each block's mask alone, so nothing is read
+        from the device."""
+        layers = getattr(getattr(self, "conf", None), "layers", None) or ()
+        blocks = [(i, l) for i, l in enumerate(layers)
+                  if hasattr(l, "attn_score_entries")]
+        if not blocks:
+            return
+        shape = jax.tree_util.tree_leaves(x)[0].shape
+        key = (steps, shape)
+        if getattr(self, "_attn_entries_key", None) != key:
+            batch, seq = shape[-2:]
+            name = self.conf.global_conf.dtype
+            dtype = (common.resolve_policy(name) if name
+                     else common.get_policy()).output_dtype
+            self._attn_entries_key = key
+            self._attn_entries = [
+                (str(i), *l.attn_score_entries(batch, seq, dtype))
+                for i, l in blocks]
+        for layer, computed, visible in self._attn_entries:
+            _attn_computed.labels(layer=layer).inc(steps * computed)
+            _attn_visible.labels(layer=layer).inc(steps * visible)
+
     #: ``(rows (K, layers, 3) on the device, tokens)`` of the dispatched
     #: groups whose expert-layer rows the host has not read yet
     _pending_moe_rows: tuple = ()
@@ -928,6 +963,7 @@ class LazyScore:
             self._pending_moe_rows = (*self._pending_moe_rows,
                                       (rest[0], tokens))
             self._note_moe_rows()
+        self._note_attn_entries(n, x)
         fields = {} if owner is None else owner.note_steps(n)
         self._book_steps(name, n, losses if multi else [losses], t0_ns,
                          t1_ns, dt, **fields)

@@ -50,6 +50,8 @@ MOE_ROUTED_ROWS_TOTAL = "dl4j_moe_routed_rows_total"
 MOE_COMPUTED_ROWS_TOTAL = "dl4j_moe_computed_rows_total"
 MOE_EXPERT_ROWS_MAX = "dl4j_moe_expert_rows_max"
 MOE_EXPERT_ROWS_MAX_TOTAL = "dl4j_moe_expert_rows_max_total"
+ATTN_SCORE_ENTRIES_COMPUTED_TOTAL = "dl4j_attn_score_entries_computed_total"
+ATTN_SCORE_ENTRIES_VISIBLE_TOTAL = "dl4j_attn_score_entries_visible_total"
 
 # --- recurrent engine (ops/lstm.py) ----------------------------------------
 LSTM_DISPATCH_TOTAL = "dl4j_lstm_dispatch_total"

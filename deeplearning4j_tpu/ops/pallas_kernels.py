@@ -59,18 +59,24 @@ _VMEM_BUDGET = 14 * 1024 * 1024
 _VMEM_CEILING = 32 * 1024 * 1024
 
 
-def _causal_mask(s, q0, k0, q_axis: int = 0):
-    """Mask a score tile to q_pos >= k_pos, where the tile starts at absolute
+def _causal_mask(s, q0, k0, q_axis: int = 0, window: int = None):
+    """Mask a score tile to q_pos >= k_pos, and under a ``window`` to
+    q_pos - k_pos < window as well, where the tile starts at absolute
     positions (q0, k0) and its queries run along ``q_axis`` (0: S, the
     forward's; 1: S transposed, the backward's). ONE shared convention for
     the forward and the backward kernel — they must never disagree."""
     ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
              - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return jnp.where(ahead >= k0 - q0, s, _NEG)
+    keep = ahead >= k0 - q0
+    if window is not None:
+        keep = jnp.logical_and(keep, ahead < k0 - q0 + window)
+    return jnp.where(keep, s, _NEG)
 
 
 def _flatten_heads(a):
-    """(B, T, H, D) -> (B*H, T, D) kernel layout."""
+    """(B, T, H, D) -> (B*H, T, D) kernel layout: head ``h`` of sequence
+    ``b`` is row ``b * H + h``, so with G key/value heads under H query heads
+    row ``bh`` of the queries reads row ``bh // (H // G)`` of the keys."""
     B, T, H, D = a.shape
     return a.transpose(0, 2, 1, 3).reshape(B * H, T, D)
 
@@ -158,7 +164,23 @@ def _causal_block_crossed(qi, kj, blk_q: int, blk_k: int):
 _QUARTERED_FROM = 512
 
 
-def _per_causal_tile(tile, causal: bool, qi, kj, blk_q: int, blk_k: int):
+def _tile_state(q0, nq: int, k0, nk: int, window: int):
+    """``(live, crossed)`` of the ``nq`` queries from ``q0`` against the
+    ``nk`` keys from ``k0`` under a causal mask with a ``window``: key j is
+    visible to query i where ``0 <= i - j < window``. Live: some score is
+    visible; crossed: some is hidden, so the tile needs the mask. Integers,
+    numpy arrays (``flash_score_entries``) or traced scalars alike."""
+    d_max, d_min = q0 + (nq - 1) - k0, q0 - k0 - (nk - 1)
+    return ((d_max >= 0) & (d_min < window),
+            (d_min < 0) | (d_max >= window))
+
+
+def _quartered(blk_q: int, blk_k: int) -> bool:
+    return blk_q == blk_k and blk_q >= _QUARTERED_FROM
+
+
+def _per_causal_tile(tile, causal: bool, qi, kj, blk_q: int, blk_k: int,
+                     window: int = None, valid=True):
     """Run ``tile(masked, rows, cols)`` over one (q-block, k-block) tile of a
     flash kernel, forward or backward; ``rows`` and ``cols`` are the static
     slices of the tile's queries and keys a call covers. A dead tile is
@@ -167,16 +189,43 @@ def _per_causal_tile(tile, causal: bool, qi, kj, blk_q: int, blk_k: int):
     masked body. Square tiles are crossed where q-block == k-block, corner
     to corner: from ``_QUARTERED_FROM`` rows such a tile runs as three
     quarters (two masked ones on the diagonal, the plain one below it) and
-    its dead quarter is left out."""
+    its dead quarter is left out.
+
+    Under a ``window`` a tile is dead on either side of the band, plain
+    inside it and crossed on either edge (``_tile_state``); a crossed
+    quarterable tile runs quarter by quarter, each dead, plain or masked by
+    the same rule, wherever the edges fall. ``valid`` False marks a grid step
+    beyond the last block (the window's shortened block axis)."""
     whole = pl.ds(0, blk_q), pl.ds(0, blk_k)
     if not causal:
         tile(False, *whole)
+        return
+    if window is not None:
+        q0, k0 = qi * blk_q, kj * blk_k
+        live, crossed = _tile_state(q0, blk_q, k0, blk_k, window)
+        live = jnp.logical_and(live, valid)
+        pl.when(jnp.logical_and(live, jnp.logical_not(crossed)))(
+            lambda: tile(False, *whole))
+        edge = jnp.logical_and(live, crossed)
+        if not _quartered(blk_q, blk_k):
+            pl.when(edge)(lambda: tile(True, *whole))
+            return
+        half = blk_q // 2
+        for r0 in (0, half):
+            for c0 in (0, half):
+                rows, cols = pl.ds(r0, half), pl.ds(c0, half)
+                l, c = _tile_state(q0 + r0, half, k0 + c0, half, window)
+                l = jnp.logical_and(edge, l)
+                pl.when(jnp.logical_and(l, jnp.logical_not(c)))(
+                    lambda rows=rows, cols=cols: tile(False, rows, cols))
+                pl.when(jnp.logical_and(l, c))(
+                    lambda rows=rows, cols=cols: tile(True, rows, cols))
         return
     crossed = _causal_block_crossed(qi, kj, blk_q, blk_k)
     pl.when(jnp.logical_not(crossed))(lambda: tile(False, *whole))
     on_diagonal = jnp.logical_and(
         crossed, _causal_block_live(qi, kj, blk_q, blk_k))
-    if blk_q == blk_k and blk_q >= _QUARTERED_FROM:
+    if _quartered(blk_q, blk_k):
         half = blk_q // 2
         lo, hi = pl.ds(0, half), pl.ds(half, half)
 
@@ -187,6 +236,63 @@ def _per_causal_tile(tile, causal: bool, qi, kj, blk_q: int, blk_k: int):
             tile(True, hi, hi)
     else:
         pl.when(on_diagonal)(lambda: tile(True, *whole))
+
+
+def _first_live(start, window: int, blk: int):
+    """The first block of ``blk`` positions that holds a position within
+    ``window`` back from ``start``: a q-block's first live k-block."""
+    return jnp.maximum(start - (window - 1), 0) // blk
+
+
+def _live_tiles(n_q: int, blk_q: int, n_k: int, blk_k: int, window: int):
+    """``(live, crossed)`` of every (q-block, k-block) tile under a window,
+    as numpy matrices [n_q, n_k] (``_tile_state`` over the whole grid)."""
+    import numpy as np
+
+    q0 = (np.arange(n_q, dtype=np.int64) * blk_q)[:, None]
+    k0 = (np.arange(n_k, dtype=np.int64) * blk_k)[None, :]
+    return (q0, k0) + _tile_state(q0, blk_q, k0, blk_k, window)
+
+
+def _window_steps(n_q: int, blk_q: int, n_k: int, blk_k: int, window: int,
+                  stream_k: bool) -> int:
+    """Grid steps of the streamed block axis under a window: the most live
+    tiles any block of the other axis has (they lie side by side: q-blocks
+    stream k-blocks from ``_first_live`` up to the diagonal; k-blocks stream
+    q-blocks from the diagonal up to the window's far edge)."""
+    live = _live_tiles(n_q, blk_q, n_k, blk_k, window)[2]
+    return int(live.sum(axis=1 if stream_k else 0).max())
+
+
+def flash_score_entries(tq: int, dk: int, dv: int, dtype, window: int = None,
+                        blk_q: int = None, blk_k: int = None,
+                        engaged: bool = None) -> tuple:
+    """``(computed, visible)`` score entries of one head's causal forward at
+    ``tq`` positions under the tiles the shapes give: what the kernel's plan
+    computes (whole plain tiles, the live quarters or the whole of a crossed
+    one) and what the mask leaves visible. The backward computes the same
+    tiles. Where the kernel does not engage (``engaged`` None: as
+    ``flash_attention`` decides on this device), the XLA math computes all
+    ``tq * tq``."""
+    w = tq if window is None else min(window, tq)   # causal: a window of tq
+    visible = w * (w + 1) // 2 + (tq - w) * w
+    if engaged is None:
+        engaged = use_pallas() and tq >= _MIN_SEQ
+    tiles = engaged and _flash_tiles(tq, tq, dk, dv, dtype, blk_q, blk_k)
+    if not tiles or tq % tiles[0] or tq % tiles[1]:
+        return tq * tq, visible
+    bq, bk = tiles
+    q0, k0, live, crossed = _live_tiles(tq // bq, bq, tq // bk, bk, w)
+    computed = int((live & ~crossed).sum()) * bq * bk
+    edge = live & crossed
+    if not _quartered(bq, bk):
+        return computed + int(edge.sum()) * bq * bk, visible
+    half = bq // 2
+    for r0 in (0, half):
+        for c0 in (0, half):
+            quarter = _tile_state(q0 + r0, half, k0 + c0, half, w)[0]
+            computed += int((edge & quarter).sum()) * half * half
+    return computed, visible
 
 
 def _lanes(d: int) -> int:
@@ -233,7 +339,8 @@ def _flash_params(semantics, need: int):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
-                      blk_k: int, scale: float, has_mask: bool):
+                      blk_k: int, scale: float, has_mask: bool,
+                      window: int = None):
     """One (batch*head, q-block, k-block) program of the online softmax.
 
     The k-block axis is the innermost, sequential grid axis: K/V arrive one
@@ -249,16 +356,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
     online-softmax pass. With has_mask, a (1, blk_k, 1) {0,1} key-padding
     mask block precedes the outputs: masked keys get -inf logits. Under a
     causal mask a tile wholly above the diagonal is skipped and only a tile
-    the diagonal crosses is masked (``_per_causal_tile``).
+    the diagonal crosses is masked (``_per_causal_tile``). Under a
+    ``window`` the k-block axis is as long as the band is wide
+    (``_window_steps``) and starts at the q-block's first live k-block. A
+    row with no visible key in a tile leaves ``exp(0)`` behind; the row's
+    diagonal tile, its last, wipes that with ``alpha = 0``.
     """
     if has_mask:
         km_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
     else:
         o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = kj = pl.program_id(2)
+    if window is not None:
+        kj = _first_live(qi * blk_q, window, blk_k) + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
@@ -272,7 +385,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
             s = jnp.where(km_blk[None, :] > 0, s, _NEG)
         if masked:
             s = _causal_mask(s, qi * blk_q + rows.start,
-                             kj * blk_k + cols.start)
+                             kj * blk_k + cols.start, window=window)
         m = m_sc[rows, :]                                 # (rows, 1)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -288,9 +401,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
             p.astype(v_blk.dtype), v_blk, 1, 0)
         m_sc[rows, :] = m_new
 
-    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k)
+    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k, window)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_sc[...], 1e-20)
         o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
@@ -311,15 +424,29 @@ def _bh_mask(key_mask: Array, H: int) -> Array:
                             (B, H, Tk)).reshape(B * H, Tk, 1)
 
 
-def _kv_block(causal: bool, blk_q: int, blk_k: int):
+def _kv_row(group: int):
+    """Row of the flattened keys and values that row ``bh`` of the flattened
+    queries reads, ``group`` query heads to a key/value head."""
+    return (lambda bh: bh) if group == 1 else (lambda bh: bh // group)
+
+
+def _kv_block(causal: bool, blk_q: int, blk_k: int, window: int = None,
+              group: int = 1):
     """Index map of a K/V block on a (bh, q-block, k-block) grid. Under a
     causal mask a tile above the diagonal is never computed
     (``_causal_block_live``) and names the diagonal's block again
-    (``_diagonal_k_block``)."""
+    (``_diagonal_k_block``); under a ``window`` the k-block axis counts from
+    the q-block's first live block. ``group`` query heads share a key/value
+    head: ``group`` consecutive rows of the grid read the same K/V row."""
+    row = _kv_row(group)
     if not causal:
-        return lambda bh, i, j: (bh, j, 0)
+        return lambda bh, i, j: (row(bh), j, 0)
+    if window is None:
+        return lambda bh, i, j: (
+            row(bh), jnp.minimum(j, _diagonal_k_block(i, blk_q, blk_k)), 0)
     return lambda bh, i, j: (
-        bh, jnp.minimum(j, _diagonal_k_block(i, blk_q, blk_k)), 0)
+        row(bh), jnp.minimum(j + _first_live(i * blk_q, window, blk_k),
+                             _diagonal_k_block(i, blk_q, blk_k)), 0)
 
 
 def _flash_tiles(tq: int, tk: int, dk: int, dv: int, dtype,
@@ -357,24 +484,50 @@ def _tiles_or_raise(tq: int, tk: int, *args, **kwargs):
     return tiles
 
 
+def _head_group(q: Array, k: Array, v: Array, key_mask=None) -> int:
+    """Query heads to a key/value head (1: as many of each)."""
+    H, G = q.shape[2], k.shape[2]
+    if v.shape[2] != G or H % G or (key_mask is not None and H != G):
+        raise ValueError(f"{H} query heads over {G} key and {v.shape[2]} "
+                         "value heads" + (" under a key mask"
+                                          if key_mask is not None else ""))
+    return H // G
+
+
+def _check_window(window, causal: bool, tq: int, tk: int, key_mask=None):
+    if window is None:
+        return
+    if not causal or tq != tk or key_mask is not None or window < 1:
+        raise ValueError(
+            "a window needs causal self-attention (Tq == Tk) without a key "
+            f"mask: causal {causal}, lengths ({tq},{tk}), window {window}")
+
+
 def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
                    blk_q: int = None, blk_k: int = None,
                    interpret: bool = False, key_mask: Array = None,
-                   scale: float = None):
-    """q,k: (B, T, H, Dk), v: (B, T, H, Dv) -> (out (B, T, H, Dv), lse
-    (B*H, Tq) f32). None block sizes -> chosen from the shapes
-    (``_flash_tiles``). key_mask: optional [B, Tk] {0,1} key-padding mask.
-    ``scale`` None is ``Dk ** -0.5``."""
+                   scale: float = None, window: int = None):
+    """q: (B, T, H, Dk), k: (B, T, G, Dk), v: (B, T, G, Dv) -> (out (B, T, H,
+    Dv), lse (B*H, Tq) f32); G divides H, and query head h reads key/value
+    head ``h // (H // G)`` from where it lies, never repeated. None block
+    sizes -> chosen from the shapes (``_flash_tiles``). key_mask: optional
+    [B, Tk] {0,1} key-padding mask. ``scale`` None is ``Dk ** -0.5``.
+    ``window``: a query sees the ``window`` keys ending at itself."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
+    group = _head_group(q, k, v, key_mask)
+    _check_window(window, causal, Tq, Tk, key_mask)
     blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     has_mask = key_mask is not None
 
     kernel = functools.partial(_flash_fwd_kernel, causal=causal, blk_q=blk_q,
-                               blk_k=blk_k, scale=scale, has_mask=has_mask)
-    kv = _kv_block(causal, blk_q, blk_k)
+                               blk_k=blk_k, scale=scale, has_mask=has_mask,
+                               window=window)
+    kv = _kv_block(causal, blk_q, blk_k, window, group)
+    n_k = Tk // blk_k if window is None else _window_steps(
+        Tq // blk_q, blk_q, Tk // blk_k, blk_k, window, True)
     in_specs = [
         pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, blk_k, D), kv),
@@ -386,7 +539,7 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
         operands.append(_bh_mask(key_mask, H))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, Tq // blk_q, Tk // blk_k),
+        grid=(B * H, Tq // blk_q, n_k),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, blk_q, Dv), lambda bh, i, j: (bh, i, 0)),
@@ -410,11 +563,22 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
     return _unflatten_heads(out, B, H), lse[:, :, 0]
 
 
-def _attention_xla(q, k, v, causal, scale=None):
+def _repeat_kv(q, k, v):
+    """K and V with every key/value head repeated for the query heads that
+    share it: the XLA fallbacks' way (the Pallas path never repeats one)."""
+    group = _head_group(q, k, v)
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+def _attention_xla(q, k, v, causal, scale=None, window=None):
     # Single source of truth for the reference math (also the ring-attention
     # correctness oracle) — keep one copy so masking/scaling can't diverge.
     from deeplearning4j_tpu.parallel.ring_attention import attention_reference
-    return attention_reference(q, k, v, causal, scale).astype(q.dtype)
+    _check_window(window, causal, q.shape[1], k.shape[1])
+    k, v = _repeat_kv(q, k, v)
+    return attention_reference(q, k, v, causal, scale, window).astype(q.dtype)
 
 
 def _in_shard_map() -> bool:
@@ -606,15 +770,21 @@ def _masked_bwd_rule(causal, interpret, force, res, g):
 _masked_attention_vjp.defvjp(_masked_fwd_rule, _masked_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
                     interpret: bool = False,
                     force_pallas: bool = False,
-                    scale: float = None) -> Array:
+                    scale: float = None, window: int = None) -> Array:
     """Tiled attention: pallas forward on TPU (shapes that don't tile fall
     back to the identical XLA math rather than erroring), XLA elsewhere.
     ``v`` may be narrower or wider than ``q`` and ``k`` (latent attention:
     192-wide keys, 128-wide values); ``scale`` None is ``Dk ** -0.5``.
+    ``k`` and ``v`` may have fewer heads than ``q`` (grouped heads: query
+    head h reads key/value head ``h // (H // G)``, never repeated in memory
+    on the Pallas path; dK and dV sum over the group). ``window`` (causal
+    self-attention only): a query sees the ``window`` keys ending at itself;
+    tiles wholly outside the band are neither fetched nor computed, forward
+    or backward, and only the tiles an edge crosses are masked.
     Backward is tiled pallas too, recomputing P from the saved logsumexp
     (flash-attention practice: trade FLOPs for HBM; peak extra memory
     O(blk·T), never O(Tq·Tk)): ONE kernel for dQ, dK and dV where a head's
@@ -646,17 +816,25 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     tileable lengths, and the vma-checked shard_map guard, where
     pallas_call would be rejected outright."""
     ok = _pallas_ok(q, k, interpret, force_pallas)
-    _note_dispatch("flash_attention", ok)
+    _note_dispatch("flash_attention" + _variant(q, k, window), ok)
     if ok:
         return _flash_forward(q, k, v, causal, interpret=interpret,
-                              scale=scale)[0]
-    return _attention_xla(q, k, v, causal, scale)
+                              scale=scale, window=window)[0]
+    return _attention_xla(q, k, v, causal, scale, window)
+
+
+def _variant(q, k, window) -> str:
+    """Suffix of a dispatch note's kernel name: which plan the call takes
+    (``_window``, ``_grouped``), so a fallback of either is seen by name."""
+    return (("_window" if window is not None else "")
+            + ("_grouped" if k.shape[2] != q.shape[2] else ""))
 
 
 # --------------------------------------------------- pallas backward kernel
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       causal: bool, blk_q: int, blk_k: int, scale: float,
-                      has_mask: bool, want_dq: bool, want_dkv: bool):
+                      has_mask: bool, want_dq: bool, want_dkv: bool,
+                      window: int = None, n_q: int = None):
     """One (q-block, k-block) tile of the backward, for whichever of dQ and
     dK/dV the call wants — ONE body, so each score tile is recomputed, masked
     and exponentiated once for every gradient it feeds:
@@ -676,7 +854,11 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     and dK += dS^T Q are plain products, and only dQ += (dS^T)^T K contracts
     the sublane axis. The score scale of dS is applied once to the float32
     accumulators instead of to every score. Dead causal tiles are skipped
-    and only tiles the diagonal crosses are masked, as in the forward."""
+    and only tiles the diagonal crosses are masked, as in the forward. Under
+    a ``window`` the streamed block axis (the innermost) is as long as the
+    band is wide and counts from the outer block's first live tile: the
+    diagonal's q-block where q-blocks stream (a step past the ``n_q``
+    q-blocks is dead), ``_first_live`` where k-blocks do."""
     rest = list(rest)
     km_ref = rest.pop(0) if has_mask else None
     n_out = want_dq + 2 * want_dkv
@@ -687,6 +869,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     kj = pl.program_id(3 - q_axis)
     first_q, last_q = qi == 0, qi == pl.num_programs(q_axis) - 1
     first_k, last_k = kj == 0, kj == pl.num_programs(3 - q_axis) - 1
+    valid = True
+    if window is not None and want_dkv:
+        qi = (kj * blk_k) // blk_q + qi
+        valid = qi < n_q
+    elif window is not None:
+        kj = _first_live(qi * blk_q, window, blk_k) + kj
     if want_dq:
         dq_ref, dq_sc = outs[0], scratch[0]
 
@@ -710,7 +898,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             st = jnp.where(km_ref[0, cols, :] > 0, st, _NEG)   # a column
         if masked:
             st = _causal_mask(st, qi * blk_q + rows.start,
-                              kj * blk_k + cols.start, q_axis=1)
+                              kj * blk_k + cols.start, q_axis=1,
+                              window=window)
         pt = jnp.exp(st - lse_ref[0, :, rows])            # a (1, rows) row
         if has_mask:
             # masked entries clamp to P = 0 rather than exp(S - lse): for a
@@ -729,7 +918,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                        rows.size) if fused else rows
             dq_sc[at, :] += _dot(dst, k_blk, 0, 0)        # (rows, Dk)
 
-    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k)
+    _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k, window, valid)
 
     if want_dq:
         @pl.when(jnp.logical_and(last_k, last_q) if fused else last_k)
@@ -759,14 +948,19 @@ def _fused_bwd_fits(tq: int, dk: int, dtype) -> bool:
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     blk_k: int = None, interpret: bool = False,
                     key_mask: Array = None, scale: float = None,
-                    fused: bool = None):
+                    fused: bool = None, window: int = None):
     """Tiled pallas backward from the saved forward logsumexp. key_mask:
     optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
     ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``.
     ``fused`` None: one kernel where a head's dQ fits VMEM
-    (``_fused_bwd_fits``), else the dQ + dK/dV pair."""
+    (``_fused_bwd_fits``), else the dQ + dK/dV pair. With fewer key/value
+    heads than query heads every query head reads its key/value head in
+    place and writes its own part of dK and dV, which are summed over the
+    group afterwards (float32). ``window`` as the forward's."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
+    group = _head_group(q, k, v, key_mask)
+    _check_window(window, causal, Tq, Tk, key_mask)
     blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k,
                                    backward=True)
     if fused is None:
@@ -793,23 +987,34 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         # under a causal mask a dead tile (``_causal_block_live``) names the
         # nearest live tile's streamed block again, so nothing is fetched
         # for it: the k-block is held at the diagonal where the k axis
-        # streams (dQ alone), the q-block at the first live one where q does
+        # streams (dQ alone), the q-block at the first live one where q does.
+        # Under a window the streamed axis counts from the first live block
+        # and is held at the last
         def q_idx(*g):
             i, j = g[q_pos], g[3 - q_pos]
-            if causal and q_pos == 2:
+            if causal and q_pos == 2 and window is not None:
+                i = jnp.minimum(i + (j * blk_k) // blk_q, nq - 1)
+            elif causal and q_pos == 2:
                 i = jnp.maximum(i, (j * blk_k) // blk_q)
             return i
 
         def k_idx(*g):
             i, j = g[q_pos], g[3 - q_pos]
+            if causal and q_pos == 1 and window is not None:
+                j = j + _first_live(i * blk_q, window, blk_k)
             if causal and q_pos == 1:
                 j = jnp.minimum(j, _diagonal_k_block(i, blk_q, blk_k))
             return j
+
+        kv_row = _kv_row(group)
 
         def q_map(*g):
             return (g[0], q_idx(*g), 0)
 
         def k_map(*g):
+            return (kv_row(g[0]), k_idx(*g), 0)
+
+        def km_map(*g):
             return (g[0], k_idx(*g), 0)
 
         def row_map(*g):
@@ -822,7 +1027,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     pl.BlockSpec((1, 1, blk_q), row_map),
                     pl.BlockSpec((1, 1, blk_q), row_map)]
         if has_mask:
-            in_specs.append(pl.BlockSpec((1, blk_k, 1), k_map))
+            in_specs.append(pl.BlockSpec((1, blk_k, 1), km_map))
         out_specs, out_shape, scratch = [], [], []
         if want_dq:
             out_specs.append(
@@ -844,12 +1049,20 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         semantics = ("parallel",
                      "arbitrary" if whole else "parallel",
                      "arbitrary")
+        if window is None:
+            grid = (B * H, nk, nq) if want_dkv else (B * H, nq, nk)
+        elif want_dkv:
+            grid = (B * H, nk, _window_steps(nq, blk_q, nk, blk_k, window,
+                                             False))
+        else:
+            grid = (B * H, nq, _window_steps(nq, blk_q, nk, blk_k, window,
+                                             True))
         return pl.pallas_call(
             functools.partial(
                 _flash_bwd_kernel, causal=causal, blk_q=blk_q, blk_k=blk_k,
                 scale=scale, has_mask=has_mask, want_dq=want_dq,
-                want_dkv=want_dkv),
-            grid=(B * H, nk, nq) if want_dkv else (B * H, nq, nk),
+                want_dkv=want_dkv, window=window, n_q=nq),
+            grid=grid,
             in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=scratch,
             compiler_params=_flash_params(semantics, _flash_vmem_bytes(
@@ -862,12 +1075,16 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         dq, dk, dv = call(True, True)
     else:
         (dq,), (dk, dv) = call(True, False), call(False, True)
-    return (_unflatten_heads(dq, B, H), _unflatten_heads(dk, B, H),
-            _unflatten_heads(dv, B, H))
+    if group > 1:
+        dk, dv = (a.reshape(B * H // group, group, Tk, a.shape[-1])
+                  .astype(jnp.float32).sum(1).astype(a.dtype)
+                  for a in (dk, dv))
+    return (_unflatten_heads(dq, B, H), _unflatten_heads(dk, B, H // group),
+            _unflatten_heads(dv, B, H // group))
 
 
 def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
-                           scale: float = None):
+                           scale: float = None, window: int = None):
     """Chunked attention backward: lax.scan over query blocks, recomputing the
     (blk_q, Tk) score tile per step. dK/dV accumulate in f32 in the carry.
 
@@ -875,11 +1092,14 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
     dV = Pᵀ dO, dP = dO Vᵀ, dS = P ∘ (dP − rowsum(P ∘ dP)), dQ = dS·K·scale,
     dK = dSᵀ·Q·scale. Query rows padded up to a block multiple carry dO = 0,
     which makes their dS exactly 0, so padding contributes nothing.
-    None blk_q -> 128 rows.
+    None blk_q -> 128 rows. Grouped key/value heads are repeated here and
+    their gradients summed over the group; ``window`` as the forward's.
     """
     blk_q = blk_q or 128
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
+    _check_window(window, causal, Tq, Tk)
+    kv_heads, (k, v) = k.shape[2], _repeat_kv(q, k, v)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     blk_q = min(blk_q, Tq)
     pad = (-Tq) % blk_q
@@ -901,6 +1121,9 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
         if causal:
             q_pos = idx * blk_q + jnp.arange(blk_q)
             mask = q_pos[:, None] >= jnp.arange(Tk)[None, :]
+            if window is not None:
+                mask = jnp.logical_and(
+                    mask, q_pos[:, None] - jnp.arange(Tk)[None, :] < window)
             s = jnp.where(mask[None, None], s, _NEG)
         p = jax.nn.softmax(s, axis=-1)
         dp = jnp.einsum("bqhd,bkhd->bhqk", gc, vf)
@@ -916,6 +1139,9 @@ def _attention_bwd_chunked(q, k, v, g, causal, blk_q: int = None,
     (dk, dv), dqs = jax.lax.scan(
         chunk, ((kf * 0.0), (vf * 0.0)), (qs, gs, jnp.arange(n)))
     dq = dqs.transpose(1, 0, 2, 3, 4).reshape(B, Tq + pad, H, D)[:, :Tq]
+    if kv_heads != H:
+        dk, dv = (a.reshape(B, Tk, kv_heads, H // kv_heads, a.shape[-1])
+                  .sum(3) for a in (dk, dv))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -934,28 +1160,32 @@ def _pallas_bwd_enabled(seq_k: int = None, force: bool = False) -> bool:
     return force or seq_k is None or seq_k >= _PBWD_MIN_SEQ
 
 
-def _flash_fwd_rule(q, k, v, causal, interpret, force, scale):
+def _flash_fwd_rule(q, k, v, causal, interpret, force, scale, window):
     tiled_bwd = (_pallas_ok(q, k, interpret, force)
                  and _pallas_bwd_enabled(k.shape[1], force))
-    _note_dispatch("flash_attention_bwd", tiled_bwd)
+    variant = _variant(q, k, window)
+    _note_dispatch("flash_attention_bwd" + variant, tiled_bwd)
     # which backward the program holds: one kernel, or the dQ + dK/dV pair
-    _note_dispatch("flash_attention_bwd_fused", tiled_bwd and _fused_bwd_fits(
-        q.shape[1], q.shape[-1], q.dtype))
+    _note_dispatch("flash_attention_bwd_fused" + variant,
+                   tiled_bwd and _fused_bwd_fits(
+                       q.shape[1], q.shape[-1], q.dtype))
     if tiled_bwd:
-        _note_dispatch("flash_attention", True)
+        _note_dispatch("flash_attention" + variant, True)
         out, lse = _flash_forward(q, k, v, causal, interpret=interpret,
-                                  scale=scale)
+                                  scale=scale, window=window)
         return out, (q, k, v, out, lse)
-    return (flash_attention(q, k, v, causal, interpret, force, scale),
+    return (flash_attention(q, k, v, causal, interpret, force, scale, window),
             (q, k, v, None, None))
 
 
-def _flash_bwd_rule(causal, interpret, force, scale, res, g):
+def _flash_bwd_rule(causal, interpret, force, scale, window, res, g):
     q, k, v, out, lse = res
     if lse is not None:
         return _flash_backward(q, k, v, out, lse, g, causal,
-                               interpret=interpret, scale=scale)
-    return _attention_bwd_chunked(q, k, v, g, causal, scale=scale)
+                               interpret=interpret, scale=scale,
+                               window=window)
+    return _attention_bwd_chunked(q, k, v, g, causal, scale=scale,
+                                  window=window)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -43,11 +43,12 @@ _collective_per_step = _obs_registry().gauge(
 
 
 def attention_reference(q: Array, k: Array, v: Array, causal: bool = False,
-                        scale=None) -> Array:
+                        scale=None, window=None) -> Array:
     """Plain full-sequence softmax attention (the correctness oracle).
 
     Shapes: q,k = (B, T, H, Dk), v = (B, T, H, Dv) -> (B, T, H, Dv);
-    ``scale`` None is ``Dk ** -0.5``.
+    ``scale`` None is ``Dk ** -0.5``; under ``window`` (causal only) a query
+    sees the ``window`` keys ending at itself.
     """
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
@@ -55,6 +56,9 @@ def attention_reference(q: Array, k: Array, v: Array, causal: bool = False,
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.arange(tq)[:, None]
+                                   - jnp.arange(tk)[None, :] < window)
         s = jnp.where(mask, s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)

@@ -173,3 +173,29 @@ def test_embedding_int_ids_not_mistaken_for_onehot():
     out, _ = lyr.apply(params, {}, ids)
     expect = params["W"][jnp.asarray([0, 3, 2, 1])] + params["b"]
     np.testing.assert_allclose(np.asarray(out[0]), np.asarray(expect))
+
+
+@pytest.mark.parametrize("kv_heads,window", [(4, 6), (2, None), (1, 5)])
+def test_attend_passes_a_window_and_grouped_heads_to_the_core(kv_heads,
+                                                               window):
+    """``attend`` hands a window and fewer key/value heads to the
+    single-device core (the XLA math on the CPU) and refuses them, by name,
+    under a key mask."""
+    from deeplearning4j_tpu.nn.conf.layers.attention import attend
+    from deeplearning4j_tpu.parallel.ring_attention import attention_reference
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, 16, 4, 8))
+    k, v = (jax.random.normal(kk, (2, 16, kv_heads, 8)) for kk in ks[1:])
+    got = attend(q, k, v, True, window=window)
+    rep = 4 // kv_heads
+    want = attention_reference(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
+                               True, None, window)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    if window is not None:
+        # position 15 sees the last `window` keys only
+        far = k.at[:, :16 - window].set(100.0)
+        np.testing.assert_allclose(attend(q, far, v, True, window=window)[:, -1],
+                                   got[:, -1], atol=1e-5)
+    with pytest.raises(NotImplementedError, match="window.*fewer key/value"):
+        attend(q, k, v, True, mask=jnp.ones((2, 16)), window=window)

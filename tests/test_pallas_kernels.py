@@ -657,7 +657,7 @@ def test_force_pallas_bypasses_length_gate_not_hard_constraints(monkeypatch):
     calls = []
 
     def fake_forward(qq, kk, vv, causal, interpret=False, key_mask=None,
-                     scale=None):
+                     scale=None, window=None):
         calls.append(1)
         if key_mask is not None:
             return pk._masked_attention_xla(qq, kk, vv, key_mask, causal), None
@@ -695,3 +695,134 @@ def test_force_pallas_bypasses_length_gate_not_hard_constraints(monkeypatch):
     assert not calls, "force_pallas must not override the checked-shard_map guard"
     np.testing.assert_allclose(np.asarray(got), np.asarray(out),
                                rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------------ window and grouped heads
+def _masked_f32(q, k, v, window, scale=None):
+    """Causal attention cut to ``window`` keys, head h reading key/value
+    head ``h // (H // G)``: masked float32 math, one head at a time."""
+    B, T, H, D = q.shape
+    G = k.shape[2]
+    ahead = np.arange(T)[:, None] - np.arange(T)[None, :]
+    seen = (ahead >= 0) & (ahead < (window or T))
+    heads = []
+    for h in range(H):
+        s = jnp.einsum("bqd,bkd->bqk", q[:, :, h], k[:, :, h // (H // G)],
+                       precision="highest") * (scale or D ** -0.5)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        heads.append(jnp.einsum("bqk,bkd->bqd", p, v[:, :, h // (H // G)],
+                                precision="highest"))
+    return jnp.stack(heads, axis=2)
+
+
+@pytest.mark.parametrize("T,W,H,G,bq,bk,quartered", [
+    (160, 48, 4, 1, 32, 32, True),     # T no multiple of W, W none of a tile
+    (128, 64, 4, 4, 32, 32, True),     # W two tiles: the edge corner to corner
+    (128, 40, 8, 2, 64, 32, False),    # oblong tiles, grouped 4 to 1
+    (128, 50, 4, 2, 32, 64, False),
+    (128, 48, 4, 1, 32, 32, False),    # square, crossed tiles whole
+    (128, None, 4, 2, 32, 32, True),   # grouped, no window
+    (128, 200, 4, 4, 32, 32, True),    # a window wider than the sequence
+    (96, 1, 2, 1, 32, 32, True),       # a query sees itself alone
+])
+def test_windowed_grouped_core_matches_masked_float32(monkeypatch, T, W, H, G,
+                                                      bq, bk, quartered):
+    """Forward, the one-kernel backward, the dQ + dK/dV pair and the chunked
+    XLA backward, in interpret mode, against masked float32 math."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_QUARTERED_FROM", 32 if quartered else 512)
+    ks = jax.random.split(jax.random.PRNGKey(T + (W or 0)), 4)
+    q, g = (jax.random.normal(kk, (2, T, H, 16)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, T, G, 16)) for kk in ks[2:])
+    want, vjp = jax.vjp(lambda *a: _masked_f32(*a, W), q, k, v)
+    want_g = vjp(g)
+    out, lse = pk._flash_forward(q, k, v, True, blk_q=bq, blk_k=bk,
+                                 interpret=True, window=W)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    for fused in (True, False):
+        got = pk._flash_backward(q, k, v, out, lse, g, True, blk_q=bq,
+                                 blk_k=bk, interpret=True, fused=fused,
+                                 window=W)
+        for a, b in zip(got, want_g):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=5e-5)
+    for a, b in zip(pk._attention_bwd_chunked(q, k, v, g, True, blk_q=32,
+                                              window=W), want_g):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+    np.testing.assert_allclose(pk._attention_xla(q, k, v, True, None, W),
+                               want, atol=2e-5)
+    # the plan computes every visible entry and no tile outside the band
+    computed, visible = pk.flash_score_entries(T, 16, 16, q.dtype, W, bq, bk,
+                                               engaged=True)
+    w = min(W or T, T)
+    assert visible == sum(min(r + 1, w) for r in range(T))
+    edge = (bq + bk) * T if not quartered else (bq + bk) * T // 2
+    assert visible <= computed <= visible + 2 * edge
+
+
+def test_flash_attention_takes_a_window_and_groups_under_grad():
+    """The public entry point, interpret mode: value and all three gradients,
+    dK and dV in the key/value heads' own shape."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, 256, 4, 16))
+    k, v = (jax.random.normal(kk, (1, 256, 2, 16)) for kk in ks[1:])
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+
+    got = jax.grad(loss(lambda *a: flash_attention(*a, True, True, True, None,
+                                                   96)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda *a: _masked_f32(*a, 96)), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("kwargs,what", [
+    (dict(causal=False, window=8), "causal"),
+    (dict(causal=True, window=0), "window"),
+    (dict(causal=True, window=8, key_mask=True), "key mask"),
+    (dict(causal=True, kv_heads=3), "heads"),
+])
+def test_window_and_groups_refuse_what_they_cannot_do(kwargs, what):
+    from deeplearning4j_tpu.ops.pallas_kernels import _flash_forward
+
+    q = jnp.zeros((1, 64, 4, 16))
+    kv = jnp.zeros((1, 64, kwargs.get("kv_heads", 4), 16))
+    km = jnp.ones((1, 64)) if kwargs.get("key_mask") else None
+    with pytest.raises(ValueError, match=what):
+        _flash_forward(q, kv, kv, kwargs["causal"], blk_q=32, blk_k=32,
+                       interpret=True, key_mask=km,
+                       window=kwargs.get("window"))
+
+
+def test_no_window_and_equal_heads_touch_none_of_the_new_plan(monkeypatch):
+    """``window=None`` with as many key/value heads as query heads traces
+    the program it traced before either existed: none of the window's plan
+    is reached, and the result is the same to the bit as with the new
+    helpers made to raise."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q, k, v = (jax.random.normal(kk, (1, 128, 2, 16)) for kk in ks)
+
+    def run():
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(pk.flash_attention(
+            *a, True, True, True, 0.3))), (0, 1, 2))(q, k, v)
+
+    def text():
+        return str(jax.make_jaxpr(lambda *a: pk.flash_attention(
+            *a, True, True, True, 0.3))(q, k, v))
+
+    before, jaxpr = run(), text()
+
+    def boom(*a, **kw):
+        raise AssertionError("the window's plan was reached")
+
+    for name in ("_tile_state", "_first_live", "_window_steps", "_repeat_kv"):
+        monkeypatch.setattr(pk, name, boom)
+    after = run()
+    for a, b in zip(before, after):
+        assert np.array_equal(a, b)
+    assert text() == jaxpr

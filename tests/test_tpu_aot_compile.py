@@ -159,6 +159,24 @@ def _latent(grad, T=4096):
         (2 if T == 4096 else 3) if grad else 1)
 
 
+def _windowed(grad, window=2048, T=8192):
+    """The grouped core at Trinity-Mini's widths: 2 sequences of 8,192, 32
+    query heads over 4 key/value heads, 128 wide; a 2,048-key window on the
+    sliding layers, none on the full ones. Forward, and under grad the ONE
+    backward kernel (a head's dQ, 8,192 x 128, fits VMEM)."""
+    shapes = [((2, T, h, 128), BF16) for h in (32, 4, 4)]
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, True, False, False, None, window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    assert pk._fused_bwd_fits(T, 128, BF16)
+    return (bwd if grad else fwd), shapes, (2 if grad else 1)
+
+
 def _grouped(grad, policy="bfloat16_full"):
     """The dropless expert dispatch at DeepSeek-V2-Lite's widths: 16,384
     tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408."""
@@ -186,6 +204,10 @@ CASES = {
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
     "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
+    "window-fwd-T8192-W2048-bfloat16": (_windowed, (False,)),
+    "window-grad-T8192-W2048-bfloat16": (_windowed, (True,)),
+    "window-grad-T8192-full-bfloat16": (_windowed, (True, None)),
+    "window-grad-T4096-W1536-bfloat16": (_windowed, (True, 1536, 4096)),
     "grouped-fwd-S16384-bfloat16": (_grouped, (False,)),
     "grouped-grad-S16384-bfloat16": (_grouped, (True,)),
     "grouped-grad-S16384-float32": (_grouped, (True, "float32")),
