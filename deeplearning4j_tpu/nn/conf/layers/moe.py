@@ -1,12 +1,28 @@
-"""Mixture-of-Experts layer with top-1 (Switch-style) routing.
+"""Mixture-of-Experts layers: the language models' top-k dropless expert
+products and the top-1 (Switch-style) layer.
 
-Absent in the reference; part of the TPU-native parallelism surface (expert
-parallelism — SURVEY.md §2.4 note). The layer itself is mesh-agnostic: the
-dense ``apply`` computes the routed FFN on one device (every expert evaluated
-via batched einsum — fine at test scale), while
-``parallel/moe.py::ExpertParallelMoE`` runs the same parameters across an
-``expert`` mesh axis with all_to_all dispatch/combine (GShard-style) and
-matches the dense math exactly when no tokens overflow capacity.
+**Top-k, dropless** (``grouped_expert_ffn``, called by ``DecoderBlock``'s
+``ffn="moe"`` for the experts one chip of an expert-parallel group holds):
+the (token, choice) pairs routed here are sorted by expert into a row buffer
+a quarter of all pairs long (all of them when one expert draws more), the
+three gated feed-forward products run grouped over the held experts
+(megablox ``gmm`` on a TPU, ``jax.lax.ragged_dot`` elsewhere), the pair's
+weight multiplies the down projection's input, and the buffer's rows are
+summed into their tokens' rows. Both trips between tokens and buffer touch
+the buffer's rows only: the way in is a gather of that many rows
+(``_take_token_rows``), the way out their sum by token
+(``_sum_token_rows``: on a TPU one gather into token order and a 0/1
+selector's grouped product over tiles of tokens, elsewhere a segment sum),
+and each is the other's cotangent.
+
+**Top-1** (``MoELayer``, ``MoETransformerBlock``). Absent in the reference;
+part of the TPU-native parallelism surface (expert parallelism — SURVEY.md
+§2.4 note). The layer itself is mesh-agnostic: the dense ``apply`` computes
+the routed FFN on one device (every expert evaluated via batched einsum —
+fine at test scale), while ``parallel/moe.py::ExpertParallelMoE`` runs the
+same parameters across an ``expert`` mesh axis with all_to_all
+dispatch/combine (GShard-style) and matches the dense math exactly when no
+tokens overflow capacity.
 
 Params: "Wg" [F, E] router; experts batched on the leading axis —
 "W1" [E, F, H], "b1" [E, H], "W2" [E, H, F], "b2" [E, F].
@@ -26,62 +42,104 @@ from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.serde import register_config
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _take_pair_rows(x2d, head, inv, n_routed, k: int):
-    """``rows[i] = x2d[head[i] % S]``: the token of the i-th sorted (token,
-    choice) pair, for the first ``len(head)`` pairs of the sorted order
-    (those routed here come first). Pairs are numbered choice-major, ``j * S
-    + s``, so a token's k pairs lie S apart and their sum is a sum of k
-    ``[S, F]`` slabs (token-major, the sum would run over a second-minor axis
-    of k, which the TPU's tiling pads and relays out). ``inv`` [S * k] is
-    the order's inverse, so the backward is a gather too (a routed pair's
-    row back to its place, then the slabs' sum), not a scatter-add.
-    Cotangent rows from ``n_routed`` on belong to no pair routed here (a
-    grouped product leaves them unwritten): they are dropped, not summed."""
-    return x2d[head % x2d.shape[0]]
+#: tokens a group of the return trips' grouped product sums into (the rows of
+#: its 0/1 selector), and the buffer rows one of its steps reads
+TOKEN_TILE = 256
 
 
-def _take_fwd(x2d, head, inv, n_routed, k):
-    return _take_pair_rows(x2d, head, inv, n_routed, k), (inv, n_routed)
+def _buffer_plan(token, n_routed, pair_held, by_tiles: bool):
+    """What both trips between the tokens and a row buffer need, ``(token,
+    n_routed, tiles)``: the buffer's i-th row is ``token[i]``'s for ``i <
+    n_routed`` and no pair's from there on. ``tiles`` is None, or with
+    ``by_tiles`` (the TPU kernel; S a multiple of ``TOKEN_TILE``) ``(by_token,
+    sizes)``: the rows' order by token with the rows past ``n_routed`` last,
+    and how many live rows each tile of ``TOKEN_TILE`` tokens has, from
+    ``pair_held`` [k, S] (which pairs are routed here: all of them are in
+    the buffer)."""
+    if not by_tiles:
+        return token, n_routed, None
+    k, S = pair_held.shape
+    live = jnp.arange(token.shape[0]) < n_routed
+    by_token = jnp.argsort(jnp.where(live, token, S)).astype(jnp.int32)
+    sizes = jnp.sum(pair_held.reshape(k, -1, TOKEN_TILE), axis=(0, 2),
+                    dtype=jnp.int32)
+    return token, n_routed, (by_token, sizes)
 
 
-def _take_bwd(k, res, g):
-    inv, n_routed = res
-    g = _rows_of_pairs(g, inv, n_routed)
-    summed = jnp.sum(g.reshape(k, -1, g.shape[-1]).astype(jnp.float32),
-                     axis=0)
-    return summed.astype(g.dtype), None, None, None
+def _sum_rows_by_token(rows, plan, S: int, interpret: bool = False):
+    """Both return trips out of the row buffer: ``y[s] = sum of rows[i]``
+    over the buffer's rows ``i < n_routed`` with ``token[i] == s``, [S, F] in
+    ``rows``' dtype, summed in float32. It touches the buffer's M rows, never
+    all ``S * k`` pairs. Rows from ``n_routed`` on may hold anything (a
+    grouped product leaves them unwritten): they are selected away, never
+    multiplied.
+
+    Without ``tiles`` in the plan: XLA's segment sum (off the TPU, in a
+    partitioned jit; a TPU's scatter-add takes 120 ns a 4 KB row). With
+    them, one gather puts the rows in token order, then a tile's tokens are
+    a 0/1 selector's product with the tile's rows: a grouped product whose
+    groups are the token tiles (megablox ``tgmm``, which masks a step's rows
+    outside its group and visits no row past the groups' sum)."""
+    token, n_routed, tiles = plan
+    if tiles is None:
+        live = jnp.arange(rows.shape[0])[:, None] < n_routed
+        return jax.ops.segment_sum(
+            jnp.where(live, rows.astype(at_least_f32(rows.dtype)), 0), token,
+            num_segments=S).astype(rows.dtype)
+    import importlib
+
+    # the kernels' own module: the package's attribute ``gmm`` is the
+    # differentiable function, which hides the module of that name
+    tgmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm").tgmm
+    by_token, sizes = tiles
+    at = token[by_token] % TOKEN_TILE
+    select = (at[None, :] == jnp.arange(TOKEN_TILE, dtype=at.dtype)[:, None]
+              ).astype(rows.dtype)
+    y = tgmm(select, rows[by_token], sizes, rows.dtype,
+             (TOKEN_TILE, TOKEN_TILE, min(rows.shape[1], 2048)),
+             interpret=interpret)
+    return y.reshape(S, rows.shape[1])
 
 
-_take_pair_rows.defvjp(_take_fwd, _take_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_token_rows(x2d, plan, interpret: bool = False):
+    """The way into the row buffer, ``rows[i] = x2d[token[i]]``: a gather of
+    M rows. Its cotangent is the way out, which drops the rows from
+    ``n_routed`` on."""
+    return x2d[plan[0]]
 
 
-def _rows_of_pairs(rows, inv, n_routed):
-    """``out[p] = rows[inv[p]]`` where pair p was routed here (its place in
-    the sorted order lies below ``n_routed``, which never exceeds
-    ``len(rows)``), else 0."""
-    at = jnp.minimum(inv, rows.shape[0] - 1)
-    return jnp.where((inv < n_routed)[:, None], rows[at], 0)
+def _take_fwd(x2d, plan, interpret):
+    return x2d[plan[0]], (plan, x2d.shape[0])
 
 
-@jax.custom_vjp
-def _untake_pair_rows(rows, head, inv, n_routed):
-    """Sorted rows back in (token, choice) order, 0 for a pair not routed
-    here (``_rows_of_pairs``); the backward gathers with ``head`` and drops
-    the rows that carry no pair."""
-    return _rows_of_pairs(rows, inv, n_routed)
+def _take_bwd(interpret, res, g):
+    plan, S = res
+    return _sum_rows_by_token(g, plan, S, interpret), None
 
 
-def _untake_bwd(res, g):
-    head, n_routed = res
-    live = jnp.arange(head.shape[0])[:, None] < n_routed
-    return jnp.where(live, g[head], 0), None, None, None
+_take_token_rows.defvjp(_take_fwd, _take_bwd)
 
 
-_untake_pair_rows.defvjp(
-    lambda rows, head, inv, n_routed: (
-        _untake_pair_rows(rows, head, inv, n_routed), (head, n_routed)),
-    _untake_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _sum_token_rows(rows, plan, S: int, interpret: bool = False):
+    """The way out of the row buffer, every token's sum of its rows
+    (``_sum_rows_by_token``). Its cotangent is the way in: a token's row to
+    each of its buffer rows (a row that carries no pair gets some token's
+    too: no grouped product reads it)."""
+    return _sum_rows_by_token(rows, plan, S, interpret)
+
+
+def _sum_fwd(rows, plan, S, interpret):
+    return _sum_rows_by_token(rows, plan, S, interpret), plan[0]
+
+
+def _sum_bwd(S, interpret, token, g):
+    return g[token], None
+
+
+_sum_token_rows.defvjp(_sum_fwd, _sum_bwd)
 
 
 #: row tile of the grouped products: a group's rows are computed in whole
@@ -157,21 +215,26 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
     the rows the grouped products ran over (padding included), the largest
     expert's rows.
 
-    **Sort and group.** The ``S * k`` pairs (numbered choice-major, see
-    ``_take_pair_rows``) are sorted by expert, pairs for absent experts
-    last; each sorted row takes its token's activations, the
-    three products run grouped over the G experts (``_grouped_matmul``), and
-    the rows go back to pair order, are weighted and summed per token.
+    **Sort and group.** The ``S * k`` pairs (numbered choice-major: pair
+    ``j * S + s`` is token s's j-th choice) are sorted by expert, pairs for
+    absent experts last; each of the buffer's rows takes its token's
+    activations (``_take_token_rows``), the three products run grouped over
+    the G experts (``_grouped_matmul``), the gated activation is weighted by
+    its pair's weight in float32 before its one rounding (the down
+    projection is linear in its rows), and the rows are summed into their
+    tokens' rows (``_sum_token_rows``). Nothing here is ``S * k`` rows by F:
+    the trips between tokens and buffer run over the buffer's rows.
 
     **No pair is dropped whatever the imbalance.** The row buffer has one of
     two static sizes, chosen on the device from the count of pairs routed
     here (``lax.cond``): ``_usual_bound`` (a quarter of all pairs) where
     they fit in it, else all ``S * k`` rows, which is every pair there is
     (one expert may take them all). Rows past the routed count belong to no
-    expert held here: the grouped products do not compute them, the combine
-    replaces them with 0 before weighting, their cotangent is dropped on the
-    way back, and they are not counted as work (``rows[1]`` stops at the
-    last group's last tile)."""
+    expert held here: the grouped products do not compute them (a kernel
+    leaves them unwritten), the way out selects them away, their cotangent
+    is dropped on the way back, their weight is 0 and takes no gradient, and
+    they are not counted as work (``rows[1]`` stops at the last group's last
+    tile)."""
     S, k = choice.shape
     G = w_gate.shape[0]
     pairs = S * k
@@ -181,8 +244,6 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
         local = choice.T.reshape(pairs) - first_held
         key = jnp.where((local >= 0) & (local < G), local, G).astype(jnp.int32)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(pairs, dtype=jnp.int32))
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(G, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
@@ -194,24 +255,32 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
 
         kernel = _kernel_engaged(bound, jnp.dtype(pol.compute_dtype))
         _note_dispatch("grouped_matmul", kernel)
+        by_tiles = kernel and S % TOKEN_TILE == 0
+        _note_dispatch("moe_combine", by_tiles)
 
         def layer(x2d, weight, wg, wu, wd):
-            head = order[:bound]
             with jax.named_scope("moe/dispatch"):
-                rows = _take_pair_rows(x2d, head, inv, n_routed, k)
+                head = order[:bound]
+                plan = _buffer_plan(head % S, n_routed,
+                                    (key < G).reshape(k, S), by_tiles)
+                rows = _take_token_rows(x2d, plan)
+                # a row's weight, float32; 0 where the row carries no pair,
+                # so that nothing comes back to that pair's weight
+                w = jnp.where(jnp.arange(bound) < n_routed,
+                              weight.T.reshape(pairs)[head], 0)[:, None]
             with jax.named_scope("moe/experts"):
                 rows = rows.astype(pol.compute_dtype)
                 gate = _grouped_matmul(rows, wg, group_sizes, kernel)
                 up = _grouped_matmul(rows, wu, group_sizes, kernel)
-                act = (jax.nn.silu(gate.astype(at_least_f32(gate.dtype)))
-                       .astype(gate.dtype) * up)
+                # the down projection is linear in its rows, so the pair's
+                # weight goes in here, in float32 before the one rounding
+                f32 = at_least_f32(gate.dtype)
+                act = (jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+                       * w.astype(f32)).astype(gate.dtype)
                 out = _grouped_matmul(act, wd, group_sizes, kernel).astype(
                     pol.output_dtype)
             with jax.named_scope("moe/dispatch"):
-                out = _untake_pair_rows(out, head, inv, n_routed)
-                w = weight.T.astype(at_least_f32(out.dtype))[..., None]
-                y = jnp.sum(out.reshape(k, S, -1).astype(w.dtype) * w,
-                            axis=0).astype(out.dtype)
+                y = _sum_token_rows(out, plan, S)
             return y, _tiled_rows(group_sizes) if kernel else n_routed
 
         return layer

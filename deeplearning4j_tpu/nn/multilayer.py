@@ -521,7 +521,7 @@ class LazyScore:
         if callable(raw):
             raw = float(raw())
             self._score_raw = raw
-            self._note_moe_rows()
+            self._note_moe_rows(finished=True)
         elif not isinstance(raw, float):
             raw = float(raw)
             self._score_raw = raw
@@ -556,17 +556,20 @@ class LazyScore:
     #: groups whose expert-layer rows the host has not read yet
     _pending_moe_rows: tuple = ()
 
-    def _note_moe_rows(self) -> None:
+    def _note_moe_rows(self, finished: bool = False) -> None:
         """Book the expert layers' rows of every dispatched group whose step
         has finished (``dl4j_moe_*``), oldest first, and never wait for one:
         called after each dispatch, where flow control has just waited for
-        the group two back, and when a score has been read."""
+        the group two back, and when a score has been read. ``finished``:
+        the newest group's score has just been read, so every pending group
+        has finished, whatever ``is_ready`` says of its rows in that
+        instant (they are outputs of the program that wrote the score)."""
         pending = self._pending_moe_rows
         if not pending:
             return
         layers = [str(i) for i, st in enumerate(self.state_list)
                   if isinstance(st, dict) and "moe_rows" in st]
-        while pending and pending[0][0].is_ready():
+        while pending and (finished or pending[0][0].is_ready()):
             (rows, tokens), pending = pending[0], pending[1:]
             self._pending_moe_rows = pending
             rows = np.asarray(rows)           # (K, layers, 3), finished

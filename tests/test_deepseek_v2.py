@@ -244,6 +244,132 @@ def test_both_buffer_sizes_give_the_dense_result(monkeypatch, held, fits):
         _close(a, b, what="gradient")
 
 
+def _choices(case, S=32, k=3, E=16):
+    """``choice`` [S, k] over E experts of which 4..7 are held, the buffer's
+    rows and the routed count the case must give."""
+    rng = np.random.default_rng(5)
+    even = np.argsort(rng.random((S, E)), axis=1)[:, :k]
+    away = 8 + np.argsort(rng.random((S, 8)), axis=1)[:, :k]
+    if case == "even":                      # about a quarter held
+        return even, 32, None
+    if case == "all-on-one-expert":         # the full-size buffer
+        return np.full((S, k), 5), S * k, S * k
+    if case == "none-here":
+        return away, 32, 0
+    if case == "one-token-all-local":       # token 9 alone, all k here
+        away[9] = [4, 6, 7]
+        return away, 32, 3
+    # the routed count one short of / one past a tile of 8 rows: tokens
+    # 0..4 with all k here, token 5 with none or two more
+    n = {"tile-minus-one": 15, "tile-plus-one": 17}[case]
+    for t in range(n // k):
+        away[t] = [4, 5, 6]
+    away[n // k, :n % k] = 7
+    return away, 32, n
+
+
+CASES = ["even", "all-on-one-expert", "none-here", "one-token-all-local",
+         "tile-minus-one", "tile-plus-one"]
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+@pytest.mark.parametrize("case", CASES)
+def test_rows_sum_into_their_tokens_as_the_dense_sum(monkeypatch, case, path):
+    """The dispatch's way out (``_sum_token_rows``) and way in
+    (``_take_token_rows``), each the other's cotangent, against a dense
+    float32 sum over a one-hot of the rows' tokens. Rows past the routed
+    count hold NaN on entry, as a grouped product may leave them, and reach
+    no result. ``kernel``: the token-order gather and megablox's ``tgmm`` in
+    interpret mode, at tiles of 8."""
+    from deeplearning4j_tpu.nn.conf.layers import moe
+
+    monkeypatch.setattr(moe, "TOKEN_TILE", 8)
+    choice, bound, n = _choices(case)
+    (S, k), F = choice.shape, 128
+    # the buffer as grouped_expert_ffn lays it out: pairs numbered
+    # choice-major, sorted by held expert, the first ``bound``
+    local = jnp.asarray(choice.T.reshape(S * k) - 4, jnp.int32)
+    key = jnp.where((local >= 0) & (local < 4), local, 4)
+    token = (jnp.argsort(key, stable=True)[:bound] % S).astype(jnp.int32)
+    pair_held = (key < 4).reshape(k, S)
+    n_routed = jnp.sum(pair_held).astype(jnp.int32)
+    assert int(n_routed) <= bound and (n is None or int(n_routed) == n)
+    live = (jnp.arange(bound) < n_routed)[:, None]
+    rows = jnp.where(live, jax.random.normal(jax.random.PRNGKey(3),
+                                             (bound, F)), jnp.nan)
+    kernel = path == "kernel"
+    plan = moe._buffer_plan(token, n_routed, pair_held, kernel)
+    onehot = (live & (token[:, None] == jnp.arange(S)[None, :])).astype(
+        jnp.float32)
+    want = jnp.einsum("is,if->sf", onehot, jnp.where(live, rows, 0),
+                      precision="highest")
+
+    def way_out(rows):
+        return moe._sum_token_rows(rows, plan, S, kernel)
+
+    def way_in(x):
+        return moe._take_token_rows(x, plan, kernel)
+
+    y, pull = jax.vjp(way_out, rows)
+    assert bool(jnp.all(jnp.isfinite(y)))
+    _close(y, want, what="the way out")
+    x = jax.random.normal(jax.random.PRNGKey(7), (S, F))
+    _close(jnp.where(live, pull(x)[0], 0), jnp.where(live, x[token], 0),
+           what="the way out's cotangent")
+    taken, pull = jax.vjp(way_in, x)
+    _close(taken, x[token], what="the way in")
+    dx, = pull(rows)                    # NaN past the count
+    assert bool(jnp.all(jnp.isfinite(dx)))
+    _close(dx, want, what="the way in's cotangent")
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_expert_layer_and_its_gradients_equal_the_dense_sum(monkeypatch,
+                                                           case):
+    """``grouped_expert_ffn`` over the same routings against every held
+    expert evaluated on every token: the value and the gradients by the
+    tokens' rows, by the pairs' weights (0 for a pair routed elsewhere) and
+    by an expert's weights."""
+    from deeplearning4j_tpu import common
+    from deeplearning4j_tpu.nn.conf.layers import moe
+
+    monkeypatch.setattr(moe, "GROUP_ROW_TILE", 8)
+    choice, bound, n = _choices(case)
+    (S, k), F, H, G = choice.shape, 16, 24, 4
+    assert moe._usual_bound(S * k) == 24 or bound == S * k
+    choice = jnp.asarray(choice, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(ks[0], (S, F))
+    weight = jax.random.uniform(ks[1], (S, k)) + 0.5
+    wg, wu = (jax.random.normal(kk, (G, F, H)) / 4 for kk in ks[2:4])
+    wd = jax.random.normal(ks[4], (G, H, F)) / 4
+
+    def got(x, weight, wg):
+        with common.override_policy("float32"):
+            return moe.grouped_expert_ffn(x, choice, weight, wg, wu, wd, 4)
+
+    def want(x, weight, wg):
+        y = 0
+        for e in range(G):
+            h = (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e]
+            y = y + h * jnp.sum(jnp.where(choice == 4 + e, weight, 0),
+                                axis=1)[:, None]
+        return y
+
+    y, stats = got(x, weight, wg)
+    assert n is None or int(stats[0]) == n
+    _close(y, want(x, weight, wg), what="value")
+    g = jax.grad(lambda *a: jnp.sum(jnp.sin(got(*a)[0])),
+                 argnums=(0, 1, 2))(x, weight, wg)
+    e = jax.grad(lambda *a: jnp.sum(jnp.sin(want(*a))),
+                 argnums=(0, 1, 2))(x, weight, wg)
+    for a, b, what in zip(g, e, ("rows", "weights", "an expert's weights")):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        _close(a, b, what="gradient by the " + what)
+    held = (choice >= 4) & (choice < 8)
+    assert not bool(jnp.any(jnp.where(held, 0, g[1])))
+
+
 # (e) ---------------------------------------------------------------------
 def test_integer_ids_above_256_reach_the_step_unchanged(monkeypatch):
     batches = _batches(4)

@@ -177,13 +177,15 @@ def _windowed(grad, window=2048, T=8192):
     return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
-def _grouped(grad, policy="bfloat16_full"):
+def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8):
     """The dropless expert dispatch at DeepSeek-V2-Lite's widths: 16,384
-    tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408."""
+    tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408; or
+    at Trinity-Mini's (``benchmark/configs/trinity-mini-ep8.json``): 8
+    choices over 16 held of 128, experts 2048 x 1024."""
     from deeplearning4j_tpu import common
     from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 
-    S, k, F, H, G = 16384, 6, 2048, 1408, 8
+    S, F = 16384, 2048
     shapes = [((S, F), BF16), ((S, k), jnp.int32), ((S, k), F32),
               ((G, F, H), BF16), ((G, F, H), BF16), ((G, H, F), BF16)]
 
@@ -195,9 +197,13 @@ def _grouped(grad, policy="bfloat16_full"):
         return jax.grad(lambda *a: fwd(a[0], c, *a[1:]).astype(F32).sum(),
                         argnums=(0, 1, 2, 3, 4))(x, w, g, u, d)
 
-    # three grouped products forward, each with two more backward, in both
-    # of the buffer's sizes (the cond's two branches)
-    return (bwd if grad else fwd), shapes, (18 if grad else 6)
+    # in both of the buffer's sizes (the cond's two branches). Forward: the
+    # three grouped products and the way out's selector product. Under grad
+    # of a sum: the gate and up products again (no cotangent needs the down
+    # product's output, the pair's weight having gone into its input), two
+    # more for each of the three, and the way in's cotangent, which is the
+    # selector product again (the way out's own cotangent is a gather)
+    return (bwd if grad else fwd), shapes, (18 if grad else 8)
 
 
 CASES = {
@@ -211,6 +217,8 @@ CASES = {
     "grouped-fwd-S16384-bfloat16": (_grouped, (False,)),
     "grouped-grad-S16384-bfloat16": (_grouped, (True,)),
     "grouped-grad-S16384-float32": (_grouped, (True, "float32")),
+    "grouped-grad-S16384-k8-G16-bfloat16": (_grouped, (True, "bfloat16_full",
+                                                       8, 1024, 16)),
     **{f"flash-{'grad' if g else 'fwd'}-T{T}-{jnp.dtype(d).name}":
        (_flash, (T, d, g))
        for g in (False, True)
@@ -245,6 +253,56 @@ def test_kernel_compiles_for_v5e_or_gate_refuses(name, chip):
     assert case is not None, f"{name}: the gate refused a shape it admitted"
     f, shapes, want = case
     assert _n_kernels(chip, f, *shapes) == want
+
+
+def test_usual_dispatch_buffer_holds_no_array_of_every_pair(chip):
+    """At Trinity-Mini's shape, forward and backward: outside the full-size
+    branch of the dispatch's ``cond`` (whose buffer is ``S * k`` rows by
+    definition) the program defines no array of ``S * k`` rows by F; both
+    trips between tokens and buffer run over the buffer's rows."""
+    import re
+
+    fwd, shapes, _ = _grouped(False, "bfloat16_full", 8, 1024, 16)
+
+    def f(x, c, *rest):                 # a loss that needs the forward's y
+        return jax.grad(lambda x, *a: (fwd(x, c, *a).astype(F32) ** 2).sum(),
+                        argnums=(0, 1, 2, 3, 4))(x, *rest)
+
+    hlo = jax.jit(f).lower(*[jax.ShapeDtypeStruct(s, d, sharding=chip)
+                             for s, d in shapes]).compile().as_text()
+    bodies, name = {}, None
+    for line in hlo.split("\n"):
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+    every_pair = re.compile(r"= \(?\w+\[(?:131072,2048|8,16384,2048)\]")
+
+    def arrays_under(comp, seen):
+        if comp in seen or comp not in bodies:
+            return 0
+        seen.add(comp)
+        called = {c.strip().lstrip("%") for line in bodies[comp]
+                  for group in re.findall(
+                      r"(?:calls|to_apply|body|condition|branch_computations)"
+                      r"=\{?([^}\s]+(?:, [^}\s]+)*)", line)
+                  for c in group.rstrip(",").split(",")}
+        return (sum(1 for line in bodies[comp] if every_pair.search(line))
+                + sum(arrays_under(c, seen) for c in called))
+
+    conds = [line for body in bodies.values() for line in body
+             if " conditional(" in line]
+    assert conds
+    inside = set()
+    for line in conds:
+        full, usual = (c.strip().lstrip("%") for c in re.search(
+            r"branch_computations=\{([^}]*)\}", line).group(1).split(","))
+        assert arrays_under(full, inside) > 0      # the reader sees them
+        assert arrays_under(usual, inside) == 0
+    assert sum(1 for comp, body in bodies.items() if comp not in inside
+               for line in body if every_pair.search(line)) == 0
 
 
 def test_lstm_refusal_names_the_shape():
