@@ -35,3 +35,17 @@ def attention_core(batch: int, heads: int, seq: int, qk: int, v: int,
 def least_seconds(flops: float, nbytes: float, peak: dict) -> float:
     return max(flops / peak["bf16_flops_per_s"],
                nbytes / peak["hbm_bytes_per_s"])
+
+
+class Share(float):
+    """A percentage that keeps the two numbers it was divided from, named,
+    numerator first: ``Share(least_s=0.131, device_s=0.242)`` is 54.1... and
+    its ``operands`` say of what. ``run.py`` prints them where a share of a
+    roofline or of the peak reads over 100: one of the two was counted
+    wrong, and the reader that divided them knows which they were."""
+
+    def __new__(cls, **operands):
+        numerator, denominator = operands.values()
+        self = super().__new__(cls, 100.0 * numerator / denominator)
+        self.operands = operands
+        return self

@@ -8,10 +8,13 @@ K-step program over a global batch sharded on the mesh's ``data`` axis, the
 parameters replicated. Batch-norm statistics are therefore over the global
 batch, and the plain reference follows the same steps at the global batch,
 unchanged. Parameters from the traffic file are ``train_fit``'s; ``batch`` is
-the global batch. The wrapper stages a group with its own function
-(``np.stack`` in the host dtype, one sharded ``device_put``): the traffic's
-``stage_dtype`` reaches only the reference, which is exact for a program
-whose first product rounds its input to that dtype anyway (``bfloat16_full``).
+the global batch. The wrapper's synchronous loop is the networks' own staged
+loop (``LazyScore._fit_epoch`` with the wrapper as its ``LoopOwner``): each
+batch is cast to the network's ``stage_dtype`` (the traffic's, set by
+``train_fit``'s ``build``) into a slot of a host ring the wrapper keeps, and
+every chip is sent its shard of the K-step group. The groups carry the same
+spans as a network's (``input.pull|stack|cast|h2d``, ``fit.dispatch`` with the
+all-reduce's ``collective_bytes``), so the span metrics read this cell too.
 """
 from __future__ import annotations
 

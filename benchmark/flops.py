@@ -32,11 +32,29 @@ def train_flops_per_sample(layers: list) -> int:
                for l in layers)
 
 
-def train_flops_of(config: dict) -> int:
-    """``train_flops_per_sample`` of a configuration file's network, whose
-    products its plain reference lists."""
+def _layers_of(config: dict) -> list:
+    """The products of a configuration file's network, as its plain
+    reference lists them."""
     ref = importlib.import_module("reference." + config["reference"])
-    return train_flops_per_sample(ref.layers(config["builder"]["kwargs"]))
+    return ref.layers(config["builder"]["kwargs"])
+
+
+def train_flops_of(config: dict) -> int:
+    """``train_flops_per_sample`` of a configuration file's network."""
+    return train_flops_per_sample(_layers_of(config))
+
+
+def train_flops_by_scope(config: dict) -> dict:
+    """``train_flops_of`` split by the entries' ``scope``: the scope path a
+    kernel with a roofline metric of its own is traced under
+    (``"attn/core"``, ``"moe/experts"``), or None for an entry without the
+    key, a dense product. The values add up to ``train_flops_of``, which
+    ignores the key."""
+    out = {}
+    for l in _layers_of(config):
+        scope = l.get("scope")
+        out[scope] = out.get(scope, 0) + train_flops_per_sample([l])
+    return out
 
 
 def param_bytes(layers: list, itemsize: int = 4) -> int:

@@ -22,4 +22,4 @@ def read(ctx):
         *costs.grouped_ffn(rows / steps, kw["hidden_size"],
                            kw["moe_intermediate_size"], end - first),
         ctx["peak"]) for rows in routed.values())
-    return 100.0 * least / (ms / 1e3)
+    return costs.Share(least_s=least, device_s=ms / 1e3)

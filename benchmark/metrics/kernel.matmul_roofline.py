@@ -5,6 +5,7 @@ dot, bare or at the root of an output fusion). Compute-bound at these shapes
 (hundreds of FLOPs per byte), so the bound is the FLOP peak. Reads the same
 work whatever implements it."""
 import flops
+from costs import Share
 
 
 def read(ctx):
@@ -16,4 +17,4 @@ def read(ctx):
     need = flops.train_flops_of(ctx["cell"]["config"])
     chips = ctx["device"]["count"]
     least = need * samples / (ctx["peak"]["bf16_flops_per_s"] * chips)
-    return 100.0 * least / t["matmul_s"]
+    return Share(least_s=least, matmul_s=t["matmul_s"])

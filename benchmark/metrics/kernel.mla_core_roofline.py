@@ -18,4 +18,4 @@ def read(ctx):
         *costs.attention_core(int(tr["batch"]), kw["n_heads"], kw["seq_len"],
                               kw["qk_nope_head_dim"] + kw["qk_rope_head_dim"],
                               kw["v_head_dim"]), ctx["peak"])
-    return 100.0 * one * kw["n_layers"] / (ms / 1e3)
+    return costs.Share(least_s=one * kw["n_layers"], device_s=ms / 1e3)

@@ -21,4 +21,4 @@ def read(ctx):
         *costs_window.masked_core(batch, kw["n_heads"], kw["n_kv_heads"],
                                   kw["seq_len"], kw["head_dim"], w),
         ctx["peak"]) for w in windows)
-    return 100.0 * least / (ms / 1e3)
+    return costs.Share(least_s=least, device_s=ms / 1e3)

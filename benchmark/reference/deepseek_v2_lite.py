@@ -92,15 +92,19 @@ def layers(cfg) -> list:
         raise ValueError("the expected routed rows of a sequence are not whole")
     out = []
 
-    def add(name, nin, nout):
-        out.append({"kind": "dense", "name": name, "nin": nin, "nout": nout,
-                    "first": False})
+    def add(name, nin, nout, scope=None):
+        entry = {"kind": "dense", "name": name, "nin": nin, "nout": nout,
+                 "first": False}
+        if scope:   # a kernel with a roofline metric of its own runs it
+            entry["scope"] = scope
+        out.append(entry)
 
     for i in range(1, c["n_layers"] + 1):
         add(f"{i}/Wq", T * F, H * (dn + dr))
         add(f"{i}/Wkva", T * F, r + dr)
         add(f"{i}/Wkvb", T * r, H * (dn + dv))
-        add(f"{i}/core", H * (T * (T + 1) // 2), dn + dr + dv)
+        add(f"{i}/core", H * (T * (T + 1) // 2), dn + dr + dv,
+            "attn/core")
         add(f"{i}/Wo", T * H * dv, F)
         if i <= c["first_k_dense"]:
             add(f"{i}/ffn", T * F, 3 * c["intermediate_size"])
@@ -108,7 +112,7 @@ def layers(cfg) -> list:
             add(f"{i}/Wr", T * F, c["n_router_outputs"])
             add(f"{i}/shared", T * F, 3 * c["n_shared_experts"] * He)
             add(f"{i}/routed", routed_rows // c["n_router_outputs"] * F,
-                3 * He)
+                3 * He, "moe/experts")
     add(f"{c['n_layers'] + 2}/W", T * F, c["vocab_rows"])
     return out
 

@@ -116,16 +116,20 @@ def layers(cfg) -> list:
         raise ValueError("the expected routed rows of a sequence are not whole")
     out = []
 
-    def add(name, nin, nout):
-        out.append({"kind": "dense", "name": name, "nin": nin, "nout": nout,
-                    "first": False})
+    def add(name, nin, nout, scope=None):
+        entry = {"kind": "dense", "name": name, "nin": nin, "nout": nout,
+                 "first": False}
+        if scope:   # a kernel with a roofline metric of its own runs it
+            entry["scope"] = scope
+        out.append(entry)
 
     for i in range(1, c["n_layers"] + 1):
         add(f"{i}/Wq", T * F, H * D)
         add(f"{i}/Wk", T * F, G * D)
         add(f"{i}/Wv", T * F, G * D)
         add(f"{i}/Wz", T * F, H * D)
-        add(f"{i}/core", H * visible_pairs(T, _window(c, i)), 2 * D)
+        add(f"{i}/core", H * visible_pairs(T, _window(c, i)), 2 * D,
+            "attn/core")
         add(f"{i}/Wo", T * H * D, F)
         if i <= c["n_dense_layers"]:
             add(f"{i}/ffn", T * F, 3 * c["intermediate_size"])
@@ -133,7 +137,7 @@ def layers(cfg) -> list:
             add(f"{i}/Wr", T * F, c["n_router_outputs"])
             add(f"{i}/shared", T * F, 3 * c["n_shared_experts"] * He)
             add(f"{i}/routed", routed_rows // c["n_router_outputs"] * F,
-                3 * He)
+                3 * He, "moe/experts")
     add(f"{c['n_layers'] + 2}/W", T * F, c["vocab_rows"])
     return out
 

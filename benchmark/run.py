@@ -68,6 +68,29 @@ def load_metrics(cell_name: str) -> list:
     return out
 
 
+def read_metrics(ctx: dict, readers: list) -> dict:
+    """``{name: {"value", "unit"}}`` of the readers that found something to
+    read. A share of a roofline or of the peak over 100 is printed as it was
+    read, and named on standard error with the two numbers it was divided
+    from: its operations are counted too high or its time leaves out part of
+    the work, and the driver refuses a run that reads one over 105."""
+    out = {}
+    for desc, read in readers:
+        value = read(ctx)
+        if value is None:
+            continue
+        name = desc["name"]
+        out[name] = {"value": value, "unit": desc["unit"]}
+        if (desc["unit"] == "%" and ("roofline" in name or "mfu" in name)
+                and value > 100):
+            operands = getattr(value, "operands", {})
+            log(f"IMPOSSIBLE SHARE: {name} reads {value:.6g} % of a bound it "
+                f"cannot pass: " + (" over ".join(
+                    f"{k} {v:.6g}" for k, v in operands.items())
+                    or "its reader gives no operands"))
+    return out
+
+
 def find_devices(chips: int, platform: str = "tpu"):
     """The chips the cell asks for and their row of peaks.json, or an error:
     no fallback to another platform, no default peak."""
@@ -257,10 +280,7 @@ def run(args, find=find_devices, driver_cls=None) -> int:
                "device": device, "setup_s": setup_s,
                "setup_events": [(e, d) for t, e, d in monitor.events
                                 if t <= t_setup]}
-        for desc, read in load_metrics(cell["name"]):
-            value = read(ctx)
-            if value is not None:
-                metrics[desc["name"]] = {"value": value, "unit": desc["unit"]}
+        metrics = read_metrics(ctx, load_metrics(cell["name"]))
     else:
         for name, value in win["end_to_end"].items():
             metrics[name] = {"value": value, "unit": cell["traffic"]["units"][name]}

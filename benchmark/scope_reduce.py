@@ -20,7 +20,9 @@ from __future__ import annotations
 import bisect
 import re
 
+import flops
 import span_reduce
+from costs import Share
 from trace_reduce import CONTAINER, _clip
 
 _memo: dict = {}
@@ -131,6 +133,37 @@ ATTENTION = r"/attn(/|$)"
 ATTENTION_CORE = r"/attn/core(/|$)"
 MOE = r"/moe/(router|dispatch|experts|shared)(/|$)"
 MOE_EXPERTS = r"/moe/experts(/|$)"
+DENSE = re.compile(r"/(attn|ffn|moe/shared|moe/router)(/|$)")
+
+
+def dense_share(ctx: dict):
+    """A language model's dense products against the chip's peak, by scope
+    and not by who runs the operation (``metrics/kernel.dense_roofline.py``).
+
+    Numerator: the operations a step requires (forward and two gradient
+    products, ``flops.py``'s rule; nothing recomputed) of every product that
+    no kernel metric of its own accounts for. The configuration's reference
+    says which: an entry of its ``layers()`` with a ``scope`` belongs to the
+    kernel traced under that scope; one without is a dense product.
+    Denominator: the device time a step of every event under ``attn``,
+    ``ffn``, ``moe/shared``, ``moe/router`` and ``loss``, less the time under
+    a scope a tagged entry names. None where the reference tags nothing
+    (every product is XLA's: ``kernel.matmul_roofline`` reads such a cell) or
+    nothing was traced under those scopes."""
+    need = flops.train_flops_by_scope(ctx["cell"]["config"])
+    dense = need.pop(None, 0)
+    events = _events(ctx)
+    if not need or not dense or not events:
+        return None
+    tagged = re.compile("/(" + "|".join(map(re.escape, need)) + ")(/|$)")
+    ms = sum(t for name, t in events
+             if (DENSE.search(name) or span_reduce._LOSS.search(name))
+             and not tagged.search(name))
+    if not ms:
+        return None
+    peak = ctx["peak"]["bf16_flops_per_s"] * ctx["device"]["count"]
+    batch = int(ctx["cell"]["traffic"]["batch"])
+    return Share(least_s=dense * batch / peak, device_s=ms / 1e3)
 
 
 def by_layer(ctx: dict, counter: str) -> dict:

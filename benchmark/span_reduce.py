@@ -328,7 +328,7 @@ def idle_owners(idle: list, spans: list) -> dict:
 
 def reduce_profile(profile, spans: list, module_text, trace: dict,
                    ksteps: int) -> dict:
-    """Everything the eight readers take, from one profile, the program's
+    """Everything the span readers take, from one profile, the program's
     spans and its step module's text; ``trace`` is ``trace_reduce``'s
     reduction of the same profile."""
     t_zero = profile_start_ns(profile)
@@ -493,18 +493,6 @@ def reduce(ctx: dict):
 def phase_ms(ctx: dict, phase: str):
     r = reduce(ctx)
     return r["phase_ms"][phase] if r and r["phase_ms"] else None
-
-
-def launch_ms_p50(ctx: dict):
-    r = reduce(ctx)
-    if not r or not r["launch_ms"]:
-        return None
-    if min(r["launch_ms"]) < 0:
-        log(f"a device start precedes the host call that caused it by "
-            f"{-min(r['launch_ms']):.3f} ms: the clocks are not shared to "
-            f"that precision, fit.launch_ms_p50 is not reported")
-        return None
-    return statistics.median(r["launch_ms"])
 
 
 def idle_attributed_pct(ctx: dict):

@@ -38,21 +38,30 @@ def test_manifest_matches_files():
         assert os.path.exists(os.path.join(
             BENCH, "drivers", cell["traffic"]["driver"] + ".py"))
     e2e = {e["name"] for e in m["end_to_end"]}
+    cells = {w["name"] for w in m["workloads"]}
     for p in m["per_layer"]:
         with open(os.path.join(BENCH, "metrics", p["name"] + ".json")) as f:
             desc = json.load(f)
-        assert {k: p[k] for k in desc} == desc
+        # the same in both places, the `workloads` list too
+        assert {k: v for k, v in p.items() if k != "name"} == desc
         assert p["moves"] in e2e
+        assert set(p.get("workloads", ())) <= cells, p["name"]
         assert os.path.exists(os.path.join(BENCH, "metrics", p["name"] + ".py"))
     on_disk = {f[:-5] for f in os.listdir(os.path.join(BENCH, "metrics"))
                if f.endswith(".json")}
     assert on_disk == {p["name"] for p in m["per_layer"]}
 
 
-def test_both_cells_load_with_their_metrics():
-    for w in manifest()["workloads"]:
-        names = [d["name"] for d, _ in run.load_metrics(w["name"])]
-        assert len(names) == len(manifest()["per_layer"])
+def test_every_cell_loads_the_metrics_that_list_it():
+    m = manifest()
+    for w in m["workloads"]:
+        names = {d["name"] for d, _ in run.load_metrics(w["name"])}
+        assert names == {p["name"] for p in m["per_layer"]
+                         if w["name"] in p.get("workloads", [w["name"]])}
+        # the contract: every cell reports at least one per-layer metric
+        # that moves each end-to-end metric it reports
+        moved = {p["moves"] for p in m["per_layer"] if p["name"] in names}
+        assert moved == {e["name"] for e in m["end_to_end"]}
 
 
 def test_unknown_device_kind_is_an_error():

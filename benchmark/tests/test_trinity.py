@@ -11,7 +11,8 @@ import pytest
 
 from conftest import BENCH, HERE
 from test_harness import KEYS, drive
-from test_tokens import TINY_LIMITS, UNLISTED, assert_not_correct, drive_fault
+from test_tokens import (BOTH_LM, TINY_LIMITS, UNLISTED, assert_not_correct,
+                         drive_fault)
 
 import costs
 import costs_window
@@ -127,9 +128,9 @@ def test_block_windows_and_the_scope_of_a_block():
     assert not rx.search("jvp(layer/1_DecoderBlock)/attn/Wq/dot_general")
 
 
-def test_the_cell_reads_the_four_new_metrics_and_the_unlisted_nine():
+def test_the_cell_reads_its_listed_metrics_and_the_unlisted_eight():
     names = {d["name"] for d, _ in run.load_metrics(CELL)}
-    assert names == NEW | UNLISTED
+    assert names == NEW | BOTH_LM | UNLISTED
     for other in ("resnet50-train-b128", "vgg16-train-b128",
                   "deepseek-v2-lite-ep8-train-seq4096"):
         assert not NEW & {d["name"] for d, _ in run.load_metrics(other)}
