@@ -7,3 +7,4 @@ from deeplearning4j_tpu.models.vgg import vgg16
 from deeplearning4j_tpu.models.transformer import moe_transformer_lm, transformer_lm
 from deeplearning4j_tpu.models.deepseek_v2 import deepseek_v2_lite
 from deeplearning4j_tpu.models.trinity_mini import trinity_mini
+from deeplearning4j_tpu.models.keye_vl2 import keye_vl2_lm

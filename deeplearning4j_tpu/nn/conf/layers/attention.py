@@ -28,7 +28,7 @@ Array = jax.Array
 
 
 def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
-           scale=None, window=None) -> Array:
+           scale=None, window=None, select=None, with_lse: bool = False):
     """The ONE attention-core dispatch every attention-bearing layer uses.
 
     Single device (no active ParallelContext): flash_attention (Pallas on
@@ -45,7 +45,11 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
     value: latent attention's core. ``window`` (a query sees the ``window``
     keys ending at itself) and fewer key/value heads than query heads
     (``k``, ``v`` [B, T, G, D]: query head h reads head ``h // (H // G)``)
-    reach the single-device core as they are.
+    reach the single-device core as they are, and so does ``select`` (int8
+    [B, T, T], the keys each query attends to, as ``ops.indexer.select_topk``
+    chooses them: the softmax runs over those alone); ``with_lse`` then
+    returns ``(out, lse [B * H, T])``, the log-sum-exp for use under
+    ``stop_gradient`` (``flash_attention``).
     """
     from deeplearning4j_tpu.ops.pallas_kernels import (
         flash_attention, masked_attention,
@@ -54,17 +58,20 @@ def attend(q: Array, k: Array, v: Array, causal: bool, mask=None,
 
     ctx = pctx.current()
     if (scale is not None or v.shape[-1] != q.shape[-1]
-            or window is not None or k.shape[2] != q.shape[2]):
+            or window is not None or k.shape[2] != q.shape[2]
+            or select is not None):
         # latent attention (values narrower than keys, a score scale of its
-        # own), a window, grouped key/value heads: the single-device core
-        # only; neither the sequence-parallel bodies nor the key-masked
-        # kernel know those shapes yet
+        # own), a window, grouped key/value heads, a selection of keys per
+        # query: the single-device core only; neither the sequence-parallel
+        # bodies nor the key-masked kernel know those shapes yet
         if mask is not None or (ctx is not None and ctx.seq_axis is not None):
             raise NotImplementedError(
                 "attention with its own scale or value width (latent "
-                "attention), a window, or fewer key/value heads than query "
-                "heads runs unmasked on one device only")
-        return flash_attention(q, k, v, causal, False, False, scale, window)
+                "attention), a window, a selection of keys per query, or "
+                "fewer key/value heads than query heads runs unmasked on "
+                "one device only")
+        return flash_attention(q, k, v, causal, False, False, scale, window,
+                               select, with_lse)
     if ctx is not None and ctx.seq_axis is not None and mask is None:
         from deeplearning4j_tpu.parallel.ring_attention import (
             ring_attention_sharded, ulysses_attention_sharded)

@@ -18,16 +18,29 @@ and not a class of its own:
   RMS norm over each head's query and key (one scale vector for all heads),
   the rotary embedding on the whole head in halves where ``rope_theta`` is
   set, a causal mask cut to the last ``window`` keys where that is set, and
-  an output gate ``sigmoid(u Wz)`` on the core's result.
+  (``output_gate``, on by default) an output gate ``sigmoid(u Wz)`` on the
+  core's result. ``index_heads`` > 0 adds a learned indexer that chooses
+  each query's keys (``ops/indexer.py``): on the block's normed input with
+  the gradient stopped, ``qI = rope(u WqI)`` (``index_heads`` heads
+  ``index_dim`` wide), ``kI = rope(rms(u WkI))`` (ONE key head),
+  ``w = u Ww * index_heads^-0.5 * index_dim^-0.5``, ``I[t, s] = sum_j
+  w[t, j] relu(qI[t, j] . kI[s])``; the core runs over each query's
+  ``min(t + 1, index_topk)`` keys with the largest ``I`` alone, and the
+  indexer's four leaves learn from ``index_loss``, the mean over queries of
+  ``KL(p || softmax_chosen I)`` with ``p`` the core's own head-averaged
+  probabilities (a constant), and from nothing else; the trunk gets no
+  gradient from it.
 * ``ffn``: ``"swiglu"`` (``(silu(u Wg) * (u Wu)) Wd``, no biases) or
   ``"moe"``: routing over ``n_experts`` router outputs, a shared expert
   computed for every token, and the routed experts this chip holds
   (``experts_held``, a ``[first, end)`` range of expert ids; None: all)
-  through the dropless grouped dispatch of ``moe.grouped_expert_ffn``. What
-  absent experts would add is left out: a chip's share of an
-  expert-parallel layer, without the exchange. ``router``: ``"softmax"``,
+  through the dropless grouped dispatch of ``moe.grouped_expert_ffn``
+  (``dispatch_eighths``: its usual buffer's rows in eighths of all (token,
+  choice) pairs, 2 by default). What absent experts would add is left out:
+  a chip's share of an expert-parallel layer, without the exchange. ``router``: ``"softmax"``,
   the ``experts_per_token`` largest probabilities taken greedily and
-  weighted by themselves (not renormalised), with an auxiliary loss; or
+  weighted by themselves (``router_renorm``: divided by their sum), with
+  an auxiliary loss; or
   ``"sigmoid_bias"``: sigmoid scores, the largest of ``score + bias`` taken,
   weighted by their scores (without the bias) renormalised to
   ``route_scale``, no auxiliary loss, and the bias moved against each
@@ -43,7 +56,9 @@ K-step program hands back with its losses, and by its router ``aux_loss``
 (the sequence-wise balance term of DeepSeek-V2's ``seq_aux`` branch, over all
 router outputs; the fit loop adds ``aux_loss_weight`` times it) or
 ``router_bias`` (float32 [n_experts], from 0): state a step changes without
-a gradient, as batch norm's running statistics are. After a training step
+a gradient, as batch norm's running statistics are. A block with an indexer
+also carries ``index_loss``, which the fit loop adds ``index_loss_weight``
+times (``multilayer._aux_losses``). After a training step
 ``bias += d - mean(d)`` with ``d = bias_update_rate * sign(mean(c) - c)``
 and ``c`` the step's (token, choice) pairs on each output, over this chip's
 tokens (a deployment sums ``c`` over its chips first).
@@ -64,6 +79,7 @@ from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.feedforward import _dense
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
+from deeplearning4j_tpu.ops import indexer
 
 _NORMS, _ATTENTIONS, _FFNS = ("rms",), ("mla", "gqa"), ("swiglu", "moe")
 _PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
@@ -120,6 +136,14 @@ class DecoderBlock(FeedForwardLayer):
     n_kv_heads: int = 0
     head_dim: int = 0
     window: Optional[int] = None
+    #: "gqa": ``sigmoid(u Wz)`` on the core's result (False: no ``Wz``)
+    output_gate: bool = True
+    #: "gqa": the indexer's heads (0: none), their width, the keys a query
+    #: keeps, and the weight of its loss in the step's
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_loss_weight: float = 1.0
     #: latent width, query/key widths without and with rotation,
     #: value width
     kv_rank: int = 0
@@ -142,6 +166,13 @@ class DecoderBlock(FeedForwardLayer):
     experts_held: Optional[list] = None
     router: str = "softmax"
     aux_loss_weight: float = 0.001
+    #: "softmax": the chosen probabilities divided by their sum
+    router_renorm: bool = False
+    #: rows of the usual dispatch buffer in eighths of all (token, choice)
+    #: pairs (``moe.grouped_expert_ffn``): 2 holds twice an even router's
+    #: share of an eighth of the experts; a layer that gets more takes the
+    #: slow full-size buffer for that step
+    dispatch_eighths: int = 2
     #: "sigmoid_bias": what the chosen scores add up to, and the bias's step
     route_scale: float = 1.0
     bias_update_rate: float = 0.001
@@ -157,6 +188,14 @@ class DecoderBlock(FeedForwardLayer):
             if getattr(self, field) not in known:
                 raise ValueError(f"DecoderBlock.{field} = "
                                  f"{getattr(self, field)!r}; known: {known}")
+        if self.index_heads and (self.attention != "gqa" or self.window
+                                 or self.index_dim < 2
+                                 or self.index_topk < 1):
+            raise ValueError(
+                "an indexer goes with \"gqa\" attention without a window, "
+                f"and needs index_dim and index_topk: {self.index_heads} "
+                f"heads of {self.index_dim}, topk {self.index_topk}, "
+                f"attention {self.attention!r}, window {self.window}")
 
     def set_n_in(self, itype: InputType) -> None:
         if not self.n_in:
@@ -203,7 +242,9 @@ class DecoderBlock(FeedForwardLayer):
             if not G or H % G:
                 raise ValueError(f"{H} query heads over {G} key/value heads")
             p["Wk"], p["Wv"] = w(F, G * D), w(F, G * D)
-            p["Wz"], p["Wo"] = w(F, H * D), w(H * D, F)
+            if self.output_gate:
+                p["Wz"] = w(F, H * D)
+            p["Wo"] = w(H * D, F)
             p["q_norm_g"] = jnp.ones((D,), jnp.float32)
             p["k_norm_g"] = jnp.ones((D,), jnp.float32)
         else:
@@ -226,16 +267,22 @@ class DecoderBlock(FeedForwardLayer):
                 p["Sg"], p["Su"] = (w(F, self.shared_hidden),
                                     w(F, self.shared_hidden))
                 p["Sd"] = w(self.shared_hidden, F)
+        if self.index_heads:
+            J, E = self.index_heads, self.index_dim
+            p["WqI"], p["WkI"], p["Ww"] = w(F, J * E), w(F, E), w(F, J)
+            p["kI_norm_g"] = jnp.ones((E,), jnp.float32)
         return p
 
     def regularizable_params(self):
         return ("Wq", "Wkva", "Wkvb", "Wk", "Wv", "Wz", "Wo", "Wg", "Wu",
-                "Wd", "Eg", "Eu", "Ed", "Sg", "Su", "Sd")
+                "Wd", "Eg", "Eu", "Ed", "Sg", "Su", "Sd", "WqI", "WkI", "Ww")
 
     def init_state(self, itype: InputType) -> dict:
+        index = ({"index_loss": jnp.zeros((), jnp.float32)}
+                 if self.index_heads else {})
         if self.ffn != "moe":
-            return {}
-        rows = {"moe_rows": jnp.zeros((3,), jnp.int32)}
+            return index
+        rows = {"moe_rows": jnp.zeros((3,), jnp.int32), **index}
         if self.router == "sigmoid_bias":
             return {"router_bias": jnp.zeros((self.n_experts,), jnp.float32),
                     **rows}
@@ -245,7 +292,23 @@ class DecoderBlock(FeedForwardLayer):
     def _norm(self, params, name, x):
         return rms_norm(x, params[name + "_g"], self.norm_eps)
 
+    def _index_part(self, params, u):
+        """The indexer's operands from the normed input, no gradient to it:
+        ``(qI [B, T, J, E], kI [B, T, E], w [B, T, J] float32)``."""
+        B, T, _ = u.shape
+        J, E = self.index_heads, self.index_dim
+        u = jax.lax.stop_gradient(u)
+        qi = _mm(u, params["WqI"]).reshape(B, T, J, E)
+        ki = rms_norm(_mm(u, params["WkI"]), params["kI_norm_g"],
+                      self.norm_eps).reshape(B, T, 1, E)
+        if self.rope_theta:
+            freq = rope_inv_freq(E, self.rope_theta, self.rope_scaling)
+            qi, ki = apply_rope(qi, freq, True), apply_rope(ki, freq, True)
+        w = _mm(u, params["Ww"]).astype(jnp.float32) * (J * E) ** -0.5
+        return qi, ki.reshape(B, T, E), w
+
     def _gqa_part(self, params, u, mask):
+        """-> ``(A(u), index_loss or None)``."""
         B, T, _ = u.shape
         H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
         q = rms_norm(_mm(u, params["Wq"]).reshape(B, T, H, D),
@@ -253,19 +316,42 @@ class DecoderBlock(FeedForwardLayer):
         k = rms_norm(_mm(u, params["Wk"]).reshape(B, T, G, D),
                      params["k_norm_g"], self.norm_eps)
         v = _mm(u, params["Wv"]).reshape(B, T, G, D)
-        z = _mm(u, params["Wz"])
+        if self.output_gate:
+            z = _mm(u, params["Wz"])
         if self.rope_theta:
             freq = rope_inv_freq(D, self.rope_theta, self.rope_scaling)
             q, k = apply_rope(q, freq, True), apply_rope(k, freq, True)
-        with jax.named_scope("core"):
-            o = attend(q, k, v, True, mask, window=self.window)
-        gate = jax.nn.sigmoid(z.astype(at_least_f32(z.dtype))).astype(z.dtype)
-        return _mm(o.reshape(B, T, H * D) * gate, params["Wo"])
+        index_loss = None
+        if self.index_heads:
+            # the indexer's projections are dense products of ``attn``; its
+            # scope holds the kernels alone (scores, selection, loss)
+            qi, ki, w = self._index_part(params, u)
+            with jax.named_scope("indexer"):
+                scores = indexer.index_scores(qi, ki, w)
+                with jax.named_scope("select"):
+                    select, lse_i = indexer.select_topk(scores,
+                                                        self.index_topk)
+            with jax.named_scope("core"):
+                o, lse = attend(q, k, v, True, mask, select=select,
+                                with_lse=True)
+            with jax.named_scope("indexer"):
+                index_loss = indexer.index_kl(qi, ki, w, scores, select,
+                                              lse_i, q, k, lse, D ** -0.5)
+        else:
+            with jax.named_scope("core"):
+                o = attend(q, k, v, True, mask, window=self.window)
+        o = o.reshape(B, T, H * D)
+        if self.output_gate:
+            o = o * jax.nn.sigmoid(
+                z.astype(at_least_f32(z.dtype))).astype(z.dtype)
+        return _mm(o, params["Wo"]), index_loss
 
     def attention_part(self, params, u, mask=None):
-        """``A(u)``: u [B, T, F] normed input -> [B, T, F]."""
+        """``A(u)``: u [B, T, F] normed input -> [B, T, F]; a block with an
+        indexer returns ``(A(u), index_loss)``."""
         if self.attention == "gqa":
-            return self._gqa_part(params, u, mask)
+            a, index_loss = self._gqa_part(params, u, mask)
+            return (a, index_loss) if self.index_heads else a
         B, T, _ = u.shape
         H = self.n_heads
         dn, dr, dv, r = (self.qk_nope_dim, self.qk_rope_dim, self.v_dim,
@@ -290,7 +376,8 @@ class DecoderBlock(FeedForwardLayer):
     def route(self, params, u, bias=None):
         """-> (choice [B, T, k] int32 over all experts, weight [B, T, k],
         probs [B, T, E] float32): softmax over every router output, the k
-        largest taken greedily, each weighted by its own probability. With
+        largest taken greedily, each weighted by its own probability
+        (``router_renorm``: divided by the sum of the k). With
         the ``"sigmoid_bias"`` router: sigmoid scores, the k largest of
         ``score + bias`` taken, weighted by their scores renormalised to
         ``route_scale`` (the bias chooses and weighs nothing)."""
@@ -308,6 +395,8 @@ class DecoderBlock(FeedForwardLayer):
             return choice.astype(jnp.int32), weight, scores
         probs = jax.nn.softmax(logits, axis=-1)
         weight, choice = jax.lax.top_k(probs, self.experts_per_token)
+        if self.router_renorm:
+            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
         return choice.astype(jnp.int32), weight, probs
 
     def next_bias(self, bias, choice):
@@ -335,7 +424,8 @@ class DecoderBlock(FeedForwardLayer):
         """The held experts' part of the layer for [S, F] tokens:
         ``(y [S, F], rows int32 [3])``."""
         return grouped_expert_ffn(u2d, choice2d, weight2d, params["Eg"],
-                                  params["Eu"], params["Ed"], self._held()[0])
+                                  params["Eu"], params["Ed"], self._held()[0],
+                                  self.dispatch_eighths)
 
     def shared_part(self, params, u):
         return swiglu(u, params["Sg"], params["Su"], params["Sd"])
@@ -344,16 +434,21 @@ class DecoderBlock(FeedForwardLayer):
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         B, T, F = x.shape
         sandwich = self.norm_placement == "sandwich"
+        index = {}
         with jax.named_scope("attn"):
             a = self.attention_part(params, self._norm(params, "norm1", x),
                                     mask)
+            if self.index_heads:
+                a, index_loss = a
+                index = {"index_loss": (index_loss if train
+                                        else jnp.zeros_like(index_loss))}
             h = x + (self._norm(params, "post1", a) if sandwich else a)
         u = self._norm(params, "norm2", h)
         if self.ffn == "swiglu":
             with jax.named_scope("ffn"):
                 f = swiglu(u, params["Wg"], params["Wu"], params["Wd"])
                 y = h + (self._norm(params, "post2", f) if sandwich else f)
-            return self.act_fn()(y), state
+            return self.act_fn()(y), {**state, **index}
         with jax.named_scope("moe/router"):
             choice, weight, probs = self.route(params, u,
                                                state.get("router_bias"))
@@ -374,21 +469,33 @@ class DecoderBlock(FeedForwardLayer):
             if train:
                 with jax.named_scope("update"):
                     bias = self.next_bias(bias, choice)
-            new_state = {"router_bias": bias, "moe_rows": rows}
+            new_state = {"router_bias": bias, "moe_rows": rows, **index}
         else:
             new_state = {"aux_loss": aux if train else jnp.zeros_like(aux),
-                         "moe_rows": rows}
+                         "moe_rows": rows, **index}
         return self.act_fn()(h + f), new_state
 
     def attn_score_entries(self, batch: int, seq: int, dtype) -> tuple:
         """``(computed, visible)`` score entries of one step's forward core
         over ``batch`` sequences of ``seq`` tokens in ``dtype``: what the
         flash kernel's plan computes under this block's mask and what the
-        mask leaves visible (``pallas_kernels.flash_score_entries``)."""
+        mask leaves visible (``pallas_kernels.flash_score_entries``). A
+        block with an indexer computes the causal plan's tiles and leaves
+        the selected pairs visible."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_score_entries
 
         dv = self.head_dim if self.attention == "gqa" else self.v_dim
         computed, visible = flash_score_entries(seq, self._qk_dim(), dv,
                                                 dtype, self.window)
         heads = batch * self.n_heads
+        if self.index_heads:
+            visible = self.index_pairs(1, seq)[1]
         return heads * computed, heads * visible
+
+    def index_pairs(self, batch: int, seq: int) -> tuple:
+        """``(scored, selected)`` (query, key) pairs of one step's indexer
+        over ``batch`` sequences of ``seq`` tokens: every causal pair is
+        scored, and query t keeps ``min(t + 1, index_topk)``."""
+        k = min(self.index_topk, seq)
+        return (batch * (seq * (seq + 1) // 2),
+                batch * (k * (k + 1) // 2 + (seq - k) * k))

@@ -191,17 +191,17 @@ def _tiled_rows(group_sizes):
     return jnp.sum(tiles) * t
 
 
-def _usual_bound(pairs: int) -> int:
-    """The smaller of the dispatch buffer's two static sizes: a quarter of
-    all pairs in whole row tiles (with an even router and an eighth of the
-    experts held, twice what is routed here), or all of them where that is
-    less than a tile."""
-    quarter = -(-pairs // (4 * GROUP_ROW_TILE)) * GROUP_ROW_TILE
-    return quarter if GROUP_ROW_TILE <= quarter < pairs else pairs
+def _usual_bound(pairs: int, eighths: int = 2) -> int:
+    """The smaller of the dispatch buffer's two static sizes: ``eighths``
+    eighths of all pairs in whole row tiles (by default a quarter: with an
+    even router and an eighth of the experts held, twice what is routed
+    here), or all of them where that is less than a tile."""
+    share = -(-pairs * eighths // (8 * GROUP_ROW_TILE)) * GROUP_ROW_TILE
+    return share if GROUP_ROW_TILE <= share < pairs else pairs
 
 
 def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
-                       first_held: int = 0):
+                       first_held: int = 0, usual_eighths: int = 2):
     """The routed experts' part of a top-k expert layer for the experts held
     here, dropless: ``y[t] = sum over t's choices e held here of
     weight[t, e] * E_e(x[t])``, ``E_e`` a gated SiLU feed-forward
@@ -227,9 +227,13 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
 
     **No pair is dropped whatever the imbalance.** The row buffer has one of
     two static sizes, chosen on the device from the count of pairs routed
-    here (``lax.cond``): ``_usual_bound`` (a quarter of all pairs) where
-    they fit in it, else all ``S * k`` rows, which is every pair there is
-    (one expert may take them all). Rows past the routed count belong to no
+    here (``lax.cond``): ``_usual_bound`` (``usual_eighths`` eighths of all
+    pairs, a quarter by default) where they fit in it, else all ``S * k``
+    rows, which is every pair there is (one expert may take them all). The
+    full buffer is several times slower (its trips run over every row), so
+    a model whose router sends a layer more than twice an even share asks
+    for a larger usual one: m experts that every token chooses are m eighths
+    of all pairs at 8 choices a token. Rows past the routed count belong to no
     expert held here: the grouped products do not compute them (a kernel
     leaves them unwritten), the way out selects them away, their cotangent
     is dropped on the way back, their weight is 0 and takes no gradient, and
@@ -287,7 +291,7 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
 
     operands = (x2d, weight) + tuple(
         w.astype(pol.compute_dtype) for w in (w_gate, w_up, w_down))
-    usual = _usual_bound(pairs)
+    usual = _usual_bound(pairs, usual_eighths)
     if usual < pairs:
         # each branch is rematerialised: the cond's backward then needs the
         # operands alone, not both branches' intermediates (the full

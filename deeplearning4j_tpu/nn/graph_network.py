@@ -22,7 +22,7 @@ from deeplearning4j_tpu import common
 from deeplearning4j_tpu.nn.conf.graphconf import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.vertices import LayerVertex
 from deeplearning4j_tpu.nn.multilayer import (
-    LazyScore, _batch_size, _updater_spec,
+    _AUX_TERMS, LazyScore, _batch_size, _updater_spec,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -149,17 +149,20 @@ def graph_loss(conf, params, states, inputs, labels, rng, fmasks=None, lmasks=No
 
 
 def _aux_losses(conf, new_states):
-    """Layer-declared auxiliary objectives (MoE load-balance etc.), published
-    through the vertex state pytree as "aux_loss". Shared by the standard and
-    TBPTT train objectives so a MoE vertex keeps its balance term under
-    truncated BPTT too (reference computeGradientAndScore:952 adds every
-    layer's contribution regardless of backprop type)."""
+    """Layer-declared auxiliary objectives (MoE load-balance, an indexer's
+    divergence: ``multilayer._AUX_TERMS``), published through the vertex
+    state pytree. Shared by the standard and TBPTT train objectives so a MoE
+    vertex keeps its balance term under truncated BPTT too (reference
+    computeGradientAndScore:952 adds every layer's contribution regardless
+    of backprop type)."""
     total = jnp.float32(0.0)
     for name, ns in new_states.items():
-        if isinstance(ns, dict) and "aux_loss" in ns:
-            vertex = conf.vertices[name]
-            w = getattr(getattr(vertex, "layer", None), "aux_loss_weight", 1.0)
-            total = total + w * ns["aux_loss"]
+        if not isinstance(ns, dict):
+            continue
+        layer = getattr(conf.vertices[name], "layer", None)
+        for term, weight in _AUX_TERMS:
+            if term in ns:
+                total = total + getattr(layer, weight, 1.0) * ns[term]
     return total
 
 

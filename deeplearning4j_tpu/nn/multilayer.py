@@ -43,6 +43,7 @@ from deeplearning4j_tpu.observability.flight_recorder import (
     global_recorder as _flight_recorder,
 )
 from deeplearning4j_tpu.observability.names import (
+    ATTN_INDEX_PAIRS_SCORED_TOTAL, ATTN_PAIRS_SELECTED_TOTAL,
     ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
     FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
     MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
@@ -106,6 +107,13 @@ _attn_computed = _obs_registry().counter(
 _attn_visible = _obs_registry().counter(
     ATTN_SCORE_ENTRIES_VISIBLE_TOTAL, "attention score entries the masks of "
     "the same cores leave visible, by decoder block")
+_attn_scored = _obs_registry().counter(
+    ATTN_INDEX_PAIRS_SCORED_TOTAL, "(query, key) pairs the indexers of "
+    "dispatched steps scored (every causal pair of a sequence), by decoder "
+    "block")
+_attn_selected = _obs_registry().counter(
+    ATTN_PAIRS_SELECTED_TOTAL, "(query, key) pairs the same indexers "
+    "selected for the core (min(t + 1, topk) a query), by decoder block")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -214,14 +222,27 @@ def loss_fn(conf: MultiLayerConfiguration, params_list, state_list, x, y, rng,
     return loss, new_states
 
 
+#: auxiliary objectives a layer may publish as scalars in its state, each
+#: with the layer's field that weighs it
+_AUX_TERMS = (("aux_loss", "aux_loss_weight"),
+              ("index_loss", "index_loss_weight"))
+
+
 def _aux_losses(layers, new_states):
-    """Sum layer-declared auxiliary objectives (a layer publishes one by
-    returning an "aux_loss" scalar in its state — e.g. MoELayer's Switch
-    load-balance term, weighted by its ``aux_loss_weight``)."""
+    """Sum layer-declared auxiliary objectives: a layer publishes one by
+    returning a scalar in its state under a name of ``_AUX_TERMS``:
+    "aux_loss" (e.g. MoELayer's Switch load-balance term, a DecoderBlock's
+    sequence-wise one) weighted by its ``aux_loss_weight``, and "index_loss"
+    (a DecoderBlock's indexer: the divergence of its scores from the core's
+    probabilities, whose gradient reaches the indexer's leaves alone)
+    weighted by its ``index_loss_weight``. A layer may publish both."""
     total = jnp.float32(0.0)
     for layer, ns in zip(layers, new_states):
-        if isinstance(ns, dict) and "aux_loss" in ns:
-            total = total + getattr(layer, "aux_loss_weight", 1.0) * ns["aux_loss"]
+        if not isinstance(ns, dict):
+            continue
+        for term, weight in _AUX_TERMS:
+            if term in ns:
+                total = total + getattr(layer, weight, 1.0) * ns[term]
     return total
 
 
@@ -529,7 +550,9 @@ class LazyScore:
 
     def _note_attn_entries(self, steps: int, x) -> None:
         """Book ``steps`` dispatched steps' attention score entries
-        (``dl4j_attn_score_entries_*``) for the decoder blocks: a function
+        (``dl4j_attn_score_entries_*``, and for a block with an indexer
+        ``dl4j_attn_index_pairs_scored_total`` and
+        ``dl4j_attn_pairs_selected_total``) for the decoder blocks: a function
         of the batch's shape and each block's mask alone, so nothing is read
         from the device."""
         layers = getattr(getattr(self, "conf", None), "layers", None) or ()
@@ -546,11 +569,16 @@ class LazyScore:
                      else common.get_policy()).output_dtype
             self._attn_entries_key = key
             self._attn_entries = [
-                (str(i), *l.attn_score_entries(batch, seq, dtype))
+                (str(i), *l.attn_score_entries(batch, seq, dtype),
+                 *(l.index_pairs(batch, seq)
+                   if getattr(l, "index_heads", 0) else (0, 0)))
                 for i, l in blocks]
-        for layer, computed, visible in self._attn_entries:
+        for layer, computed, visible, scored, selected in self._attn_entries:
             _attn_computed.labels(layer=layer).inc(steps * computed)
             _attn_visible.labels(layer=layer).inc(steps * visible)
+            if scored:
+                _attn_scored.labels(layer=layer).inc(steps * scored)
+                _attn_selected.labels(layer=layer).inc(steps * selected)
 
     #: ``(rows (K, layers, 3) on the device, tokens)`` of the dispatched
     #: groups whose expert-layer rows the host has not read yet

@@ -52,6 +52,8 @@ MOE_EXPERT_ROWS_MAX = "dl4j_moe_expert_rows_max"
 MOE_EXPERT_ROWS_MAX_TOTAL = "dl4j_moe_expert_rows_max_total"
 ATTN_SCORE_ENTRIES_COMPUTED_TOTAL = "dl4j_attn_score_entries_computed_total"
 ATTN_SCORE_ENTRIES_VISIBLE_TOTAL = "dl4j_attn_score_entries_visible_total"
+ATTN_INDEX_PAIRS_SCORED_TOTAL = "dl4j_attn_index_pairs_scored_total"
+ATTN_PAIRS_SELECTED_TOTAL = "dl4j_attn_pairs_selected_total"
 
 # --- recurrent engine (ops/lstm.py) ----------------------------------------
 LSTM_DISPATCH_TOTAL = "dl4j_lstm_dispatch_total"

@@ -340,7 +340,7 @@ def _flash_params(semantics, need: int):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
                       blk_k: int, scale: float, has_mask: bool,
-                      window: int = None):
+                      window: int = None, has_select: bool = False):
     """One (batch*head, q-block, k-block) program of the online softmax.
 
     The k-block axis is the innermost, sequential grid axis: K/V arrive one
@@ -360,12 +360,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
     ``window`` the k-block axis is as long as the band is wide
     (``_window_steps``) and starts at the q-block's first live k-block. A
     row with no visible key in a tile leaves ``exp(0)`` behind; the row's
-    diagonal tile, its last, wipes that with ``alpha = 0``.
+    diagonal tile, its last, wipes that with ``alpha = 0``. With has_select a
+    (1, blk_q, blk_k) int8 block of the per-query selection precedes the
+    outputs: a pair that is not selected gets a -inf logit in EVERY live
+    tile (the selection lies within the causal half and stands for the
+    mask); every row selects a key, and the first tile that holds one wipes
+    what the tiles before it left, as above.
     """
-    if has_mask:
-        km_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
-    else:
-        o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
+    rest = list(rest)
+    km_ref = rest.pop(0) if has_mask else None
+    sel_ref = rest.pop(0) if has_select else None
+    o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
     qi = pl.program_id(1)
     step = kj = pl.program_id(2)
     if window is not None:
@@ -383,7 +388,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, causal: bool, blk_q: int,
         if has_mask:
             km_blk = km_ref[0, cols, 0].astype(jnp.float32)
             s = jnp.where(km_blk[None, :] > 0, s, _NEG)
-        if masked:
+        if has_select:
+            s = jnp.where(sel_ref[0, rows, cols] != 0, s, _NEG)
+        elif masked:
             s = _causal_mask(s, qi * blk_q + rows.start,
                              kj * blk_k + cols.start, window=window)
         m = m_sc[rows, :]                                 # (rows, 1)
@@ -494,6 +501,23 @@ def _head_group(q: Array, k: Array, v: Array, key_mask=None) -> int:
     return H // G
 
 
+def _check_select(select, causal: bool, window, key_mask, shape):
+    """A selection is int8 [B, Tq, Tk] over causal self-attention, every
+    selected pair within the causal half (what ``indexer.select_topk``
+    makes): it stands for the mask, so neither a window nor a key mask goes
+    with it."""
+    if select is None:
+        return
+    if (not causal or window is not None or key_mask is not None
+            or shape[1] != shape[2] or tuple(select.shape) != tuple(shape)
+            or select.dtype != jnp.int8):
+        raise ValueError(
+            "a selection needs causal self-attention without a window or a "
+            f"key mask and an int8 [B, T, T] matrix: causal {causal}, window "
+            f"{window}, select {select.dtype}{list(select.shape)} for "
+            f"{list(shape)}")
+
+
 def _check_window(window, causal: bool, tq: int, tk: int, key_mask=None):
     if window is None:
         return
@@ -506,17 +530,21 @@ def _check_window(window, causal: bool, tq: int, tk: int, key_mask=None):
 def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
                    blk_q: int = None, blk_k: int = None,
                    interpret: bool = False, key_mask: Array = None,
-                   scale: float = None, window: int = None):
+                   scale: float = None, window: int = None,
+                   select: Array = None):
     """q: (B, T, H, Dk), k: (B, T, G, Dk), v: (B, T, G, Dv) -> (out (B, T, H,
     Dv), lse (B*H, Tq) f32); G divides H, and query head h reads key/value
     head ``h // (H // G)`` from where it lies, never repeated. None block
     sizes -> chosen from the shapes (``_flash_tiles``). key_mask: optional
     [B, Tk] {0,1} key-padding mask. ``scale`` None is ``Dk ** -0.5``.
-    ``window``: a query sees the ``window`` keys ending at itself."""
+    ``window``: a query sees the ``window`` keys ending at itself.
+    ``select``: int8 [B, Tq, Tk], the pairs the core runs over
+    (``_check_select``)."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     group = _head_group(q, k, v, key_mask)
     _check_window(window, causal, Tq, Tk, key_mask)
+    _check_select(select, causal, window, key_mask, (B, Tq, Tk))
     blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
@@ -524,7 +552,7 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
 
     kernel = functools.partial(_flash_fwd_kernel, causal=causal, blk_q=blk_q,
                                blk_k=blk_k, scale=scale, has_mask=has_mask,
-                               window=window)
+                               window=window, has_select=select is not None)
     kv = _kv_block(causal, blk_q, blk_k, window, group)
     n_k = Tk // blk_k if window is None else _window_steps(
         Tq // blk_q, blk_q, Tk // blk_k, blk_k, window, True)
@@ -537,6 +565,12 @@ def _flash_forward(q: Array, k: Array, v: Array, causal: bool,
     if has_mask:
         in_specs.append(pl.BlockSpec((1, blk_k, 1), kv))
         operands.append(_bh_mask(key_mask, H))
+    if select is not None:
+        # one selection for all the heads of a sequence; a dead tile names
+        # the diagonal's block again, as K and V do
+        in_specs.append(pl.BlockSpec(
+            (1, blk_q, blk_k), lambda bh, i, j: (bh // H, i, kv(bh, i, j)[1])))
+        operands.append(select)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, Tq // blk_q, n_k),
@@ -579,6 +613,21 @@ def _attention_xla(q, k, v, causal, scale=None, window=None):
     _check_window(window, causal, q.shape[1], k.shape[1])
     k, v = _repeat_kv(q, k, v)
     return attention_reference(q, k, v, causal, scale, window).astype(q.dtype)
+
+
+def _selected_attention_xla(q, k, v, select, scale=None):
+    """The statement of the core over a selection: softmax over each
+    query's selected keys alone -> (out [B, T, H, Dv], lse [B * H, T])."""
+    B, T, H, D = q.shape
+    k, v = _repeat_kv(q, k, v)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s * (D ** -0.5 if scale is None else scale)
+    s = jnp.where(select[:, None] != 0, s, _NEG)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype), lse.reshape(B * H, T)
 
 
 def _in_shard_map() -> bool:
@@ -770,11 +819,11 @@ def _masked_bwd_rule(causal, interpret, force, res, g):
 _masked_attention_vjp.defvjp(_masked_fwd_rule, _masked_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
                     interpret: bool = False,
                     force_pallas: bool = False,
-                    scale: float = None, window: int = None) -> Array:
+                    scale: float = None, window: int = None,
+                    select: Array = None, with_lse: bool = False):
     """Tiled attention: pallas forward on TPU (shapes that don't tile fall
     back to the identical XLA math rather than erroring), XLA elsewhere.
     ``v`` may be narrower or wider than ``q`` and ``k`` (latent attention:
@@ -814,7 +863,31 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     sequence-parallel body whose per-shard lengths sit under the gate). It
     never overrides the hard constraints: TPU/interpret availability,
     tileable lengths, and the vma-checked shard_map guard, where
-    pallas_call would be rejected outright."""
+    pallas_call would be rejected outright.
+
+    ``select`` (causal self-attention only, no window): int8 [B, T, T], 1
+    where query t attends to key s, every 1 at ``s <= t`` and at least one
+    a row (``indexer.select_topk``): the softmax runs over each query's
+    selected keys alone, forward and backward, in every causal tile (this
+    first plan skips no tile the selection leaves empty). It takes no
+    gradient. ``with_lse`` (with a selection): also return the float32
+    log-sum-exp of each query head's scaled selected scores, [B * H, T],
+    whose cotangent is dropped: it is for use under ``stop_gradient``
+    (``indexer.index_kl`` makes the core's probabilities from it)."""
+    if select is None:
+        if with_lse:
+            raise ValueError("with_lse goes with a selection")
+        return _flash_attention(q, k, v, causal, interpret, force_pallas,
+                                scale, window)
+    _check_select(select, causal, window, None,
+                  (q.shape[0], q.shape[1], k.shape[1]))
+    out, lse = _selected_attention(q, k, v, select, interpret, force_pallas,
+                                   scale)
+    return (out, lse) if with_lse else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, interpret, force_pallas, scale, window):
     ok = _pallas_ok(q, k, interpret, force_pallas)
     _note_dispatch("flash_attention" + _variant(q, k, window), ok)
     if ok:
@@ -823,18 +896,66 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     return _attention_xla(q, k, v, causal, scale, window)
 
 
-def _variant(q, k, window) -> str:
+def _variant(q, k, window, select=None) -> str:
     """Suffix of a dispatch note's kernel name: which plan the call takes
-    (``_window``, ``_grouped``), so a fallback of either is seen by name."""
+    (``_window``, ``_select``, ``_grouped``), so a fallback of any is seen by
+    name."""
     return (("_window" if window is not None else "")
+            + ("_select" if select is not None else "")
             + ("_grouped" if k.shape[2] != q.shape[2] else ""))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _selected_attention(q, k, v, select, interpret, force, scale):
+    """-> (out, lse) of the core over ``select`` (``flash_attention``)."""
+    ok = _pallas_ok(q, k, interpret, force)
+    _note_dispatch("flash_attention" + _variant(q, k, None, select), ok)
+    if ok:
+        return _flash_forward(q, k, v, True, interpret=interpret, scale=scale,
+                              select=select)
+    return _selected_attention_xla(q, k, v, select, scale)
+
+
+def _selected_fwd_rule(q, k, v, select, interpret, force, scale):
+    tiled_bwd = (_pallas_ok(q, k, interpret, force)
+                 and _pallas_bwd_enabled(k.shape[1], force))
+    variant = _variant(q, k, None, select)
+    _note_dispatch("flash_attention_bwd" + variant, tiled_bwd)
+    _note_dispatch("flash_attention_bwd_fused" + variant,
+                   tiled_bwd and _fused_bwd_fits(
+                       q.shape[1], q.shape[-1], q.dtype))
+    if tiled_bwd:
+        _note_dispatch("flash_attention" + variant, True)
+        out, lse = _flash_forward(q, k, v, True, interpret=interpret,
+                                  scale=scale, select=select)
+        return (out, lse), (q, k, v, select, out, lse)
+    return (_selected_attention(q, k, v, select, interpret, force, scale),
+            (q, k, v, select, None, None))
+
+
+def _selected_bwd_rule(interpret, force, scale, res, g):
+    q, k, v, select, out, lse = res
+    g = g[0]                       # the log-sum-exp's cotangent is dropped
+    if lse is not None:
+        dq, dk, dv = _flash_backward(q, k, v, out, lse, g, True,
+                                     interpret=interpret, scale=scale,
+                                     select=select)
+    else:
+        _, vjp = jax.vjp(lambda a, b, c: _selected_attention_xla(
+            a, b, c, select, scale)[0], q, k, v)
+        dq, dk, dv = vjp(g)
+    return dq, dk, dv, None
+
+
+_selected_attention.defvjp(_selected_fwd_rule, _selected_bwd_rule)
 
 
 # --------------------------------------------------- pallas backward kernel
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       causal: bool, blk_q: int, blk_k: int, scale: float,
                       has_mask: bool, want_dq: bool, want_dkv: bool,
-                      window: int = None, n_q: int = None):
+                      window: int = None, n_q: int = None,
+                      has_select: bool = False):
     """One (q-block, k-block) tile of the backward, for whichever of dQ and
     dK/dV the call wants — ONE body, so each score tile is recomputed, masked
     and exponentiated once for every gradient it feeds:
@@ -858,9 +979,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     a ``window`` the streamed block axis (the innermost) is as long as the
     band is wide and counts from the outer block's first live tile: the
     diagonal's q-block where q-blocks stream (a step past the ``n_q``
-    q-blocks is dead), ``_first_live`` where k-blocks do."""
+    q-blocks is dead), ``_first_live`` where k-blocks do. With has_select a
+    (1, blk_k, blk_q) int8 block of the selection TRANSPOSED follows the
+    inputs and stands for the mask in every live tile, as in the forward."""
     rest = list(rest)
     km_ref = rest.pop(0) if has_mask else None
+    selt_ref = rest.pop(0) if has_select else None
     n_out = want_dq + 2 * want_dkv
     outs, scratch = rest[:n_out], rest[n_out:]
     fused = want_dq and want_dkv
@@ -896,7 +1020,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         st = _dot(k_blk, q_blk, 1, 1) * scale             # (cols, rows)
         if has_mask:
             st = jnp.where(km_ref[0, cols, :] > 0, st, _NEG)   # a column
-        if masked:
+        if has_select:
+            st = jnp.where(selt_ref[0, cols, rows] != 0, st, _NEG)
+        elif masked:
             st = _causal_mask(st, qi * blk_q + rows.start,
                               kj * blk_k + cols.start, q_axis=1,
                               window=window)
@@ -948,7 +1074,8 @@ def _fused_bwd_fits(tq: int, dk: int, dtype) -> bool:
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     blk_k: int = None, interpret: bool = False,
                     key_mask: Array = None, scale: float = None,
-                    fused: bool = None, window: int = None):
+                    fused: bool = None, window: int = None,
+                    select: Array = None):
     """Tiled pallas backward from the saved forward logsumexp. key_mask:
     optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
     ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``.
@@ -956,7 +1083,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
     (``_fused_bwd_fits``), else the dQ + dK/dV pair. With fewer key/value
     heads than query heads every query head reads its key/value head in
     place and writes its own part of dK and dV, which are summed over the
-    group afterwards (float32). ``window`` as the forward's."""
+    group afterwards (float32). ``window`` and ``select`` as the forward's;
+    the kernels read the selection transposed (one XLA transpose of it)."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     group = _head_group(q, k, v, key_mask)
@@ -974,7 +1102,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     axis=-1)[:, None, :]
     has_mask = key_mask is not None
     operands = [qr, kr, vr, gr, lse[:, None, :], delta] + (
-        [_bh_mask(key_mask, H)] if has_mask else [])
+        [_bh_mask(key_mask, H)] if has_mask else []) + (
+        [jnp.swapaxes(select, 1, 2)] if select is not None else [])
     nq, nk = Tq // blk_q, Tk // blk_k
 
     def call(want_dq: bool, want_dkv: bool):
@@ -1028,6 +1157,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                     pl.BlockSpec((1, 1, blk_q), row_map)]
         if has_mask:
             in_specs.append(pl.BlockSpec((1, blk_k, 1), km_map))
+        if select is not None:
+            in_specs.append(pl.BlockSpec(
+                (1, blk_k, blk_q),
+                lambda *g: (g[0] // H, k_idx(*g), q_idx(*g))))
         out_specs, out_shape, scratch = [], [], []
         if want_dq:
             out_specs.append(
@@ -1061,7 +1194,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
             functools.partial(
                 _flash_bwd_kernel, causal=causal, blk_q=blk_q, blk_k=blk_k,
                 scale=scale, has_mask=has_mask, want_dq=want_dq,
-                want_dkv=want_dkv, window=window, n_q=nq),
+                want_dkv=want_dkv, window=window, n_q=nq,
+                has_select=select is not None),
             grid=grid,
             in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=scratch,
@@ -1174,7 +1308,8 @@ def _flash_fwd_rule(q, k, v, causal, interpret, force, scale, window):
         out, lse = _flash_forward(q, k, v, causal, interpret=interpret,
                                   scale=scale, window=window)
         return out, (q, k, v, out, lse)
-    return (flash_attention(q, k, v, causal, interpret, force, scale, window),
+    return (_flash_attention(q, k, v, causal, interpret, force, scale,
+                             window),
             (q, k, v, None, None))
 
 
@@ -1188,7 +1323,7 @@ def _flash_bwd_rule(causal, interpret, force, scale, window, res, g):
                                   window=window)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ------------------------------------------------------- fused softmax-xent

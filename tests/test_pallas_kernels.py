@@ -826,3 +826,137 @@ def test_no_window_and_equal_heads_touch_none_of_the_new_plan(monkeypatch):
     for a, b in zip(before, after):
         assert np.array_equal(a, b)
     assert text() == jaxpr
+
+
+# ------------------------------------------- a learned selection of keys
+# (ops/indexer.py and flash_attention(select=...)): the kernels in interpret
+# mode against the XLA statement of the same math. Float32 operands at
+# "highest" precision on both sides, so what is left is the order of float32
+# sums: 1e-5 relative, far below what a wrong mask or a missing term moves.
+from deeplearning4j_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles small enough that 512 positions are four query tiles by two
+    key tiles, several row blocks and four chunks of the selection."""
+    from deeplearning4j_tpu.ops import indexer
+
+    monkeypatch.setattr(indexer, "_tiles", lambda t: (128, 256))
+    monkeypatch.setattr(indexer, "_SELECT_CHUNK", 128)
+    monkeypatch.setattr(indexer, "_SELECT_BLOCK_BYTES", 64 * 512 * 4)
+    monkeypatch.setattr(pk, "_TILE_SIZES", (128,))
+    return indexer
+
+
+def _selection_case(seed=0, B=2, T=512, H=4, G=2, D=32, J=3, E=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = jax.random.normal
+    return dict(q=n(ks[0], (B, T, H, D)), k=n(ks[1], (B, T, G, D)),
+                v=n(ks[2], (B, T, G, D)), qi=n(ks[3], (B, T, J, E)),
+                ki=n(ks[4], (B, T, E)), w=0.1 * n(ks[5], (B, T, J)))
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / max(np.abs(want).max(), 1e-30)
+
+
+def test_index_scores_kernel_matches_xla_below_the_diagonal(small_tiles):
+    ix, c = small_tiles, _selection_case()
+    with jax.default_matmul_precision("highest"):
+        want = ix.index_scores_xla(c["qi"], c["ki"], c["w"])
+        got = ix.index_scores(c["qi"], c["ki"], c["w"], interpret=True)
+    causal = np.tril(np.ones((512, 512), bool))
+    assert _rel(np.where(causal, got, 0), np.where(causal, want, 0)) < 1e-5
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_selection_is_exact_causal_and_breaks_ties_to_the_lower_key(
+        small_tiles, ties):
+    """Exactly ``min(t + 1, topk)`` keys a query, none above the diagonal
+    (which holds NaN here: it is never read), and the XLA statement's
+    choice entry for entry, also where a quarter-step rounding makes
+    thousands of scores equal."""
+    ix, c = small_tiles, _selection_case(1)
+    scores = ix.index_scores_xla(c["qi"], c["ki"], c["w"])
+    if ties:
+        scores = jnp.round(scores * 4) / 4
+    want, want_lse = ix.select_topk_xla(scores, 70)
+    causal = np.tril(np.ones((512, 512), bool))
+    got, lse = ix.select_topk(jnp.where(causal, scores, jnp.nan), 70,
+                              interpret=True)
+    got = np.asarray(got)
+    assert got.dtype == np.int8 and set(np.unique(got)) == {0, 1}
+    assert (got.sum(-1) == np.minimum(np.arange(512) + 1, 70)).all()
+    assert not got[:, ~causal].any()
+    assert np.array_equal(got, np.asarray(want))
+    assert np.abs(np.asarray(lse) - np.asarray(want_lse)).max() < 1e-5
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_core_over_a_selection_matches_xla_forward_and_backward(
+        small_tiles, fused):
+    """Grouped heads, four tiles a side; the one-kernel backward and the
+    dQ + dK/dV pair, which reads the selection transposed."""
+    ix, c = small_tiles, _selection_case(2)
+    select, _ = ix.select_topk_xla(
+        ix.index_scores_xla(c["qi"], c["ki"], c["w"]), 70)
+    with jax.default_matmul_precision("highest"):
+        def run(core):
+            def f(q, k, v):
+                out, lse = core(q, k, v)
+                return jnp.sum(jnp.sin(out)), (out, lse)
+            return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+                c["q"], c["k"], c["v"])
+
+        (_, (want, want_lse)), want_g = run(
+            lambda q, k, v: pk._selected_attention_xla(q, k, v, select))
+        (_, (got, lse)), got_g = run(lambda q, k, v: pk.flash_attention(
+            q, k, v, True, True, True, select=select, with_lse=True))
+        if not fused:
+            got_g = pk._flash_backward(
+                c["q"], c["k"], c["v"], got, lse, jnp.cos(got), True,
+                interpret=True, fused=False, select=select)
+    assert _rel(got, want) < 1e-5 and _rel(lse, want_lse) < 1e-5
+    for g, w in zip(got_g, want_g):
+        assert _rel(g, w) < 2e-5
+
+
+def test_indexer_loss_kernels_match_xla_and_reach_the_indexer_alone(
+        small_tiles):
+    ix, c = small_tiles, _selection_case(3)
+    scale = 32 ** -0.5
+    with jax.default_matmul_precision("highest"):
+        scores = ix.index_scores_xla(c["qi"], c["ki"], c["w"])
+        select, lse_i = ix.select_topk_xla(scores, 70)
+        _, lse = pk._selected_attention_xla(c["q"], c["k"], c["v"], select)
+        want, want_g = jax.value_and_grad(
+            lambda qi, ki, w: ix.index_kl_xla(qi, ki, w, select, c["q"],
+                                              c["k"], lse, scale),
+            argnums=(0, 1, 2))(c["qi"], c["ki"], c["w"])
+
+        def loss(qi, ki, w, q, k, lse):
+            return ix.index_kl(qi, ki, w, scores, select, lse_i, q, k, lse,
+                               scale, interpret=True)
+
+        got, got_g = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5))(
+            c["qi"], c["ki"], c["w"], c["q"], c["k"], lse)
+    assert abs(float(got) - float(want)) < 1e-5 * float(want) > 0
+    for g, w in zip(got_g[:3], want_g):
+        assert _rel(g, w) < 2e-5
+    # the core's operands are constants of this loss
+    assert all(not np.asarray(g).any() for g in got_g[3:])
+
+
+def test_a_selection_refuses_what_it_cannot_stand_for():
+    q = jnp.zeros((1, 128, 2, 8))
+    select = jnp.ones((1, 128, 128), jnp.int8)
+    with pytest.raises(ValueError, match="selection"):
+        pk.flash_attention(q, q, q, False, select=select)
+    with pytest.raises(ValueError, match="selection"):
+        pk.flash_attention(q, q, q, True, window=16, select=select)
+    with pytest.raises(ValueError, match="selection"):
+        pk.flash_attention(q, q, q, True, select=select.astype(jnp.int32))
+    with pytest.raises(ValueError, match="with_lse"):
+        pk.flash_attention(q, q, q, True, with_lse=True)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from deeplearning4j_tpu.ops import indexer
 from deeplearning4j_tpu.ops import lstm as lstm_engine
 from deeplearning4j_tpu.ops import paged_attention, quant
 from deeplearning4j_tpu.ops import pallas_kernels as pk
@@ -177,11 +178,13 @@ def _windowed(grad, window=2048, T=8192):
     return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
-def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8):
+def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8, eighths=2):
     """The dropless expert dispatch at DeepSeek-V2-Lite's widths: 16,384
     tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408; or
     at Trinity-Mini's (``benchmark/configs/trinity-mini-ep8.json``): 8
-    choices over 16 held of 128, experts 2048 x 1024."""
+    choices over 16 held of 128, experts 2048 x 1024; or at
+    Keye-VL-2.0-30B-A3B's: experts 2048 x 768 and a usual buffer of three
+    eighths of all pairs (``DecoderBlock.dispatch_eighths``)."""
     from deeplearning4j_tpu import common
     from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 
@@ -191,7 +194,7 @@ def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8):
 
     def fwd(x, c, w, g, u, d):
         with common.override_policy(policy):
-            return grouped_expert_ffn(x, c, w, g, u, d, 0)[0]
+            return grouped_expert_ffn(x, c, w, g, u, d, 0, eighths)[0]
 
     def bwd(x, c, w, g, u, d):
         return jax.grad(lambda *a: fwd(a[0], c, *a[1:]).astype(F32).sum(),
@@ -206,7 +209,86 @@ def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8):
     return (bwd if grad else fwd), shapes, (18 if grad else 8)
 
 
+def _indexer(part, grad=False, T=16384, topk=2048):
+    """The sparse-attention indexer and the core over its selection at
+    Keye-VL-2.0-30B-A3B's widths (``benchmark/configs/
+    keye-vl2-30b-a3b-ep8.json``): one sequence of 16,384, 32 query heads over
+    4 key/value heads of 128, 16 index heads of 64 over one key head, 2,048
+    keys a query. ``scores``: one kernel; ``select``: one (128 rows of all
+    16,384 keys in VMEM, the keys' scratch beside them); ``core``: the
+    forward with the selection's int8 tile, under grad the dQ + dK/dV pair
+    reading it transposed (a head's dQ does not fit at 16,384; at 4,096 the
+    ONE backward kernel); ``kl``: the
+    kernel that sums the heads' probabilities into a tile, under grad its
+    forward and the two of the index scores' backward."""
+    B, H, G, D, J, E = 1, 32, 4, 128, 16, 64
+    qkv = [((B, T, h, D), BF16) for h in (H, G, G)]
+    idx = [((B, T, J, E), BF16), ((B, T, E), BF16), ((B, T, J), F32)]
+    if part == "scores":
+        return (lambda a, b, c: indexer.index_scores(a, b, c)), idx, 1
+    if part == "select":
+        return (lambda s: indexer.select_topk(s, topk)), [((B, T, T), F32)], 1
+    if part == "core":
+        def fwd(q, k, v, s):
+            return pk.flash_attention(q, k, v, True, select=s, with_lse=True)
+
+        def bwd(q, k, v, s):
+            return jax.grad(lambda *a: fwd(*a, s)[0].astype(F32).sum(),
+                            argnums=(0, 1, 2))(q, k, v)
+
+        want = (2 if pk._fused_bwd_fits(T, D, BF16) else 3) if grad else 1
+        return (bwd if grad else fwd), qkv + [((B, T, T), jnp.int8)], want
+
+    if part == "block":
+        from deeplearning4j_tpu import common
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        from deeplearning4j_tpu.nn.conf.layers import DecoderBlock
+
+        # the whole attention of a block under grad, as the step program
+        # differentiates it: no kernel may be asked for a JVP of its own
+        # (the scores and the selection carry no gradient; the core and the
+        # indexer's loss bring theirs): 4 forward kernels, the core's
+        # backward pair and the index scores' backward pair
+        layer = DecoderBlock(
+            n_in=2048, n_out=2048, attention="gqa", n_heads=H, n_kv_heads=G,
+            head_dim=D, output_gate=False, rope_theta=1e7, index_heads=J,
+            index_dim=E, index_topk=topk, ffn="moe", n_experts=128,
+            experts_per_token=8, expert_hidden=768, experts_held=[0, 16])
+        params = jax.eval_shape(lambda: layer.init_params(
+            jax.random.PRNGKey(0), InputType.recurrent(2048, T)))
+        names = sorted(params)
+
+        def f(u, *leaves):
+            def loss(u, p):
+                with common.override_policy("bfloat16_full"):
+                    a, index_loss = layer.attention_part(p, u)
+                return a.astype(F32).sum() + index_loss
+            return jax.grad(loss, argnums=(0, 1))(u, dict(zip(names, leaves)))
+
+        shapes = [((B, T, 2048), BF16)] + [
+            (params[n].shape, F32) for n in names]
+        return f, shapes, 8
+
+    def kl(qi, ki, w, scores, s, lse_i, q, k, lse):
+        return indexer.index_kl(qi, ki, w, scores, s, lse_i, q, k, lse,
+                                D ** -0.5)
+
+    shapes = idx + [((B, T, T), F32), ((B, T, T), jnp.int8), ((B, T), F32),
+                    qkv[0], qkv[1], ((B * H, T), F32)]
+    if grad:
+        return (lambda *a: jax.grad(kl, argnums=(0, 1, 2))(*a)), shapes, 3
+    return kl, shapes, 1
+
+
 CASES = {
+    "indexer-scores-T16384-bfloat16": (_indexer, ("scores",)),
+    "indexer-select-T16384-k2048": (_indexer, ("select",)),
+    "indexer-core-fwd-T16384-bfloat16": (_indexer, ("core",)),
+    "indexer-core-grad-T16384-bfloat16": (_indexer, ("core", True)),
+    "indexer-kl-fwd-T16384-bfloat16": (_indexer, ("kl",)),
+    "indexer-kl-grad-T16384-bfloat16": (_indexer, ("kl", True)),
+    "indexer-core-grad-T4096-bfloat16": (_indexer, ("core", True, 4096)),
+    "indexer-block-grad-T16384-bfloat16": (_indexer, ("block",)),
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
     "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
@@ -219,6 +301,8 @@ CASES = {
     "grouped-grad-S16384-float32": (_grouped, (True, "float32")),
     "grouped-grad-S16384-k8-G16-bfloat16": (_grouped, (True, "bfloat16_full",
                                                        8, 1024, 16)),
+    "grouped-grad-S16384-k8-G16-H768-3eighths-bfloat16": (
+        _grouped, (True, "bfloat16_full", 8, 768, 16, 3)),
     **{f"flash-{'grad' if g else 'fwd'}-T{T}-{jnp.dtype(d).name}":
        (_flash, (T, d, g))
        for g in (False, True)
