@@ -64,10 +64,14 @@ class GlobalConf:
     mini_batch: bool = True
     use_regularization: bool = False
     max_num_line_search_iterations: int = 5
-    #: rematerialize per-layer activations in backward (jax.checkpoint):
-    #: trades recompute FLOPs for activation HBM — the TPU-native memory
-    #: lever for deep/long-sequence models (no reference equivalent; the
-    #: JVM runtime keeps all activations)
+    #: rematerialize per-layer activations in backward (jax.checkpoint
+    #: through ``ops/remat.py::checkpoint_layer``): trades recompute FLOPs
+    #: for activation HBM — the TPU-native memory lever for
+    #: deep/long-sequence models (no reference equivalent; the JVM runtime
+    #: keeps all activations). A layer keeps its input and its attention
+    #: kernels' outputs, which are not made again: per decoder block
+    #: tokens x heads x value width x 2 B of the flash core's output, and
+    #: B x T x T bytes of an indexer's int8 selection, until its backward
     gradient_checkpointing: bool = False
     #: per-network dtype policy, serialized with the config (the reference's
     #: one global Nd4j data type, made declarative): None -> whatever global

@@ -70,6 +70,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.common import at_least_f32
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -79,7 +80,7 @@ from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.feedforward import _dense
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
-from deeplearning4j_tpu.ops import indexer
+from deeplearning4j_tpu.ops import indexer, remat
 
 _NORMS, _ATTENTIONS, _FFNS = ("rms",), ("mla", "gqa"), ("swiglu", "moe")
 _PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
@@ -219,6 +220,9 @@ class DecoderBlock(FeedForwardLayer):
             return self.head_dim
         return self.qk_nope_dim + self.qk_rope_dim
 
+    def _v_dim(self) -> int:
+        return self.head_dim if self.attention == "gqa" else self.v_dim
+
     def _score_scale(self) -> float:
         scale = self._qk_dim() ** -0.5
         sc = self.rope_scaling
@@ -331,6 +335,10 @@ class DecoderBlock(FeedForwardLayer):
                 with jax.named_scope("select"):
                     select, lse_i = indexer.select_topk(scores,
                                                         self.index_topk)
+                    # a checkpointed layer keeps both (ops/remat.py): the
+                    # recomputed forward does not select a second time
+                    select = checkpoint_name(select, remat.SELECT)
+                    lse_i = checkpoint_name(lse_i, remat.SELECT_LSE)
             with jax.named_scope("core"):
                 o, lse = attend(q, k, v, True, mask, select=select,
                                 with_lse=True)
@@ -484,13 +492,29 @@ class DecoderBlock(FeedForwardLayer):
         the selected pairs visible."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_score_entries
 
-        dv = self.head_dim if self.attention == "gqa" else self.v_dim
-        computed, visible = flash_score_entries(seq, self._qk_dim(), dv,
-                                                dtype, self.window)
+        computed, visible = flash_score_entries(
+            seq, self._qk_dim(), self._v_dim(), dtype, self.window)
         heads = batch * self.n_heads
         if self.index_heads:
             visible = self.index_pairs(1, seq)[1]
         return heads * computed, heads * visible
+
+    def remat_kept_bytes(self, batch: int, seq: int, dtype) -> dict:
+        """Bytes by name (``ops/remat.py::KEPT``) that this block keeps from
+        its forward to its backward under ``gradient_checkpointing``, besides
+        its input, for one step over ``batch`` sequences of ``seq`` tokens in
+        ``dtype``: the core's output and log-sum-exp where the flash kernels
+        engage forward and backward on this device, an indexer's int8
+        selection and its log-sum-exp."""
+        from deeplearning4j_tpu.ops.pallas_kernels import flash_kept_bytes
+
+        out, lse = flash_kept_bytes(batch, seq, self.n_heads, self._v_dim(),
+                                    dtype)
+        kept = {remat.CORE_OUT: out, remat.CORE_LSE: lse}
+        if self.index_heads:
+            kept[remat.SELECT] = batch * seq * seq
+            kept[remat.SELECT_LSE] = batch * seq * 4
+        return kept
 
     def index_pairs(self, batch: int, seq: int) -> tuple:
         """``(scored, selected)`` (query, key) pairs of one step's indexer

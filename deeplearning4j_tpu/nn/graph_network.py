@@ -36,6 +36,7 @@ from deeplearning4j_tpu.nn.updaters import (
     effective_lr, grads_to_param_dtype, normalize_gradients, updater_init,
     updater_step_with_param,
 )
+from deeplearning4j_tpu.ops.remat import checkpoint_layer
 from deeplearning4j_tpu.utils.pytree import flatten_params, num_params, unflatten_params
 
 Array = jax.Array
@@ -111,11 +112,11 @@ def graph_forward(conf: ComputationGraphConfiguration, params: dict, states: dic
         # forward and backward (see multilayer._layer_scope)
         with jax.named_scope(f"layer/{name}"):
             if remat and isinstance(vertex, LayerVertex):
-                # jax.checkpoint per layer vertex: backward recomputes this
+                # checkpointed per layer vertex: backward recomputes this
                 # vertex's forward instead of holding its activations
                 def f(p, vi, _v=vertex, _s=states.get(name, {}), _r=rngs[i]):
                     return _v.apply(p, _s, vi, train=True, rng=_r, mask=mask)
-                y, ns = jax.checkpoint(f)(params.get(name, {}), vins)
+                y, ns = checkpoint_layer(f)(params.get(name, {}), vins)
             else:
                 y, ns = vertex.apply(params.get(name, {}),
                                      states.get(name, {}), vins, train=train,

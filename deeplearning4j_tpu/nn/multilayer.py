@@ -21,6 +21,7 @@ ParallelWrapper's synchronous loop runs the same code as a ``LoopOwner``.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -47,6 +48,7 @@ from deeplearning4j_tpu.observability.names import (
     ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
     FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
     MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
+    REMAT_KEPT_BYTES_TOTAL,
 )
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry,
@@ -62,6 +64,7 @@ from deeplearning4j_tpu.nn.updaters import (
     UpdaterSpec, effective_lr, grads_to_param_dtype, normalize_gradients,
     updater_init, updater_step, updater_step_with_param,
 )
+from deeplearning4j_tpu.ops.remat import checkpoint_layer
 from deeplearning4j_tpu.utils.pytree import flatten_params, num_params, unflatten_params
 
 Array = jax.Array
@@ -114,6 +117,10 @@ _attn_scored = _obs_registry().counter(
 _attn_selected = _obs_registry().counter(
     ATTN_PAIRS_SELECTED_TOTAL, "(query, key) pairs the same indexers "
     "selected for the core (min(t + 1, topk) a query), by decoder block")
+_remat_kept = _obs_registry().counter(
+    REMAT_KEPT_BYTES_TOTAL, "bytes the checkpointed decoder blocks of "
+    "dispatched steps kept from forward to backward besides their inputs "
+    "(ops/remat.py), summed over the blocks, by the kept value's name")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -184,9 +191,15 @@ def loss_fn(conf: MultiLayerConfiguration, params_list, state_list, x, y, rng,
     Returns (loss, new_state_list).
 
     With ``gradient_checkpointing`` set, each layer application is wrapped in
-    ``jax.checkpoint``: backward recomputes the layer's forward instead of
-    holding its activations in HBM — peak activation memory drops from
-    O(depth) to O(1) layers at ~1.3x FLOPs."""
+    ``ops/remat.py::checkpoint_layer``: backward recomputes the layer's
+    forward instead of holding its activations in HBM, at ~1.3x FLOPs. A
+    layer keeps its input and, by name, its attention kernels' outputs, which
+    the recomputed forward then does not make again: per decoder block
+    tokens x heads x value width x 2 B of the flash core's output (float32
+    log-sum-exps beside it), and B x T x T bytes of int8 selection under an
+    indexer (``DecoderBlock.remat_kept_bytes``; counter
+    ``dl4j_remat_kept_bytes_total``). Every other activation lives for O(1)
+    layers; the kept bytes grow with the depth."""
     layers = conf.layers
     last = layers[-1]
     if not last.has_loss():
@@ -205,7 +218,7 @@ def loss_fn(conf: MultiLayerConfiguration, params_list, state_list, x, y, rng,
                 def f(p, hh, _layer=layer, _s=state_list[i], _r=rngs[i]):
                     return _layer.apply(p, _s, hh, train=True, rng=_r,
                                         mask=fmask)
-                h, ns = jax.checkpoint(f)(params_list[i], h)
+                h, ns = checkpoint_layer(f)(params_list[i], h)
             else:
                 h, ns = layer.apply(params_list[i], state_list[i], h,
                                     train=True, rng=rngs[i], mask=fmask)
@@ -552,9 +565,10 @@ class LazyScore:
         """Book ``steps`` dispatched steps' attention score entries
         (``dl4j_attn_score_entries_*``, and for a block with an indexer
         ``dl4j_attn_index_pairs_scored_total`` and
-        ``dl4j_attn_pairs_selected_total``) for the decoder blocks: a function
-        of the batch's shape and each block's mask alone, so nothing is read
-        from the device."""
+        ``dl4j_attn_pairs_selected_total``) for the decoder blocks, and under
+        ``gradient_checkpointing`` the bytes they kept for their backward
+        (``dl4j_remat_kept_bytes_total``): a function of the batch's shape
+        and each block's fields alone, so nothing is read from the device."""
         layers = getattr(getattr(self, "conf", None), "layers", None) or ()
         blocks = [(i, l) for i, l in enumerate(layers)
                   if hasattr(l, "attn_score_entries")]
@@ -573,12 +587,19 @@ class LazyScore:
                  *(l.index_pairs(batch, seq)
                    if getattr(l, "index_heads", 0) else (0, 0)))
                 for i, l in blocks]
+            kept = collections.Counter()
+            if self.conf.global_conf.gradient_checkpointing:
+                for _, l in blocks:
+                    kept.update(l.remat_kept_bytes(batch, seq, dtype))
+            self._kept_bytes = sorted(kept.items())
         for layer, computed, visible, scored, selected in self._attn_entries:
             _attn_computed.labels(layer=layer).inc(steps * computed)
             _attn_visible.labels(layer=layer).inc(steps * visible)
             if scored:
                 _attn_scored.labels(layer=layer).inc(steps * scored)
                 _attn_selected.labels(layer=layer).inc(steps * selected)
+        for name, nbytes in self._kept_bytes:
+            _remat_kept.labels(name=name).inc(steps * nbytes)
 
     #: ``(rows (K, layers, 3) on the device, tokens)`` of the dispatched
     #: groups whose expert-layer rows the host has not read yet

@@ -247,7 +247,10 @@ def select_topk(scores: Array, topk: int, *, interpret: bool = False) -> tuple:
     ``select`` holds exactly ``min(t + 1, topk)`` ones, at the keys ``s <=
     t`` with the largest ``scores[b, t, s]`` (equal scores: the lower key),
     zeros elsewhere; ``lse[b, t]`` is the log-sum-exp of the chosen scores.
-    Entries of ``scores`` above the diagonal are never read. No gradient."""
+    Entries of ``scores`` above the diagonal are never read. No gradient.
+    ``DecoderBlock`` tags the two results ``attn_select`` and
+    ``attn_select_lse`` (``ops/remat.py``): a checkpointed layer keeps them,
+    B x T x T bytes and B x T float32, and does not select a second time."""
     B, T, _ = scores.shape
     scores = jax.lax.stop_gradient(scores)
     rows = _select_rows(T)

@@ -38,8 +38,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import remat
 
 Array = jax.Array
 _NEG = -1e30
@@ -873,7 +876,15 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     gradient. ``with_lse`` (with a selection): also return the float32
     log-sum-exp of each query head's scaled selected scores, [B * H, T],
     whose cotangent is dropped: it is for use under ``stop_gradient``
-    (``indexer.index_kl`` makes the core's probabilities from it)."""
+    (``indexer.index_kl`` makes the core's probabilities from it).
+
+    Where the tiled backward engages, the forward rule tags its output and
+    log-sum-exp ``attn_core_out`` and ``attn_core_lse``
+    (``jax.ad_checkpoint.checkpoint_name``; ``ops/remat.py``): a layer
+    checkpointed through ``remat.checkpoint_layer`` keeps both, B x T x H x
+    Dv of the operands' dtype and B x H x T float32, and its recomputed
+    forward does not run this kernel again. Anywhere else the tags are the
+    identity."""
     if select is None:
         if with_lse:
             raise ValueError("with_lse goes with a selection")
@@ -884,6 +895,27 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     out, lse = _selected_attention(q, k, v, select, interpret, force_pallas,
                                    scale)
     return (out, lse) if with_lse else out
+
+
+def _kept(out, lse):
+    """A forward rule's ``out`` and ``lse`` under the names a checkpointed
+    layer keeps (``ops/remat.py``): the tagged values are both what the rule
+    returns and what its residuals hold, so a recomputed forward has no use
+    for the forward kernel. The identity outside such a policy."""
+    return (checkpoint_name(out, remat.CORE_OUT),
+            checkpoint_name(lse, remat.CORE_LSE))
+
+
+def flash_kept_bytes(batch: int, tq: int, heads: int, dv: int, dtype) -> tuple:
+    """``(out, lse)`` bytes the forward rule tags for ``batch`` causal
+    sequences of ``tq`` positions and ``heads`` query heads: zeros where the
+    tiled backward does not engage on this device (the XLA backward's
+    residuals hold neither)."""
+    if not (use_pallas() and _tileable(tq, tq) and tq >= _MIN_SEQ
+            and _pallas_bwd_enabled(tq)):
+        return 0, 0
+    return (batch * tq * heads * dv * jnp.dtype(dtype).itemsize,
+            batch * heads * tq * 4)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -926,8 +958,8 @@ def _selected_fwd_rule(q, k, v, select, interpret, force, scale):
                        q.shape[1], q.shape[-1], q.dtype))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
-        out, lse = _flash_forward(q, k, v, True, interpret=interpret,
-                                  scale=scale, select=select)
+        out, lse = _kept(*_flash_forward(q, k, v, True, interpret=interpret,
+                                         scale=scale, select=select))
         return (out, lse), (q, k, v, select, out, lse)
     return (_selected_attention(q, k, v, select, interpret, force, scale),
             (q, k, v, select, None, None))
@@ -1305,8 +1337,9 @@ def _flash_fwd_rule(q, k, v, causal, interpret, force, scale, window):
                        q.shape[1], q.shape[-1], q.dtype))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
-        out, lse = _flash_forward(q, k, v, causal, interpret=interpret,
-                                  scale=scale, window=window)
+        out, lse = _kept(*_flash_forward(q, k, v, causal,
+                                         interpret=interpret, scale=scale,
+                                         window=window))
         return out, (q, k, v, out, lse)
     return (_flash_attention(q, k, v, causal, interpret, force, scale,
                              window),

@@ -31,6 +31,7 @@ from deeplearning4j_tpu.observability.flight_recorder import (
     global_recorder as _flight_recorder,
 )
 from deeplearning4j_tpu.observability.watchdog import beat as _wd_beat
+from deeplearning4j_tpu.ops.remat import checkpoint_layer
 from deeplearning4j_tpu.parallel.mesh import build_mesh
 from deeplearning4j_tpu.parallel.pipeline import PipelineParallel
 from deeplearning4j_tpu.nn.multilayer import (
@@ -101,7 +102,7 @@ class PipelineTrainer:
         if conf.global_conf.gradient_checkpointing:
             # same remat contract as multilayer.loss_fn: backward recomputes
             # each block's forward instead of holding its activations
-            block_fn = jax.checkpoint(block_fn)
+            block_fn = checkpoint_layer(block_fn)
         self.pipe = PipelineParallel(
             self.mesh, block_fn, n_blocks=i1 - i0, axis_name=axis_name,
             n_microbatches=n_microbatches)
@@ -132,7 +133,7 @@ class PipelineTrainer:
             if remat:
                 def f(p, hh, _l=layers[i], _s=state_list[i], _r=rngs[i]):
                     return _l.apply(p, _s, hh, train=True, rng=_r, mask=fmask)
-                return jax.checkpoint(f)(params_list[i], h)
+                return checkpoint_layer(f)(params_list[i], h)
             return layers[i].apply(params_list[i], state_list[i], h,
                                    train=True, rng=rngs[i], mask=fmask)
 

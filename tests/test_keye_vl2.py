@@ -27,6 +27,7 @@ from deeplearning4j_tpu.datasets.dataset import DataSet  # noqa: E402
 from deeplearning4j_tpu.models import keye_vl2_lm  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers import DecoderBlock  # noqa: E402
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork  # noqa: E402
+from deeplearning4j_tpu.observability.metrics import global_registry  # noqa: E402
 from deeplearning4j_tpu.ops import indexer  # noqa: E402
 from test_trinity_mini import (  # noqa: E402  (the sibling model's helpers)
     _batches as _trinity_batches, _close as _close_to, _counters)
@@ -238,6 +239,12 @@ def test_renormalisation_is_a_switch_on_the_softmax_router():
            what="divided by their sum")
 
 
+def _kept_bytes():
+    fam = global_registry().snapshot().get("dl4j_remat_kept_bytes_total")
+    return ({s["labels"]["name"]: s["value"] for s in fam["series"]}
+            if fam else {})
+
+
 # (e) ---------------------------------------------------------------------
 def test_fit_iterator_follows_the_reference_and_books_the_pairs():
     k = 3
@@ -251,6 +258,7 @@ def test_fit_iterator_follows_the_reference_and_books_the_pairs():
 
     net.set_listeners(Rec())
     moe, attn = _counters("dl4j_moe_"), _counters("dl4j_attn_")
+    kept = _kept_bytes()
     net.fit_iterator([DataSet(x, y) for x, y in batches])
     want = ref.follow(ref.make_loss_and_grad(TINY), ref.init(5, TINY),
                       batches, TINY["learning_rate"])
@@ -283,6 +291,13 @@ def test_fit_iterator_follows_the_reference_and_books_the_pairs():
             seqs * TINY["n_heads"] * 70)
         assert seen("dl4j_attn_index_pairs_scored_total", i) == seqs * 136
         assert seen("dl4j_attn_pairs_selected_total", i) == seqs * 70
+    # the model sets ``gradient_checkpointing``: its two blocks keep their
+    # int8 selections of 16 x 16 and the 16 float32 log-sum-exps beside
+    # them a sequence; the XLA core of this CPU keeps nothing
+    assert net.conf.global_conf.gradient_checkpointing
+    assert {n: v - kept.get(n, 0) for n, v in _kept_bytes().items()} == {
+        "attn_select": 2 * seqs * 256, "attn_select_lse": 2 * seqs * 64,
+        "attn_core_out": 0, "attn_core_lse": 0}
 
 
 # (f) ---------------------------------------------------------------------
