@@ -9,6 +9,13 @@ Top-level convenience re-exports mirror the reference's most-used entry points
 (reference: deeplearning4j-nn/src/main/java/org/deeplearning4j/nn).
 """
 
+# the package's own import is a span of the process's start
+# (observability/startup.py): its clock starts here, with nothing but ``time``
+# imported, so that everything below, ``observability`` included, is inside it
+import time as _time
+
+_T0_NS = _time.time_ns()
+
 __version__ = "0.1.0"
 
 from deeplearning4j_tpu.nn.conf.builders import NeuralNetConfiguration
@@ -21,3 +28,7 @@ __all__ = [
     "MultiLayerNetwork",
     "__version__",
 ]
+
+from deeplearning4j_tpu.observability.startup import record_import as _record_import
+
+_record_import(_T0_NS)

@@ -118,6 +118,11 @@ def begin_group() -> int:
     return group
 
 
+def end_group() -> None:
+    """This thread is working on no group any more (a fit call returned)."""
+    _working_on.group = None
+
+
 def current_group() -> Optional[int]:
     """The staged item this thread is working on (module docstring), or None
     where the thread has touched none."""

@@ -64,8 +64,10 @@ import time
 import zlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from deeplearning4j_tpu.datasets.prefetch import current_group
 from deeplearning4j_tpu.observability.compile_tracker import (_signature,
                                                               global_tracker)
+from deeplearning4j_tpu.observability.flight_recorder import global_recorder
 from deeplearning4j_tpu.observability.metrics import global_registry
 from deeplearning4j_tpu.observability.names import (
     COMPILE_CACHE_BYTES, COMPILE_CACHE_HITS_TOTAL, COMPILE_CACHE_LOAD_SECONDS,
@@ -215,13 +217,36 @@ def _placement_key(args: tuple, kwargs: dict) -> Optional[Tuple]:
 
 
 def _flight(kind: str, **fields) -> None:
-    try:
-        from deeplearning4j_tpu.observability.flight_recorder import \
-            global_recorder
+    global_recorder().record(kind, **fields)
 
-        global_recorder().record(kind, **fields)
-    except Exception:  # pragma: no cover - recorder import cycle guard  # lint: swallowed-exception-ok (flight forwarding is best-effort)
-        pass
+
+def _resolve_part(name: str, t0_ns: int, **fields) -> None:
+    """One part of a program's resolution, ended now: a ``compile.*`` span
+    under the ``compile.resolve`` that ``CachedProgram._build`` writes when
+    it returns, in the calling thread's staged group (a resolution inside a
+    fit loop's dispatch is that group's)."""
+    global_recorder().record_span(name, t0_ns, time.time_ns(),
+                                  group=current_group(),
+                                  cause="compile.resolve", **fields)
+
+
+_MEMORY_FIGURES = (("temp_bytes", "temp_size_in_bytes"),
+                   ("argument_bytes", "argument_size_in_bytes"),
+                   ("output_bytes", "output_size_in_bytes"),
+                   ("alias_bytes", "alias_size_in_bytes"),
+                   ("code_bytes", "generated_code_size_in_bytes"))
+
+
+def _memory_figures(compiled) -> dict:
+    """The executable's own memory figures (``memory_analysis()``), under
+    the names the ``compile.resolve`` span carries; empty where the runtime
+    gives none for this executable."""
+    try:
+        stats = compiled.memory_analysis()
+        return {name: int(getattr(stats, attr))
+                for name, attr in _MEMORY_FIGURES}
+    except Exception:  # lint: swallowed-exception-ok (a runtime without the analysis leaves the figures out)
+        return {}
 
 
 def observe_warmup(site: str, seconds: float) -> None:
@@ -276,8 +301,11 @@ class CompileCache:
     def get(self, fp_hex: str, name: str) -> Optional[tuple]:
         """-> (payload, in_tree, out_tree, meta) or None. Any validation
         failure (bad magic, truncation, digest mismatch, unpicklable body)
-        quarantines the entry and reads as a miss."""
+        quarantines the entry and reads as a miss. An entry that is returned
+        leaves the span ``compile.store_read`` (open, read, digest,
+        decompress, unpickle) with the file's ``bytes``."""
         path = self.entry_path(fp_hex)
+        t0_ns = time.time_ns()
         try:
             with open(path, "rb") as f:
                 raw = f.read()
@@ -295,7 +323,9 @@ class CompileCache:
                 why = "digest-mismatch"
             else:
                 try:
-                    return pickle.loads(zlib.decompress(body))
+                    entry = pickle.loads(zlib.decompress(body))
+                    _resolve_part("compile.store_read", t0_ns, bytes=len(raw))
+                    return entry
                 except Exception as e:
                     why = f"unpicklable: {e!r}"
         self.quarantine(fp_hex, name=name, why=why)
@@ -315,7 +345,9 @@ class CompileCache:
 
     # ------------------------------------------------------------ write
     def put(self, fp_hex: str, payload: bytes, in_tree, out_tree,
-            meta: dict) -> None:
+            meta: dict) -> int:
+        """Write one entry; returns the bytes of its file, 0 where the write
+        failed (a failed write is a no-op)."""
         try:
             body = zlib.compress(
                 pickle.dumps((payload, in_tree, out_tree, meta)), 1)
@@ -334,8 +366,10 @@ class CompileCache:
                     pass
                 raise
             self._prune()
+            return len(MAGIC) + _DIGEST_LEN + len(body)
         except Exception as e:
             log.debug("compile cache write failed for %s: %r", fp_hex, e)
+            return 0
 
     def _prune(self) -> None:
         """Keep the store under ``max_bytes`` by evicting oldest-mtime
@@ -474,14 +508,36 @@ class CachedProgram:
 
     def _build(self, sig: Tuple, pk: Optional[Tuple], args: tuple,
                kwargs: dict) -> Callable:
+        """Resolve one signature's executable, once: from the store, else
+        compiled and written back. The ring's ``compile`` record of it is
+        the span ``compile.resolve`` (entry to return; ``fn``, ``hit``,
+        ``payload_bytes``, the executable's memory figures), caused by the
+        ``fit.dispatch`` of the calling thread's group where it has one,
+        with its parts under it: ``compile.store_read`` and
+        ``compile.deserialize`` on a hit, ``compile.lower``,
+        ``compile.backend`` and ``compile.store_write`` on a miss."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
             recorded_dispatch, replay_dispatch)
 
+        t0_ns = time.time_ns()
         tracker = self._tr()
         tracker._ensure_monitoring()
         fp = self._fp_hex(sig, pk)
         store = self._store()
         reg = global_registry()
+        group = current_group()
+
+        def resolved(compiled, *, hit: bool, wall_s: float,
+                     payload_bytes: int) -> Callable:
+            tracker.note_executable(self._name, compiled)
+            self.cache_hit = hit
+            tracker.record_compile(
+                self._name, cache_key=self._cache_key, wall_s=wall_s,
+                shapes=sig[0], cache_hit=hit, span=(t0_ns, time.time_ns()),
+                group=group, cause=None if group is None else "fit.dispatch",
+                hit=hit, payload_bytes=payload_bytes,
+                **_memory_figures(compiled))
+            return compiled
 
         # disk hit: deserialize instead of compiling
         if fp is not None:
@@ -497,9 +553,11 @@ class CachedProgram:
                     # that spans the host
                     payload, in_tree, out_tree, meta = got
                     by_id = {d.id: d for d in jax.devices()}
+                    d0_ns = time.time_ns()
                     compiled = se.deserialize_and_load(
                         payload, in_tree, out_tree, execution_devices=[
                             by_id[i] for i in meta["device_ids"]])
+                    _resolve_part("compile.deserialize", d0_ns)
                     replay_dispatch(meta["dispatch"])
                     load_s = time.perf_counter() - t0
                     reg.counter(
@@ -508,14 +566,11 @@ class CachedProgram:
                     ).labels(fn=self._name).inc()
                     reg.histogram(
                         COMPILE_CACHE_LOAD_SECONDS,
-                        "deserialize_and_load wall time on cache hits"
+                        "wall time of a cache hit: the store's read (open, "
+                        "digest, decompress, unpickle) + deserialize_and_load"
                     ).labels(fn=self._name).observe(load_s)
-                    tracker.record_compile(
-                        self._name, cache_key=self._cache_key, wall_s=load_s,
-                        shapes=sig[0], cache_hit=True)
-                    tracker.note_executable(self._name, compiled)
-                    self.cache_hit = True
-                    return compiled
+                    return resolved(compiled, hit=True, wall_s=load_s,
+                                    payload_bytes=len(payload))
                 except Exception as e:
                     store.quarantine(fp, name=self._name,
                                      why=f"deserialize failed: {e!r}")
@@ -530,7 +585,12 @@ class CachedProgram:
         t0 = time.perf_counter()
         try:
             with recorded_dispatch() as dispatch_notes:
-                compiled = self._jitted.lower(*args, **kwargs).compile()
+                l0_ns = time.time_ns()
+                lowered = self._jitted.lower(*args, **kwargs)
+                _resolve_part("compile.lower", l0_ns)
+                b0_ns = time.time_ns()
+                compiled = lowered.compile()
+                _resolve_part("compile.backend", b0_ns)
         except Exception as e:
             log.debug("AOT compile failed for %s (%r); using plain jit",
                       self._name, e)
@@ -541,25 +601,27 @@ class CachedProgram:
         reg.counter(COMPILE_CACHE_MISSES_TOTAL,
                     "compile-cache misses (fresh XLA compiles)"
                     ).labels(fn=self._name).inc()
-        tracker.record_compile(self._name, cache_key=self._cache_key,
-                               wall_s=wall, shapes=sig[0], cache_hit=False)
-        tracker.note_executable(self._name, compiled)
-        self.cache_hit = False
+        payload_bytes = 0
         if fp is not None:
             try:
                 from jax.experimental import serialize_executable as se
 
+                w0_ns = time.time_ns()
                 payload, in_tree, out_tree = se.serialize(compiled)
-                store.put(fp, payload, in_tree, out_tree,
-                          {"fn": self._fingerprint_name,
-                           "wall_s": wall, "shapes": repr(sig[0]),
-                           "dispatch": dispatch_notes,
-                           "device_ids": [
-                               d.id for d in compiled.runtime_executable()
-                               .local_devices()]})
+                payload_bytes = len(payload)
+                written = store.put(
+                    fp, payload, in_tree, out_tree,
+                    {"fn": self._fingerprint_name,
+                     "wall_s": wall, "shapes": repr(sig[0]),
+                     "dispatch": dispatch_notes,
+                     "device_ids": [
+                         d.id for d in compiled.runtime_executable()
+                         .local_devices()]})
+                _resolve_part("compile.store_write", w0_ns, bytes=written)
             except Exception as e:
                 log.debug("serialize failed for %s: %r", self._name, e)
-        return compiled
+        return resolved(compiled, hit=False, wall_s=wall,
+                        payload_bytes=payload_bytes)
 
     # ------------------------------------------------------------ public
     def __call__(self, *args, **kwargs):

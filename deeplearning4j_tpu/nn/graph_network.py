@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Optional
 
 import jax
@@ -454,6 +455,7 @@ class ComputationGraph(LazyScore):
 
     # ------------------------------------------------------------------ lifecycle
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
+        t0_ns = time.time_ns()
         g = self.conf.global_conf
         key = jax.random.PRNGKey(g.seed if seed is None else seed)
         self._rng = jax.random.fold_in(key, 0xC6)
@@ -481,6 +483,7 @@ class ComputationGraph(LazyScore):
             if isinstance(self.conf.vertices[name], LayerVertex) else {}
             for name, params in self.params_list.items()
         }
+        self._book_init(t0_ns)
         return self
 
     def set_listeners(self, *listeners) -> None:

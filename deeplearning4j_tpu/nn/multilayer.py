@@ -34,7 +34,7 @@ import numpy as np
 
 from deeplearning4j_tpu import common
 from deeplearning4j_tpu.datasets.prefetch import (
-    DevicePrefetcher, HostGroupRing, begin_group, current_group,
+    DevicePrefetcher, HostGroupRing, begin_group, current_group, end_group,
 )
 from deeplearning4j_tpu.observability.compile_tracker import (
     global_tracker as _compile_tracker,
@@ -55,6 +55,9 @@ from deeplearning4j_tpu.observability.metrics import (
 )
 from deeplearning4j_tpu.observability.profiler import (
     note_dispatch as _profile_note_dispatch,
+)
+from deeplearning4j_tpu.observability.startup import (
+    log_time_to_first_step as _log_time_to_first_step,
 )
 from deeplearning4j_tpu.observability.watchdog import beat as _wd_beat
 from deeplearning4j_tpu.nn.conf.multilayer import MultiLayerConfiguration
@@ -489,6 +492,19 @@ def wait_for_step(losses) -> None:
         group=current_group(), cause="fit.dispatch")
 
 
+def book_fit_call(path: str, k: int, epochs: int, t0_ns: int) -> None:
+    """The span ``fit.call`` of one ``fit_iterator`` / ``ParallelWrapper.fit``
+    call that began at ``t0_ns`` and returns now (or raises): the root of
+    its groups' spans. The calling thread's group ends with it, so that what
+    the thread resolves afterwards is not booked to the call's last
+    dispatch; the process's first call logs the time to the first step
+    (``observability/startup.py``)."""
+    _flight_recorder().record_span("fit.call", t0_ns, time.time_ns(),
+                                   path=path, k=k, epochs=epochs)
+    end_group()
+    _log_time_to_first_step()
+
+
 def _batch_size(x, axis: int = 0) -> int:
     """Examples in a batch (arrays, or a list of arrays per stream), read off
     its first leaf; ``axis`` 1 for a stacked (K, B, ...) group."""
@@ -639,6 +655,14 @@ class LazyScore:
     NOT_INITIALIZED_MSG = (
         "Network not initialized — call net.init() before fit/output "
         "(reference MultiLayerNetwork.init:386 / ComputationGraph.init:266)")
+
+    def _book_init(self, t0_ns: int) -> None:
+        """The span ``startup.init`` of an ``init()`` that began at ``t0_ns``
+        and returns now (under a caller's ``jax.jit`` it times the trace):
+        the class and the parameters' count, from their shapes."""
+        _flight_recorder().record_span(
+            "startup.init", t0_ns, time.time_ns(), cause="startup.import",
+            cls=type(self).__name__, params=num_params(self.params_list))
 
     def _require_init(self) -> None:
         """Raise the reference's actionable not-initialized error instead of a
@@ -819,6 +843,7 @@ class LazyScore:
         batches, iterations>1 configs, or ragged batch shapes.
         """
         k = self.dispatch_ksteps if ksteps is None else max(1, ksteps)
+        t0_ns = time.time_ns()
         try:
             for _ in range(epochs):
                 for listener in self.listeners:
@@ -837,6 +862,7 @@ class LazyScore:
                 self.epoch += 1
         finally:
             self._release_staging()
+            book_fit_call(self._fit_path, k, epochs, t0_ns)
 
     def _fit_epoch(self, iterator, k: int, owner=None) -> None:
         """One pass over ``iterator``: through the staged loop where the
@@ -1073,6 +1099,7 @@ class MultiLayerNetwork(LazyScore):
 
     # ------------------------------------------------------------------ lifecycle
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
+        t0_ns = time.time_ns()
         g = self.conf.global_conf
         key = jax.random.PRNGKey(g.seed if seed is None else seed)
         self._rng = jax.random.fold_in(key, 0xD14)
@@ -1096,6 +1123,7 @@ class MultiLayerNetwork(LazyScore):
              for name, p in params.items()}
             for layer, params in zip(self.conf.layers, self.params_list)
         ]
+        self._book_init(t0_ns)
         return self
 
     def set_listeners(self, *listeners) -> None:

@@ -324,12 +324,19 @@ class CompileTracker:
     # ------------------------------------------------------------ tracking
     def record_compile(self, name: str, *, cache_key: Any = None,
                        wall_s: float = 0.0, shapes: Any = None,
-                       policy: Any = None, cache_hit: bool = False) -> dict:
+                       policy: Any = None, cache_hit: bool = False,
+                       span: Optional[Tuple[int, int]] = None,
+                       **span_fields) -> dict:
         """Record one compile event (the wrap() path calls this; seams that
         build executables eagerly may call it directly). ``cache_hit=True``
         marks a warm load from the executable cache: counted and flight-
         recorded like any compile, but excluded from storm accounting —
-        warm loads are the fix for compile storms, not a symptom of one."""
+        warm loads are the fix for compile storms, not a symptom of one.
+
+        ``span``: the resolution's ``(t0_ns, t1_ns)`` on ``time.time_ns()``'s
+        clock where the seam took them (``CachedProgram._build``): the
+        ring's one ``compile`` record is then the span ``compile.resolve``,
+        with ``span_fields`` beside the event's own."""
         total, wall_hist, _, storm_total = self._metrics()
         total.labels(fn=name).inc()
         if wall_s:
@@ -361,7 +368,12 @@ class CompileTracker:
         try:
             from .flight_recorder import global_recorder
 
-            global_recorder().record("compile", **event)
+            if span is None:
+                global_recorder().record("compile", **event)
+            else:
+                global_recorder().record_span(
+                    "compile.resolve", *span, kind="compile", **event,
+                    **span_fields)
         except Exception:  # pragma: no cover - recorder import cycle guard  # lint: swallowed-exception-ok (recorder forwarding is best-effort)
             pass
         if storm:

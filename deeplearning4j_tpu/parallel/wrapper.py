@@ -34,7 +34,9 @@ import numpy as np
 from jax.sharding import Mesh
 
 from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
-from deeplearning4j_tpu.nn.multilayer import LoopOwner, _t_staging
+from deeplearning4j_tpu.nn.multilayer import (
+    LoopOwner, _t_staging, book_fit_call,
+)
 from deeplearning4j_tpu.observability.flight_recorder import (
     dump_on_unhandled as _dump_on_unhandled,
 )
@@ -389,12 +391,20 @@ class ParallelWrapper:
     def fit(self, iterator, epochs: int = 1) -> None:
         """Reference fit(DataSetIterator):322. Batches are sharded over the mesh;
         each global batch must be divisible by the number of workers."""
-        if self.prefetch:
-            iterator = AsyncDataSetIterator(iterator, queue_size=self.prefetch)
-        if self.averaging_frequency == 1:
-            self._fit_sync(iterator, epochs)
-        else:
-            self._fit_local_sgd(iterator, epochs)
+        t0_ns = _time.time_ns()
+        sync = self.averaging_frequency == 1
+        try:
+            if self.prefetch:
+                iterator = AsyncDataSetIterator(iterator,
+                                                queue_size=self.prefetch)
+            if sync:
+                self._fit_sync(iterator, epochs)
+            else:
+                self._fit_local_sgd(iterator, epochs)
+        finally:
+            book_fit_call("wrapper_sync" if sync else "wrapper_local_sgd",
+                          max(1, self.model.dispatch_ksteps) if sync else 1,
+                          epochs, t0_ns)
 
     # ------------------------------------------------------- synchronous DP (freq=1)
     def _make_sync_step(self):

@@ -170,6 +170,15 @@ def test_corrupt_entry_falls_back_to_fresh_compile(corrupt):
     falls = [e for e in global_recorder().snapshot()[r0:]
              if e.get("kind") == "compile_cache_fallback"]
     assert falls, "quarantine must leave a flight-recorder trail"
+    # the ring's compile record of that fallback is the span of a miss: no
+    # store_read of an entry that was not returned, the three parts of a
+    # fresh compile under it
+    spans = [e for e in global_recorder().snapshot()[r0:]
+             if e.get("name", "").startswith("compile.")]
+    assert [e["name"] for e in spans] == [
+        "compile.lower", "compile.backend", "compile.store_write",
+        "compile.resolve"]
+    assert spans[-1]["kind"] == "compile" and spans[-1]["hit"] is False
     # the quarantined bytes are gone: the fresh compile re-persisted a
     # valid entry (magic + digest check out) at the same fingerprint
     import hashlib

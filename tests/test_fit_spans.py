@@ -51,9 +51,12 @@ def batches(n, batch=8, n_in=16, seed=0):
 
 
 def spans_by_group():
+    """The staged groups' own spans by group and name (a ``fit.call`` and
+    the ``compile.*`` spans of a program's resolution are
+    ``tests/test_startup_spans.py``'s)."""
     out = {}
     for e in global_recorder().snapshot():
-        if "t0_ns" in e:
+        if "t0_ns" in e and e["name"] in STAGES + FIT + ("fit.step_wait",):
             out.setdefault(e["group"], {})[e["name"]] = e
     return out
 

@@ -142,6 +142,31 @@ def test_dump_bundle_completeness(tmp_path):
     assert FlightRecorder(capacity=4).dump(reason="nowhere") is None
 
 
+def test_bundle_keeps_a_resolution_as_one_compile_record(tmp_path):
+    """The crash bundle's readers find a program's resolution where they
+    found the ``compile`` event: one record of that kind, with the event's
+    fields, now the span ``compile.resolve`` with its interval."""
+    from deeplearning4j_tpu.nn import compile_cache as cc
+    from deeplearning4j_tpu.observability.compile_tracker import (
+        CompileTracker,
+    )
+
+    rec = fr_mod.global_recorder()
+    rec.clear()
+    program = cc.CachedProgram("bundle_probe", jax.jit(lambda a: a - 1),
+                               tracker=CompileTracker())
+    program(np.ones((3,), np.float32))
+    path = rec.dump(dir=str(tmp_path), reason="resolution")
+    _, events = _assert_complete_bundle(path, expect_extra=False)
+    (compiled,) = [e for e in events if e["kind"] == "compile"]
+    assert compiled["name"] == "compile.resolve"
+    assert compiled["fn"] == "bundle_probe" and compiled["cache_hit"] is False
+    assert {"step", "wall_s", "cache_key", "shapes", "policy"} <= set(compiled)
+    assert compiled["t0_ns"] <= compiled["t1_ns"]
+    assert compiled["ts"] == pytest.approx(compiled["t1_ns"] / 1e9)
+    rec.clear()
+
+
 def test_list_bundles_newest_first(tmp_path):
     rec = FlightRecorder(capacity=4, dump_dir=str(tmp_path),
                          registry=MetricsRegistry())
