@@ -49,16 +49,18 @@ _NEG = -1e30
 
 #: bytes of VMEM one kernel program may plan for by default: the compiler's
 #: scoped default is 16 MiB per core; the rest is headroom for Mosaic's own
-#: scratch
+#: scratch. Beside ``_VMEM_CEILING`` it is also the room the one-kernel
+#: backward's whole dQ may take (``_fused_bwd_fits``)
 _VMEM_BUDGET = 14 * 1024 * 1024
 
 #: the most the TILES of a flash program may plan for (``_flash_vmem_bytes``
 #: as ``_flash_tiles`` counts it: scores, operand blocks, accumulators, dQ at
 #: ``blk_q`` rows), where the count passes the scoped default and the kernel
 #: asks for what it says (``vmem_limit_bytes``): a quarter of a v5e core's
-#: 128 MiB. The one-kernel backward adds a head's whole dQ, which
-#: ``_fused_bwd_fits`` holds to ``_VMEM_BUDGET`` apart: a program plans for at
-#: most the two together, 46 MiB, and asks for at most 61.5 (``_flash_params``)
+#: 128 MiB. The one-kernel backward adds a head's whole dQ, and
+#: ``_fused_bwd_fits`` holds its whole program, tiles and dQ, to this and
+#: ``_VMEM_BUDGET`` together: at most 46 MiB planned, at most 61.5 asked for
+#: (``_flash_params``)
 _VMEM_CEILING = 32 * 1024 * 1024
 
 
@@ -839,9 +841,10 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     or backward, and only the tiles an edge crosses are masked.
     Backward is tiled pallas too, recomputing P from the saved logsumexp
     (flash-attention practice: trade FLOPs for HBM; peak extra memory
-    O(blk·T), never O(Tq·Tk)): ONE kernel for dQ, dK and dV where a head's
-    dQ fits VMEM (``_fused_bwd_fits``), so each score tile is recomputed
-    once, else a dQ and a dK/dV kernel. Set DL4J_FLASH_PALLAS_BWD=0 to use
+    O(blk·T), never O(Tq·Tk)): ONE kernel for dQ, dK and dV where its
+    program, the tiles and a head's whole dQ, fits the VMEM a kernel may
+    ask for (``_fused_bwd_fits``), so each score tile is recomputed once,
+    else a dQ and a dK/dV kernel. Set DL4J_FLASH_PALLAS_BWD=0 to use
     the XLA chunked-scan backward instead.
 
     Tiles are chosen from the operands' shape (``_flash_tiles``): up to
@@ -955,7 +958,8 @@ def _selected_fwd_rule(q, k, v, select, interpret, force, scale):
     _note_dispatch("flash_attention_bwd" + variant, tiled_bwd)
     _note_dispatch("flash_attention_bwd_fused" + variant,
                    tiled_bwd and _fused_bwd_fits(
-                       q.shape[1], q.shape[-1], q.dtype))
+                       q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+                       q.dtype))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
         out, lse = _kept(*_flash_forward(q, k, v, True, interpret=interpret,
@@ -1090,17 +1094,21 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _fused_bwd_fits(tq: int, dk: int, dtype) -> bool:
-    """Whether ONE backward kernel serves the shape: a head's whole dQ (the
-    Tq x Dk float32 accumulator and its double-buffered output block) has to
-    sit in VMEM beside the tiles, and may take what the compiler's scoped
-    default leaves a program. The tiles come on top, held to
-    ``_VMEM_CEILING`` by ``_flash_tiles`` whether or not this says yes, and
-    the kernel asks for the sum (34 MiB planned at 4,096 x 192/128 with
-    1,024-square tiles). 4,096 x 192 in bfloat16 is 8 MiB and fits; 16,384
-    rows do not, and keep the dQ + dK/dV pair, whose VMEM does not grow with
-    the sequence."""
-    return _dq_bytes(tq, dk, jnp.dtype(dtype).itemsize) <= _VMEM_BUDGET
+def _fused_bwd_fits(tq: int, tk: int, dk: int, dv: int, dtype,
+                    blk_q: int = None, blk_k: int = None) -> bool:
+    """Whether ONE backward kernel serves the shape: its whole program
+    (``_flash_vmem_bytes`` at the tiles the backward takes, ``_flash_tiles``,
+    with a head's whole dQ: the Tq x Dk float32 accumulator and its
+    double-buffered output block) fits ``_VMEM_CEILING + _VMEM_BUDGET``, and
+    the kernel asks for what it counts (``_flash_params``). In bfloat16 at
+    1,024-square tiles 4,096 x 192/128 counts 34 MiB, 8,192 x 128 32 and
+    16,384 x 128 40, and take it; 16,384 x 192/128 counts 58 and 32,768 x
+    128 56, and keep the dQ + dK/dV pair, whose VMEM does not grow with the
+    sequence. The program and the dispatch note both ask this."""
+    tiles = _flash_tiles(tq, tk, dk, dv, dtype, blk_q, blk_k, backward=True)
+    return bool(tiles) and _flash_vmem_bytes(
+        *tiles, dk, dv, jnp.dtype(dtype).itemsize, True,
+        dq_rows=tq) <= _VMEM_CEILING + _VMEM_BUDGET
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
@@ -1111,12 +1119,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
     """Tiled pallas backward from the saved forward logsumexp. key_mask:
     optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
     ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``.
-    ``fused`` None: one kernel where a head's dQ fits VMEM
-    (``_fused_bwd_fits``), else the dQ + dK/dV pair. With fewer key/value
-    heads than query heads every query head reads its key/value head in
-    place and writes its own part of dK and dV, which are summed over the
-    group afterwards (float32). ``window`` and ``select`` as the forward's;
-    the kernels read the selection transposed (one XLA transpose of it)."""
+    ``fused`` None: one kernel where its program, a head's whole dQ
+    included, fits the VMEM a kernel may ask for (``_fused_bwd_fits``), else
+    the dQ + dK/dV pair. With fewer key/value heads than query heads every
+    query head reads its key/value head in place and writes its own part of
+    dK and dV, which are summed over the group afterwards (float32).
+    ``window`` and ``select`` as the forward's; the kernels read the
+    selection transposed (one XLA transpose of it)."""
     B, Tq, H, D = q.shape
     Tk, Dv = k.shape[1], v.shape[-1]
     group = _head_group(q, k, v, key_mask)
@@ -1124,7 +1133,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
     blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k,
                                    backward=True)
     if fused is None:
-        fused = _fused_bwd_fits(Tq, D, q.dtype)
+        fused = _fused_bwd_fits(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     gr, outr = _flatten_heads(g), _flatten_heads(out)
@@ -1334,7 +1343,8 @@ def _flash_fwd_rule(q, k, v, causal, interpret, force, scale, window):
     # which backward the program holds: one kernel, or the dQ + dK/dV pair
     _note_dispatch("flash_attention_bwd_fused" + variant,
                    tiled_bwd and _fused_bwd_fits(
-                       q.shape[1], q.shape[-1], q.dtype))
+                       q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+                       q.dtype))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
         out, lse = _kept(*_flash_forward(q, k, v, causal,

@@ -272,20 +272,27 @@ def test_square_diagonal_tile_runs_as_three_quarters(key_mask, monkeypatch):
 
 def test_tiles_and_backward_follow_the_shape():
     """No switch chooses the tiles or the backward: the operands' shape
-    does. The language-model cell's core (4,096 x 192/128, bfloat16) takes
-    1,024-square tiles and the one-kernel backward; at 16,384 a head's dQ
-    does not fit beside the tiles and the pair stays; lengths that a tile
-    does not divide keep a smaller standard one."""
+    does. The language-model cells' cores (4,096 x 192/128, 8,192 x 128 and
+    16,384 x 128, bfloat16) take 1,024-square tiles and the one-kernel
+    backward; at 16,384 x 192/128 and at 32,768 x 128 the one kernel's
+    program passes what a kernel may ask for and the pair stays; lengths
+    that a tile does not divide keep a smaller standard one."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
     bf16 = jnp.bfloat16
     for backward in (False, True):
         assert pk._flash_tiles(4096, 4096, 192, 128, bf16,
                                backward=backward) == (1024, 1024)
-    assert pk._fused_bwd_fits(4096, 192, bf16)
-    assert pk._fused_bwd_fits(4096, 64, jnp.float32)
-    assert not pk._fused_bwd_fits(16384, 64, bf16)
-    assert not pk._fused_bwd_fits(16384, 192, bf16)
+    assert pk._fused_bwd_fits(4096, 4096, 192, 128, bf16)
+    assert pk._fused_bwd_fits(4096, 4096, 64, 64, jnp.float32)
+    assert pk._fused_bwd_fits(8192, 8192, 128, 128, bf16)
+    assert pk._fused_bwd_fits(16384, 16384, 64, 64, bf16)
+    assert pk._fused_bwd_fits(16384, 16384, 128, 128, bf16)
+    assert not pk._fused_bwd_fits(16384, 16384, 192, 128, bf16)
+    assert not pk._fused_bwd_fits(32768, 32768, 128, 128, bf16)
+    # explicit tiles are counted as given: a 128-row query tile leaves room
+    # for a head's dQ where the 1,024-square one does not
+    assert pk._fused_bwd_fits(16384, 16384, 192, 128, bf16, 128, 512)
     # the widest tile that divides, down to one a sequence: measured
     # faster than four a side at T = 1,024 and 2,048 (PERF.md §6, PR 31)
     assert pk._flash_tiles(1024, 1024, 64, 64, bf16) == (1024, 1024)
@@ -313,27 +320,45 @@ def test_tiles_and_backward_follow_the_shape():
 @pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128),
                                     (256, 256)], ids=str)
 def test_one_kernel_backward_plans_within_tiles_plus_dq(widths, dtype):
-    """``_VMEM_CEILING`` holds the tiles and ``_VMEM_BUDGET`` a head's whole
-    dQ, each checked apart: whatever shape takes the one-kernel backward
-    plans for at most their sum, and asks the compiler for under half a
-    core's 128 MiB."""
+    """``_VMEM_CEILING`` holds the tiles alone; whatever shape takes the
+    one-kernel backward plans for at most ``_VMEM_CEILING + _VMEM_BUDGET``
+    with a head's whole dQ, and asks the compiler for under half a core's
+    128 MiB."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
     dk, dv = widths
     itemsize = jnp.dtype(dtype).itemsize
     fused = 0
-    for t in (1024, 1280, 2048, 4096, 8192, 16384):
+    for t in (1024, 1280, 2048, 4096, 8192, 16384, 32768):
         bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
         tiles = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True)
         assert tiles <= pk._VMEM_CEILING
-        if pk._fused_bwd_fits(t, dk, dtype):
+        if pk._fused_bwd_fits(t, t, dk, dv, dtype):
             fused += 1
             need = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True,
                                         dq_rows=t)
-            assert need <= pk._VMEM_CEILING + pk._VMEM_BUDGET
             limit = pk._flash_params(("parallel",), need).vmem_limit_bytes
             assert limit is None or need < limit <= 64 << 20
     assert fused  # some length of every width takes the one kernel
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128),
+                                    (256, 256)], ids=str)
+@pytest.mark.parametrize("t", [4096, 8192, 16384, 32768])
+def test_one_kernel_gate_is_its_whole_programs_count(t, widths, dtype):
+    """The gate stated as the invariant: a shape takes the one-kernel
+    backward exactly where its program at the backward's tiles, a head's
+    whole dQ included, counts within ``_VMEM_CEILING + _VMEM_BUDGET``."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    dk, dv = widths
+    bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
+    need = pk._flash_vmem_bytes(bq, bk, dk, dv, jnp.dtype(dtype).itemsize,
+                                True, dq_rows=t)
+    assert pk._fused_bwd_fits(t, t, dk, dv, dtype) == (
+        need <= pk._VMEM_CEILING + pk._VMEM_BUDGET)
 
 
 def _dispatch_counts():
@@ -357,8 +382,8 @@ def test_traced_gradient_notes_which_backward_it_holds(fits, monkeypatch):
     a run says which backward its program holds."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
-    if not fits:     # a budget no head's dQ fits under
-        monkeypatch.setattr(pk, "_VMEM_BUDGET", 16 * 1024)
+    if not fits:     # a gate that refuses every shape
+        monkeypatch.setattr(pk, "_fused_bwd_fits", lambda *a, **kw: False)
     q, k, v, g = _latent_operands(64, seed=13, B=1)
     before = _dispatch_counts()
     grads = jax.grad(lambda a, b, c: jnp.sum(

@@ -75,11 +75,12 @@ def _flash(T, dtype, grad):
                                   argnums=(0, 1, 2))(q, k, v)
 
     # forward kernel; under grad it is joined from _PBWD_MIN_SEQ by the one
-    # backward kernel where a head's dQ fits VMEM, else by the dq + dkv pair
-    # (T = 16,384)
+    # backward kernel where its program, a head's whole dQ included, fits
+    # what a kernel may ask for (T = 16,384 at 64 wide: 40 MiB in bfloat16,
+    # 33.5 in float32), else by the dq + dkv pair
     want = 1
     if grad and T >= pk._PBWD_MIN_SEQ:
-        want = 2 if pk._fused_bwd_fits(T, 64, dtype) else 3
+        want = 2 if pk._fused_bwd_fits(T, T, 64, 64, dtype) else 3
     return (bwd if grad else fwd), qkv, want
 
 
@@ -144,8 +145,9 @@ def _latent(grad, T=4096):
     """Latent attention's core at DeepSeek-V2-Lite's widths: 192-wide
     queries and keys, 128-wide values, 4 sequences of 4,096, 16 heads: the
     forward at the tiles the shape chooses, and under grad the ONE backward
-    kernel (a head's dQ, 4,096 x 192, fits VMEM). At 16,384 it does not,
-    and the dq + dkv pair stays."""
+    kernel (its program, a head's 4,096 x 192 dQ included, counts 34 MiB).
+    At 16,384 it counts 58, past what a kernel may ask for, and the dq + dkv
+    pair stays: the case that guards the pair."""
     shapes = [((4 * 4096 // T, T, 16, d), BF16) for d in (192, 192, 128)]
 
     def fwd(q, k, v):
@@ -155,7 +157,7 @@ def _latent(grad, T=4096):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    assert pk._fused_bwd_fits(T, 192, BF16) == (T == 4096)
+    assert pk._fused_bwd_fits(T, T, 192, 128, BF16) == (T == 4096)
     return (bwd if grad else fwd), shapes, (
         (2 if T == 4096 else 3) if grad else 1)
 
@@ -164,7 +166,8 @@ def _windowed(grad, window=2048, T=8192):
     """The grouped core at Trinity-Mini's widths: 2 sequences of 8,192, 32
     query heads over 4 key/value heads, 128 wide; a 2,048-key window on the
     sliding layers, none on the full ones. Forward, and under grad the ONE
-    backward kernel (a head's dQ, 8,192 x 128, fits VMEM)."""
+    backward kernel (its program, a head's 8,192 x 128 dQ included, counts
+    32 MiB)."""
     shapes = [((2, T, h, 128), BF16) for h in (32, 4, 4)]
 
     def fwd(q, k, v):
@@ -174,7 +177,7 @@ def _windowed(grad, window=2048, T=8192):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    assert pk._fused_bwd_fits(T, 128, BF16)
+    assert pk._fused_bwd_fits(T, T, 128, 128, BF16)
     return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
@@ -216,9 +219,9 @@ def _indexer(part, grad=False, T=16384, topk=2048):
     4 key/value heads of 128, 16 index heads of 64 over one key head, 2,048
     keys a query. ``scores``: one kernel; ``select``: one (128 rows of all
     16,384 keys in VMEM, the keys' scratch beside them); ``core``: the
-    forward with the selection's int8 tile, under grad the dQ + dK/dV pair
-    reading it transposed (a head's dQ does not fit at 16,384; at 4,096 the
-    ONE backward kernel); ``kl``: the
+    forward with the selection's int8 tile, under grad the ONE backward
+    kernel reading it transposed (its program, a head's 16,384 x 128 dQ
+    included, counts 40 MiB and asks for 54); ``kl``: the
     kernel that sums the heads' probabilities into a tile, under grad its
     forward and the two of the index scores' backward."""
     B, H, G, D, J, E = 1, 32, 4, 128, 16, 64
@@ -236,7 +239,9 @@ def _indexer(part, grad=False, T=16384, topk=2048):
             return jax.grad(lambda *a: fwd(*a, s)[0].astype(F32).sum(),
                             argnums=(0, 1, 2))(q, k, v)
 
-        want = (2 if pk._fused_bwd_fits(T, D, BF16) else 3) if grad else 1
+        # Keye's shape takes the one kernel: Mosaic is asked for 54 MiB
+        assert pk._fused_bwd_fits(T, T, D, D, BF16)
+        want = 2 if grad else 1
         return (bwd if grad else fwd), qkv + [((B, T, T), jnp.int8)], want
 
     if part == "block":
@@ -247,8 +252,8 @@ def _indexer(part, grad=False, T=16384, topk=2048):
         # the whole attention of a block under grad, as the step program
         # differentiates it: no kernel may be asked for a JVP of its own
         # (the scores and the selection carry no gradient; the core and the
-        # indexer's loss bring theirs): 4 forward kernels, the core's
-        # backward pair and the index scores' backward pair
+        # indexer's loss bring theirs): 4 forward kernels, the core's one
+        # backward kernel and the index scores' backward pair
         layer = DecoderBlock(
             n_in=2048, n_out=2048, attention="gqa", n_heads=H, n_kv_heads=G,
             head_dim=D, output_gate=False, rope_theta=1e7, index_heads=J,
@@ -267,7 +272,7 @@ def _indexer(part, grad=False, T=16384, topk=2048):
 
         shapes = [((B, T, 2048), BF16)] + [
             (params[n].shape, F32) for n in names]
-        return f, shapes, 8
+        return f, shapes, 7
 
     def kl(qi, ki, w, scores, s, lse_i, q, k, lse):
         return indexer.index_kl(qi, ki, w, scores, s, lse_i, q, k, lse,
