@@ -8,3 +8,4 @@ from deeplearning4j_tpu.models.transformer import moe_transformer_lm, transforme
 from deeplearning4j_tpu.models.deepseek_v2 import deepseek_v2_lite
 from deeplearning4j_tpu.models.trinity_mini import trinity_mini
 from deeplearning4j_tpu.models.keye_vl2 import keye_vl2_lm
+from deeplearning4j_tpu.models.lfm2_moe import lfm2_moe

@@ -29,7 +29,12 @@ and not a class of its own:
   indexer's four leaves learn from ``index_loss``, the mean over queries of
   ``KL(p || softmax_chosen I)`` with ``p`` the core's own head-averaged
   probabilities (a constant), and from nothing else; the trunk gets no
-  gradient from it.
+  gradient from it. ``"short_conv"``: no keys, values or core, a gated
+  short convolution along the sequence (``short_conv``): ``[Bg | Cg | X] =
+  u W_in`` (three ``F``-wide streams), ``V = Bg * X``, a causal depthwise
+  convolution of ``conv_kernel`` taps a channel, ``Z_t = sum_j w_j
+  V_{t-L+1+j}`` (the last tap weighs the token itself; positions before the
+  first read 0), ``A(u) = (Cg * Z) W_out``.
 * ``ffn``: ``"swiglu"`` (``(silu(u Wg) * (u Wu)) Wd``, no biases) or
   ``"moe"``: routing over ``n_experts`` router outputs, a shared expert
   computed for every token, and the routed experts this chip holds
@@ -82,7 +87,8 @@ from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
 from deeplearning4j_tpu.ops import indexer, remat
 
-_NORMS, _ATTENTIONS, _FFNS = ("rms",), ("mla", "gqa"), ("swiglu", "moe")
+_NORMS, _FFNS = ("rms",), ("swiglu", "moe")
+_ATTENTIONS = ("mla", "gqa", "short_conv")
 _PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
 
 
@@ -97,6 +103,23 @@ def swiglu(u, w_gate, w_up, w_down):
     g = _mm(u, w_gate)
     act = jax.nn.silu(g.astype(at_least_f32(g.dtype))).astype(g.dtype)
     return _mm(act * _mm(u, w_up), w_down)
+
+
+def short_conv(u, w_in, conv_w, w_out):
+    """``(Cg * Z) W_out`` with ``[Bg | Cg | X] = u W_in`` and ``Z_t = sum_j
+    conv_w[j] * (Bg * X)_{t-L+1+j}`` over the ``L`` taps of ``conv_w`` [L,
+    F], one filter a channel, causal (positions before the first read 0).
+    The gates and the taps, one fusion, run under the scope ``conv`` in
+    float32; the result is in the policy's output dtype."""
+    bcx = _mm(u, w_in)
+    T, L = u.shape[1], conv_w.shape[0]
+    with jax.named_scope("conv"):
+        f32 = at_least_f32(bcx.dtype)
+        b, c, x = jnp.split(bcx.astype(f32), 3, axis=-1)
+        v = jnp.pad(b * x, ((0, 0), (L - 1, 0), (0, 0)))
+        z = sum(conv_w[j].astype(f32) * v[:, j:j + T] for j in range(L))
+        y = (c * z).astype(bcx.dtype)
+    return _mm(y, w_out)
 
 
 @register_config("RMSNorm")
@@ -145,6 +168,8 @@ class DecoderBlock(FeedForwardLayer):
     index_dim: int = 0
     index_topk: int = 0
     index_loss_weight: float = 1.0
+    #: "short_conv": the convolution's taps, the token's own counted
+    conv_kernel: int = 3
     #: latent width, query/key widths without and with rotation,
     #: value width
     kv_rank: int = 0
@@ -197,6 +222,11 @@ class DecoderBlock(FeedForwardLayer):
                 f"and needs index_dim and index_topk: {self.index_heads} "
                 f"heads of {self.index_dim}, topk {self.index_topk}, "
                 f"attention {self.attention!r}, window {self.window}")
+        if self.attention == "short_conv" and (self.window
+                                               or self.conv_kernel < 1):
+            raise ValueError(
+                "a short convolution takes no window and at least one tap: "
+                f"window {self.window}, conv_kernel {self.conv_kernel}")
 
     def set_n_in(self, itype: InputType) -> None:
         if not self.n_in:
@@ -240,7 +270,12 @@ class DecoderBlock(FeedForwardLayer):
                 ("post1", "post2") if self.norm_placement == "sandwich"
                 else ()):
             p[n + "_g"] = jnp.ones((F,), jnp.float32)
-        p["Wq"] = w(F, H * self._qk_dim())
+        if self.attention == "short_conv":
+            p["W_in"] = w(F, 3 * F)
+            p["conv_w"] = w(self.conv_kernel, F)
+            p["W_out"] = w(F, F)
+        else:
+            p["Wq"] = w(F, H * self._qk_dim())
         if self.attention == "gqa":
             D, G = self.head_dim, self.n_kv_heads
             if not G or H % G:
@@ -251,7 +286,7 @@ class DecoderBlock(FeedForwardLayer):
             p["Wo"] = w(H * D, F)
             p["q_norm_g"] = jnp.ones((D,), jnp.float32)
             p["k_norm_g"] = jnp.ones((D,), jnp.float32)
-        else:
+        elif self.attention == "mla":
             p["Wkva"] = w(F, self.kv_rank + self.qk_rope_dim)
             p["kv_norm_g"] = jnp.ones((self.kv_rank,), jnp.float32)
             p["Wkvb"] = w(self.kv_rank, H * (self.qk_nope_dim + self.v_dim))
@@ -279,7 +314,8 @@ class DecoderBlock(FeedForwardLayer):
 
     def regularizable_params(self):
         return ("Wq", "Wkva", "Wkvb", "Wk", "Wv", "Wz", "Wo", "Wg", "Wu",
-                "Wd", "Eg", "Eu", "Ed", "Sg", "Su", "Sd", "WqI", "WkI", "Ww")
+                "Wd", "Eg", "Eu", "Ed", "Sg", "Su", "Sd", "WqI", "WkI", "Ww",
+                "W_in", "conv_w", "W_out")
 
     def init_state(self, itype: InputType) -> dict:
         index = ({"index_loss": jnp.zeros((), jnp.float32)}
@@ -357,6 +393,9 @@ class DecoderBlock(FeedForwardLayer):
     def attention_part(self, params, u, mask=None):
         """``A(u)``: u [B, T, F] normed input -> [B, T, F]; a block with an
         indexer returns ``(A(u), index_loss)``."""
+        if self.attention == "short_conv":
+            return short_conv(u, params["W_in"], params["conv_w"],
+                              params["W_out"])
         if self.attention == "gqa":
             a, index_loss = self._gqa_part(params, u, mask)
             return (a, index_loss) if self.index_heads else a
@@ -489,9 +528,12 @@ class DecoderBlock(FeedForwardLayer):
         flash kernel's plan computes under this block's mask and what the
         mask leaves visible (``pallas_kernels.flash_score_entries``). A
         block with an indexer computes the causal plan's tiles and leaves
-        the selected pairs visible."""
+        the selected pairs visible; a short convolution has no core and
+        computes none."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_score_entries
 
+        if self.attention == "short_conv":
+            return 0, 0
         computed, visible = flash_score_entries(
             seq, self._qk_dim(), self._v_dim(), dtype, self.window)
         heads = batch * self.n_heads
@@ -505,9 +547,11 @@ class DecoderBlock(FeedForwardLayer):
         its input, for one step over ``batch`` sequences of ``seq`` tokens in
         ``dtype``: the core's output and log-sum-exp where the flash kernels
         engage forward and backward on this device, an indexer's int8
-        selection and its log-sum-exp."""
+        selection and its log-sum-exp; a short convolution keeps nothing."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_kept_bytes
 
+        if self.attention == "short_conv":
+            return {}
         out, lse = flash_kept_bytes(batch, seq, self.n_heads, self._v_dim(),
                                     dtype)
         kept = {remat.CORE_OUT: out, remat.CORE_LSE: lse}
@@ -523,3 +567,8 @@ class DecoderBlock(FeedForwardLayer):
         k = min(self.index_topk, seq)
         return (batch * (seq * (seq + 1) // 2),
                 batch * (k * (k + 1) // 2 + (seq - k) * k))
+
+    def conv_tokens(self, batch: int, seq: int) -> int:
+        """Tokens one step over ``batch`` sequences of ``seq`` tokens runs
+        through this block's short convolution (0 for an attention)."""
+        return batch * seq if self.attention == "short_conv" else 0

@@ -55,6 +55,7 @@ ATTN_SCORE_ENTRIES_VISIBLE_TOTAL = "dl4j_attn_score_entries_visible_total"
 ATTN_INDEX_PAIRS_SCORED_TOTAL = "dl4j_attn_index_pairs_scored_total"
 ATTN_PAIRS_SELECTED_TOTAL = "dl4j_attn_pairs_selected_total"
 REMAT_KEPT_BYTES_TOTAL = "dl4j_remat_kept_bytes_total"
+SHORT_CONV_TOKENS_TOTAL = "dl4j_short_conv_tokens_total"
 
 # --- recurrent engine (ops/lstm.py) ----------------------------------------
 LSTM_DISPATCH_TOTAL = "dl4j_lstm_dispatch_total"
