@@ -57,8 +57,9 @@ _VMEM_BUDGET = 14 * 1024 * 1024
 #: as ``_flash_tiles`` counts it: scores, operand blocks, accumulators, dQ at
 #: ``blk_q`` rows), where the count passes the scoped default and the kernel
 #: asks for what it says (``vmem_limit_bytes``): a quarter of a v5e core's
-#: 128 MiB. The one-kernel backward adds a head's whole dQ, and
-#: ``_fused_bwd_fits`` holds its whole program, tiles and dQ, to this and
+#: 128 MiB. The one-kernel backward adds a head's whole dQ accumulator and
+#: dQ's output block, and ``_fused_bwd_fits`` holds its whole program, tiles
+#: and dQ, to this and
 #: ``_VMEM_BUDGET`` together: at most 46 MiB planned, at most 61.5 asked for
 #: (``_flash_params``)
 _VMEM_CEILING = 32 * 1024 * 1024
@@ -305,21 +306,33 @@ def _lanes(d: int) -> int:
     return -(-d // 128) * 128
 
 
-def _dq_bytes(rows: int, dk: int, itemsize: int) -> int:
-    """VMEM of ``rows`` rows of dQ: the float32 accumulator and the
-    double-buffered output block."""
-    return rows * _lanes(dk) * (4 + 2 * itemsize)
+def _dq_bytes(rows: int, dk: int, itemsize: int, out_rows: int = None) -> int:
+    """VMEM of dQ: the float32 accumulator of ``rows`` rows and the
+    double-buffered output block of ``out_rows`` (None: as many)."""
+    out_rows = rows if out_rows is None else out_rows
+    return _lanes(dk) * (rows * 4 + out_rows * 2 * itemsize)
+
+
+def _dq_by_key_block(causal: bool, tq: int, tk: int) -> bool:
+    """Whether the one-kernel backward writes dQ one key block at a time.
+    It walks the k-blocks in its outer grid axis; under a causal mask over
+    equal lengths query row r sees no key past r, so the rows of k-block j
+    are final once outer step j ends, under a window or a selection too.
+    Otherwise a row is final only on the head's last tile."""
+    return causal and tq == tk
 
 
 def _flash_vmem_bytes(blk_q: int, blk_k: int, dk: int, dv: int,
                       itemsize: int, backward: bool = False,
-                      dq_rows: int = 0) -> int:
+                      dq_rows: int = 0, dq_out_rows: int = None) -> int:
     """Bytes of VMEM one program of a flash kernel plans for, from its
     shapes: the float32 score tile and the tiles derived from it that are
     alive beside it (P forward; P, dP and dS backward), their copies in the
     operands' dtype for the MXU, the float32 accumulators, the pipeline's
     double-buffered operand and output blocks, and, where the backward keeps
-    a head's whole dQ (``dq_rows`` = Tq), that scratch and its output."""
+    a head's whole dQ (``dq_rows`` = Tq), that scratch and its output block
+    of ``dq_out_rows`` rows (None: as many; ``blk_k`` where dQ is written a
+    key block at a time, ``_dq_by_key_block``)."""
     dk, dv = _lanes(dk), _lanes(dv)
     tile = blk_q * blk_k
     if not backward:
@@ -330,7 +343,8 @@ def _flash_vmem_bytes(blk_q: int, blk_k: int, dk: int, dv: int,
     scores = tile * (4 * 4 + 2 * itemsize)
     blocks = 2 * itemsize * (blk_q * (dk + dv) + 2 * blk_k * (dk + dv))
     accum = blk_k * (dk + dv) * 4
-    return scores + blocks + accum + _dq_bytes(dq_rows or blk_q, dk, itemsize)
+    return scores + blocks + accum + _dq_bytes(dq_rows or blk_q, dk, itemsize,
+                                               dq_out_rows)
 
 
 def _flash_params(semantics, need: int):
@@ -842,10 +856,13 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = False,
     Backward is tiled pallas too, recomputing P from the saved logsumexp
     (flash-attention practice: trade FLOPs for HBM; peak extra memory
     O(blk·T), never O(Tq·Tk)): ONE kernel for dQ, dK and dV where its
-    program, the tiles and a head's whole dQ, fits the VMEM a kernel may
-    ask for (``_fused_bwd_fits``), so each score tile is recomputed once,
-    else a dQ and a dK/dV kernel. Set DL4J_FLASH_PALLAS_BWD=0 to use
-    the XLA chunked-scan backward instead.
+    program, the tiles and a head's whole dQ accumulator, fits the VMEM a
+    kernel may ask for (``_fused_bwd_fits``), so each score tile is
+    recomputed once, else a dQ and a dK/dV kernel. Under a causal mask the
+    one kernel writes dQ one key block at a time, as each block's rows
+    become final, and so holds one key block of dQ's output, not the whole
+    head's. Set DL4J_FLASH_PALLAS_BWD=0 to use the XLA chunked-scan backward
+    instead.
 
     Tiles are chosen from the operands' shape (``_flash_tiles``): up to
     1,024 square, smaller where the sequence or VMEM says so. Under a causal mask a dead tile is skipped and
@@ -959,7 +976,7 @@ def _selected_fwd_rule(q, k, v, select, interpret, force, scale):
     _note_dispatch("flash_attention_bwd_fused" + variant,
                    tiled_bwd and _fused_bwd_fits(
                        q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
-                       q.dtype))
+                       q.dtype, True))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
         out, lse = _kept(*_flash_forward(q, k, v, True, interpret=interpret,
@@ -1002,8 +1019,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     * ``want_dq`` alone: grid (batch*head, q-block, k-block); K/V stream, dQ
       accumulates across the k-blocks (the forward's streaming shape).
     * both (the fused backward): the dK/dV grid, and dQ for the WHOLE head
-      in a (Tq, Dk) float32 scratch, each tile adding to its rows, written
-      out on the head's last tile.
+      in a (Tq, Dk) float32 scratch, each tile adding to its rows. Under a
+      causal mask (``_dq_by_key_block``) the output block is one k-block's
+      rows, written on the last q-block of its outer step, where those rows
+      are final; otherwise it is the whole head, written on its last tile.
 
     The tile is S TRANSPOSED, (blk_k, blk_q): P^T = exp(S^T - lse) with
     S^T = K Q^T, dP^T = V dO^T, dS^T = P^T o (dP^T - delta), so lse and delta
@@ -1082,7 +1101,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     _per_causal_tile(_tile, causal, qi, kj, blk_q, blk_k, window, valid)
 
-    if want_dq:
+    if want_dq and fused and dq_ref.shape[1] < dq_sc.shape[0]:
+        # the output block is one k-block's rows (``_dq_by_key_block``):
+        # those of the outer step, kj, which a window never remaps here
+        @pl.when(last_q)
+        def _finalize_dq_block():
+            at = pl.ds(pl.multiple_of(kj * blk_k, blk_k), blk_k)
+            dq_ref[0] = (dq_sc[at, :] * scale).astype(dq_ref.dtype)
+    elif want_dq:
         @pl.when(jnp.logical_and(last_k, last_q) if fused else last_k)
         def _finalize_dq():
             dq_ref[0] = (dq_sc[...] * scale).astype(dq_ref.dtype)
@@ -1094,21 +1120,25 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _fused_bwd_fits(tq: int, tk: int, dk: int, dv: int, dtype,
+def _fused_bwd_fits(tq: int, tk: int, dk: int, dv: int, dtype, causal: bool,
                     blk_q: int = None, blk_k: int = None) -> bool:
     """Whether ONE backward kernel serves the shape: its whole program
     (``_flash_vmem_bytes`` at the tiles the backward takes, ``_flash_tiles``,
-    with a head's whole dQ: the Tq x Dk float32 accumulator and its
-    double-buffered output block) fits ``_VMEM_CEILING + _VMEM_BUDGET``, and
-    the kernel asks for what it counts (``_flash_params``). In bfloat16 at
-    1,024-square tiles 4,096 x 192/128 counts 34 MiB, 8,192 x 128 32 and
-    16,384 x 128 40, and take it; 16,384 x 192/128 counts 58 and 32,768 x
-    128 56, and keep the dQ + dK/dV pair, whose VMEM does not grow with the
-    sequence. The program and the dispatch note both ask this."""
+    with a head's whole dQ: the Tq x Dk float32 accumulator and the
+    double-buffered output block, one key block's rows under a causal mask,
+    ``_dq_by_key_block``, else the whole head's) fits ``_VMEM_CEILING +
+    _VMEM_BUDGET``, and the kernel asks for what it counts
+    (``_flash_params``). Causal, in bfloat16 at 1,024-square tiles: 4,096 x
+    192/128 counts 31 MiB, 8,192 x 128 28.5, 16,384 x 128 32.5, 16,384 x
+    192/128 43, 32,768 x 64 or 128 40.5, and take it; 32,768 x 192/128
+    counts 59 and 65,536 x 64 56.5, and keep the dQ + dK/dV pair, whose VMEM
+    does not grow with the sequence. The program and the dispatch note both
+    ask this."""
     tiles = _flash_tiles(tq, tk, dk, dv, dtype, blk_q, blk_k, backward=True)
     return bool(tiles) and _flash_vmem_bytes(
-        *tiles, dk, dv, jnp.dtype(dtype).itemsize, True,
-        dq_rows=tq) <= _VMEM_CEILING + _VMEM_BUDGET
+        *tiles, dk, dv, jnp.dtype(dtype).itemsize, True, dq_rows=tq,
+        dq_out_rows=tiles[1] if _dq_by_key_block(causal, tq, tk) else None
+    ) <= _VMEM_CEILING + _VMEM_BUDGET
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
@@ -1120,10 +1150,11 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
     optional [B, Tk] {0,1} key-padding mask, same semantics as forward;
     ``v``, ``out`` and ``g`` are ``Dv`` wide, ``q`` and ``k`` ``Dk``.
     ``fused`` None: one kernel where its program, a head's whole dQ
-    included, fits the VMEM a kernel may ask for (``_fused_bwd_fits``), else
-    the dQ + dK/dV pair. With fewer key/value heads than query heads every
-    query head reads its key/value head in place and writes its own part of
-    dK and dV, which are summed over the group afterwards (float32).
+    accumulator included, fits the VMEM a kernel may ask for
+    (``_fused_bwd_fits``), else the dQ + dK/dV pair. With fewer key/value
+    heads than query heads every query head reads its key/value head in
+    place and writes its own part of dK and dV, which are summed over the
+    group afterwards (float32).
     ``window`` and ``select`` as the forward's; the kernels read the
     selection transposed (one XLA transpose of it)."""
     B, Tq, H, D = q.shape
@@ -1133,7 +1164,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
     blk_q, blk_k = _tiles_or_raise(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k,
                                    backward=True)
     if fused is None:
-        fused = _fused_bwd_fits(Tq, Tk, D, Dv, q.dtype, blk_q, blk_k)
+        fused = _fused_bwd_fits(Tq, Tk, D, Dv, q.dtype, causal, blk_q, blk_k)
     scale = 1.0 / (D ** 0.5) if scale is None else scale
     qr, kr, vr = _flatten_heads(q), _flatten_heads(k), _flatten_heads(v)
     gr, outr = _flatten_heads(g), _flatten_heads(out)
@@ -1152,7 +1183,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
         whose axis ``q_pos`` walks the q-blocks and whose other block axis
         walks the k-blocks."""
         q_pos = 2 if want_dkv else 1
-        whole = want_dq and want_dkv   # the fused kernel: a head's dQ at once
+        whole = want_dq and want_dkv   # the fused kernel: a head's dQ in VMEM
+        by_block = whole and _dq_by_key_block(causal, Tq, Tk)
 
         # under a causal mask a dead tile (``_causal_block_live``) names the
         # nearest live tile's streamed block again, so nothing is fetched
@@ -1204,7 +1236,11 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
                 lambda *g: (g[0] // H, k_idx(*g), q_idx(*g))))
         out_specs, out_shape, scratch = [], [], []
         if want_dq:
+            # the fused kernel's dQ leaves one k-block (its outer axis) at a
+            # time where a causal head's rows are final block by block
             out_specs.append(
+                pl.BlockSpec((1, blk_k, D), lambda bh, j, i: (bh, j, 0))
+                if by_block else
                 pl.BlockSpec((1, Tq, D), lambda bh, a, b: (bh, 0, 0)) if whole
                 else pl.BlockSpec((1, blk_q, D), lambda bh, i, j: (bh, i, 0)))
             out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype))
@@ -1242,7 +1278,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, blk_q: int = None,
             scratch_shapes=scratch,
             compiler_params=_flash_params(semantics, _flash_vmem_bytes(
                 blk_q, blk_k, D, Dv, q.dtype.itemsize, True,
-                dq_rows=Tq if whole else 0)),
+                dq_rows=Tq if whole else 0,
+                dq_out_rows=blk_k if by_block else None)),
             interpret=interpret,
         )(*operands)
 
@@ -1344,7 +1381,7 @@ def _flash_fwd_rule(q, k, v, causal, interpret, force, scale, window):
     _note_dispatch("flash_attention_bwd_fused" + variant,
                    tiled_bwd and _fused_bwd_fits(
                        q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
-                       q.dtype))
+                       q.dtype, causal))
     if tiled_bwd:
         _note_dispatch("flash_attention" + variant, True)
         out, lse = _kept(*_flash_forward(q, k, v, causal,

@@ -152,30 +152,71 @@ def _tail_mask(B, T):
     return jnp.asarray(mask)
 
 
-@pytest.mark.parametrize("tiles", [(64, 32), (32, 32), (32, 64)],
-                         ids=lambda t: f"q{t[0]}k{t[1]}")
-@pytest.mark.parametrize("key_mask", [False, True], ids=["nomask", "keymask"])
-@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-def test_fused_backward_matches_pair_and_reference(causal, key_mask, tiles):
+def _causal_plan(plan, T, seed, B=2, scale=None):
+    """A causal core under ``plan``, 4 query heads over 2 key/value heads:
+    ``grouped`` alone, a 40-key ``window`` over them, or a random
+    ``select``ion of keys that holds each query's own -> (q, k, v, g, the
+    kernels' keywords, the plain math's forward)."""
+    from deeplearning4j_tpu.ops.pallas_kernels import _selected_attention_xla
+
+    q, k, v, g = _latent_operands(T, seed, B=B, H=4)
+    k, v = k[:, :, ::2], v[:, :, ::2]
+    if plan == "select":
+        rng = np.random.default_rng(seed)
+        pick = np.tril(rng.random((B, T, T)) < 0.3) | np.eye(T, dtype=bool)
+        select = jnp.asarray(pick.astype(np.int8))
+        return q, k, v, g, {"select": select}, (
+            lambda a, b, c: _selected_attention_xla(a, b, c, select,
+                                                    scale)[0])
+    window = 40 if plan == "window" else None
+    return q, k, v, g, {"window": window}, (
+        lambda a, b, c: _masked_f32(a, b, c, window, scale))
+
+
+def _tiles_id(tiles):
+    return f"q{tiles[0]}k{tiles[1]}"
+
+
+_FUSED_TILES = ((64, 32), (32, 32), (32, 64))
+
+
+@pytest.mark.parametrize("causal,key_mask,plan,tiles", [
+    pytest.param(c, m, "plain", t, id="-".join((
+        "causal" if c else "full", "keymask" if m else "nomask",
+        _tiles_id(t))))
+    for c in (False, True) for m in (False, True) for t in _FUSED_TILES] + [
+    pytest.param(True, False, p, t, id=f"causal-{p}-{_tiles_id(t)}")
+    for p in ("grouped", "window", "select") for t in _FUSED_TILES])
+def test_fused_backward_matches_pair_and_reference(causal, key_mask, plan,
+                                                   tiles):
     """ONE backward kernel (dQ for the whole head in VMEM beside dK/dV's
-    accumulators) gives what the dQ + dK/dV pair gives and what the plain
-    math gives (`_attention_bwd_chunked`; under a key mask the XLA masked
-    attention's gradient), at Dk != Dv, with the query tile larger than,
-    equal to and smaller than the key tile."""
+    accumulators) gives the dQ + dK/dV pair's gradients bit for bit and
+    what the plain math gives (`_attention_bwd_chunked`; under a key mask
+    the XLA masked attention's gradient; grouped heads, a window or a
+    selection against their own statements), at Dk != Dv, with the query
+    tile larger than, equal to and smaller than the key tile. A causal one
+    kernel writes dQ one key block at a time, 2 or 4 of them a head here:
+    a block written before its last tile would differ from the pair's."""
     from deeplearning4j_tpu.ops.pallas_kernels import (
         _attention_bwd_chunked, _flash_backward, _flash_forward,
         _masked_attention_xla)
     T, scale = 128, 24 ** -0.5
-    q, k, v, g = _latent_operands(T, seed=11)
-    km = _tail_mask(q.shape[0], T) if key_mask else None
+    if plan == "plain":
+        q, k, v, g = _latent_operands(T, seed=11)
+        km = _tail_mask(q.shape[0], T) if key_mask else None
+        kw = {"key_mask": km}
+    else:
+        q, k, v, g, kw, ref = _causal_plan(plan, T, seed=11)
     bq, bk = tiles
     out, lse = _flash_forward(q, k, v, causal, blk_q=bq, blk_k=bk,
-                              interpret=True, key_mask=km)
+                              interpret=True, **kw)
     fused, pair = (
         _flash_backward(q, k, v, out, lse, g, causal, blk_q=bq, blk_k=bk,
-                        interpret=True, key_mask=km, fused=f)
+                        interpret=True, fused=f, **kw)
         for f in (True, False))
-    if key_mask:
+    if plan != "plain":
+        expect = jax.vjp(ref, q, k, v)[1](g)
+    elif key_mask:
         _, vjp = jax.vjp(
             lambda a, b, c: _masked_attention_xla(a, b, c, km, causal),
             q, k, v)
@@ -185,26 +226,39 @@ def test_fused_backward_matches_pair_and_reference(causal, key_mask, tiles):
                                         scale=scale)
     for name, a, b, c in zip(("dq", "dk", "dv"), fused, pair, expect):
         assert np.all(np.isfinite(np.asarray(a))), name
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6, err_msg=name)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=5e-4, atol=5e-5, err_msg=name)
 
 
-@pytest.mark.parametrize("tiles", [(48, 32), (32, 48), (16, 96), (96, 16),
-                                   (24, 16)],
-                         ids=lambda t: f"q{t[0]}k{t[1]}")
-def test_diagonal_crosses_tiles_at_every_offset(tiles):
+_OFFSET_TILES = ((48, 32), (32, 48), (16, 96), (96, 16), (24, 16))
+
+
+@pytest.mark.parametrize("plan,tiles", [
+    pytest.param("plain", t, id=_tiles_id(t)) for t in _OFFSET_TILES] + [
+    pytest.param(p, t, id=f"{p}-{_tiles_id(t)}")
+    for p in ("grouped", "window", "select")
+    for t in ((48, 32), (32, 48), (24, 16))])
+def test_diagonal_crosses_tiles_at_every_offset(plan, tiles):
     """Only a tile the diagonal crosses is masked, a tile wholly below it
     takes the body without the mask and a dead one is skipped: with a query
     tile that is no multiple of the key tile (and the reverse) the diagonal
     enters tiles at every offset, and forward and both backwards still give
-    the masked reference's result."""
+    the masked reference's result, with grouped heads, a window or a
+    selection too; the one kernel, which writes dQ a key block at a time
+    (up to 6 a head here), gives the pair's gradients bit for bit."""
     from deeplearning4j_tpu.ops.pallas_kernels import (
         _causal_block_crossed, _causal_block_live, _flash_backward,
         _flash_forward)
     T, scale = 96, 0.17
-    q, k, v, g = _latent_operands(T, seed=12, B=1)
+    if plan == "plain":
+        q, k, v, g = _latent_operands(T, seed=12, B=1)
+        kw = {}
+        forward = lambda a, b, c: attention_reference(a, b, c, True, scale)
+    else:
+        q, k, v, g, kw, forward = _causal_plan(plan, T, seed=12, B=1,
+                                               scale=scale)
     bq, bk = tiles
     # the predicates against the positions they stand for
     for qi in range(T // bq):
@@ -214,18 +268,22 @@ def test_diagonal_crosses_tiles_at_every_offset(tiles):
             assert _causal_block_live(qi, kj, bq, bk) == (ks[0] <= qs[-1])
             assert _causal_block_crossed(qi, kj, bq, bk) == (ks[-1] > qs[0])
     out, lse = _flash_forward(q, k, v, True, blk_q=bq, blk_k=bk,
-                              interpret=True, scale=scale)
-    ref, vjp = jax.vjp(
-        lambda a, b, c: attention_reference(a, b, c, True, scale), q, k, v)
+                              interpret=True, scale=scale, **kw)
+    ref, vjp = jax.vjp(forward, q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
+    grads = []
     for fused in (True, False):
         got = _flash_backward(q, k, v, out, lse, g, True, blk_q=bq, blk_k=bk,
-                              interpret=True, scale=scale, fused=fused)
+                              interpret=True, scale=scale, fused=fused, **kw)
+        grads.append(got)
         for name, a, b in zip(("dq", "dk", "dv"), got, vjp(g)):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
                 err_msg=f"{name} fused={fused}")
+    for name, a, b in zip(("dq", "dk", "dv"), *grads):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
 
 
 @pytest.mark.parametrize("key_mask", [False, True], ids=["nomask", "keymask"])
@@ -272,10 +330,13 @@ def test_square_diagonal_tile_runs_as_three_quarters(key_mask, monkeypatch):
 
 def test_tiles_and_backward_follow_the_shape():
     """No switch chooses the tiles or the backward: the operands' shape
-    does. The language-model cells' cores (4,096 x 192/128, 8,192 x 128 and
-    16,384 x 128, bfloat16) take 1,024-square tiles and the one-kernel
-    backward; at 16,384 x 192/128 and at 32,768 x 128 the one kernel's
-    program passes what a kernel may ask for and the pair stays; lengths
+    does. The language-model cells' cores (4,096 x 192/128, 8,192 x 128,
+    16,384 x 128 and 32,768 x 64, bfloat16, causal) take 1,024-square tiles
+    and the one-kernel backward, which writes a causal head's dQ one key
+    block at a time; so do 16,384 x 192/128 and 32,768 x 128. At 32,768 x
+    192/128 and 65,536 x 64 the one kernel's program passes what a kernel
+    may ask for and the pair stays, as it does without the causal mask from
+    16,384 x 192/128, where a head's whole dQ is written at once; lengths
     that a tile does not divide keep a smaller standard one."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
@@ -283,16 +344,26 @@ def test_tiles_and_backward_follow_the_shape():
     for backward in (False, True):
         assert pk._flash_tiles(4096, 4096, 192, 128, bf16,
                                backward=backward) == (1024, 1024)
-    assert pk._fused_bwd_fits(4096, 4096, 192, 128, bf16)
-    assert pk._fused_bwd_fits(4096, 4096, 64, 64, jnp.float32)
-    assert pk._fused_bwd_fits(8192, 8192, 128, 128, bf16)
-    assert pk._fused_bwd_fits(16384, 16384, 64, 64, bf16)
-    assert pk._fused_bwd_fits(16384, 16384, 128, 128, bf16)
-    assert not pk._fused_bwd_fits(16384, 16384, 192, 128, bf16)
-    assert not pk._fused_bwd_fits(32768, 32768, 128, 128, bf16)
+    assert pk._flash_tiles(32768, 32768, 64, 64, bf16,
+                           backward=True) == (1024, 1024)
+    for causal in (False, True):
+        assert pk._fused_bwd_fits(4096, 4096, 192, 128, bf16, causal)
+        assert pk._fused_bwd_fits(4096, 4096, 64, 64, jnp.float32, causal)
+        assert pk._fused_bwd_fits(8192, 8192, 128, 128, bf16, causal)
+        assert pk._fused_bwd_fits(16384, 16384, 64, 64, bf16, causal)
+        assert pk._fused_bwd_fits(16384, 16384, 128, 128, bf16, causal)
+        for t, dk, dv in ((16384, 192, 128), (32768, 64, 64),
+                          (32768, 128, 128)):
+            assert pk._fused_bwd_fits(t, t, dk, dv, bf16, causal) == causal
+        assert not pk._fused_bwd_fits(32768, 32768, 192, 128, bf16, causal)
+        assert not pk._fused_bwd_fits(65536, 65536, 64, 64, bf16, causal)
     # explicit tiles are counted as given: a 128-row query tile leaves room
-    # for a head's dQ where the 1,024-square one does not
-    assert pk._fused_bwd_fits(16384, 16384, 192, 128, bf16, 128, 512)
+    # for a head's whole dQ where the 1,024-square one does not
+    assert pk._fused_bwd_fits(16384, 16384, 192, 128, bf16, False, 128, 512)
+    # a causal mask over unequal lengths leaves rows final only at the end
+    assert not pk._dq_by_key_block(True, 4096, 8192)
+    assert pk._dq_by_key_block(True, 8192, 8192)
+    assert not pk._dq_by_key_block(False, 8192, 8192)
     # the widest tile that divides, down to one a sequence: measured
     # faster than four a side at T = 1,024 and 2,048 (PERF.md §6, PR 31)
     assert pk._flash_tiles(1024, 1024, 64, 64, bf16) == (1024, 1024)
@@ -307,12 +378,22 @@ def test_tiles_and_backward_follow_the_shape():
     # an explicit size is taken as given, capped at the sequence
     assert pk._flash_tiles(4096, 256, 64, 64, bf16, 128, 512) == (
         128, 256)
-    # the count grows with the tile and with a head's dQ
+    # the count grows with the tile and with a head's dQ: the float32
+    # accumulator over Tq rows, and the double-buffered output block over
+    # Tq rows, or over blk_k where a causal head's dQ leaves a key block at
+    # a time (lanes: 192 pads to 256)
     small = pk._flash_vmem_bytes(128, 512, 192, 128, 2, True)
     assert small < pk._flash_vmem_bytes(512, 512, 192, 128, 2, True)
     assert pk._flash_vmem_bytes(512, 512, 192, 128, 2, True, dq_rows=4096) \
         == pk._flash_vmem_bytes(512, 512, 192, 128, 2, True) \
         + (4096 - 512) * 256 * 8
+    assert pk._flash_vmem_bytes(512, 512, 192, 128, 2, True, dq_rows=4096,
+                                dq_out_rows=512) \
+        == pk._flash_vmem_bytes(512, 512, 192, 128, 2, True) \
+        + (4096 - 512) * 256 * 4
+    # LFM2's core: 24 MiB of tiles, 16 of accumulator, half of output block
+    assert pk._flash_vmem_bytes(1024, 1024, 64, 64, 2, True, dq_rows=32768,
+                                dq_out_rows=1024) == 40.5 * 2 ** 20
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -321,25 +402,28 @@ def test_tiles_and_backward_follow_the_shape():
                                     (256, 256)], ids=str)
 def test_one_kernel_backward_plans_within_tiles_plus_dq(widths, dtype):
     """``_VMEM_CEILING`` holds the tiles alone; whatever shape takes the
-    one-kernel backward plans for at most ``_VMEM_CEILING + _VMEM_BUDGET``
-    with a head's whole dQ, and asks the compiler for under half a core's
-    128 MiB."""
+    one-kernel backward, causal or not, plans for at most ``_VMEM_CEILING +
+    _VMEM_BUDGET`` with a head's whole dQ accumulator and its output block,
+    and asks the compiler for under half a core's 128 MiB."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
     dk, dv = widths
     itemsize = jnp.dtype(dtype).itemsize
-    fused = 0
-    for t in (1024, 1280, 2048, 4096, 8192, 16384, 32768):
-        bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
-        tiles = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True)
-        assert tiles <= pk._VMEM_CEILING
-        if pk._fused_bwd_fits(t, t, dk, dv, dtype):
-            fused += 1
-            need = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True,
-                                        dq_rows=t)
-            limit = pk._flash_params(("parallel",), need).vmem_limit_bytes
-            assert limit is None or need < limit <= 64 << 20
-    assert fused  # some length of every width takes the one kernel
+    for causal in (False, True):
+        fused = 0
+        for t in (1024, 1280, 2048, 4096, 8192, 16384, 32768):
+            bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
+            tiles = pk._flash_vmem_bytes(bq, bk, dk, dv, itemsize, True)
+            assert tiles <= pk._VMEM_CEILING
+            if pk._fused_bwd_fits(t, t, dk, dv, dtype, causal):
+                fused += 1
+                need = pk._flash_vmem_bytes(
+                    bq, bk, dk, dv, itemsize, True, dq_rows=t,
+                    dq_out_rows=bk if causal else None)
+                limit = pk._flash_params(("parallel",),
+                                         need).vmem_limit_bytes
+                assert limit is None or need < limit <= 64 << 20
+        assert fused  # some length of every width takes the one kernel
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -350,15 +434,19 @@ def test_one_kernel_backward_plans_within_tiles_plus_dq(widths, dtype):
 def test_one_kernel_gate_is_its_whole_programs_count(t, widths, dtype):
     """The gate stated as the invariant: a shape takes the one-kernel
     backward exactly where its program at the backward's tiles, a head's
-    whole dQ included, counts within ``_VMEM_CEILING + _VMEM_BUDGET``."""
+    whole dQ accumulator and dQ's output block included (a key block's rows
+    under a causal mask, the whole head's without), counts within
+    ``_VMEM_CEILING + _VMEM_BUDGET``."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
 
     dk, dv = widths
     bq, bk = pk._flash_tiles(t, t, dk, dv, dtype, backward=True)
-    need = pk._flash_vmem_bytes(bq, bk, dk, dv, jnp.dtype(dtype).itemsize,
-                                True, dq_rows=t)
-    assert pk._fused_bwd_fits(t, t, dk, dv, dtype) == (
-        need <= pk._VMEM_CEILING + pk._VMEM_BUDGET)
+    for causal in (False, True):
+        need = pk._flash_vmem_bytes(
+            bq, bk, dk, dv, jnp.dtype(dtype).itemsize, True, dq_rows=t,
+            dq_out_rows=bk if causal else None)
+        assert pk._fused_bwd_fits(t, t, dk, dv, dtype, causal) == (
+            need <= pk._VMEM_CEILING + pk._VMEM_BUDGET)
 
 
 def _dispatch_counts():

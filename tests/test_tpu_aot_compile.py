@@ -75,12 +75,13 @@ def _flash(T, dtype, grad):
                                   argnums=(0, 1, 2))(q, k, v)
 
     # forward kernel; under grad it is joined from _PBWD_MIN_SEQ by the one
-    # backward kernel where its program, a head's whole dQ included, fits
-    # what a kernel may ask for (T = 16,384 at 64 wide: 40 MiB in bfloat16,
-    # 33.5 in float32), else by the dq + dkv pair
+    # backward kernel where its program, a head's whole dQ accumulator and
+    # one key block of dQ's output included, fits what a kernel may ask for
+    # (T = 16,384 at 64 wide: 32.5 MiB in bfloat16, 18 in float32), else by
+    # the dq + dkv pair
     want = 1
     if grad and T >= pk._PBWD_MIN_SEQ:
-        want = 2 if pk._fused_bwd_fits(T, T, 64, 64, dtype) else 3
+        want = 2 if pk._fused_bwd_fits(T, T, 64, 64, dtype, True) else 3
     return (bwd if grad else fwd), qkv, want
 
 
@@ -145,10 +146,11 @@ def _latent(grad, T=4096):
     """Latent attention's core at DeepSeek-V2-Lite's widths: 192-wide
     queries and keys, 128-wide values, 4 sequences of 4,096, 16 heads: the
     forward at the tiles the shape chooses, and under grad the ONE backward
-    kernel (its program, a head's 4,096 x 192 dQ included, counts 34 MiB).
-    At 16,384 it counts 58, past what a kernel may ask for, and the dq + dkv
-    pair stays: the case that guards the pair."""
-    shapes = [((4 * 4096 // T, T, 16, d), BF16) for d in (192, 192, 128)]
+    kernel (its program, a head's 4,096 x 192 dQ accumulator and one key
+    block of dQ's output included, counts 31 MiB; at 16,384, one sequence,
+    43). At 32,768 it counts 59, past what a kernel may ask for, and the dq
+    + dkv pair stays: the case that guards the pair."""
+    shapes = [((max(1, 16384 // T), T, 16, d), BF16) for d in (192, 192, 128)]
 
     def fwd(q, k, v):
         return pk.flash_attention(q, k, v, True, False, False, 0.1147)
@@ -157,17 +159,17 @@ def _latent(grad, T=4096):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    assert pk._fused_bwd_fits(T, T, 192, 128, BF16) == (T == 4096)
+    assert pk._fused_bwd_fits(T, T, 192, 128, BF16, True) == (T <= 16384)
     return (bwd if grad else fwd), shapes, (
-        (2 if T == 4096 else 3) if grad else 1)
+        (2 if T <= 16384 else 3) if grad else 1)
 
 
 def _windowed(grad, window=2048, T=8192):
     """The grouped core at Trinity-Mini's widths: 2 sequences of 8,192, 32
     query heads over 4 key/value heads, 128 wide; a 2,048-key window on the
     sliding layers, none on the full ones. Forward, and under grad the ONE
-    backward kernel (its program, a head's 8,192 x 128 dQ included, counts
-    32 MiB)."""
+    backward kernel (its program, a head's 8,192 x 128 dQ accumulator and
+    one key block of dQ's output included, counts 28.5 MiB)."""
     shapes = [((2, T, h, 128), BF16) for h in (32, 4, 4)]
 
     def fwd(q, k, v):
@@ -177,7 +179,28 @@ def _windowed(grad, window=2048, T=8192):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    assert pk._fused_bwd_fits(T, T, 128, 128, BF16)
+    assert pk._fused_bwd_fits(T, T, 128, 128, BF16, True)
+    return (bwd if grad else fwd), shapes, (2 if grad else 1)
+
+
+def _lfm2_core(grad, T=32768):
+    """The attention core of LFM2-24B-A2B (``benchmark/configs/
+    lfm2-24b-a2b-ep8.json``): one sequence of 32,768, 32 query heads over 8
+    key/value heads of 64, causal. Under grad the ONE backward kernel: its
+    program, a head's 32,768 x 64 float32 dQ accumulator and one 1,024-row
+    key block of dQ's output beside the tiles, counts 40.5 MiB and asks for
+    54.6 (the whole head's output block would make it 56, past the 46 a
+    kernel may plan for)."""
+    shapes = [((1, T, h, 64), BF16) for h in (32, 8, 8)]
+
+    def fwd(q, k, v):
+        return pk.flash_attention(q, k, v, True)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    assert pk._fused_bwd_fits(T, T, 64, 64, BF16, True)
     return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
@@ -221,7 +244,8 @@ def _indexer(part, grad=False, T=16384, topk=2048):
     16,384 keys in VMEM, the keys' scratch beside them); ``core``: the
     forward with the selection's int8 tile, under grad the ONE backward
     kernel reading it transposed (its program, a head's 16,384 x 128 dQ
-    included, counts 40 MiB and asks for 54); ``kl``: the
+    accumulator and one key block of dQ's output included, counts 32.5 MiB
+    and asks for 44.6); ``kl``: the
     kernel that sums the heads' probabilities into a tile, under grad its
     forward and the two of the index scores' backward."""
     B, H, G, D, J, E = 1, 32, 4, 128, 16, 64
@@ -239,8 +263,8 @@ def _indexer(part, grad=False, T=16384, topk=2048):
             return jax.grad(lambda *a: fwd(*a, s)[0].astype(F32).sum(),
                             argnums=(0, 1, 2))(q, k, v)
 
-        # Keye's shape takes the one kernel: Mosaic is asked for 54 MiB
-        assert pk._fused_bwd_fits(T, T, D, D, BF16)
+        # Keye's shape takes the one kernel: Mosaic is asked for 44.6 MiB
+        assert pk._fused_bwd_fits(T, T, D, D, BF16, True)
         want = 2 if grad else 1
         return (bwd if grad else fwd), qkv + [((B, T, T), jnp.int8)], want
 
@@ -297,6 +321,8 @@ CASES = {
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
     "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
+    "latent-grad-T32768-bfloat16": (_latent, (True, 32768)),
+    "lfm2-core-grad-T32768-bfloat16": (_lfm2_core, (True,)),
     "window-fwd-T8192-W2048-bfloat16": (_windowed, (False,)),
     "window-grad-T8192-W2048-bfloat16": (_windowed, (True,)),
     "window-grad-T8192-full-bfloat16": (_windowed, (True, None)),
