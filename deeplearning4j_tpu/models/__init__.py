@@ -9,3 +9,4 @@ from deeplearning4j_tpu.models.deepseek_v2 import deepseek_v2_lite
 from deeplearning4j_tpu.models.trinity_mini import trinity_mini
 from deeplearning4j_tpu.models.keye_vl2 import keye_vl2_lm
 from deeplearning4j_tpu.models.lfm2_moe import lfm2_moe
+from deeplearning4j_tpu.models.nemotron_h import nemotron_h

@@ -34,8 +34,19 @@ and not a class of its own:
   u W_in`` (three ``F``-wide streams), ``V = Bg * X``, a causal depthwise
   convolution of ``conv_kernel`` taps a channel, ``Z_t = sum_j w_j
   V_{t-L+1+j}`` (the last tap weighs the token itself; positions before the
-  first read 0), ``A(u) = (Cg * Z) W_out``.
-* ``ffn``: ``"swiglu"`` (``(silu(u Wg) * (u Wu)) Wd``, no biases) or
+  first read 0), ``A(u) = (Cg * Z) W_out``. ``"mamba2"``: a Mamba-2
+  state-space mixer (``_mamba_part``): ``[z | xBC | dt] = u W_in``, a causal
+  depthwise convolution of ``conv_kernel`` taps over ``xBC`` with a bias and
+  SiLU, ``[x | B | C]`` from it (``ssm_heads`` heads ``ssm_head_dim`` wide;
+  ``ssm_groups`` groups of ``ssm_state``-wide B and C), ``dt =
+  softplus(dt + dt_bias)``, ``A = -exp(A_log)``, the scan ``S_t =
+  exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t`` in
+  chunks of ``ssm_chunk`` tokens (``ops/ssd.py``), ``y * silu(z)`` RMS-normed
+  over each of ``ssm_groups`` groups of channels, then ``W_out``.
+  ``"none"``: no mixer; the block is ``y = x + F(N(x))``. ``qk_norm`` False
+  drops ``"gqa"``'s per-head query and key norms.
+* ``ffn``: ``"swiglu"`` (``(silu(u Wg) * (u Wu)) Wd``, no biases), ``"none"``
+  (no feed-forward; the block is ``y = x + A(N(x))``) or
   ``"moe"``: routing over ``n_experts`` router outputs, a shared expert
   computed for every token, and the routed experts this chip holds
   (``experts_held``, a ``[first, end)`` range of expert ids; None: all)
@@ -49,7 +60,12 @@ and not a class of its own:
   ``"sigmoid_bias"``: sigmoid scores, the largest of ``score + bias`` taken,
   weighted by their scores (without the bias) renormalised to
   ``route_scale``, no auxiliary loss, and the bias moved against each
-  output's load after every training step.
+  output's load after every training step. ``expert_act``: every expert,
+  routed and shared, a gated SiLU (``"swiglu"``, three matrices) or
+  ``"relu2"``, ``relu(u Wu)^2 Wd`` (two).
+
+A single-branch block has the one norm of its branch (``norm1`` before a
+mixer, ``norm2`` before a feed-forward) and no params or state of the other.
 
 Each field's second value is a branch in ``_norm``, ``attention_part`` or
 ``route`` beside its first caller, not a class of its own
@@ -77,7 +93,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from deeplearning4j_tpu.common import at_least_f32
+from deeplearning4j_tpu.common import at_least_f32, get_policy
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers.attention import (
     apply_rope, attend, rms_norm, rope_inv_freq, yarn_mscale)
@@ -85,11 +101,12 @@ from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.feedforward import _dense
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
-from deeplearning4j_tpu.ops import indexer, remat
+from deeplearning4j_tpu.ops import indexer, remat, ssd
 
-_NORMS, _FFNS = ("rms",), ("swiglu", "moe")
-_ATTENTIONS = ("mla", "gqa", "short_conv")
+_NORMS, _FFNS = ("rms",), ("swiglu", "moe", "none")
+_ATTENTIONS = ("mla", "gqa", "short_conv", "mamba2", "none")
 _PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
+_EXPERT_ACTS = ("swiglu", "relu2")
 
 
 def _mm(x, w):
@@ -103,6 +120,13 @@ def swiglu(u, w_gate, w_up, w_down):
     g = _mm(u, w_gate)
     act = jax.nn.silu(g.astype(at_least_f32(g.dtype))).astype(g.dtype)
     return _mm(act * _mm(u, w_up), w_down)
+
+
+def relu2(u, w_up, w_down):
+    """``relu(u Wu)^2 Wd``; the square in float32."""
+    up = _mm(u, w_up)
+    r = jax.nn.relu(up.astype(at_least_f32(up.dtype)))
+    return _mm((r * r).astype(up.dtype), w_down)
 
 
 def short_conv(u, w_in, conv_w, w_out):
@@ -168,8 +192,19 @@ class DecoderBlock(FeedForwardLayer):
     index_dim: int = 0
     index_topk: int = 0
     index_loss_weight: float = 1.0
-    #: "short_conv": the convolution's taps, the token's own counted
+    #: "gqa": an RMS norm over each head's query and key (False: none)
+    qk_norm: bool = True
+    #: "short_conv", "mamba2": the convolution's taps, the token's own
+    #: counted
     conv_kernel: int = 3
+    #: "mamba2": heads, a head's width, the state's width, the groups of B
+    #: and C (head h reads group ``h // (ssm_heads / ssm_groups)``; also the
+    #: groups of the gated norm), tokens a chunk of the scan
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
     #: latent width, query/key widths without and with rotation,
     #: value width
     kv_rank: int = 0
@@ -190,6 +225,8 @@ class DecoderBlock(FeedForwardLayer):
     expert_hidden: int = 0
     shared_hidden: int = 0
     experts_held: Optional[list] = None
+    #: every expert, routed and shared: "swiglu" or "relu2"
+    expert_act: str = "swiglu"
     router: str = "softmax"
     aux_loss_weight: float = 0.001
     #: "softmax": the chosen probabilities divided by their sum
@@ -210,7 +247,8 @@ class DecoderBlock(FeedForwardLayer):
     def __post_init__(self):
         for field, known in (("norm", _NORMS), ("attention", _ATTENTIONS),
                              ("ffn", _FFNS), ("router", _ROUTERS),
-                             ("norm_placement", _PLACEMENTS)):
+                             ("norm_placement", _PLACEMENTS),
+                             ("expert_act", _EXPERT_ACTS)):
             if getattr(self, field) not in known:
                 raise ValueError(f"DecoderBlock.{field} = "
                                  f"{getattr(self, field)!r}; known: {known}")
@@ -227,6 +265,26 @@ class DecoderBlock(FeedForwardLayer):
             raise ValueError(
                 "a short convolution takes no window and at least one tap: "
                 f"window {self.window}, conv_kernel {self.conv_kernel}")
+        if self.attention == self.ffn == "none":
+            raise ValueError("a block needs a mixer or a feed-forward")
+        if "none" in (self.attention, self.ffn) and (
+                self.norm_placement != "pre"):
+            raise ValueError("a single-branch block takes \"pre\" norms: "
+                             f"norm_placement {self.norm_placement!r}")
+        if self.attention == "mamba2" and (
+                self.window or self.index_heads or self.rope_theta
+                or self.conv_kernel < 1 or self.ssm_chunk < 1
+                or not self.ssm_heads or not self.ssm_head_dim
+                or not self.ssm_state or self.ssm_groups < 1
+                or self.ssm_heads % self.ssm_groups):
+            raise ValueError(
+                "a Mamba-2 mixer takes no window, indexer or rotary "
+                "embedding, and needs heads over its groups, a width, a "
+                f"state, taps and a chunk: window {self.window}, index_heads "
+                f"{self.index_heads}, rope_theta {self.rope_theta}, "
+                f"{self.ssm_heads} heads of {self.ssm_head_dim} over "
+                f"{self.ssm_groups} groups, state {self.ssm_state}, "
+                f"conv_kernel {self.conv_kernel}, chunk {self.ssm_chunk}")
 
     def set_n_in(self, itype: InputType) -> None:
         if not self.n_in:
@@ -266,15 +324,18 @@ class DecoderBlock(FeedForwardLayer):
         ks = iter(jax.random.split(key, 16))
         w = lambda *shape: self._init_w(next(ks), shape)
         p = {}
-        for n in ("norm1", "norm2") + (
-                ("post1", "post2") if self.norm_placement == "sandwich"
-                else ()):
+        branches = (("norm1", self.attention), ("norm2", self.ffn))
+        for n in [n for n, part in branches if part != "none"] + (
+                ["post1", "post2"] if self.norm_placement == "sandwich"
+                else []):
             p[n + "_g"] = jnp.ones((F,), jnp.float32)
         if self.attention == "short_conv":
             p["W_in"] = w(F, 3 * F)
             p["conv_w"] = w(self.conv_kernel, F)
             p["W_out"] = w(F, F)
-        else:
+        elif self.attention == "mamba2":
+            p.update(self._mamba_params(next(ks)))
+        elif self.attention != "none":
             p["Wq"] = w(F, H * self._qk_dim())
         if self.attention == "gqa":
             D, G = self.head_dim, self.n_kv_heads
@@ -284,8 +345,9 @@ class DecoderBlock(FeedForwardLayer):
             if self.output_gate:
                 p["Wz"] = w(F, H * D)
             p["Wo"] = w(H * D, F)
-            p["q_norm_g"] = jnp.ones((D,), jnp.float32)
-            p["k_norm_g"] = jnp.ones((D,), jnp.float32)
+            if self.qk_norm:
+                p["q_norm_g"] = jnp.ones((D,), jnp.float32)
+                p["k_norm_g"] = jnp.ones((D,), jnp.float32)
         elif self.attention == "mla":
             p["Wkva"] = w(F, self.kv_rank + self.qk_rope_dim)
             p["kv_norm_g"] = jnp.ones((self.kv_rank,), jnp.float32)
@@ -294,23 +356,51 @@ class DecoderBlock(FeedForwardLayer):
         if self.ffn == "swiglu":
             p["Wg"], p["Wu"] = w(F, self.ffn_hidden), w(F, self.ffn_hidden)
             p["Wd"] = w(self.ffn_hidden, F)
-        else:
+        elif self.ffn == "moe":
             first, end = self._held()
             G, He = end - first, self.expert_hidden
             stack = lambda a, b: jax.vmap(
                 lambda k: self._init_w(k, (a, b)))(
                     jax.random.split(next(ks), G))
+            gated = self.expert_act == "swiglu"
             p["Wr"] = w(F, self.n_experts)
-            p["Eg"], p["Eu"], p["Ed"] = stack(F, He), stack(F, He), stack(He, F)
+            if gated:
+                p["Eg"] = stack(F, He)
+            p["Eu"], p["Ed"] = stack(F, He), stack(He, F)
             if self.shared_hidden:
-                p["Sg"], p["Su"] = (w(F, self.shared_hidden),
-                                    w(F, self.shared_hidden))
+                if gated:
+                    p["Sg"] = w(F, self.shared_hidden)
+                p["Su"] = w(F, self.shared_hidden)
                 p["Sd"] = w(self.shared_hidden, F)
         if self.index_heads:
             J, E = self.index_heads, self.index_dim
             p["WqI"], p["WkI"], p["Ww"] = w(F, J * E), w(F, E), w(F, J)
             p["kI_norm_g"] = jnp.ones((E,), jnp.float32)
         return p
+
+    def _mamba_params(self, key) -> dict:
+        """A Mamba-2 mixer's leaves: ``W_in`` [F, 2 d + 2 G N + H] (``d`` =
+        heads x width; ``z``, ``xBC``, ``dt`` in that order), the taps
+        ``conv_w`` [L, d + 2 G N] and their bias ``conv_b`` (0), ``dt_bias``
+        (softplus^-1 of a step drawn log-uniform in [0.001, 0.1], floored at
+        1e-4), ``A_log`` (log U[1, 16]), ``D`` (1), the gated norm's scale
+        ``ssm_norm_g`` (1) and ``W_out`` [d, F]."""
+        F, H = self.n_out, self.ssm_heads
+        d = H * self.ssm_head_dim
+        conv_dim = d + 2 * self.ssm_groups * self.ssm_state
+        k_in, k_conv, k_dt, k_a, k_out = jax.random.split(key, 5)
+        lo, hi = jnp.log(1e-3), jnp.log(1e-1)
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(k_dt, (H,)) * (hi - lo)
+                                 + lo), 1e-4)
+        return {"W_in": self._init_w(k_in, (F, d + conv_dim + H)),
+                "conv_w": self._init_w(k_conv, (self.conv_kernel, conv_dim)),
+                "conv_b": jnp.zeros((conv_dim,), jnp.float32),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jax.random.uniform(k_a, (H,), minval=1.0,
+                                                    maxval=16.0)),
+                "D": jnp.ones((H,), jnp.float32),
+                "ssm_norm_g": jnp.ones((d,), jnp.float32),
+                "W_out": self._init_w(k_out, (d, F))}
 
     def regularizable_params(self):
         return ("Wq", "Wkva", "Wkvb", "Wk", "Wv", "Wz", "Wo", "Wg", "Wu",
@@ -351,10 +441,10 @@ class DecoderBlock(FeedForwardLayer):
         """-> ``(A(u), index_loss or None)``."""
         B, T, _ = u.shape
         H, G, D = self.n_heads, self.n_kv_heads, self.head_dim
-        q = rms_norm(_mm(u, params["Wq"]).reshape(B, T, H, D),
-                     params["q_norm_g"], self.norm_eps)
-        k = rms_norm(_mm(u, params["Wk"]).reshape(B, T, G, D),
-                     params["k_norm_g"], self.norm_eps)
+        qk = lambda t, g: (rms_norm(t, params[g], self.norm_eps)
+                           if self.qk_norm else t)
+        q = qk(_mm(u, params["Wq"]).reshape(B, T, H, D), "q_norm_g")
+        k = qk(_mm(u, params["Wk"]).reshape(B, T, G, D), "k_norm_g")
         v = _mm(u, params["Wv"]).reshape(B, T, G, D)
         if self.output_gate:
             z = _mm(u, params["Wz"])
@@ -390,12 +480,67 @@ class DecoderBlock(FeedForwardLayer):
                 z.astype(at_least_f32(z.dtype))).astype(z.dtype)
         return _mm(o, params["Wo"]), index_loss
 
+    def _mamba_part(self, params, u):
+        """A Mamba-2 mixer: the two projections under ``attn``; everything
+        from ``xBC`` to the gated, normed ``y`` under ``attn/ssd`` in float32
+        (the scan's products in the policy's compute dtype), the chunked
+        scan itself under ``attn/ssd/scan``. From ``xBC`` to ``y`` nothing
+        mixes the groups (a group's channels of ``x``, its ``B`` and ``C``,
+        its heads' steps, its channels of ``z`` and of the norm), so that part
+        runs one group at a time (``lax.map``, each group checkpointed): a
+        block's backward holds one group's intermediates, not all of them."""
+        B, T, _ = u.shape
+        H, G, N = self.ssm_heads, self.ssm_groups, self.ssm_state
+        d = H * self.ssm_head_dim
+        zxd = _mm(u, params["W_in"])
+        with jax.named_scope("ssd"):
+            cuts = (d, 2 * d, 2 * d + G * N, 2 * d + 2 * G * N)
+            by_group = lambda a: jnp.moveaxis(
+                a.reshape(*a.shape[:-1], G, a.shape[-1] // G), -2, 0)
+            z, x, b, c, dt = map(by_group, jnp.split(zxd, cuts, axis=-1))
+            xbc = (d, d + G * N)
+            taps = map(by_group, jnp.split(params["conv_w"], xbc, axis=-1))
+            bias = map(by_group, jnp.split(params["conv_b"], xbc, axis=-1))
+            heads = (params[n].reshape(G, H // G)
+                     for n in ("dt_bias", "A_log", "D"))
+            y = jax.lax.map(jax.checkpoint(self._mamba_group), (
+                z, x, b, c, dt, *taps, *bias, *heads,
+                params["ssm_norm_g"].reshape(G, d // G)))
+            y = jnp.moveaxis(y, 0, 2).reshape(B, T, d)
+        return _mm(y, params["W_out"])
+
+    def _mamba_group(self, args):
+        """One group's part of ``_mamba_part``: z, x [B, T, d / G], b, c [B,
+        T, N], dt [B, T, H / G] and the group's leaves -> the gated, normed
+        ``y`` [B, T, d / G] in ``z``'s dtype."""
+        (z, x, b, c, dt, wx, wb, wc, bx, bb, bc, dt_bias, a_log, dd,
+         g) = args
+        B, T, _ = x.shape
+        f32, cd = at_least_f32(z.dtype), get_policy().compute_dtype
+        L = self.conv_kernel
+
+        def conv(v, w, bias):
+            v = jnp.pad(v.astype(f32), ((0, 0), (L - 1, 0), (0, 0)))
+            return jax.nn.silu(bias.astype(f32) + sum(
+                w[j].astype(f32) * v[:, j:j + T] for j in range(L))).astype(cd)
+
+        x, b, c = conv(x, wx, bx), conv(b, wb, bb), conv(c, wc, bc)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        with jax.named_scope("scan"):
+            y = ssd.ssd_scan(x.reshape(B, T, -1, self.ssm_head_dim), dt,
+                             -jnp.exp(a_log.astype(f32)), b[:, :, None],
+                             c[:, :, None], dd, self.ssm_chunk)
+        y = y.reshape(B, T, -1) * jax.nn.silu(z.astype(f32))
+        return rms_norm(y, g, self.norm_eps).astype(z.dtype)
+
     def attention_part(self, params, u, mask=None):
         """``A(u)``: u [B, T, F] normed input -> [B, T, F]; a block with an
         indexer returns ``(A(u), index_loss)``."""
         if self.attention == "short_conv":
             return short_conv(u, params["W_in"], params["conv_w"],
                               params["W_out"])
+        if self.attention == "mamba2":
+            return self._mamba_part(params, u)
         if self.attention == "gqa":
             a, index_loss = self._gqa_part(params, u, mask)
             return (a, index_loss) if self.index_heads else a
@@ -470,26 +615,31 @@ class DecoderBlock(FeedForwardLayer):
     def routed_part(self, params, u2d, choice2d, weight2d):
         """The held experts' part of the layer for [S, F] tokens:
         ``(y [S, F], rows int32 [3])``."""
-        return grouped_expert_ffn(u2d, choice2d, weight2d, params["Eg"],
+        return grouped_expert_ffn(u2d, choice2d, weight2d, params.get("Eg"),
                                   params["Eu"], params["Ed"], self._held()[0],
                                   self.dispatch_eighths)
 
     def shared_part(self, params, u):
+        if self.expert_act == "relu2":
+            return relu2(u, params["Su"], params["Sd"])
         return swiglu(u, params["Sg"], params["Su"], params["Sd"])
 
     # --------------------------------------------------------------- apply
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         B, T, F = x.shape
         sandwich = self.norm_placement == "sandwich"
-        index = {}
-        with jax.named_scope("attn"):
-            a = self.attention_part(params, self._norm(params, "norm1", x),
-                                    mask)
-            if self.index_heads:
-                a, index_loss = a
-                index = {"index_loss": (index_loss if train
-                                        else jnp.zeros_like(index_loss))}
-            h = x + (self._norm(params, "post1", a) if sandwich else a)
+        index, h = {}, x
+        if self.attention != "none":
+            with jax.named_scope("attn"):
+                a = self.attention_part(params,
+                                        self._norm(params, "norm1", x), mask)
+                if self.index_heads:
+                    a, index_loss = a
+                    index = {"index_loss": (index_loss if train
+                                            else jnp.zeros_like(index_loss))}
+                h = x + (self._norm(params, "post1", a) if sandwich else a)
+        if self.ffn == "none":
+            return self.act_fn()(h), {**state, **index}
         u = self._norm(params, "norm2", h)
         if self.ffn == "swiglu":
             with jax.named_scope("ffn"):
@@ -528,11 +678,11 @@ class DecoderBlock(FeedForwardLayer):
         flash kernel's plan computes under this block's mask and what the
         mask leaves visible (``pallas_kernels.flash_score_entries``). A
         block with an indexer computes the causal plan's tiles and leaves
-        the selected pairs visible; a short convolution has no core and
-        computes none."""
+        the selected pairs visible; a short convolution, a Mamba-2 mixer
+        and a block without a mixer have no core and compute none."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_score_entries
 
-        if self.attention == "short_conv":
+        if not self._has_core():
             return 0, 0
         computed, visible = flash_score_entries(
             seq, self._qk_dim(), self._v_dim(), dtype, self.window)
@@ -547,10 +697,11 @@ class DecoderBlock(FeedForwardLayer):
         its input, for one step over ``batch`` sequences of ``seq`` tokens in
         ``dtype``: the core's output and log-sum-exp where the flash kernels
         engage forward and backward on this device, an indexer's int8
-        selection and its log-sum-exp; a short convolution keeps nothing."""
+        selection and its log-sum-exp; a block without a core keeps
+        nothing."""
         from deeplearning4j_tpu.ops.pallas_kernels import flash_kept_bytes
 
-        if self.attention == "short_conv":
+        if not self._has_core():
             return {}
         out, lse = flash_kept_bytes(batch, seq, self.n_heads, self._v_dim(),
                                     dtype)
@@ -572,3 +723,11 @@ class DecoderBlock(FeedForwardLayer):
         """Tokens one step over ``batch`` sequences of ``seq`` tokens runs
         through this block's short convolution (0 for an attention)."""
         return batch * seq if self.attention == "short_conv" else 0
+
+    def ssm_tokens(self, batch: int, seq: int) -> int:
+        """Tokens one step over ``batch`` sequences of ``seq`` tokens runs
+        through this block's state-space scan (0 for any other mixer)."""
+        return batch * seq if self.attention == "mamba2" else 0
+
+    def _has_core(self) -> bool:
+        return self.attention in ("mla", "gqa")

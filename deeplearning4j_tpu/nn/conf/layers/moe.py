@@ -205,7 +205,8 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
     """The routed experts' part of a top-k expert layer for the experts held
     here, dropless: ``y[t] = sum over t's choices e held here of
     weight[t, e] * E_e(x[t])``, ``E_e`` a gated SiLU feed-forward
-    ``(silu(x Wg_e) * (x Wu_e)) Wd_e``.
+    ``(silu(x Wg_e) * (x Wu_e)) Wd_e``, or with ``w_gate`` None a squared
+    ReLU ``relu(x Wu_e)^2 Wd_e`` (two grouped products, not three).
 
     ``x2d`` [S, F]; ``choice`` [S, k] int32 expert ids over ALL experts;
     ``weight`` [S, k]; ``w_gate``/``w_up`` [G, F, H] and ``w_down`` [G, H, F]
@@ -240,7 +241,7 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
     they are not counted as work (``rows[1]`` stops at the last group's last
     tile)."""
     S, k = choice.shape
-    G = w_gate.shape[0]
+    G = w_up.shape[0]
     pairs = S * k
     pol = get_policy()
     with jax.named_scope("moe/dispatch"):
@@ -274,13 +275,18 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
                               weight.T.reshape(pairs)[head], 0)[:, None]
             with jax.named_scope("moe/experts"):
                 rows = rows.astype(pol.compute_dtype)
-                gate = _grouped_matmul(rows, wg, group_sizes, kernel)
+                gate = (None if wg is None
+                        else _grouped_matmul(rows, wg, group_sizes, kernel))
                 up = _grouped_matmul(rows, wu, group_sizes, kernel)
                 # the down projection is linear in its rows, so the pair's
                 # weight goes in here, in float32 before the one rounding
-                f32 = at_least_f32(gate.dtype)
-                act = (jax.nn.silu(gate.astype(f32)) * up.astype(f32)
-                       * w.astype(f32)).astype(gate.dtype)
+                f32 = at_least_f32(up.dtype)
+                if gate is None:
+                    act = jax.nn.relu(up.astype(f32))
+                    act = act * act
+                else:
+                    act = jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+                act = (act * w.astype(f32)).astype(up.dtype)
                 out = _grouped_matmul(act, wd, group_sizes, kernel).astype(
                     pol.output_dtype)
             with jax.named_scope("moe/dispatch"):
@@ -290,7 +296,8 @@ def grouped_expert_ffn(x2d, choice, weight, w_gate, w_up, w_down,
         return layer
 
     operands = (x2d, weight) + tuple(
-        w.astype(pol.compute_dtype) for w in (w_gate, w_up, w_down))
+        None if w is None else w.astype(pol.compute_dtype)
+        for w in (w_gate, w_up, w_down))
     usual = _usual_bound(pairs, usual_eighths)
     if usual < pairs:
         # each branch is rematerialised: the cond's backward then needs the

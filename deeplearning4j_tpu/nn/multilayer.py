@@ -48,7 +48,7 @@ from deeplearning4j_tpu.observability.names import (
     ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
     FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
     MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
-    REMAT_KEPT_BYTES_TOTAL, SHORT_CONV_TOKENS_TOTAL,
+    REMAT_KEPT_BYTES_TOTAL, SHORT_CONV_TOKENS_TOTAL, SSM_TOKENS_TOTAL,
 )
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry,
@@ -127,6 +127,9 @@ _remat_kept = _obs_registry().counter(
 _conv_tokens = _obs_registry().counter(
     SHORT_CONV_TOKENS_TOTAL, "tokens the short-convolution mixers of "
     "dispatched steps ran through, by decoder block")
+_ssm_tokens = _obs_registry().counter(
+    SSM_TOKENS_TOTAL, "tokens the state-space scans of dispatched steps ran "
+    "through, by decoder block")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -585,8 +588,10 @@ class LazyScore:
         (``dl4j_attn_score_entries_*``, and for a block with an indexer
         ``dl4j_attn_index_pairs_scored_total`` and
         ``dl4j_attn_pairs_selected_total``) for the decoder blocks, the
-        tokens of a block whose mixer is a short convolution instead
-        (``dl4j_short_conv_tokens_total``), and under
+        tokens of a block whose mixer is a short convolution or a
+        state-space scan instead (``dl4j_short_conv_tokens_total``,
+        ``dl4j_ssm_tokens_total``; a block without a mixer books nothing),
+        and under
         ``gradient_checkpointing`` the bytes they kept for their backward
         (``dl4j_remat_kept_bytes_total``): a function of the batch's shape
         and each block's fields alone, so nothing is read from the device."""
@@ -607,17 +612,20 @@ class LazyScore:
                 (str(i), *l.attn_score_entries(batch, seq, dtype),
                  *(l.index_pairs(batch, seq)
                    if getattr(l, "index_heads", 0) else (0, 0)),
-                 l.conv_tokens(batch, seq))
+                 l.conv_tokens(batch, seq), l.ssm_tokens(batch, seq))
                 for i, l in blocks]
             kept = collections.Counter()
             if self.conf.global_conf.gradient_checkpointing:
                 for _, l in blocks:
                     kept.update(l.remat_kept_bytes(batch, seq, dtype))
             self._kept_bytes = sorted(kept.items())
-        for (layer, computed, visible, scored, selected,
-             conv) in self._attn_entries:
+        for (layer, computed, visible, scored, selected, conv,
+             ssm) in self._attn_entries:
             if conv:
                 _conv_tokens.labels(layer=layer).inc(steps * conv)
+            if ssm:
+                _ssm_tokens.labels(layer=layer).inc(steps * ssm)
+            if not computed:
                 continue
             _attn_computed.labels(layer=layer).inc(steps * computed)
             _attn_visible.labels(layer=layer).inc(steps * visible)

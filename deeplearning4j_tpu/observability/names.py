@@ -56,6 +56,7 @@ ATTN_INDEX_PAIRS_SCORED_TOTAL = "dl4j_attn_index_pairs_scored_total"
 ATTN_PAIRS_SELECTED_TOTAL = "dl4j_attn_pairs_selected_total"
 REMAT_KEPT_BYTES_TOTAL = "dl4j_remat_kept_bytes_total"
 SHORT_CONV_TOKENS_TOTAL = "dl4j_short_conv_tokens_total"
+SSM_TOKENS_TOTAL = "dl4j_ssm_tokens_total"
 
 # --- recurrent engine (ops/lstm.py) ----------------------------------------
 LSTM_DISPATCH_TOTAL = "dl4j_lstm_dispatch_total"
