@@ -9,6 +9,7 @@ case below either compiles a kernel at a real shape and finds the
 refuses the shape — so a default path that does not lower is caught here,
 at no chip time. Nothing runs; a compile that passes is not a chip run.
 """
+import contextlib
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
@@ -19,7 +20,7 @@ import pytest
 
 from deeplearning4j_tpu.ops import indexer
 from deeplearning4j_tpu.ops import lstm as lstm_engine
-from deeplearning4j_tpu.ops import paged_attention, quant
+from deeplearning4j_tpu.ops import paged_attention, quant, ssd
 from deeplearning4j_tpu.ops import pallas_kernels as pk
 
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -204,6 +205,58 @@ def _lfm2_core(grad, T=32768):
     return (bwd if grad else fwd), shapes, (2 if grad else 1)
 
 
+def _ssd(part, T=16384):
+    """The Mamba-2 chunked scan of Nemotron-3-Nano-30B-A3B (``benchmark/
+    configs/nemotron-3-nano-30b-a3b-ep16.json``) at one group call of the
+    cell: one sequence of 16,384, 8 heads of 64 over one group's 128-wide
+    state, chunks of 128. ``fwd``: the forward kernel; ``grad``: under grad
+    of a loss that needs ``y``, the forward that also writes the state
+    entering each chunk, and the backward; ``block``: the whole mixer of a
+    block under grad as the step program differentiates it (8 groups under
+    ``lax.map``, each checkpointed): the forward kernel in the forward map,
+    the one that writes the states in the backward's recomputation, and the
+    backward."""
+    K, P, N, chunk = 8, 64, 128, 128
+    shapes = [((1, T, K, P), BF16), ((1, T, K), F32), ((K,), F32),
+              ((1, T, 1, N), BF16), ((1, T, 1, N), BF16), ((K,), F32)]
+    assert ssd._vmem_bytes(chunk, K, P, N, BF16, True) <= pk._VMEM_BUDGET
+    if part == "fwd":
+        return (lambda *a: ssd.ssd_scan(*a, chunk)), shapes, 1
+    if part == "grad":
+        return (lambda *a: jax.grad(
+            lambda *b: jnp.sum(ssd.ssd_scan(*b, chunk) ** 2),
+            argnums=tuple(range(6)))(*a)), shapes, 2
+    f, shapes = _mamba_block(T)
+    return f, shapes, 3
+
+
+def _mamba_block(T=16384, F=2688, scopes=()):
+    """Grad of one Nemotron Mamba-2 mixer (``attention_part``) at the
+    published widths under ``bfloat16_full``, inside the named ``scopes`` a
+    block's step opens around it: -> (f, shapes)."""
+    from deeplearning4j_tpu import common
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import DecoderBlock
+
+    layer = DecoderBlock(n_in=F, n_out=F, attention="mamba2", ffn="none",
+                         ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+                         ssm_groups=8, ssm_chunk=128, conv_kernel=4)
+    params = jax.eval_shape(lambda: layer.init_params(
+        jax.random.PRNGKey(0), InputType.recurrent(F, T)))
+    names = sorted(params)
+
+    def f(u, *leaves):
+        def loss(u, p):
+            with contextlib.ExitStack() as scoped:
+                for name in scopes:
+                    scoped.enter_context(jax.named_scope(name))
+                with common.override_policy("bfloat16_full"):
+                    return layer.attention_part(p, u).astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1))(u, dict(zip(names, leaves)))
+
+    return f, [((1, T, F), BF16)] + [(params[n].shape, F32) for n in names]
+
+
 def _grouped(grad, policy="bfloat16_full", k=6, H=1408, G=8, eighths=2):
     """The dropless expert dispatch at DeepSeek-V2-Lite's widths: 16,384
     tokens x 6 choices over 8 held experts of 64, experts 2048 x 1408; or
@@ -318,6 +371,9 @@ CASES = {
     "indexer-kl-grad-T16384-bfloat16": (_indexer, ("kl", True)),
     "indexer-core-grad-T4096-bfloat16": (_indexer, ("core", True, 4096)),
     "indexer-block-grad-T16384-bfloat16": (_indexer, ("block",)),
+    "ssd-scan-fwd-T16384-bfloat16": (_ssd, ("fwd",)),
+    "ssd-scan-grad-T16384-bfloat16": (_ssd, ("grad",)),
+    "ssd-block-grad-T16384-bfloat16": (_ssd, ("block",)),
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
     "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
@@ -368,6 +424,27 @@ def test_kernel_compiles_for_v5e_or_gate_refuses(name, chip):
     assert case is not None, f"{name}: the gate refused a shape it admitted"
     f, shapes, want = case
     assert _n_kernels(chip, f, *shapes) == want
+
+
+def test_ssd_kernels_lie_under_the_scan_scope(chip):
+    """Every Mosaic kernel of a Mamba-2 mixer's gradient, forward and
+    backward, carries an op name under ``attn/ssd/.../scan``, the scope
+    ``benchmark/costs_ssd.py`` reads the chunked scan's time from."""
+    import re
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    import costs_ssd
+
+    f, shapes = _mamba_block(T=1024, scopes=("layer", "attn"))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if '"tpu_custom_call"' in line]
+    assert len(names) == 3, names
+    assert all(re.search(costs_ssd.SCAN_SCOPE, "/" + n) for n in names), names
+    assert any("transpose(" in n for n in names), names
 
 
 def test_usual_dispatch_buffer_holds_no_array_of_every_pair(chip):
