@@ -90,7 +90,7 @@ _DIGEST_LEN = 32
 #: are in neither this store's key nor (by default) JAX's own, so an entry
 #: written before the scopes existed would be loaded for the program that has
 #: them, and every scope would read nothing.
-PROGRAM_REV = "named-scopes-11-ssd-scan-kernel"
+PROGRAM_REV = "named-scopes-12-mamba-core"
 
 _DEFAULT_MAX_MB = 512.0
 

@@ -482,35 +482,52 @@ class DecoderBlock(FeedForwardLayer):
 
     def _mamba_part(self, params, u):
         """A Mamba-2 mixer: the two projections under ``attn``; everything
-        from ``xBC`` to the gated, normed ``y`` under ``attn/ssd`` in float32
-        (the scan's products in the policy's compute dtype), the chunked
-        scan itself under ``attn/ssd/scan``. From ``xBC`` to ``y`` nothing
-        mixes the groups (a group's channels of ``x``, its ``B`` and ``C``,
-        its heads' steps, its channels of ``z`` and of the norm), so that part
-        runs one group at a time (``lax.map``, each group checkpointed): a
-        block's backward holds one group's intermediates, not all of them."""
-        B, T, _ = u.shape
-        H, G, N = self.ssm_heads, self.ssm_groups, self.ssm_state
-        d = H * self.ssm_head_dim
+        from ``W_in``'s output to the gated, normed ``y`` under ``attn/ssd``
+        in float32 (the scan's products in the policy's compute dtype). On a
+        TPU that is two Pallas kernels, one each way
+        (``ssd.mamba_core``), which run under ``attn/ssd/scan``; where
+        their gate refuses, ``_mamba_groups``."""
         zxd = _mm(u, params["W_in"])
         with jax.named_scope("ssd"):
-            cuts = (d, 2 * d, 2 * d + G * N, 2 * d + 2 * G * N)
-            by_group = lambda a: jnp.moveaxis(
-                a.reshape(*a.shape[:-1], G, a.shape[-1] // G), -2, 0)
-            z, x, b, c, dt = map(by_group, jnp.split(zxd, cuts, axis=-1))
-            xbc = (d, d + G * N)
-            taps = map(by_group, jnp.split(params["conv_w"], xbc, axis=-1))
-            bias = map(by_group, jnp.split(params["conv_b"], xbc, axis=-1))
-            heads = (params[n].reshape(G, H // G)
-                     for n in ("dt_bias", "A_log", "D"))
-            y = jax.lax.map(jax.checkpoint(self._mamba_group), (
-                z, x, b, c, dt, *taps, *bias, *heads,
-                params["ssm_norm_g"].reshape(G, d // G)))
-            y = jnp.moveaxis(y, 0, 2).reshape(B, T, d)
+            core = ssd.Core(self.ssm_groups, self.ssm_heads // self.ssm_groups,
+                            self.ssm_head_dim, self.ssm_state, self.ssm_chunk,
+                            self.conv_kernel, self.norm_eps,
+                            get_policy().compute_dtype)
+            if ssd.core_kernels_ok(zxd, core):
+                y = ssd.mamba_core(zxd, *(params[n] for n in (
+                    "conv_w", "conv_b", "dt_bias", "A_log", "D",
+                    "ssm_norm_g")), core)
+            else:
+                y = self._mamba_groups(params, zxd)
         return _mm(y, params["W_out"])
 
+    def _mamba_groups(self, params, zxd):
+        """``_mamba_part``'s core in ``jax.numpy`` from ``W_in``'s output
+        ``zxd`` (the statement the kernels are held to), the chunked scan
+        under ``scan`` (``ssd.ssd_scan``). Nothing in it mixes the groups (a
+        group's channels of ``x``, its ``B`` and ``C``, its heads' steps,
+        its channels of ``z`` and of the norm), so it runs one group at a
+        time (``lax.map``, each group checkpointed): a block's backward
+        holds one group's intermediates, not all of them."""
+        B, T, _ = zxd.shape
+        H, G, N = self.ssm_heads, self.ssm_groups, self.ssm_state
+        d = H * self.ssm_head_dim
+        cuts = (d, 2 * d, 2 * d + G * N, 2 * d + 2 * G * N)
+        by_group = lambda a: jnp.moveaxis(
+            a.reshape(*a.shape[:-1], G, a.shape[-1] // G), -2, 0)
+        z, x, b, c, dt = map(by_group, jnp.split(zxd, cuts, axis=-1))
+        xbc = (d, d + G * N)
+        taps = map(by_group, jnp.split(params["conv_w"], xbc, axis=-1))
+        bias = map(by_group, jnp.split(params["conv_b"], xbc, axis=-1))
+        heads = (params[n].reshape(G, H // G)
+                 for n in ("dt_bias", "A_log", "D"))
+        y = jax.lax.map(jax.checkpoint(self._mamba_group), (
+            z, x, b, c, dt, *taps, *bias, *heads,
+            params["ssm_norm_g"].reshape(G, d // G)))
+        return jnp.moveaxis(y, 0, 2).reshape(B, T, d)
+
     def _mamba_group(self, args):
-        """One group's part of ``_mamba_part``: z, x [B, T, d / G], b, c [B,
+        """One group's part of ``_mamba_groups``: z, x [B, T, d / G], b, c [B,
         T, N], dt [B, T, H / G] and the group's leaves -> the gated, normed
         ``y`` [B, T, d / G] in ``z``'s dtype."""
         (z, x, b, c, dt, wx, wb, wc, bx, bb, bc, dt_bias, a_log, dd,

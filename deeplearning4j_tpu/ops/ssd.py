@@ -36,6 +36,7 @@ to, and the fallback everywhere else.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -126,11 +127,17 @@ def _heads_per_slab(K: int, P: int) -> int:
 
 
 def _vmem_bytes(chunk: int, K: int, P: int, N: int, dtype,
-                backward: bool = False) -> int:
+                backward: bool = False, taps: int = 0) -> int:
     """VMEM a program plans for: the pipeline's double-buffered blocks of a
     chunk's tokens and of the state entering the chunk, the carried state
     (or its cotangent) and the chunk's temporaries, a few ``[chunk, chunk]``
-    float32 tiles and slabs of 128 lanes."""
+    float32 tiles and slabs of 128 lanes. ``taps``: the whole core's
+    (``mamba_core``), which adds the blocks of z, of the gated y, of the
+    float32 y before the gate, of the ``_HALO`` tokens before the chunk and
+    of the steps, backward the cotangents of z, x, B and C (staged for their
+    copies out) and of the steps, and the taps' rows of the chunk and its
+    halo, ``[_HALO + chunk, K P + 2 N]`` float32 (twice backward), with
+    their temporaries."""
     isz, lanes = jnp.dtype(dtype).itemsize, pk._lanes
     KP, n = K * P, lanes(N)
     small = chunk * (2 * lanes(K) * 4 + 8 * 4) + 8 * lanes(KP) * 4
@@ -139,6 +146,14 @@ def _vmem_bytes(chunk: int, K: int, P: int, N: int, dtype,
     if backward:
         blocks += chunk * (KP * 4 + KP * isz + 2 * n * isz) + small
         temps *= 2
+    if taps:
+        rows = (_HALO + chunk) * lanes(KP + 2 * n) * 4
+        blocks += (chunk * KP * (2 * isz + 4) + _HALO * (KP + 2 * n) * isz
+                   + 8 * lanes(chunk) * 4)
+        if backward:
+            blocks += chunk * (2 * KP + 2 * n) * isz + 8 * lanes(chunk) * 4
+        temps += (2 if backward else 1) * (rows + 4 * chunk * lanes(
+            KP + 2 * n) * 4)
     return 2 * blocks + KP * n * 4 + temps
 
 
@@ -350,11 +365,10 @@ def _dot_parts(parts, b, ca: int, cb: int, first: bool = True):
     return functools.reduce(lambda u, v: u + v, dots)
 
 
-def _chunk_sums(dt_ref, cs_ref):
+def _chunk_sums(dt, cs):
     """The chunk's dt, cs, the decay to its end ``exp(cs_L - cs)``, its
     whole decay ``exp(cs_L)`` [1, K] and the weight ``dt exp(cs_L - cs)`` of
     each token in the state it leaves."""
-    dt, cs = dt_ref[0], cs_ref[0]
     last = cs[-1:, :]
     to_end = jnp.exp(last - cs)
     return dt, cs, to_end, jnp.exp(last), dt * to_end
@@ -382,14 +396,27 @@ def _fwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, y_ref,
 
     if st_ref:
         st_ref[0][0, 0] = s_ref[...]
-    W, K, cd = hs * P, dt_ref.shape[-1], x_ref.dtype
-    cm, bm = c_ref[0], b_ref[0]
-    dt, cs, _, whole, w = _chunk_sums(dt_ref, cs_ref)
-    cst, ecs = cst_ref[0, 0], jnp.exp(cs)
+    W = hs * P
+    ys = _fwd_chunk(x_ref[0], dt_ref[0], cs_ref[0], cst_ref[0, 0], b_ref[0],
+                    c_ref[0], d_ref, s_ref, P, hs)
+    for j, y in enumerate(ys):
+        y_ref[0, :, j * W:(j + 1) * W] = y.astype(y_ref.dtype)
+
+
+def _fwd_chunk(x, dt, cs, cst, bm, cm, d_ref, s_ref, P: int, hs: int):
+    """One chunk of the forward walk: x [L, K P] in the products' dtype, dt
+    and cs [L, K], cst [K, L], B and C [L, N], d [1, K P]; ``s_ref`` the
+    carried state [N, K P], advanced to the chunk's end; ``d_ref`` D over
+    each head's lanes [1, 1, K P]. -> y's slabs [L, hs P] float32, the skip
+    included."""
+    W, K, cd = hs * P, dt.shape[-1], x.dtype
+    dt, cs, _, whole, w = _chunk_sums(dt, cs)
+    ecs = jnp.exp(cs)
     cb = pk._dot(cm, bm, 1, 1)                                   # [t, s]
+    ys = []
     for j in range(K // hs):
         lanes = slice(j * W, (j + 1) * W)
-        xf = x_ref[0, :, lanes].astype(jnp.float32)
+        xf = x[:, lanes].astype(jnp.float32)
         ex = functools.partial(_expand, k0=j * hs, hs=hs, P=P, shape=xf.shape)
         xdt = (xf * ex(dt)).astype(cd)
         y = None
@@ -399,8 +426,9 @@ def _fwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, y_ref,
             y = part if y is None else jnp.where(
                 _head_of_lane(xf.shape, P) == i, part, y)
         y = y + pk._dot(cm, s_ref[:, lanes].astype(cd), 1, 0) * ex(ecs)
-        y_ref[0, :, lanes] = (y + d_ref[0, :, lanes] * xf).astype(y_ref.dtype)
+        ys.append(y + d_ref[0, :, lanes] * xf)
         _advance(s_ref, j, xf, bm, w, whole, hs, P)
+    return ys
 
 
 def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
@@ -414,15 +442,37 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
         ds_ref[...] = jnp.zeros_like(ds_ref)
         dd_ref[...] = jnp.zeros_like(dd_ref)
 
+    W = hs * P
+    dxs, dB, dC, ddt, dcs, dcst, dds = _bwd_chunk(
+        x_ref[0], dt_ref[0], cs_ref[0], cst_ref[0, 0], b_ref[0], c_ref[0],
+        d_ref, st_ref[0, 0], g_ref[0], ds_ref, P, hs)
+    for j, (dx, dd) in enumerate(zip(dxs, dds)):
+        lanes = slice(j * W, (j + 1) * W)
+        dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
+        dd_ref[0, :, lanes] += dd
+    dc_ref[0] = dC.astype(dc_ref.dtype)
+    db_ref[0] = dB.astype(db_ref.dtype)
+    ddt_ref[0] = ddt
+    dcs_ref[0] = dcs
+    dcst_ref[0, 0] = dcst
+
+
+def _bwd_chunk(x, dt, cs, cst, bm, cm, d_ref, sin, g_all, ds_ref, P: int,
+               hs: int):
+    """One chunk of the reverse walk (``_fwd_chunk``'s operands, ``sin`` the
+    state entering the chunk [N, K P], ``g_all`` y's cotangent [L, K P]
+    float32); ``ds_ref`` the cotangent of the state leaving the chunk, left
+    holding that of the state entering it. -> dx's slabs [L, hs P], dB, dC
+    [L, N] float32, d(dt) direct and d(cs) [L, K] and [K, L] (the parts the
+    sums reach along rows and along lanes), dd's slabs [1, hs P]."""
     f32 = jnp.float32
-    W, K, cd = hs * P, dt_ref.shape[-1], x_ref.dtype
-    L = x_ref.shape[1]
+    W, K, cd = hs * P, dt.shape[-1], x.dtype
+    L = x.shape[0]
     col = lax.broadcasted_iota(jnp.int32, (L, K), 1)
     row = lax.broadcasted_iota(jnp.int32, (K, L), 0)
     last_row = lax.broadcasted_iota(jnp.int32, (L, 1), 0) == L - 1
-    cm, bm = c_ref[0], b_ref[0]
-    dt, cs, to_end, whole, w = _chunk_sums(dt_ref, cs_ref)
-    cst, ecs = cst_ref[0, 0], jnp.exp(cs)
+    dt, cs, to_end, whole, w = _chunk_sums(dt, cs)
+    ecs = jnp.exp(cs)
     cb = pk._dot(cm, bm, 1, 1)                                   # [t, s]
     dcb = jnp.zeros((L, L), f32)
     dC = jnp.zeros(cm.shape, f32)
@@ -430,10 +480,11 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
     ddt = jnp.zeros((L, K), f32)
     dcs = jnp.zeros((L, K), f32)
     dcst = jnp.zeros((K, L), f32)
+    dx_slabs, dd_slabs = [], []
     for j in range(K // hs):
         lanes = slice(j * W, (j + 1) * W)
-        xf = x_ref[0, :, lanes].astype(f32)
-        g = g_ref[0, :, lanes]
+        xf = x[:, lanes].astype(f32)
+        g = g_all[:, lanes]
         gp = _parts(g, cd)
         ex = functools.partial(_expand, k0=j * hs, hs=hs, P=P, shape=xf.shape)
         head = _head_of_lane(xf.shape, P)
@@ -453,8 +504,8 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
             dcb = dcb + dm * dec
             zs.append(dm * mf)
         # the carried state's part: y += (C S^T) exp(cs)
-        sin = st_ref[0, 0, :, lanes]                             # [N, W]
-        sc = sin.astype(cd)
+        sinj = sin[:, lanes]                                     # [N, W]
+        sc = sinj.astype(cd)
         ge = g * ex(ecs)
         gep = _parts(ge, cd)
         dC = dC + _dot_parts(gep, sc, 1, 1)
@@ -465,12 +516,11 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
         dsop = _parts(dso, cd)
         dxs = _dot_parts(dsop, bm, 0, 1, first=False)            # [L, W]
         dB = dB + _dot_parts(dsop, (xf * we).astype(cd), 1, 1, first=False)
-        dx = dxdt * dte + dxs * we + d_ref[0, :, lanes] * g
-        dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
-        dd_ref[0, :, lanes] += jnp.sum(g * xf, axis=0, keepdims=True)
+        dx_slabs.append(dxdt * dte + dxs * we + d_ref[0, :, lanes] * g)
+        dd_slabs.append(jnp.sum(g * xf, axis=0, keepdims=True))
         sums = zip(_sum_heads(dxdt * xf, hs, P), _sum_heads(dxs * xf, hs, P),
                    _sum_heads(into, hs, P), zs, _sum_heads(jnp.sum(
-                       dso * sin, axis=0, keepdims=True), hs, P))
+                       dso * sinj, axis=0, keepdims=True), hs, P))
         for i, (r_dt, r_w, r_e, z, r_s) in enumerate(sums):
             k = j * hs + i
             du = r_w * w[:, k:k + 1]
@@ -483,8 +533,422 @@ def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
                              dcst)
         ds_ref[:, lanes] = dso * ex(whole, shape=(1, W)) + dsc
     dcbp = _parts(dcb, cd)
-    dc_ref[0] = (dC + _dot_parts(dcbp, bm, 1, 0)).astype(dc_ref.dtype)
-    db_ref[0] = (dB + _dot_parts(dcbp, cm, 0, 0)).astype(db_ref.dtype)
-    ddt_ref[0] = ddt
-    dcs_ref[0] = dcs
-    dcst_ref[0, 0] = dcst
+    dC = dC + _dot_parts(dcbp, bm, 1, 0)
+    dB = dB + _dot_parts(dcbp, cm, 0, 0)
+    return dx_slabs, dB, dC, ddt, dcs, dcst, dd_slabs
+
+
+# ------------------------------------------------- the whole mixer's core
+#: rows of the token blocks the taps read before a chunk: the backward's
+#: block of the tokens before it, the forward's carried copy of its last
+#: rows (a bfloat16 block's sublane tile)
+_HALO = 16
+
+
+class Core(NamedTuple):
+    """A Mamba-2 core's geometry (``DecoderBlock``'s ``ssm_*`` fields):
+    groups, heads a group, a head's width, the state's width, a chunk's
+    tokens, the taps, the norm's epsilon, the products' dtype; and, as the
+    kernels see it, the sequence's tokens before its padding."""
+    G: int
+    K: int
+    P: int
+    N: int
+    chunk: int
+    taps: int
+    eps: float
+    cd: jnp.dtype
+    T: int = 0
+
+
+def core_kernels_ok(zxd, core: Core) -> bool:
+    """Whether :func:`mamba_core` runs the Mamba-2 core on ``W_in``'s output
+    ``zxd`` [Bt, T, 2 d + 2 G N + H]; the choice is booked on
+    ``dl4j_pallas_dispatch_total{kernel="mamba_core"}``, and on
+    ``kernel="mamba_core_bwd"`` as the plan a backward of the call takes.
+    Besides the scan kernels' gate (a TPU, no GSPMD jit nor checked
+    ``shard_map`` around, slabs of heads, a program whose counted VMEM
+    fits): a group's channels, B and C whole blocks of 128 lanes at their
+    place in ``zxd``, a chunk of whole blocks of 128 tokens (the lanes of
+    the transposed cotangent the backward copies out) and taps that reach
+    no further back than the halo."""
+    ok = _core_ok(zxd, core)
+    pk._note_dispatch("mamba_core", ok)
+    pk._note_dispatch("mamba_core_bwd", ok)
+    return ok
+
+
+def _core_ok(zxd, core: Core) -> bool:
+    if pk.pallas_unavailable() is not None or pk._in_checked_shard_map(zxd):
+        return False
+    G, K, P, N, chunk = core[:5]
+    W = K * P
+    ok_dtype = lambda t: jnp.dtype(t) in (jnp.bfloat16, jnp.float32)
+    if not (ok_dtype(zxd.dtype) and ok_dtype(core.cd)) or chunk % 128:
+        return False
+    if W % 128 or N % 128 or 2 * G * W % N or not 1 <= core.taps <= _HALO:
+        return False
+    width = _heads_per_slab(K, P) * P
+    if not (width == W or width % 128 == 0) or (K > 1 and P % 8):
+        return False
+    return _vmem_bytes(chunk, K, P, N, core.cd, backward=True,
+                       taps=core.taps) <= pk._VMEM_BUDGET
+
+
+def mamba_core(zxd, conv_w, conv_b, dt_bias, A_log, D, norm_g, core: Core,
+               interpret: bool = False):
+    """``DecoderBlock._mamba_part``'s core through the kernels: ``zxd`` =
+    ``[z | x | B | C | dt]``, ``W_in``'s output [Bt, T, 2 d + 2 G N + H],
+    and the block's leaves -> the gated, normed ``y`` [Bt, T, d] in
+    ``zxd``'s dtype. A grid step is one chunk of one group of one sequence;
+    the kernels read a group's columns of ``zxd`` in place, and make the
+    taps with their bias and SiLU, the steps, the scan, the skip, the gate
+    and the group's norm in VMEM. Here only the steps' columns are laid out
+    by group and chunk, and the leaves by group."""
+    Bt, T, _ = zxd.shape
+    G, K, P, N, L, taps = core[:6]
+    W, f32 = K * P, jnp.float32
+    pad = -T % L
+    if pad:
+        zxd = jnp.pad(zxd, ((0, 0), (0, pad), (0, 0)))
+    xbc = jnp.concatenate([conv_w, conv_b[None]]).astype(f32)
+    by_group = lambda a, w: jnp.swapaxes(a.reshape(taps + 1, G, w), 0, 1)
+    d = G * W
+    w = jnp.concatenate([by_group(xbc[:, :d], W),
+                         by_group(xbc[:, d:d + G * N], N),
+                         by_group(xbc[:, d + G * N:], N)], axis=-1)
+    col = lambda v: v.astype(f32).reshape(G, K, 1)
+    skip = jnp.repeat(D.astype(f32).reshape(G, K), P, axis=1)
+    y = _core(zxd, w, col(dt_bias), col(A_log), skip.reshape(G, 1, W),
+              norm_g.astype(f32).reshape(G, 1, W), core._replace(T=T),
+              interpret)
+    return y[:, :T]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _core(zxd, w, dt_bias, a_log, d, g, core, interpret):
+    """y [Bt, Tp, d] of ``zxd`` padded to whole chunks and the leaves by
+    group: ``w`` [G, taps + 1, W + 2 N] the taps of the group's x, B and C
+    then their bias; ``dt_bias``, ``a_log`` [G, K, 1]; ``d`` and the norm's
+    scale ``g`` [G, 1, W] float32."""
+    return _core_forward(zxd, w, dt_bias, a_log, d, g, core, interpret)
+
+
+def _core_fwd(zxd, w, dt_bias, a_log, d, g, core, interpret):
+    y, y_pre, states = _core_forward(zxd, w, dt_bias, a_log, d, g, core,
+                                     interpret, residuals=True)
+    return y, (zxd, w, dt_bias, a_log, d, g, y_pre, states)
+
+
+def _core_bwd(core, interpret, res, gy):
+    zxd, w, dt_bias, a_log, d, g, y_pre, states = res
+    dzxd_t, ddt, *leaves = _core_backward(
+        zxd, w, dt_bias, a_log, d, g, y_pre, states, gy, core, interpret)
+    Bt, G, n, K, L = ddt.shape
+    ddt = jnp.transpose(ddt, (0, 1, 3, 2, 4)).reshape(Bt, G * K, n * L)
+    dzxd_t = lax.dynamic_update_slice(dzxd_t, ddt.astype(dzxd_t.dtype),
+                                      (0, dzxd_t.shape[1] - G * K, 0))
+    # tokens minor: the layout W_in's weight gradient, which sums over the
+    # tokens, reads at its pace (a transpose XLA lays out as a bitcast)
+    return (jnp.swapaxes(dzxd_t, 1, 2), *(a.sum(axis=0) for a in leaves))
+
+
+_core.defvjp(_core_fwd, _core_bwd)
+
+
+def _dt_by_chunk(zxd, core: Core):
+    """The steps' columns of ``zxd`` [Bt, Tp, G K] -> [Bt, G, n, K, chunk]:
+    a chunk's steps of a group along lanes."""
+    Bt, Tp, _ = zxd.shape
+    G, K, L = core.G, core.K, core.chunk
+    dt = zxd[..., zxd.shape[-1] - G * K:].reshape(Bt, Tp // L, L, G, K)
+    return jnp.transpose(dt, (0, 3, 1, 4, 2))
+
+
+def _core_specs(zxd, core: Core, reverse=False):
+    """BlockSpec makers over the grid ``(Bt, G, n)``: a chunk's tokens of
+    the group's z, x, B or C in place in ``zxd``, and the ``_HALO`` tokens
+    before the chunk; a chunk's tokens of the group's columns of an array
+    laid out by group; a chunk of a per-chunk array; a group's leaf; a
+    (sequence, group) row's accumulator. Walked from the last chunk where
+    ``reverse``."""
+    G, K, P, N, L = core[:5]
+    W, n = K * P, zxd.shape[1] // L
+    at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+    # z, x: blocks of W lanes; B, C: of N lanes, after the 2 G W of z and x
+    first = {"z": (W, 0), "x": (W, G), "B": (N, 2 * G * W // N),
+             "C": (N, 2 * G * W // N + G)}
+    part = lambda p: pl.BlockSpec(
+        (1, L, first[p][0]), lambda b, g, c: (b, at(c), first[p][1] + g))
+    halo = lambda p: pl.BlockSpec(
+        (1, _HALO, first[p][0]),
+        lambda b, g, c: (b, jnp.maximum(at(c) * (L // _HALO) - 1, 0),
+                         first[p][1] + g))
+    by_group = lambda w: pl.BlockSpec((1, L, w),
+                                      lambda b, g, c: (b, at(c), g))
+    per_chunk = lambda *s: pl.BlockSpec(
+        (1, 1, 1) + s, lambda b, g, c: (b, g, at(c)) + (0,) * len(s))
+    leaf = lambda *s: pl.BlockSpec((1,) + s, lambda b, g, c: (g, 0, 0))
+    row = lambda *s: pl.BlockSpec((1, 1) + s, lambda b, g, c: (b, g, 0, 0))
+    return part, halo, by_group, per_chunk, leaf, row
+
+
+def _core_in_specs(zxd, w, core: Core, reverse=False):
+    """The specs of the operands both kernels read (``_core_forward``'s
+    order), and of the halo of x, B and C."""
+    part, halo, _, per_chunk, leaf, _ = _core_specs(zxd, core, reverse)
+    W = core.K * core.P
+    return ([part("z"), part("x"), part("B"), part("C"),
+             per_chunk(core.K, core.chunk), leaf(*w.shape[1:]),
+             leaf(core.K, 1), leaf(core.K, 1), leaf(1, W), leaf(1, W)],
+            [halo("x"), halo("B"), halo("C")])
+
+
+def _core_params(core: Core, backward=False):
+    G, K, P, N, L = core[:5]
+    return pk._flash_params(
+        ("parallel", "parallel", "arbitrary"),
+        _vmem_bytes(L, K, P, N, core.cd, backward=backward, taps=core.taps))
+
+
+def _core_forward(zxd, w, dt_bias, a_log, d, g, core: Core, interpret,
+                  residuals=False):
+    """y [Bt, Tp, d] in ``zxd``'s dtype; and where ``residuals`` the
+    backward's: the float32 y before the gate [Bt, Tp, d] and the state
+    entering each chunk [Bt, G, n, N, W] float32."""
+    Bt, Tp, _ = zxd.shape
+    G, K, P, N, L = core[:5]
+    W, n, f32 = K * P, Tp // L, jnp.float32
+    _, _, by_group, per_chunk, _, _ = _core_specs(zxd, core)
+    in_specs, _ = _core_in_specs(zxd, w, core)
+    out_specs = [by_group(W)]
+    out_shape = [jax.ShapeDtypeStruct((Bt, Tp, G * W), zxd.dtype)]
+    if residuals:
+        out_specs += [by_group(W), per_chunk(N, W)]
+        out_shape += [jax.ShapeDtypeStruct((Bt, Tp, G * W), f32),
+                      jax.ShapeDtypeStruct((Bt, G, n, N, W), f32)]
+    with jax.named_scope("scan"):
+        out = pl.pallas_call(
+            functools.partial(_core_fwd_kernel, core=core,
+                              hs=_heads_per_slab(K, P)),
+            grid=(Bt, G, n),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((N, W), f32),
+                            pltpu.VMEM((_HALO + L, W + 2 * N), f32)],
+            compiler_params=_core_params(core),
+            interpret=interpret,
+        )(zxd, zxd, zxd, zxd, _dt_by_chunk(zxd, core), w, dt_bias, a_log, d,
+          g)
+    return tuple(out) if residuals else out[0]
+
+
+def _core_backward(zxd, w, dt_bias, a_log, d, g, y_pre, states, gy,
+                   core: Core, interpret):
+    """-> the cotangent of ``zxd`` transposed, [Bt, 2 d + 2 G N + H, Tp],
+    but for its steps' rows, which the kernel leaves unwritten (it copies
+    each chunk's cotangents of the group's z, x, B and C to their places
+    itself); that of the steps [Bt, G, n, K, chunk]; and each (sequence,
+    group) row's sums [Bt, G, ...] of the gradients of ``w``, ``dt_bias``,
+    ``a_log``, ``d`` and ``g``."""
+    Bt, Tp, _ = zxd.shape
+    G, K, P, N, L = core[:5]
+    W, n, f32 = K * P, Tp // L, jnp.float32
+    _, _, by_group, per_chunk, _, row = _core_specs(zxd, core, reverse=True)
+    in_specs, halo_specs = _core_in_specs(zxd, w, core, reverse=True)
+    out = lambda *s: jax.ShapeDtypeStruct(s, zxd.dtype)
+    acc = lambda *s: jax.ShapeDtypeStruct((Bt, G) + s, f32)
+    with jax.named_scope("scan"):
+        return pl.pallas_call(
+            functools.partial(_core_bwd_kernel, core=core,
+                              hs=_heads_per_slab(K, P)),
+            grid=(Bt, G, n),
+            in_specs=in_specs + halo_specs + [per_chunk(N, W), by_group(W),
+                                              by_group(W)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY), per_chunk(K, L),
+                       row(*w.shape[1:]), row(K, 1), row(K, 1), row(1, W),
+                       row(1, W)],
+            out_shape=[out(Bt, zxd.shape[2], Tp), out(Bt, G, n, K, L),
+                       acc(*w.shape[1:]), acc(K, 1), acc(K, 1), acc(1, W),
+                       acc(1, W)],
+            scratch_shapes=[pltpu.VMEM((N, W), f32),
+                            pltpu.VMEM((_HALO + L, W + 2 * N), f32),
+                            pltpu.VMEM((L + _HALO, W + 2 * N), f32),
+                            pltpu.VMEM((2 * W + 2 * N, L), zxd.dtype),
+                            pltpu.SemaphoreType.DMA((4,))],
+            compiler_params=_core_params(core, backward=True),
+            interpret=interpret,
+        )(zxd, zxd, zxd, zxd, _dt_by_chunk(zxd, core), w, dt_bias, a_log, d,
+          g, zxd, zxd, zxd, states, y_pre, gy)
+
+
+def _tri(L: int, lower: bool):
+    """[L, L] 0/1 in bfloat16: ``r >= c`` (``lower``) or ``r <= c``."""
+    r = lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    c = lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    return ((r >= c) if lower else (r <= c)).astype(jnp.bfloat16)
+
+
+def _sum_along_lanes(v, mask):
+    """``v`` [K, L] float32 times the 0/1 ``mask`` [L, L] as float32 sums:
+    ``v`` enters as three bfloat16 parts (8 bits each of its 24), so each
+    product is exact and only the sums round, as a cumulative sum's do."""
+    hi = v.astype(jnp.bfloat16)
+    rest = v - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return _dot_parts((hi, mid, lo), mask, 1, 0)
+
+
+def _prologue(ext, x_ref, b_ref, c_ref, dt_ref, w_ref, dtb_ref, alog_ref,
+              chunk_id, core: Core):
+    """A chunk's taps and steps, ``ext`` [_HALO + L, W + 2 N] float32
+    holding the raw x, B and C of the ``_HALO`` tokens before the chunk in
+    its first rows: it takes the chunk's raw x | B | C below them. -> (the
+    taps' sum before the SiLU [L, W + 2 N], x [L, W], B and C [L, N] in the
+    products' dtype; the steps' pre-activation, the steps and A's values,
+    [K, L] and [K, 1] float32; dt and cs [L, K]; cs along lanes [K, L])."""
+    G, K, P, N, L, taps = core[:6]
+    W, f32 = K * P, jnp.float32
+    ext[_HALO:, :W] = x_ref[0].astype(f32)
+    ext[_HALO:, W:W + N] = b_ref[0].astype(f32)
+    ext[_HALO:, W + N:] = c_ref[0].astype(f32)
+    wt = w_ref[0]
+    first = _HALO - (taps - 1)
+    pre = wt[taps:taps + 1] + sum(
+        wt[j:j + 1] * ext[first + j:first + j + L, :] for j in range(taps))
+    xbc = jax.nn.silu(pre).astype(core.cd)
+    dtr = dt_ref[0, 0, 0].astype(f32) + dtb_ref[0]
+    dts = jax.nn.softplus(dtr)
+    if core.T % L:
+        at = chunk_id * L + lax.broadcasted_iota(jnp.int32, dts.shape, 1)
+        dts = jnp.where(at < core.T, dts, 0.0)
+    A = -jnp.exp(alog_ref[0])
+    cst = _sum_along_lanes(dts * A, _tri(L, lower=False))
+    return (pre, xbc[:, :W], xbc[:, W:W + N], xbc[:, W + N:], dtr, dts, A,
+            dts.T, cst.T, cst)
+
+
+def _epilogue(y, zf, eps: float):
+    """The gate and the group's norm of y [L, W] float32 before its scale:
+    ``u / rms(u)`` of ``u = y silu(z)``; u and ``1 / rms(u)`` too."""
+    u = y * jax.nn.silu(zf)
+    inv = lax.rsqrt(jnp.mean(u * u, axis=1, keepdims=True) + eps)
+    return u * inv, u, inv
+
+
+def _dsilu(v):
+    s = jax.nn.sigmoid(v)
+    return s * (1.0 + v * (1.0 - s))
+
+
+def _core_fwd_kernel(z_ref, x_ref, b_ref, c_ref, dt_ref, w_ref, dtb_ref,
+                     alog_ref, d_ref, g_ref, y_ref, *refs, core: Core,
+                     hs: int):
+    """One step of the walk; ``refs`` the outputs of the float32 y before
+    the gate and of the states entering each chunk, where the call writes
+    them, then the carried state and the taps' rows."""
+    *res, s_ref, ext = refs
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+        ext[:_HALO] = jnp.zeros((_HALO, ext.shape[1]), jnp.float32)
+
+    if res:
+        res[1][0, 0, 0] = s_ref[...]
+    L = core.chunk
+    _, x, bm, cm, _, _, _, dt, cs, cst = _prologue(
+        ext, x_ref, b_ref, c_ref, dt_ref, w_ref, dtb_ref, alog_ref, c, core)
+    ext[:_HALO] = ext[L:]
+    ys = _fwd_chunk(x, dt, cs, cst, bm, cm, d_ref, s_ref, core.P, hs)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+    if res:
+        res[0][0] = y
+    normed, _, _ = _epilogue(y, z_ref[0].astype(jnp.float32), core.eps)
+    y_ref[0] = (normed * g_ref[0]).astype(y_ref.dtype)
+
+
+def _core_bwd_kernel(z_ref, x_ref, b_ref, c_ref, dt_ref, w_ref, dtb_ref,
+                     alog_ref, d_ref, g_ref, hx_ref, hb_ref, hc_ref, st_ref,
+                     yp_ref, gy_ref, dzxd_t_ref, ddt_ref, dw_ref, ddtb_ref,
+                     dalog_ref, dd_ref, dg_ref, ds_ref, ext, dext, out, sems,
+                     *, core: Core, hs: int):
+    """One step of the reverse walk. ``ds_ref`` holds the cotangent of the
+    state leaving the chunk; ``dext`` [L + _HALO, W + 2 N] the cotangent of
+    the taps' sum of this chunk and, below it, of the first rows of the
+    chunk after; ``out`` the chunk's cotangents of z, x, B and C,
+    transposed, which leave by copies to their rows of ``dzxd_t_ref`` (in
+    HBM)."""
+    G, K, P, N, L, taps = core[:6]
+    W, f32 = K * P, jnp.float32
+    step = pl.program_id(2)
+    c = pl.num_programs(2) - 1 - step
+
+    @pl.when(step == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+        dext[L:] = jnp.zeros((_HALO, dext.shape[1]), f32)
+        for r in (dw_ref, ddtb_ref, dalog_ref, dd_ref, dg_ref):
+            r[...] = jnp.zeros_like(r)
+
+    halo = jnp.concatenate([hx_ref[0], hb_ref[0], hc_ref[0]], axis=1)
+    ext[:_HALO] = jnp.where(c == 0, 0.0, halo.astype(f32))
+    pre, x, bm, cm, dtr, dts, A, dt, cs, cst = _prologue(
+        ext, x_ref, b_ref, c_ref, dt_ref, w_ref, dtb_ref, alog_ref, c, core)
+    # the gate and the norm
+    y, zf, g = yp_ref[0], z_ref[0].astype(f32), g_ref[0]
+    go = gy_ref[0].astype(f32)
+    normed, u, inv = _epilogue(y, zf, core.eps)
+    dg_ref[0, 0] += jnp.sum(go * normed, axis=0, keepdims=True)
+    dn = go * g
+    du = inv * dn - u * (inv * inv * inv
+                         * jnp.mean(dn * u, axis=1, keepdims=True))
+    dy = du * jax.nn.silu(zf)
+    out[:W] = (du * y * _dsilu(zf)).T.astype(out.dtype)
+    # the scan
+    dxs, dB, dC, ddt, dcs, dcst, dds = _bwd_chunk(
+        x, dt, cs, cst, bm, cm, d_ref, st_ref[0, 0, 0], dy, ds_ref, P, hs)
+    dd_ref[0, 0] += dds[0] if len(dds) == 1 else jnp.concatenate(dds, 1)
+    # the steps: cs sums dt A along the chunk, so d(dt A) sums d(cs) back
+    dcs = _sum_along_lanes(dcs.T + dcst, _tri(L, lower=True))
+    ddts = ddt.T + dcs * A
+    dalog_ref[0, 0] += jnp.sum(dcs * dts, axis=1, keepdims=True) * A
+    ddtr = ddts * jax.nn.sigmoid(dtr)
+    if core.T % L:
+        at = c * L + lax.broadcasted_iota(jnp.int32, ddtr.shape, 1)
+        ddtr = jnp.where(at < core.T, ddtr, 0.0)
+    ddtb_ref[0, 0] += jnp.sum(ddtr, axis=1, keepdims=True)
+    ddt_ref[0, 0, 0] = ddtr.astype(ddt_ref.dtype)
+    # the taps: each sums the chunk's rows and the taps - 1 before it
+    dx = dxs[0] if len(dxs) == 1 else jnp.concatenate(dxs, axis=1)
+    dpre = jnp.concatenate([dx, dB, dC], axis=1) * _dsilu(pre)
+    dext[:L] = dpre
+    first = _HALO - (taps - 1)
+    dw = [jnp.sum(dpre * ext[first + j:first + j + L, :], axis=0,
+                  keepdims=True) for j in range(taps)]
+    dw_ref[0, 0] += jnp.concatenate(dw + [jnp.sum(dpre, axis=0,
+                                                  keepdims=True)], axis=0)
+    wt = w_ref[0]
+    draw = sum(wt[j:j + 1] * dext[taps - 1 - j:taps - 1 - j + L, :]
+               for j in range(taps))
+    dext[L:] = dext[:_HALO]
+    out[W:] = draw.T.astype(out.dtype)
+    seq, group = pl.program_id(0), pl.program_id(1)
+    tokens = pl.ds(pl.multiple_of(c * L, L), L)
+    # z, x, B and C: (first row in ``out``, first column of the part in
+    # ``zxd``, the group's width)
+    copies = [pltpu.make_async_copy(
+        out.at[pl.ds(src, width)],
+        dzxd_t_ref.at[seq, pl.ds(
+            pl.multiple_of(first + group * width, 128), width), tokens],
+        sems.at[i])
+        for i, (src, first, width) in enumerate((
+            (0, 0, W), (W, G * W, W), (2 * W, 2 * G * W, N),
+            (2 * W + N, 2 * G * W + G * N, N)))]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()

@@ -3,7 +3,8 @@ statement they are held to, ``_ssd_scan_xla``, on the CPU in interpret mode:
 the forward and the gradients with respect to every operand, at float32 to
 float32's rounding and at bfloat16 within the statement's own distance from
 a float32 run; and where ``ssd.ssd_scan`` sends a call, and what a Mamba-2
-block's gradient then holds."""
+block's gradient then holds, through the scan's kernels or through the
+whole core's (``ssd.mamba_core``, its own tests in ``test_mamba_core.py``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -134,20 +135,30 @@ def _eqns(jaxpr, out=None):
     return out
 
 
+@pytest.mark.parametrize("fused", [False, True])
 def test_a_mamba2_blocks_gradient_runs_the_kernels_and_no_scan_over_chunks(
-        monkeypatch):
-    """With the kernels engaged (interpret mode), a block's gradient holds
-    the forward kernel, the forward that also writes the state entering each
-    chunk and the backward kernel, and no scan but the map over the 2 groups:
-    none over the 5 chunks."""
+        monkeypatch, fused):
+    """With the kernels engaged (interpret mode). Where a group's lanes are
+    too narrow for the whole core's kernels (``fused`` False: 16 lanes), a
+    block's gradient holds the scan's forward kernel, the forward that also
+    writes the state entering each chunk and the backward kernel, and no
+    scan but the map over the 2 groups: none over the 5 chunks. Where they
+    engage (128 lanes, chunks of 128 tokens), it holds the core's forward
+    that also writes its residuals and the core's backward, and no scan at
+    all: none over the groups nor over the 2 chunks."""
     real = pl.pallas_call
     monkeypatch.setattr(pk, "_on_tpu", lambda: True)
     monkeypatch.setattr(pl, "pallas_call",
                         lambda *a, **kw: real(*a, **{**kw, "interpret": True}))
     B, T, F, G, chunk = 2, 40, 32, 2, 8
+    P, N = (64, 128) if fused else (8, 16)
+    if fused:                  # a chunk of 128 tokens, the last one partial
+        T, chunk = 136, 128
     block = DecoderBlock(n_in=F, n_out=F, attention="mamba2", ffn="none",
-                         ssm_heads=4, ssm_head_dim=8, ssm_state=16,
+                         ssm_heads=4, ssm_head_dim=P, ssm_state=N,
                          ssm_groups=G, ssm_chunk=chunk)
+    kernels = (("mamba_core", "mamba_core_bwd") if fused
+               else ("ssd_scan", "ssd_scan_bwd"))
     with common.override_policy("float32"):
         p = block.init_params(jax.random.PRNGKey(1), InputType.recurrent(F, T))
         x = jax.random.normal(jax.random.PRNGKey(2), (B, T, F))
@@ -155,30 +166,44 @@ def test_a_mamba2_blocks_gradient_runs_the_kernels_and_no_scan_over_chunks(
         def loss(p, x):
             return jnp.sum(jnp.sin(block.apply(p, {}, x)[0]))
 
-        before = (_engaged("ssd_scan"), _engaged("ssd_scan_bwd"))
+        before = [_engaged(k) for k in kernels]
         eqns = _eqns(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
             p, x).jaxpr)
-        fwd, bwd = _engaged("ssd_scan"), _engaged("ssd_scan_bwd")
+        fwd, bwd = (_engaged(k) for k in kernels)
         assert fwd["true"] > before[0]["true"] and fwd["false"] == before[0][
             "false"]
         assert bwd["true"] > before[1]["true"]
         scans = [e.params["length"] for e in eqns
                  if e.primitive.name == "scan"]
-        assert scans and set(scans) == {G}
         calls = [tuple(v.aval.shape for v in e.outvars) for e in eqns
                  if e.primitive.name == "pallas_call"]
-        rows, H = B, 4 // G
-        # y and the states entering each chunk, then the backward's seven
-        assert ((rows, T, H * 8), (rows, T // chunk, 16, H * 8)) in calls
-        assert any(len(c) == 7 and c[0] == (rows, T, H * 8) for c in calls)
-        # a forward with no backward writes y alone
         alone = [tuple(v.aval.shape for v in e.outvars) for e in _eqns(
             jax.make_jaxpr(loss)(p, x).jaxpr)
             if e.primitive.name == "pallas_call"]
-        assert alone == [((rows, T, H * 8),)]
+        if fused:
+            W, n = 2 * P, -(-T // chunk)
+            assert scans == []
+            # y, the float32 y before the gate and the states entering each
+            # chunk; then the backward's seven, its first the cotangent of
+            # W_in's output whole, tokens minor
+            assert calls[0] == ((B, n * chunk, G * W), (B, n * chunk, G * W),
+                                (B, G, n, N, W))
+            assert len(calls) == 2 and len(calls[1]) == 7
+            assert calls[1][0] == (B, 2 * G * W + 2 * G * N + 4, n * chunk)
+            # a forward with no backward writes y alone
+            assert alone == [((B, n * chunk, G * W),)]
+        else:
+            assert scans and set(scans) == {G}
+            rows, H = B, 4 // G
+            # y and the states entering each chunk, then the backward's seven
+            assert ((rows, T, H * 8), (rows, T // chunk, 16, H * 8)) in calls
+            assert any(len(c) == 7 and c[0] == (rows, T, H * 8) for c in calls)
+            # a forward with no backward writes y alone
+            assert alone == [((rows, T, H * 8),)]
         # and the gradient the kernels give is the statement's
         got = jax.grad(loss, argnums=(0, 1))(p, x)
         monkeypatch.setattr(ssd, "ssd_scan", ssd._ssd_scan_xla)
+        monkeypatch.setattr(ssd, "_core_ok", lambda *a: False)
         want = jax.grad(loss, argnums=(0, 1))(p, x)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert _gap(np.asarray(g), np.asarray(w)) < 5e-5

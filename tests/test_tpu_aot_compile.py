@@ -212,10 +212,10 @@ def _ssd(part, T=16384):
     state, chunks of 128. ``fwd``: the forward kernel; ``grad``: under grad
     of a loss that needs ``y``, the forward that also writes the state
     entering each chunk, and the backward; ``block``: the whole mixer of a
-    block under grad as the step program differentiates it (8 groups under
-    ``lax.map``, each checkpointed): the forward kernel in the forward map,
-    the one that writes the states in the backward's recomputation, and the
-    backward."""
+    block under grad, whose core from ``W_in``'s output to the normed ``y``
+    is one kernel each way (``ssd.mamba_core``): the forward that also
+    writes its residuals, and the backward; ``block-fwd``: the mixer's
+    forward alone, the core's forward kernel."""
     K, P, N, chunk = 8, 64, 128, 128
     shapes = [((1, T, K, P), BF16), ((1, T, K), F32), ((K,), F32),
               ((1, T, 1, N), BF16), ((1, T, 1, N), BF16), ((K,), F32)]
@@ -226,14 +226,15 @@ def _ssd(part, T=16384):
         return (lambda *a: jax.grad(
             lambda *b: jnp.sum(ssd.ssd_scan(*b, chunk) ** 2),
             argnums=tuple(range(6)))(*a)), shapes, 2
-    f, shapes = _mamba_block(T)
-    return f, shapes, 3
+    f, shapes = _mamba_block(T, grad=part == "block")
+    return f, shapes, 2 if part == "block" else 1
 
 
-def _mamba_block(T=16384, F=2688, scopes=()):
-    """Grad of one Nemotron Mamba-2 mixer (``attention_part``) at the
-    published widths under ``bfloat16_full``, inside the named ``scopes`` a
-    block's step opens around it: -> (f, shapes)."""
+def _mamba_block(T=16384, F=2688, scopes=(), grad=True):
+    """Grad (or, not ``grad``, the forward) of one Nemotron Mamba-2 mixer
+    (``attention_part``) at the published widths under ``bfloat16_full``,
+    inside the named ``scopes`` a block's step opens around it: -> (f,
+    shapes)."""
     from deeplearning4j_tpu import common
     from deeplearning4j_tpu.nn.conf.inputs import InputType
     from deeplearning4j_tpu.nn.conf.layers import DecoderBlock
@@ -252,6 +253,8 @@ def _mamba_block(T=16384, F=2688, scopes=()):
                     scoped.enter_context(jax.named_scope(name))
                 with common.override_policy("bfloat16_full"):
                     return layer.attention_part(p, u).astype(F32).sum()
+        if not grad:
+            return loss(u, dict(zip(names, leaves)))
         return jax.grad(loss, argnums=(0, 1))(u, dict(zip(names, leaves)))
 
     return f, [((1, T, F), BF16)] + [(params[n].shape, F32) for n in names]
@@ -374,6 +377,7 @@ CASES = {
     "ssd-scan-fwd-T16384-bfloat16": (_ssd, ("fwd",)),
     "ssd-scan-grad-T16384-bfloat16": (_ssd, ("grad",)),
     "ssd-block-grad-T16384-bfloat16": (_ssd, ("block",)),
+    "ssd-block-fwd-T16384-bfloat16": (_ssd, ("block-fwd",)),
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
     "latent-grad-T4096-bfloat16": (_latent, (True,)),
     "latent-grad-T16384-bfloat16": (_latent, (True, 16384)),
@@ -428,8 +432,10 @@ def test_kernel_compiles_for_v5e_or_gate_refuses(name, chip):
 
 def test_ssd_kernels_lie_under_the_scan_scope(chip):
     """Every Mosaic kernel of a Mamba-2 mixer's gradient, forward and
-    backward, carries an op name under ``attn/ssd/.../scan``, the scope
-    ``benchmark/costs_ssd.py`` reads the chunked scan's time from."""
+    backward (the whole core's two, ``ssd.mamba_core``), carries an op name
+    under ``attn/ssd/.../scan``, the scope ``benchmark/costs_ssd.py`` reads
+    the chunked scan's time from: with the scan, the kernels hold the taps,
+    the steps, the gate and the norm."""
     import re
     import sys
 
@@ -442,7 +448,7 @@ def test_ssd_kernels_lie_under_the_scan_scope(chip):
     text = jax.jit(f).lower(*args).compile().as_text()
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines() if '"tpu_custom_call"' in line]
-    assert len(names) == 3, names
+    assert len(names) == 2, names
     assert all(re.search(costs_ssd.SCAN_SCOPE, "/" + n) for n in names), names
     assert any("transpose(" in n for n in names), names
 
