@@ -11,7 +11,8 @@ codec (SBE-codec equivalent, reference ui-model ui/stats/sbe/*) — consumed
 via ctypes. Device compute stays in XLA; this layer only stages host memory.
 
 The shared library is built on demand with g++ from the one input git
-commits (``native/src/dl4j_runtime.cpp``); every entry point degrades to
+commits (``native/src/dl4j_runtime.cpp``), once however many processes ask
+at the same time (:func:`_build`); every entry point degrades to
 ``None``/pure-Python when the build is unavailable so the framework never
 hard-requires the native path. A degraded load is not silent:
 :func:`load_error` says why, and ``chip_smoke.py`` fails on it wherever a
@@ -20,6 +21,7 @@ compiler exists.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -45,17 +47,33 @@ c_i32p = ctypes.POINTER(ctypes.c_int32)
 c_i64p = ctypes.POINTER(ctypes.c_int64)
 
 
-def _build() -> bool:
+def _stale(src: Path, lib: Path) -> bool:
+    return not lib.exists() or (src.exists()
+                                and src.stat().st_mtime > lib.stat().st_mtime)
+
+
+def _build(src: Path = _SRC_PATH, lib: Path = _LIB_PATH) -> bool:
+    """Compile ``src`` to ``lib`` where ``lib`` is missing or older, one
+    process at a time: an ``flock`` on ``<lib>.lock`` serialises the builds
+    of every process that asks at once, and the one that holds it checks
+    again, since another may have built it meanwhile. The compiler writes a
+    temporary name beside ``lib`` that ``os.replace`` moves onto it, so no
+    process loads a half-written library."""
     global _load_error
-    if not _SRC_PATH.exists():
-        _load_error = f"{_SRC_PATH} is missing"
+    if not src.exists():
+        _load_error = f"{src} is missing"
         return False
+    tmp = lib.with_name(f"{lib.name}.{os.getpid()}.tmp")
     try:
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-             str(_SRC_PATH), "-o", str(_LIB_PATH)],
-            check=True, capture_output=True, timeout=120)
-        return _LIB_PATH.exists()
+        with open(lib.with_name(lib.name + ".lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)  # released as the file closes
+            if _stale(src, lib):
+                subprocess.run(
+                    ["g++", "-O3", "-std=c++17", "-fPIC", "-shared",
+                     "-pthread", str(src), "-o", str(tmp)],
+                    check=True, capture_output=True, timeout=120)
+                os.replace(tmp, lib)
+        return True
     except (OSError, subprocess.SubprocessError) as e:
         _load_error = f"build failed: {e!r}"
         # leave a post-mortem breadcrumb (worker_exit-style): a silent False
@@ -65,12 +83,14 @@ def _build() -> bool:
                 global_recorder)
             stderr = getattr(e, "stderr", b"") or b""
             global_recorder().record(
-                "native_build_failed", src=str(_SRC_PATH), error=repr(e),
+                "native_build_failed", src=str(src), error=repr(e),
                 stderr=stderr[-500:].decode("utf-8", "replace")
                 if isinstance(stderr, bytes) else str(stderr)[-500:])
         except Exception:  # lint: swallowed-exception-ok (telemetry must not turn a degraded build into a crash)
             pass
         return False
+    finally:
+        tmp.unlink(missing_ok=True)
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -158,34 +178,37 @@ def _declare(lib: ctypes.CDLL) -> None:
 def get_runtime() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native runtime; None when unavailable.
     Set DL4J_TPU_DISABLE_NATIVE=1 to force the pure-Python paths."""
-    global _lib, _load_attempted, _load_error
+    global _lib, _load_attempted
     if os.environ.get("DL4J_TPU_DISABLE_NATIVE") == "1":
         return None
     with _lock:
-        if _load_attempted:
-            return _lib
-        _load_attempted = True
-        stale = (_LIB_PATH.exists() and _SRC_PATH.exists()
-                 and _SRC_PATH.stat().st_mtime > _LIB_PATH.stat().st_mtime)
-        # lint: blocking-under-lock-ok (one-time lazy native build; the module lock exists precisely to serialize first-use compilation)
-        if (not _LIB_PATH.exists() or stale) and not _build():
-            if not _LIB_PATH.exists():
-                return None
-        try:
-            lib = ctypes.CDLL(str(_LIB_PATH))
-            _declare(lib)
-            if lib.dl4j_runtime_version() != 4:
-                _load_error = (f"{_LIB_PATH} is runtime version "
-                               f"{lib.dl4j_runtime_version()}, expected 4")
-                return None
-            _lib = lib
-        except (OSError, AttributeError) as e:
-            # AttributeError: a stale older-version .so whose rebuild failed
-            # is missing current-version symbols — fall back to pure Python
-            # rather than raising out of native_available()
-            _load_error = f"load failed: {e!r}"
-            _lib = None
+        if not _load_attempted:
+            _load_attempted = True
+            _lib = _load(_SRC_PATH, _LIB_PATH)
         return _lib
+
+
+def _load(src: Path, path: Path) -> Optional[ctypes.CDLL]:
+    """The runtime at ``path``, built from ``src`` first where it is missing
+    or older; None, with :func:`load_error` saying why, where it cannot be
+    had."""
+    global _load_error
+    if _stale(src, path) and not _build(src, path) and not path.exists():
+        return None
+    try:
+        lib = ctypes.CDLL(str(path))
+        _declare(lib)
+        if lib.dl4j_runtime_version() != 4:
+            _load_error = (f"{path} is runtime version "
+                           f"{lib.dl4j_runtime_version()}, expected 4")
+            return None
+        return lib
+    except (OSError, AttributeError) as e:
+        # AttributeError: a stale older-version .so whose rebuild failed
+        # is missing current-version symbols — fall back to pure Python
+        # rather than raising out of native_available()
+        _load_error = f"load failed: {e!r}"
+        return None
 
 
 def load_error() -> Optional[str]:

@@ -101,12 +101,39 @@ from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.layers.feedforward import _dense
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn
 from deeplearning4j_tpu.nn.conf.serde import register_config
+from deeplearning4j_tpu.observability.metrics import global_registry
+from deeplearning4j_tpu.observability.names import (
+    ATTN_INDEX_PAIRS_SCORED_TOTAL, ATTN_PAIRS_SELECTED_TOTAL,
+    ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
+    SHORT_CONV_TOKENS_TOTAL, SSM_TOKENS_TOTAL,
+)
 from deeplearning4j_tpu.ops import indexer, remat, ssd
 
 _NORMS, _FFNS = ("rms",), ("swiglu", "moe", "none")
 _ATTENTIONS = ("mla", "gqa", "short_conv", "mamba2", "none")
 _PLACEMENTS, _ROUTERS = ("pre", "sandwich"), ("softmax", "sigmoid_bias")
 _EXPERT_ACTS = ("swiglu", "relu2")
+
+# what a block counts a step (``DecoderBlock.step_counts``): the fit loop
+# books each of its dispatched steps under the block's index in the network
+for _name, _help in (
+        (ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, "attention score entries the "
+         "forward cores of dispatched steps computed (the flash kernel's "
+         "tiles x their area, or the whole square on the XLA path), by "
+         "decoder block"),
+        (ATTN_SCORE_ENTRIES_VISIBLE_TOTAL, "attention score entries the "
+         "masks of the same cores leave visible, by decoder block"),
+        (ATTN_INDEX_PAIRS_SCORED_TOTAL, "(query, key) pairs the indexers of "
+         "dispatched steps scored (every causal pair of a sequence), by "
+         "decoder block"),
+        (ATTN_PAIRS_SELECTED_TOTAL, "(query, key) pairs the same indexers "
+         "selected for the core (min(t + 1, topk) a query), by decoder "
+         "block"),
+        (SHORT_CONV_TOKENS_TOTAL, "tokens the short-convolution mixers of "
+         "dispatched steps ran through, by decoder block"),
+        (SSM_TOKENS_TOTAL, "tokens the state-space scans of dispatched steps "
+         "ran through, by decoder block")):
+    global_registry().counter(_name, _help)
 
 
 def _mm(x, w):
@@ -486,7 +513,8 @@ class DecoderBlock(FeedForwardLayer):
         in float32 (the scan's products in the policy's compute dtype). On a
         TPU that is two Pallas kernels, one each way
         (``ssd.mamba_core``), which run under ``attn/ssd/scan``; where
-        their gate refuses, ``_mamba_groups``."""
+        their gate refuses (the CPU, a GSPMD jit, a geometry they do not
+        take), the statement in ``jax.numpy``, ``_mamba_groups``."""
         zxd = _mm(u, params["W_in"])
         with jax.named_scope("ssd"):
             core = ssd.Core(self.ssm_groups, self.ssm_heads // self.ssm_groups,
@@ -504,9 +532,10 @@ class DecoderBlock(FeedForwardLayer):
     def _mamba_groups(self, params, zxd):
         """``_mamba_part``'s core in ``jax.numpy`` from ``W_in``'s output
         ``zxd`` (the statement the kernels are held to), the chunked scan
-        under ``scan`` (``ssd.ssd_scan``). Nothing in it mixes the groups (a
-        group's channels of ``x``, its ``B`` and ``C``, its heads' steps,
-        its channels of ``z`` and of the norm), so it runs one group at a
+        under ``scan`` (``ssd.ssd_scan``, itself in ``jax.numpy``: no
+        kernel runs here). Nothing in it mixes the groups (a group's
+        channels of ``x``, its ``B`` and ``C``, its heads' steps, its
+        channels of ``z`` and of the norm), so it runs one group at a
         time (``lax.map``, each group checkpointed): a block's backward
         holds one group's intermediates, not all of them."""
         B, T, _ = zxd.shape
@@ -705,7 +734,7 @@ class DecoderBlock(FeedForwardLayer):
             seq, self._qk_dim(), self._v_dim(), dtype, self.window)
         heads = batch * self.n_heads
         if self.index_heads:
-            visible = self.index_pairs(1, seq)[1]
+            visible = self._index_pairs(seq)[1]
         return heads * computed, heads * visible
 
     def remat_kept_bytes(self, batch: int, seq: int, dtype) -> dict:
@@ -728,23 +757,35 @@ class DecoderBlock(FeedForwardLayer):
             kept[remat.SELECT_LSE] = batch * seq * 4
         return kept
 
-    def index_pairs(self, batch: int, seq: int) -> tuple:
-        """``(scored, selected)`` (query, key) pairs of one step's indexer
-        over ``batch`` sequences of ``seq`` tokens: every causal pair is
-        scored, and query t keeps ``min(t + 1, index_topk)``."""
+    def step_counts(self, batch: int, seq: int, dtype) -> dict:
+        """What one step over ``batch`` sequences of ``seq`` tokens in
+        ``dtype`` counts in this block, by counter name, and only what is
+        not zero: the tokens a short convolution or a state-space scan runs
+        through; the score entries a core computes and leaves visible
+        (``attn_score_entries``), and an indexer's (query, key) pairs,
+        every causal one scored and query t's ``min(t + 1, index_topk)``
+        selected. A block without a mixer counts nothing. The fit loop
+        books each a dispatched step under the block's index."""
+        counts = {}
+        if self.attention == "short_conv":
+            counts[SHORT_CONV_TOKENS_TOTAL] = batch * seq
+        elif self.attention == "mamba2":
+            counts[SSM_TOKENS_TOTAL] = batch * seq
+        computed, visible = self.attn_score_entries(batch, seq, dtype)
+        if computed:
+            counts[ATTN_SCORE_ENTRIES_COMPUTED_TOTAL] = computed
+            counts[ATTN_SCORE_ENTRIES_VISIBLE_TOTAL] = visible
+            if self.index_heads:
+                scored, selected = self._index_pairs(seq)
+                counts[ATTN_INDEX_PAIRS_SCORED_TOTAL] = batch * scored
+                counts[ATTN_PAIRS_SELECTED_TOTAL] = batch * selected
+        return counts
+
+    def _index_pairs(self, seq: int) -> tuple:
+        """``(scored, selected)`` (query, key) pairs of an indexer over one
+        sequence of ``seq`` tokens."""
         k = min(self.index_topk, seq)
-        return (batch * (seq * (seq + 1) // 2),
-                batch * (k * (k + 1) // 2 + (seq - k) * k))
-
-    def conv_tokens(self, batch: int, seq: int) -> int:
-        """Tokens one step over ``batch`` sequences of ``seq`` tokens runs
-        through this block's short convolution (0 for an attention)."""
-        return batch * seq if self.attention == "short_conv" else 0
-
-    def ssm_tokens(self, batch: int, seq: int) -> int:
-        """Tokens one step over ``batch`` sequences of ``seq`` tokens runs
-        through this block's state-space scan (0 for any other mixer)."""
-        return batch * seq if self.attention == "mamba2" else 0
+        return seq * (seq + 1) // 2, k * (k + 1) // 2 + (seq - k) * k
 
     def _has_core(self) -> bool:
         return self.attention in ("mla", "gqa")

@@ -44,11 +44,9 @@ from deeplearning4j_tpu.observability.flight_recorder import (
     global_recorder as _flight_recorder,
 )
 from deeplearning4j_tpu.observability.names import (
-    ATTN_INDEX_PAIRS_SCORED_TOTAL, ATTN_PAIRS_SELECTED_TOTAL,
-    ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, ATTN_SCORE_ENTRIES_VISIBLE_TOTAL,
     FIT_PHASE_SECONDS, MOE_COMPUTED_ROWS_TOTAL, MOE_EXPERT_ROWS_MAX,
     MOE_EXPERT_ROWS_MAX_TOTAL, MOE_ROUTED_ROWS_TOTAL, MOE_TOKENS_TOTAL,
-    REMAT_KEPT_BYTES_TOTAL, SHORT_CONV_TOKENS_TOTAL, SSM_TOKENS_TOTAL,
+    REMAT_KEPT_BYTES_TOTAL,
 )
 from deeplearning4j_tpu.observability.metrics import (
     global_registry as _obs_registry,
@@ -106,30 +104,10 @@ _moe_rows_max_total = _obs_registry().counter(
     "uneven the routing was), by expert layer")
 
 
-_attn_computed = _obs_registry().counter(
-    ATTN_SCORE_ENTRIES_COMPUTED_TOTAL, "attention score entries the forward "
-    "cores of dispatched steps computed (the flash kernel's tiles x their "
-    "area, or the whole square on the XLA path), by decoder block")
-_attn_visible = _obs_registry().counter(
-    ATTN_SCORE_ENTRIES_VISIBLE_TOTAL, "attention score entries the masks of "
-    "the same cores leave visible, by decoder block")
-_attn_scored = _obs_registry().counter(
-    ATTN_INDEX_PAIRS_SCORED_TOTAL, "(query, key) pairs the indexers of "
-    "dispatched steps scored (every causal pair of a sequence), by decoder "
-    "block")
-_attn_selected = _obs_registry().counter(
-    ATTN_PAIRS_SELECTED_TOTAL, "(query, key) pairs the same indexers "
-    "selected for the core (min(t + 1, topk) a query), by decoder block")
 _remat_kept = _obs_registry().counter(
     REMAT_KEPT_BYTES_TOTAL, "bytes the checkpointed decoder blocks of "
     "dispatched steps kept from forward to backward besides their inputs "
     "(ops/remat.py), summed over the blocks, by the kept value's name")
-_conv_tokens = _obs_registry().counter(
-    SHORT_CONV_TOKENS_TOTAL, "tokens the short-convolution mixers of "
-    "dispatched steps ran through, by decoder block")
-_ssm_tokens = _obs_registry().counter(
-    SSM_TOKENS_TOTAL, "tokens the state-space scans of dispatched steps ran "
-    "through, by decoder block")
 
 
 def _updater_spec(layer) -> UpdaterSpec:
@@ -583,55 +561,37 @@ class LazyScore:
             self._score_raw = raw
         return raw
 
-    def _note_attn_entries(self, steps: int, x) -> None:
-        """Book ``steps`` dispatched steps' attention score entries
-        (``dl4j_attn_score_entries_*``, and for a block with an indexer
-        ``dl4j_attn_index_pairs_scored_total`` and
-        ``dl4j_attn_pairs_selected_total``) for the decoder blocks, the
-        tokens of a block whose mixer is a short convolution or a
-        state-space scan instead (``dl4j_short_conv_tokens_total``,
-        ``dl4j_ssm_tokens_total``; a block without a mixer books nothing),
-        and under
-        ``gradient_checkpointing`` the bytes they kept for their backward
-        (``dl4j_remat_kept_bytes_total``): a function of the batch's shape
-        and each block's fields alone, so nothing is read from the device."""
+    def _note_step_counts(self, steps: int, x) -> None:
+        """Book ``steps`` dispatched steps of what each layer with a
+        ``step_counts`` reports a step counts, each counter labelled by the
+        layer's index, and under ``gradient_checkpointing`` the bytes the
+        layers with a ``remat_kept_bytes`` kept for their backward
+        (``dl4j_remat_kept_bytes_total``, summed over them): a function of
+        the batch's shape and each layer's fields alone, so nothing is read
+        from the device."""
         layers = getattr(getattr(self, "conf", None), "layers", None) or ()
-        blocks = [(i, l) for i, l in enumerate(layers)
-                  if hasattr(l, "attn_score_entries")]
-        if not blocks:
+        if not any(hasattr(l, "step_counts") for l in layers):
             return
         shape = jax.tree_util.tree_leaves(x)[0].shape
         key = (steps, shape)
-        if getattr(self, "_attn_entries_key", None) != key:
+        if getattr(self, "_step_counts_key", None) != key:
             batch, seq = shape[-2:]
             name = self.conf.global_conf.dtype
             dtype = (common.resolve_policy(name) if name
                      else common.get_policy()).output_dtype
-            self._attn_entries_key = key
-            self._attn_entries = [
-                (str(i), *l.attn_score_entries(batch, seq, dtype),
-                 *(l.index_pairs(batch, seq)
-                   if getattr(l, "index_heads", 0) else (0, 0)),
-                 l.conv_tokens(batch, seq), l.ssm_tokens(batch, seq))
-                for i, l in blocks]
+            self._step_counts_key = key
+            self._step_counts = [
+                (counter, str(i), n)
+                for i, l in enumerate(layers) if hasattr(l, "step_counts")
+                for counter, n in l.step_counts(batch, seq, dtype).items()]
             kept = collections.Counter()
             if self.conf.global_conf.gradient_checkpointing:
-                for _, l in blocks:
-                    kept.update(l.remat_kept_bytes(batch, seq, dtype))
+                for l in layers:
+                    if hasattr(l, "remat_kept_bytes"):
+                        kept.update(l.remat_kept_bytes(batch, seq, dtype))
             self._kept_bytes = sorted(kept.items())
-        for (layer, computed, visible, scored, selected, conv,
-             ssm) in self._attn_entries:
-            if conv:
-                _conv_tokens.labels(layer=layer).inc(steps * conv)
-            if ssm:
-                _ssm_tokens.labels(layer=layer).inc(steps * ssm)
-            if not computed:
-                continue
-            _attn_computed.labels(layer=layer).inc(steps * computed)
-            _attn_visible.labels(layer=layer).inc(steps * visible)
-            if scored:
-                _attn_scored.labels(layer=layer).inc(steps * scored)
-                _attn_selected.labels(layer=layer).inc(steps * selected)
+        for counter, layer, n in self._step_counts:
+            _obs_registry().counter(counter).labels(layer=layer).inc(steps * n)
         for name, nbytes in self._kept_bytes:
             _remat_kept.labels(name=name).inc(steps * nbytes)
 
@@ -1059,7 +1019,7 @@ class LazyScore:
             self._pending_moe_rows = (*self._pending_moe_rows,
                                       (rest[0], tokens))
             self._note_moe_rows()
-        self._note_attn_entries(n, x)
+        self._note_step_counts(n, x)
         fields = {} if owner is None else owner.note_steps(n)
         self._book_steps(name, n, losses if multi else [losses], t0_ns,
                          t1_ns, dt, **fields)

@@ -24,14 +24,15 @@ dtype and accumulate in float32. A length that is not a multiple of the
 chunk is padded with tokens whose ``dt`` is 0: they neither decay the state
 nor add to it, and their outputs are cut away.
 
-On a TPU the same mathematics runs as Pallas kernels (:func:`ssd_scan`
-decides from what the trace shows): the forward walks each sequence's chunks
-in order on a sequential grid axis and keeps the carried state of a group's
-heads, ``[N, K P]`` float32, in VMEM, and where a backward follows also writes
-the state entering each chunk; the backward walks them in reverse, carrying
-the state's cotangent. The decay and ``C B^T`` never leave VMEM and no ``lax.scan`` runs
-over the chunks. :func:`_ssd_scan_xla` is the statement the kernels are held
-to, and the fallback everywhere else.
+On a TPU the whole core of a Mamba-2 mixer, from ``W_in``'s output to the
+gated and normed ``y``, runs as one Pallas kernel each way
+(:func:`mamba_core`, where :func:`core_kernels_ok` admits the call): the
+forward walks each sequence's chunks in order on a sequential grid axis and
+keeps the carried state of a group's heads, ``[N, K P]`` float32, in VMEM;
+the backward walks them in reverse, carrying the state's cotangent. The
+decay and ``C B^T`` never leave VMEM and no ``lax.scan`` runs over the
+chunks. :func:`ssd_scan` is the chunked statement: the path everywhere
+else, and the oracle the kernels are held to.
 """
 from __future__ import annotations
 
@@ -49,26 +50,12 @@ from deeplearning4j_tpu.ops import pallas_kernels as pk
 
 
 def ssd_scan(x, dt, A, B, C, D, chunk: int):
-    """``y [Bt, T, H, P]`` (float32, at least) of the recurrence above.
+    """``y [Bt, T, H, P]`` (float32, at least) of the recurrence above, in
+    its chunked form in ``jax.numpy``: the statement of the mathematics.
 
     ``x`` [Bt, T, H, P] in the products' dtype; ``dt`` [Bt, T, H], the
     step after its softplus; ``A`` [H], negative; ``B``, ``C`` [Bt, T, G,
-    N]; ``D`` [H]; ``chunk`` the tokens a chunk holds. The kernels where
-    :func:`_kernels_ok` admits the call, else :func:`_ssd_scan_xla`; the
-    choice is booked on ``dl4j_pallas_dispatch_total{kernel="ssd_scan"}``,
-    and on ``kernel="ssd_scan_bwd"`` as the plan a backward of the call
-    takes."""
-    ok = _kernels_ok(x, B, chunk)
-    pk._note_dispatch("ssd_scan", ok)
-    pk._note_dispatch("ssd_scan_bwd", ok)
-    if ok:
-        return _ssd_scan_kernels(x, dt, A, B, C, D, chunk)
-    return _ssd_scan_xla(x, dt, A, B, C, D, chunk)
-
-
-def _ssd_scan_xla(x, dt, A, B, C, D, chunk: int):
-    """The chunked form in ``jax.numpy`` (:func:`ssd_scan`'s arguments):
-    the statement of the mathematics."""
+    N]; ``D`` [H]; ``chunk`` the tokens a chunk holds."""
     Bt, T, H, P = x.shape
     G, N = B.shape[-2:]
     K = H // G
@@ -127,17 +114,16 @@ def _heads_per_slab(K: int, P: int) -> int:
 
 
 def _vmem_bytes(chunk: int, K: int, P: int, N: int, dtype,
-                backward: bool = False, taps: int = 0) -> int:
-    """VMEM a program plans for: the pipeline's double-buffered blocks of a
-    chunk's tokens and of the state entering the chunk, the carried state
-    (or its cotangent) and the chunk's temporaries, a few ``[chunk, chunk]``
-    float32 tiles and slabs of 128 lanes. ``taps``: the whole core's
-    (``mamba_core``), which adds the blocks of z, of the gated y, of the
-    float32 y before the gate, of the ``_HALO`` tokens before the chunk and
-    of the steps, backward the cotangents of z, x, B and C (staged for their
-    copies out) and of the steps, and the taps' rows of the chunk and its
-    halo, ``[_HALO + chunk, K P + 2 N]`` float32 (twice backward), with
-    their temporaries."""
+                backward: bool = False) -> int:
+    """VMEM a program of :func:`mamba_core` plans for: the pipeline's
+    double-buffered blocks of a chunk's tokens (z, x, B, C, the gated y and
+    the float32 y before the gate), of the ``_HALO`` tokens before the
+    chunk, of the steps and of the state entering the chunk, backward also
+    the cotangents of z, x, B and C (staged for their copies out) and of
+    the steps; the carried state (or its cotangent); the taps' rows of the
+    chunk and its halo, ``[_HALO + chunk, K P + 2 N]`` float32 (twice
+    backward); and the chunk's temporaries, a few ``[chunk, chunk]``
+    float32 tiles and slabs of 128 lanes."""
     isz, lanes = jnp.dtype(dtype).itemsize, pk._lanes
     KP, n = K * P, lanes(N)
     small = chunk * (2 * lanes(K) * 4 + 8 * 4) + 8 * lanes(KP) * 4
@@ -146,172 +132,18 @@ def _vmem_bytes(chunk: int, K: int, P: int, N: int, dtype,
     if backward:
         blocks += chunk * (KP * 4 + KP * isz + 2 * n * isz) + small
         temps *= 2
-    if taps:
-        rows = (_HALO + chunk) * lanes(KP + 2 * n) * 4
-        blocks += (chunk * KP * (2 * isz + 4) + _HALO * (KP + 2 * n) * isz
-                   + 8 * lanes(chunk) * 4)
-        if backward:
-            blocks += chunk * (2 * KP + 2 * n) * isz + 8 * lanes(chunk) * 4
-        temps += (2 if backward else 1) * (rows + 4 * chunk * lanes(
-            KP + 2 * n) * 4)
+    rows = (_HALO + chunk) * lanes(KP + 2 * n) * 4
+    blocks += (chunk * KP * (2 * isz + 4) + _HALO * (KP + 2 * n) * isz
+               + 8 * lanes(chunk) * 4)
+    if backward:
+        blocks += chunk * (2 * KP + 2 * n) * isz + 8 * lanes(chunk) * 4
+    temps += (2 if backward else 1) * (rows + 4 * chunk * lanes(
+        KP + 2 * n) * 4)
     return 2 * blocks + KP * n * 4 + temps
 
 
-def _kernels_ok(x, B, chunk: int) -> bool:
-    """The kernels' gate, read from what the trace shows: a TPU, and no jit
-    that GSPMD partitions nor a vma-checked ``shard_map`` around
-    (``pk.pallas_unavailable``); ``x`` in bfloat16 or float32; a chunk of a
-    multiple of 8 rows; a group's lanes that cut into aligned slabs of
-    heads; and a program whose counted VMEM fits the budget."""
-    if pk.pallas_unavailable() is not None or pk._in_checked_shard_map(x):
-        return False
-    if x.dtype not in (jnp.bfloat16, jnp.float32) or chunk % 8:
-        return False
-    K, P, N = x.shape[2] // B.shape[2], x.shape[3], B.shape[3]
-    width = _heads_per_slab(K, P) * P
-    if not (width == K * P or width % 128 == 0) or (K > 1 and P % 8):
-        return False
-    return _vmem_bytes(chunk, K, P, N, x.dtype,
-                       backward=True) <= pk._VMEM_BUDGET
-
-
-def _ssd_scan_kernels(x, dt, A, B, C, D, chunk: int, interpret=False):
-    """:func:`ssd_scan` through the kernels: the operands laid out a
-    (sequence, group) row at a time, the chunk-local cumulative sums of
-    ``dt A`` made here as the statement makes them (their gradient's reverse
-    sum stays in XLA), ``D`` repeated over each head's lanes."""
-    Bt, T, H, P = x.shape
-    G, N = B.shape[-2:]
-    K = H // G
-    cd, f32 = x.dtype, at_least_f32(x.dtype)
-    pad = -T % chunk
-    if pad:
-        x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                       for a in (x, dt, B, C))
-    n, Tp = (T + pad) // chunk, T + pad
-    dt = dt.astype(f32)
-    cs = jnp.cumsum(dt.reshape(Bt, n, chunk, G, K)
-                    * A.astype(f32).reshape(G, K), axis=2)
-
-    def by_row(a):                            # [Bt, Tp, G, ...] -> [R, Tp, -1]
-        a = jnp.moveaxis(a.reshape(Bt, Tp, G, -1), 2, 1)
-        return a.reshape(Bt * G, Tp, a.shape[-1])
-
-    d = jnp.repeat(D.astype(f32).reshape(G, K), P, axis=1).reshape(G, 1, K * P)
-    y = _scan(by_row(x), by_row(dt), by_row(cs), by_row(B.astype(cd)),
-              by_row(C.astype(cd)), d, chunk, interpret)
-    y = jnp.moveaxis(y.reshape(Bt, G, Tp, H // G, P), 1, 2)
-    return y.reshape(Bt, Tp, H, P)[:, :T]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _scan(x, dt, cs, B, C, d, chunk, interpret):
-    """y [R, Tp, K P] of rows laid out by ``_ssd_scan_kernels``: x [R, Tp, K
-    P], dt and cs [R, Tp, K] float32, B and C [R, Tp, N], d [G, 1, K P]."""
-    return _forward(x, dt, cs, B, C, d, chunk, interpret)
-
-
-def _scan_fwd(x, dt, cs, B, C, d, chunk, interpret):
-    y, states = _forward(x, dt, cs, B, C, d, chunk, interpret, states=True)
-    return y, (x, dt, cs, B, C, d, states)
-
-
-def _scan_bwd(chunk, interpret, res, g):
-    x, dt, cs, B, C, d, states = res
-    dx, dB, dC, ddt, dcs, dcs_t, dd = _backward(x, dt, cs, B, C, d, states,
-                                                g, chunk, interpret)
-    R, Tp, K = dt.shape
-    dcs = dcs + jnp.swapaxes(dcs_t, 2, 3).reshape(R, Tp, K)
-    dd = dd.reshape(R // d.shape[0], *d.shape).sum(axis=0)
-    return dx, ddt, dcs, dB, dC, dd
-
-
-_scan.defvjp(_scan_fwd, _scan_bwd)
-
-
-def _layout(x, dt, B, chunk):
-    """(R, sequence's chunks, chunk, K, P, N, heads a slab)."""
-    R, Tp, KP = x.shape
-    K, N = dt.shape[-1], B.shape[-1]
-    return R, Tp // chunk, chunk, K, KP // K, N, _heads_per_slab(K, KP // K)
-
-
-def _specs(n, L, G, reverse=False):
-    """BlockSpec makers over the grid ``(R, n)``: a chunk of tokens, a
-    chunk-major row, D's lanes; walked backwards from the last chunk where
-    ``reverse``."""
-    at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
-    tok = lambda w: pl.BlockSpec((1, L, w), lambda r, c: (r, at(c), 0))
-    per_chunk = lambda a, b: pl.BlockSpec((1, 1, a, b),
-                                          lambda r, c: (r, at(c), 0, 0))
-    lanes = lambda w: pl.BlockSpec((1, 1, w), lambda r, c: (r % G, 0, 0))
-    return tok, per_chunk, lanes
-
-
-def _by_chunk(cs, chunk):
-    """[R, Tp, K] -> [R, n, K, chunk]: each chunk's sums along lanes."""
-    R, Tp, K = cs.shape
-    return jnp.swapaxes(cs.reshape(R, Tp // chunk, chunk, K), 2, 3)
-
-
-def _params(need):
-    return pk._flash_params(("parallel", "arbitrary"), need)
-
-
-def _forward(x, dt, cs, B, C, d, chunk, interpret, states=False):
-    """y [R, Tp, K P]; and where ``states``, the state entering each chunk
-    too, [R, n, N, K P] float32, the backward's residual."""
-    R, n, L, K, P, N, hs = _layout(x, dt, B, chunk)
-    tok, per_chunk, lanes = _specs(n, L, d.shape[0])
-    out_specs = [tok(K * P)]
-    out_shape = [jax.ShapeDtypeStruct(x.shape, at_least_f32(x.dtype))]
-    if states:
-        out_specs.append(per_chunk(N, K * P))
-        out_shape.append(jax.ShapeDtypeStruct((R, n, N, K * P), jnp.float32))
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, P=P, hs=hs),
-        grid=(R, n),
-        in_specs=[tok(K * P), tok(K), tok(K), per_chunk(K, L), tok(N),
-                  tok(N), lanes(K * P)],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((N, K * P), jnp.float32)],
-        compiler_params=_params(_vmem_bytes(L, K, P, N, x.dtype)),
-        interpret=interpret,
-    )(x, dt, cs, _by_chunk(cs, L), B, C, d)
-    return tuple(out) if states else out[0]
-
-
-def _backward(x, dt, cs, B, C, d, states, g, chunk, interpret):
-    """-> dx, dB, dC, d(dt) direct, d(cs) [R, Tp, K] and [R, n, K, chunk]
-    (the parts the sums reach along rows and along lanes), dd [R, 1, K P]."""
-    R, n, L, K, P, N, hs = _layout(x, dt, B, chunk)
-    tok, per_chunk, lanes = _specs(n, L, d.shape[0], reverse=True)
-    f32 = jnp.float32
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, P=P, hs=hs),
-        grid=(R, n),
-        in_specs=[tok(K * P), tok(K), tok(K), per_chunk(K, L), tok(N),
-                  tok(N), lanes(K * P), per_chunk(N, K * P), tok(K * P)],
-        out_specs=[tok(K * P), tok(N), tok(N), tok(K), tok(K),
-                   per_chunk(K, L),
-                   pl.BlockSpec((1, 1, K * P), lambda r, c: (r, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(B.shape, B.dtype),
-                   jax.ShapeDtypeStruct(C.shape, C.dtype),
-                   jax.ShapeDtypeStruct(dt.shape, f32),
-                   jax.ShapeDtypeStruct(dt.shape, f32),
-                   jax.ShapeDtypeStruct((R, n, K, L), f32),
-                   jax.ShapeDtypeStruct((R, 1, K * P), f32)],
-        scratch_shapes=[pltpu.VMEM((N, K * P), f32)],
-        compiler_params=_params(_vmem_bytes(L, K, P, N, x.dtype,
-                                            backward=True)),
-        interpret=interpret,
-    )(x, dt, cs, _by_chunk(cs, L), B, C, d, states, g.astype(f32))
-
-
-# a grid step of each kernel is one chunk of one row; a group's heads go by
-# slabs of lanes (``_heads_per_slab``)
+# a grid step of each kernel is one chunk of one (sequence, group) row; a
+# group's heads go by slabs of lanes (``_heads_per_slab``)
 def _expand(v, k0: int, hs: int, P: int, shape):
     """Columns ``k0 .. k0 + hs - 1`` of ``v`` [L, K], each over its head's
     ``P`` lanes of a slab ``shape`` [L, hs P]."""
@@ -384,25 +216,6 @@ def _advance(s_ref, j: int, xf, bm, w, whole, hs: int, P: int):
                        + pk._dot(bm, xs, 0, 0))
 
 
-def _fwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, y_ref,
-                *refs, P: int, hs: int):
-    """One step of the walk; ``refs`` the output of the states entering each
-    chunk, where the call writes it, then the carried state."""
-    *st_ref, s_ref = refs
-
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    if st_ref:
-        st_ref[0][0, 0] = s_ref[...]
-    W = hs * P
-    ys = _fwd_chunk(x_ref[0], dt_ref[0], cs_ref[0], cst_ref[0, 0], b_ref[0],
-                    c_ref[0], d_ref, s_ref, P, hs)
-    for j, y in enumerate(ys):
-        y_ref[0, :, j * W:(j + 1) * W] = y.astype(y_ref.dtype)
-
-
 def _fwd_chunk(x, dt, cs, cst, bm, cm, d_ref, s_ref, P: int, hs: int):
     """One chunk of the forward walk: x [L, K P] in the products' dtype, dt
     and cs [L, K], cst [K, L], B and C [L, N], d [1, K P]; ``s_ref`` the
@@ -429,32 +242,6 @@ def _fwd_chunk(x, dt, cs, cst, bm, cm, d_ref, s_ref, P: int, hs: int):
         ys.append(y + d_ref[0, :, lanes] * xf)
         _advance(s_ref, j, xf, bm, w, whole, hs, P)
     return ys
-
-
-def _bwd_kernel(x_ref, dt_ref, cs_ref, cst_ref, b_ref, c_ref, d_ref, st_ref,
-                g_ref, dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref, dcst_ref,
-                dd_ref, ds_ref, *, P: int, hs: int):
-    """One step of the reverse walk; ``ds_ref`` holds the cotangent of the
-    state leaving the chunk, and leaves holding that of the state entering
-    it."""
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        ds_ref[...] = jnp.zeros_like(ds_ref)
-        dd_ref[...] = jnp.zeros_like(dd_ref)
-
-    W = hs * P
-    dxs, dB, dC, ddt, dcs, dcst, dds = _bwd_chunk(
-        x_ref[0], dt_ref[0], cs_ref[0], cst_ref[0, 0], b_ref[0], c_ref[0],
-        d_ref, st_ref[0, 0], g_ref[0], ds_ref, P, hs)
-    for j, (dx, dd) in enumerate(zip(dxs, dds)):
-        lanes = slice(j * W, (j + 1) * W)
-        dx_ref[0, :, lanes] = dx.astype(dx_ref.dtype)
-        dd_ref[0, :, lanes] += dd
-    dc_ref[0] = dC.astype(dc_ref.dtype)
-    db_ref[0] = dB.astype(db_ref.dtype)
-    ddt_ref[0] = ddt
-    dcs_ref[0] = dcs
-    dcst_ref[0, 0] = dcst
 
 
 def _bwd_chunk(x, dt, cs, cst, bm, cm, d_ref, sin, g_all, ds_ref, P: int,
@@ -566,12 +353,14 @@ def core_kernels_ok(zxd, core: Core) -> bool:
     ``zxd`` [Bt, T, 2 d + 2 G N + H]; the choice is booked on
     ``dl4j_pallas_dispatch_total{kernel="mamba_core"}``, and on
     ``kernel="mamba_core_bwd"`` as the plan a backward of the call takes.
-    Besides the scan kernels' gate (a TPU, no GSPMD jit nor checked
-    ``shard_map`` around, slabs of heads, a program whose counted VMEM
-    fits): a group's channels, B and C whole blocks of 128 lanes at their
-    place in ``zxd``, a chunk of whole blocks of 128 tokens (the lanes of
-    the transposed cotangent the backward copies out) and taps that reach
-    no further back than the halo."""
+    The gate, read from what the trace shows: a TPU, and no jit that GSPMD
+    partitions nor a vma-checked ``shard_map`` around
+    (``pk.pallas_unavailable``); ``zxd`` and the products in bfloat16 or
+    float32; a group's channels, B and C whole blocks of 128 lanes at their
+    place in ``zxd``; a chunk of whole blocks of 128 tokens (the lanes of
+    the transposed cotangent the backward copies out); taps that reach no
+    further back than the halo; a group's lanes that cut into aligned slabs
+    of heads; and a program whose counted VMEM fits the budget."""
     ok = _core_ok(zxd, core)
     pk._note_dispatch("mamba_core", ok)
     pk._note_dispatch("mamba_core_bwd", ok)
@@ -591,8 +380,8 @@ def _core_ok(zxd, core: Core) -> bool:
     width = _heads_per_slab(K, P) * P
     if not (width == W or width % 128 == 0) or (K > 1 and P % 8):
         return False
-    return _vmem_bytes(chunk, K, P, N, core.cd, backward=True,
-                       taps=core.taps) <= pk._VMEM_BUDGET
+    return _vmem_bytes(chunk, K, P, N, core.cd,
+                       backward=True) <= pk._VMEM_BUDGET
 
 
 def mamba_core(zxd, conv_w, conv_b, dt_bias, A_log, D, norm_g, core: Core,
@@ -708,7 +497,7 @@ def _core_params(core: Core, backward=False):
     G, K, P, N, L = core[:5]
     return pk._flash_params(
         ("parallel", "parallel", "arbitrary"),
-        _vmem_bytes(L, K, P, N, core.cd, backward=backward, taps=core.taps))
+        _vmem_bytes(L, K, P, N, core.cd, backward=backward))
 
 
 def _core_forward(zxd, w, dt_bias, a_log, d, g, core: Core, interpret,

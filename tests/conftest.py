@@ -38,6 +38,11 @@ import pytest  # noqa: E402
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 gate (-m 'not slow')")
+    # the native runtime is built here, once, before any worker of a
+    # distributed run imports the test files that ask whether it loads
+    if not hasattr(config, "workerinput"):
+        from deeplearning4j_tpu import nativert
+        nativert.get_runtime()
 
 
 @pytest.fixture(scope="session")

@@ -9,6 +9,7 @@ import socket
 import struct
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -32,8 +33,9 @@ SHM_DIR = "/dev/shm"
 
 needs_shm = pytest.mark.skipif(not os.path.isdir(SHM_DIR),
                                reason="no /dev/shm on this host")
-needs_native = pytest.mark.skipif(not nativert.native_available(),
-                                  reason="native runtime unavailable")
+needs_native = pytest.mark.skipif(
+    not nativert.native_available(),
+    reason=f"native runtime unavailable: {nativert.load_error()}")
 
 
 def _shm_names():
@@ -448,25 +450,55 @@ def _leaves(net):
     return [np.array(x) for x in jax.tree_util.tree_leaves(net.params_list)]
 
 
+def _flushes_in_order(push_delta, workers):
+    """A ``ParameterServer.push_delta`` that holds each push until all
+    ``workers`` have sent theirs, then applies them in the order of their
+    sums: no worker sees another's push while it trains, and which lands
+    first (at staleness 0) is fixed by what was pushed, not by a clock.
+    (A sum, not the bytes, so that engines whose deltas differ in their
+    last bits order them alike.)"""
+    turn = threading.Condition()
+    sent, applied = [], []
+
+    def ordered(server, delta, base_version, **kw):
+        key = float(np.sum(delta, dtype=np.float64))
+        with turn:
+            sent.append(key)
+            turn.notify_all()
+            assert turn.wait_for(lambda: len(sent) >= workers, timeout=60), (
+                "a worker never flushed")
+            assert turn.wait_for(
+                lambda: sorted(sent).index(key) == len(applied), timeout=60)
+            res = push_delta(server, delta, base_version, **kw)
+            applied.append(key)
+            turn.notify_all()
+            return res
+    return ordered
+
+
 @needs_shm
 @pytest.mark.slow
-def test_fit_parity_inproc_tcp_shm_bitwise():
+def test_fit_parity_inproc_tcp_shm_bitwise(monkeypatch):
     """2-worker fits over tcp and shm produce bitwise-identical parameters
     when the push schedule is deterministic (one flush push per worker,
-    strictly ordered by worker_delays) — the transports move bytes, they
-    don't do arithmetic. The threaded inproc engine schedules its rebases
-    slightly differently, so it anchors within tolerance rather than
-    bitwise. The shm run also leaves zero segments behind."""
+    applied in an order ``_flushes_in_order`` fixes, none while a worker
+    trains) — the transports move bytes, they don't do arithmetic. The
+    threaded inproc engine runs another program on another device count,
+    so it anchors within tolerance rather than bitwise. The shm run also
+    leaves zero segments behind."""
     data = _batches()
     before = _shm_names()
+    push_delta = ParameterServer.push_delta
     results = {}
     for kind in ("inproc", "tcp", "shm"):
+        monkeypatch.setattr(ParameterServer, "push_delta",
+                            _flushes_in_order(push_delta, 2))
         net = _net()
         wrapper = (ParameterServerParallelWrapper.builder(net)
-                   .workers(2).push_frequency(100)
-                   .worker_delays(0.0, 0.2).transport(kind).build())
+                   .workers(2).push_frequency(100).transport(kind).build())
         wrapper.fit(ListDataSetIterator(data))
         assert sum(s["steps"] for s in wrapper.worker_stats) == len(data)
+        assert [s["rebased"] for s in wrapper.worker_stats] == [0, 0]
         results[kind] = _leaves(net)
     for a, b in zip(results["tcp"], results["shm"]):
         np.testing.assert_array_equal(a, b, err_msg="shm diverged from tcp")

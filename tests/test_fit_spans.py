@@ -2,7 +2,8 @@
 K-step group in the flight recorder's ring (``input.pull/stack/cast/h2d`` on
 the producer, ``fit.wait/step_wait/dispatch/listeners`` on the fit loop,
 sharing the group's number, on ``time.time_ns()``'s clock), and a ``jax.named_scope`` per
-layer and phase in the K-step program, whose module has a name of its own."""
+layer and phase in the K-step program, whose module has a name of its own;
+and what each layer says a step counts, booked a dispatched step."""
 import time
 
 import jax
@@ -259,6 +260,40 @@ def test_kstep_program_is_named_and_scoped(kind, layer):
     for op_name in (f"jvp({layer})/", "jvp(loss)/", "/update/",
                     f"transpose(jvp({layer}))/"):
         assert op_name in text, op_name
+
+
+class _Counting(DenseLayer):
+    """Not a decoder block: a layer that says what its step counts."""
+
+    def step_counts(self, batch, seq, dtype):
+        return {"dl4j_test_rows_total": batch * seq,
+                "dl4j_test_steps_total": 1}
+
+
+def test_the_loop_books_whatever_counts_a_layer_reports():
+    """The fit loop books each layer's ``step_counts`` a dispatched step,
+    under the layer's index, knowing no kind of layer: here a dense layer's
+    rows and steps over 3 groups of K steps, and nothing for the output
+    layer, which reports none."""
+    b = NeuralNetConfiguration.builder().seed(3).learning_rate(0.05)
+    net = MultiLayerNetwork(b.list().layer(
+        _Counting(n_in=16, n_out=8, activation="tanh")).layer(
+        OutputLayer(n_in=8, n_out=3, loss="mcxent", activation="softmax"))
+        .build()).init(seed=3)
+    net.dispatch_ksteps = K
+
+    def booked():
+        snap = global_registry().snapshot()
+        return {(name, s["labels"]["layer"]): s["value"]
+                for name in ("dl4j_test_rows_total", "dl4j_test_steps_total")
+                for s in snap.get(name, {}).get("series", ())}
+
+    before = booked()
+    net.fit_iterator(ListDataSetIterator(batches(3 * K)))
+    after = booked()
+    assert {k: v - before.get(k, 0) for k, v in after.items()} == {
+        ("dl4j_test_rows_total", "0"): 3 * K * 8 * 16,
+        ("dl4j_test_steps_total", "0"): 3 * K}
 
 
 def test_scopes_stay_inside_a_config_declared_policy():

@@ -24,6 +24,8 @@ from deeplearning4j_tpu.datasets.dataset import DataSet  # noqa: E402
 from deeplearning4j_tpu.models import lfm2_moe  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers import DecoderBlock  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers.decoder import short_conv  # noqa: E402
+from deeplearning4j_tpu.observability.names import (  # noqa: E402
+    SHORT_CONV_TOKENS_TOTAL)
 from test_keye_vl2 import _kept_bytes  # noqa: E402
 from test_trinity_mini import _batches, _close, _counters  # noqa: E402
 
@@ -168,7 +170,10 @@ def test_a_convolution_books_tokens_and_keeps_nothing():
     block = DecoderBlock(n_in=8, n_out=8, attention="short_conv")
     attn = DecoderBlock(n_in=8, n_out=8, attention="gqa", n_heads=2,
                         n_kv_heads=1, head_dim=4)
-    assert block.conv_tokens(3, 32) == 96 and attn.conv_tokens(3, 32) == 0
+    assert block.step_counts(3, 32, jnp.bfloat16) == {
+        SHORT_CONV_TOKENS_TOTAL: 96}
+    assert SHORT_CONV_TOKENS_TOTAL not in attn.step_counts(3, 32,
+                                                            jnp.bfloat16)
     assert block.attn_score_entries(3, 32, jnp.bfloat16) == (0, 0)
     assert block.remat_kept_bytes(3, 32, jnp.bfloat16) == {}
     assert attn.remat_kept_bytes(3, 32, jnp.bfloat16)

@@ -1,7 +1,7 @@
 """A Mamba-2 mixer's whole core as Pallas kernels (``ssd.mamba_core``: the
 taps, the steps, the chunked scan, the gate and the group's norm, from
 ``W_in``'s output to the normed ``y``) against the composition they are held
-to, ``DecoderBlock._mamba_groups`` (its scan ``_ssd_scan_xla`` on the CPU),
+to, ``DecoderBlock._mamba_groups`` (its scan the statement, ``ssd_scan``),
 in interpret mode: the forward and the gradients with respect to ``W_in``'s
 output and every leaf of the core, in float32 and in bfloat16, at lengths
 with a partial last chunk and with chunks shorter than the taps' reach

@@ -8,8 +8,9 @@ import pytest
 from deeplearning4j_tpu import nativert
 from deeplearning4j_tpu.ui.stats import StatsReport
 
-pytestmark = pytest.mark.skipif(not nativert.native_available(),
-                                reason="native runtime not built")
+pytestmark = pytest.mark.skipif(
+    not nativert.native_available(),
+    reason=f"native runtime not built: {nativert.load_error()}")
 
 
 def _write_idx(path, arr):

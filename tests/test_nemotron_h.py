@@ -28,6 +28,7 @@ from deeplearning4j_tpu.models.nemotron_h import PUBLISHED_PATTERN  # noqa: E402
 from deeplearning4j_tpu.nn.conf.inputs import InputType  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers import DecoderBlock  # noqa: E402
 from deeplearning4j_tpu.nn.conf.layers.moe import grouped_expert_ffn  # noqa: E402
+from deeplearning4j_tpu.observability.names import SSM_TOKENS_TOTAL  # noqa: E402
 from deeplearning4j_tpu.ops.ssd import ssd_scan  # noqa: E402
 from test_keye_vl2 import _kept_bytes  # noqa: E402
 from test_trinity_mini import _batches, _close, _counters  # noqa: E402
@@ -172,8 +173,8 @@ def test_a_mixer_books_tokens_and_keeps_nothing():
                            n_experts=4, expert_hidden=4)
     attn = DecoderBlock(n_in=8, n_out=8, attention="gqa", n_heads=2,
                         n_kv_heads=1, head_dim=4, ffn="none")
-    assert mixer.ssm_tokens(3, 32) == 96 and attn.ssm_tokens(3, 32) == 0
-    assert mixer.conv_tokens(3, 32) == 0
+    assert mixer.step_counts(3, 32, jnp.bfloat16) == {SSM_TOKENS_TOTAL: 96}
+    assert SSM_TOKENS_TOTAL not in attn.step_counts(3, 32, jnp.bfloat16)
     for block in (mixer, experts):
         assert block.attn_score_entries(3, 32, jnp.bfloat16) == (0, 0)
         assert block.remat_kept_bytes(3, 32, jnp.bfloat16) == {}

@@ -206,26 +206,16 @@ def _lfm2_core(grad, T=32768):
 
 
 def _ssd(part, T=16384):
-    """The Mamba-2 chunked scan of Nemotron-3-Nano-30B-A3B (``benchmark/
-    configs/nemotron-3-nano-30b-a3b-ep16.json``) at one group call of the
-    cell: one sequence of 16,384, 8 heads of 64 over one group's 128-wide
-    state, chunks of 128. ``fwd``: the forward kernel; ``grad``: under grad
-    of a loss that needs ``y``, the forward that also writes the state
-    entering each chunk, and the backward; ``block``: the whole mixer of a
-    block under grad, whose core from ``W_in``'s output to the normed ``y``
-    is one kernel each way (``ssd.mamba_core``): the forward that also
-    writes its residuals, and the backward; ``block-fwd``: the mixer's
-    forward alone, the core's forward kernel."""
+    """The Mamba-2 mixer of Nemotron-3-Nano-30B-A3B (``benchmark/configs/
+    nemotron-3-nano-30b-a3b-ep16.json``): one sequence of 16,384, 64 heads
+    of 64 over 8 groups' 128-wide state, chunks of 128, a group's 8 heads a
+    kernel's program. ``block``: the whole mixer of a block under grad,
+    whose core from ``W_in``'s output to the normed ``y`` is one kernel
+    each way (``ssd.mamba_core``): the forward that also writes its
+    residuals, and the backward; ``block-fwd``: the mixer's forward alone,
+    the core's forward kernel."""
     K, P, N, chunk = 8, 64, 128, 128
-    shapes = [((1, T, K, P), BF16), ((1, T, K), F32), ((K,), F32),
-              ((1, T, 1, N), BF16), ((1, T, 1, N), BF16), ((K,), F32)]
     assert ssd._vmem_bytes(chunk, K, P, N, BF16, True) <= pk._VMEM_BUDGET
-    if part == "fwd":
-        return (lambda *a: ssd.ssd_scan(*a, chunk)), shapes, 1
-    if part == "grad":
-        return (lambda *a: jax.grad(
-            lambda *b: jnp.sum(ssd.ssd_scan(*b, chunk) ** 2),
-            argnums=tuple(range(6)))(*a)), shapes, 2
     f, shapes = _mamba_block(T, grad=part == "block")
     return f, shapes, 2 if part == "block" else 1
 
@@ -374,8 +364,6 @@ CASES = {
     "indexer-kl-grad-T16384-bfloat16": (_indexer, ("kl", True)),
     "indexer-core-grad-T4096-bfloat16": (_indexer, ("core", True, 4096)),
     "indexer-block-grad-T16384-bfloat16": (_indexer, ("block",)),
-    "ssd-scan-fwd-T16384-bfloat16": (_ssd, ("fwd",)),
-    "ssd-scan-grad-T16384-bfloat16": (_ssd, ("grad",)),
     "ssd-block-grad-T16384-bfloat16": (_ssd, ("block",)),
     "ssd-block-fwd-T16384-bfloat16": (_ssd, ("block-fwd",)),
     "latent-fwd-T4096-bfloat16": (_latent, (False,)),
